@@ -256,14 +256,12 @@ def prefetch_reader(reader: Callable, feeder: Optional[Callable] = None,
 # ---------------------------------------------------------------- guard
 def jit_cache_size(fn) -> Optional[int]:
     """Number of compiled variants a jitted callable holds, or None when
-    the probe isn't available on this jax version."""
+    ``fn`` carries no cache probe (jax 0.9.0's ``jax.jit`` objects do:
+    the private ``_cache_size``)."""
     probe = getattr(fn, "_cache_size", None)
     if probe is None:
         return None
-    try:
-        return int(probe())
-    except Exception:  # noqa: BLE001 — a probe must never break training
-        return None
+    return int(probe())
 
 
 class RecompileError(RuntimeError):
@@ -299,12 +297,20 @@ class RecompileGuard:
     def count(self) -> Optional[int]:
         return jit_cache_size(self.fn)
 
-    def harden(self) -> Optional[int]:
+    def harden(self) -> int:
         """Freeze the current variant count as the complete set (serving
-        mode, post-warmup); returns it. On jax versions without the cache
-        probe the guard stays advisory (count None)."""
-        self.hard_baseline = self.count
-        return self.hard_baseline
+        mode, post-warmup); returns it. A callable without the cache
+        probe cannot be hardened: that is an error here, never a guard
+        that silently stays advisory."""
+        n = self.count
+        if n is None:
+            raise RuntimeError(
+                f"{self.name}: no jit-cache probe on {self.fn!r} — a "
+                "hardened RecompileGuard over it would never trip. This "
+                "jax no longer offers jit._cache_size; repair "
+                "data/prefetch.py:jit_cache_size before serving.")
+        self.hard_baseline = n
+        return n
 
     def check(self) -> Optional[int]:
         n = self.count

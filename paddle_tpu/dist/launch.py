@@ -80,18 +80,15 @@ def init_from_env() -> LaunchContext:
         master=os.environ.get("PADDLE_TPU_MASTER", ""))
     if os.environ.get("PADDLE_TPU_DISTRIBUTED") == "1":
         import jax
-        try:
-            # jaxlib >= 0.4.36 ships Gloo CPU collectives but does NOT
-            # select them by default — without this, any cross-process
-            # computation on the CPU backend dies with "Multiprocess
-            # computations aren't implemented on the CPU backend" (the
-            # local 2-process launcher test's failure mode). Set it
-            # unconditionally: it only affects the CPU backend (TPU/GPU
-            # jobs ignore it), and probing the platform here would
-            # initialize a backend BEFORE distributed.initialize.
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # older jaxlib: no knob,
-            pass                              # and no Gloo to select
+
+        # Gloo CPU collectives ship with jaxlib but are not selected by
+        # default — without this, any cross-process computation on the
+        # CPU backend dies with "Multiprocess computations aren't
+        # implemented on the CPU backend" (the local 2-process launcher
+        # test's failure mode). Set unconditionally: it only affects the
+        # CPU backend, and probing the platform here would initialize a
+        # backend BEFORE distributed.initialize.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=ctx.coordinator,
             num_processes=ctx.num_processes,

@@ -36,8 +36,13 @@ _FUSED_RNN = _env_flag("PADDLE_TPU_FUSED_RNN", False)
 _FUSED_OPT = _env_flag("PADDLE_TPU_FUSED_OPTIM", True)
 
 
-def rnn_cells_enabled() -> bool:
-    return _FUSED_RNN
+def rnn_cells_enabled(mesh=None) -> bool:
+    """Is the fused-RNN-cell switch on for a layer traced under
+    ``mesh``? Never inside a partitioned step: the cell kernels sit in
+    per-step scan bodies with no per-device wrapper, and a Mosaic
+    kernel cannot be partitioned (``ops/common.py:partitioned``)."""
+    from paddle_tpu.ops.common import partitioned
+    return _FUSED_RNN and not partitioned(mesh)
 
 
 def fused_optimizer_enabled() -> bool:

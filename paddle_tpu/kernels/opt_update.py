@@ -18,7 +18,15 @@ Contract (``docs/kernels.md``):
 - operands flatten and zero-pad to ``[rows x LANE]`` tiles via
   ``concatenate`` (CLAUDE.md bit-stability note); the padded region is
   a fixed point of both chains (all-zero in, all-zero out — Adam's
-  ``eps`` keeps the quotient finite), so the unpad slice is exact.
+  ``eps`` keeps the quotient finite), so the unpad slice is exact;
+- the grid walks ``BLOCK_ROWS``-row blocks, so VMEM use is bounded by
+  the block (Adam: 7 operands x 2 buffers x 512 KiB = 7 MiB) whatever
+  the parameter's size, and no size gate is needed — as one
+  whole-parameter block the kernel had to decline anything over its
+  VMEM gate (the headline LSTM's 15 MB embedding among them);
+- the parameter and every slot alias their outputs
+  (``input_output_aliases``), so the donated train step keeps its
+  in-place update through the custom call.
 
 Traced scalars (lr / Adam's bias-corrected alpha) ride SMEM ``(1, 1)``
 blocks; static hyper-parameters are kernel constants.
@@ -58,14 +66,32 @@ def _smem_scalar(v):
     return jnp.reshape(jnp.asarray(v, jnp.float32), (1, 1))
 
 
-def _specs(n_tiles, tile_shape, n_scalars):
+# rows of one grid block: 1024 x LANE x 4 B = 512 KiB per operand
+BLOCK_ROWS = 1024
+
+
+def _elementwise_call(kernel, tiles, scalars, aliases, n_out):
+    """One ``pallas_call`` over ``[R, LANE]`` tiles in ``BLOCK_ROWS``-row
+    blocks (the last block may be ragged: elementwise, so its
+    out-of-range rows are never written back). ``aliases`` maps tile
+    inputs onto the outputs they update in place."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    tile = pl.BlockSpec(tile_shape, lambda t: (0, 0),
-                        memory_space=pltpu.VMEM)
-    scalar = pl.BlockSpec((1, 1), lambda t: (0, 0),
+    rows = tiles[0].shape[0]
+    block = (min(rows, BLOCK_ROWS), common.LANE)
+    tile = pl.BlockSpec(block, lambda i: (i, 0), memory_space=pltpu.VMEM)
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0),
                           memory_space=pltpu.SMEM)
-    return [tile] * n_tiles + [scalar] * n_scalars, tile
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(rows, block[0]),),
+        in_specs=[tile] * len(tiles) + [scalar] * len(scalars),
+        out_specs=(tile,) * n_out,
+        out_shape=(jax.ShapeDtypeStruct(tiles[0].shape, jnp.float32),)
+        * n_out,
+        input_output_aliases=aliases,
+        interpret=common.interpret(),
+    )(*tiles, *scalars)
 
 
 def _eligible(arrays):
@@ -73,9 +99,7 @@ def _eligible(arrays):
     for a in arrays:
         if a.dtype != jnp.float32 or a.shape != shape:
             return False
-    rows = max(8, _ceil_to(-(-arrays[0].size // common.LANE), 8))
-    resident = (len(arrays) * 2) * rows * common.LANE * 4
-    return common.use_pallas(resident)
+    return common.use_pallas()
 
 
 # --------------------------------------------------------------- momentum
@@ -90,18 +114,11 @@ def _momentum_kernel(mu, p_ref, g_ref, m_ref, lr_ref, decay_ref,
 
 
 def _momentum_fused(p, g, m, lr, mu, decay):
-    from jax.experimental import pallas as pl
-    pp, gp, mp = _pad_flat(p), _pad_flat(g), _pad_flat(m)
-    in_specs, tile = _specs(3, pp.shape, 2)
-    p2, m2 = pl.pallas_call(
+    p2, m2 = _elementwise_call(
         functools.partial(_momentum_kernel, mu),
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=(tile, tile),
-        out_shape=(jax.ShapeDtypeStruct(pp.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(pp.shape, jnp.float32)),
-        interpret=common.interpret(),
-    )(pp, gp, mp, _smem_scalar(lr), _smem_scalar(decay))
+        (_pad_flat(p), _pad_flat(g), _pad_flat(m)),
+        (_smem_scalar(lr), _smem_scalar(decay)),
+        aliases={0: 0, 2: 1}, n_out=2)
     return _unpad_flat(p2, p), {"mom": _unpad_flat(m2, m)}
 
 
@@ -120,41 +137,41 @@ def _adam_kernel(b1, b2, eps, p_ref, g_ref, m_ref, v_ref, alpha_ref,
 
 
 def _adam_fused(p, g, m, v, lr, t, b1, b2, eps, decay):
-    from jax.experimental import pallas as pl
     tf = t.astype(jnp.float32)
     # the bias correction is scalar math — hoisted out of the kernel
     alpha = lr * jnp.sqrt(1 - jnp.power(b2, tf)) / (1 - jnp.power(b1, tf))
-    pp, gp, mp, vp = (_pad_flat(p), _pad_flat(g), _pad_flat(m),
-                      _pad_flat(v))
-    in_specs, tile = _specs(4, pp.shape, 2)
-    p2, m2, v2 = pl.pallas_call(
+    p2, m2, v2 = _elementwise_call(
         functools.partial(_adam_kernel, b1, b2, eps),
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=(tile, tile, tile),
-        out_shape=(jax.ShapeDtypeStruct(pp.shape, jnp.float32),) * 3,
-        interpret=common.interpret(),
-    )(pp, gp, mp, vp, _smem_scalar(alpha), _smem_scalar(decay))
+        (_pad_flat(p), _pad_flat(g), _pad_flat(m), _pad_flat(v)),
+        (_smem_scalar(alpha), _smem_scalar(decay)),
+        aliases={0: 0, 2: 1, 3: 2}, n_out=3)
     return _unpad_flat(p2, p), {"mom": _unpad_flat(m2, m),
                                 "v": _unpad_flat(v2, v)}
 
 
 # ---------------------------------------------------------------- routing
 
-def apply_one(opt, p, g, slots, lr, decay, t):
+def apply_one(opt, p, g, slots, lr, decay, t, partitioned=False):
     """Fused stand-in for ``opt._apply_one`` on the dense path. The slot
     dict may carry ``prune_mask`` (ignored here, re-attached by
-    ``_update_param``, matching ``_apply_one``'s contract)."""
+    ``_update_param``, matching ``_apply_one``'s contract).
+    ``partitioned`` operands (global arrays of a multi-device step)
+    always take ``_apply_one``: a Mosaic kernel cannot be partitioned,
+    so the fused update runs only where the update is already
+    per-device — one chip, or inside the ZeRO-1/FSDP shard_maps."""
     from paddle_tpu.kernels import dispatch
-    if not dispatch.fused_optimizer_enabled():
-        return opt._apply_one(p, g, slots, lr, decay, t)
     kind = type(opt).__name__
     keys = set(slots) - {"prune_mask"}
-    if (kind == "Momentum" and not getattr(opt, "nesterov", False)
+    fused = dispatch.fused_optimizer_enabled() and not partitioned
+    if (fused and kind == "Momentum"
+            and not getattr(opt, "nesterov", False)
             and keys == {"mom"} and _eligible((p, g, slots["mom"]))):
+        common.note("opt_update", "fused")
         return _momentum_fused(p, g, slots["mom"], lr, opt.momentum, decay)
-    if (kind == "Adam" and keys == {"mom", "v"}
+    if (fused and kind == "Adam" and keys == {"mom", "v"}
             and _eligible((p, g, slots["mom"], slots["v"]))):
+        common.note("opt_update", "fused")
         return _adam_fused(p, g, slots["mom"], slots["v"], lr, t,
                            opt.beta1, opt.beta2, opt.epsilon, decay)
+    common.note("opt_update", "apply_one")
     return opt._apply_one(p, g, slots, lr, decay, t)

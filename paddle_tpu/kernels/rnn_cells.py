@@ -77,14 +77,17 @@ def _lstm_ref_default(gates, c_prev, check_i, check_f, check_o):
 
 def _lstm_cell_kernel(gi_ref, gig_ref, gfg_ref, gog_ref, c_ref,
                       pI_ref, pF_ref, pO_ref, out_ref, state_ref):
-    c = c_ref[:]
-    i = jnp.tanh(gi_ref[:])
-    ig = jax.nn.sigmoid(gig_ref[:] + c * pI_ref[0])
-    fg = jax.nn.sigmoid(gfg_ref[:] + c * pF_ref[0])
+    # f32 math whatever the storage dtype (ops/lstm.py:_lstm_kernel)
+    f32 = jnp.float32
+    c = c_ref[:].astype(f32)
+    i = jnp.tanh(gi_ref[:].astype(f32))
+    ig = jax.nn.sigmoid(gig_ref[:].astype(f32) + c * pI_ref[0].astype(f32))
+    fg = jax.nn.sigmoid(gfg_ref[:].astype(f32) + c * pF_ref[0].astype(f32))
     state = i * ig + c * fg
-    og = jax.nn.sigmoid(gog_ref[:] + state * pO_ref[0])
-    state_ref[:] = state
-    out_ref[:] = og * jnp.tanh(state)
+    og = jax.nn.sigmoid(gog_ref[:].astype(f32)
+                        + state * pO_ref[0].astype(f32))
+    state_ref[:] = state.astype(state_ref.dtype)
+    out_ref[:] = (og * jnp.tanh(state)).astype(out_ref.dtype)
 
 
 def _lstm_pallas(gates, c_prev, check_i, check_f, check_o):
@@ -135,7 +138,9 @@ def _lstm_pallas_ok(gates, c_prev, checks, default_acts):
     Bp, Hp = _ceil_to(B, 8), _ceil_to(H, common.LANE)
     itemsize = jnp.dtype(c_prev.dtype).itemsize
     resident = (7 * Bp * Hp + 3 * Hp) * itemsize
-    return common.use_pallas(resident)
+    ok = common.use_pallas(resident)
+    common.note("lstm_cell", common.pallas_path() if ok else "ref")
+    return ok
 
 
 def lstm_cell(gates, c_prev, check_i, check_f, check_o,
@@ -170,20 +175,17 @@ def _gru_ref_default(x, h, w_gate, w_state):
 
 def _gru_cell_kernel(xz_ref, xr_ref, xc_ref, h_ref, wz_ref, wr_ref,
                      wc_ref, out_ref):
+    # f32 math whatever the storage dtype (ops/lstm.py:_lstm_kernel)
+    f32 = jnp.float32
     h = h_ref[:]
-    z = jax.nn.sigmoid(
-        xz_ref[:] + jnp.dot(h, wz_ref[:],
-                            preferred_element_type=jnp.float32
-                            ).astype(h.dtype))
-    r = jax.nn.sigmoid(
-        xr_ref[:] + jnp.dot(h, wr_ref[:],
-                            preferred_element_type=jnp.float32
-                            ).astype(h.dtype))
-    c = jnp.tanh(
-        xc_ref[:] + jnp.dot(r * h, wc_ref[:],
-                            preferred_element_type=jnp.float32
-                            ).astype(h.dtype))
-    out_ref[:] = h - z * h + z * c
+    hf = h.astype(f32)
+    z = jax.nn.sigmoid(xz_ref[:].astype(f32) + jnp.dot(
+        h, wz_ref[:], preferred_element_type=f32))
+    r = jax.nn.sigmoid(xr_ref[:].astype(f32) + jnp.dot(
+        h, wr_ref[:], preferred_element_type=f32))
+    c = jnp.tanh(xc_ref[:].astype(f32) + jnp.dot(
+        (r * hf).astype(h.dtype), wc_ref[:], preferred_element_type=f32))
+    out_ref[:] = (hf - z * hf + z * c).astype(out_ref.dtype)
 
 
 def _gru_pallas(x, h, w_gate, w_state):
@@ -230,7 +232,9 @@ def _gru_pallas_ok(x, h, default_acts):
     Bp, Hp = _ceil_to(B, 8), _ceil_to(H, common.LANE)
     itemsize = jnp.dtype(h.dtype).itemsize
     resident = (5 * Bp * Hp + 3 * Hp * Hp) * itemsize
-    return common.use_pallas(resident)
+    ok = common.use_pallas(resident)
+    common.note("gru_cell", common.pallas_path() if ok else "ref")
+    return ok
 
 
 def gru_cell(x, h, w_gate, w_state, act_input="tanh", act_gate="sigmoid"):

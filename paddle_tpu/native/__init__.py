@@ -6,31 +6,44 @@ dispatches, `paddle/utils/Queue.h`); this package is the TPU build's
 equivalent — see ``src/native.cc``. ``load_library()`` compiles the
 shared object on first use with the host toolchain (g++) and caches it
 next to the sources; ``available()`` reports whether the native path can
-be used (every consumer has a pure-Python fallback).
+be used (every consumer has a pure-Python fallback). The object is
+build output (git-ignored) named after its source's digest: file times
+mean nothing after a checkout or a copy, so "built from THIS source" is
+a name that exists, never an mtime compare.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "native.cc")
-_SO = os.path.join(_DIR, "libpaddle_tpu_native.so")
 
 _lock = threading.Lock()
 _lib = None
 _failed = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libpaddle_tpu_native.{digest}.so")
+
+
+def _build(so: str) -> bool:
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           "-o", _SO + ".tmp", _SRC]
+           "-o", so + ".tmp", _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        os.replace(_SO + ".tmp", _SO)
+        os.replace(so + ".tmp", so)
+        for old in glob.glob(os.path.join(_DIR, "libpaddle_tpu_native*.so")):
+            if old != so:  # objects of other source versions
+                os.remove(old)
         return True
     except (subprocess.SubprocessError, OSError) as e:
         import logging
@@ -45,12 +58,11 @@ def load_library():
     with _lock:
         if _lib is not None or _failed:
             return _lib
-        if not os.path.exists(_SO) or (os.path.getmtime(_SO)
-                                       < os.path.getmtime(_SRC)):
-            if not _build():
-                _failed = True
-                return None
-        lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            _failed = True
+            return None
+        lib = ctypes.CDLL(so)
         lib.ptr_writer_open.restype = ctypes.c_void_p
         lib.ptr_writer_open.argtypes = [ctypes.c_char_p]
         lib.ptr_writer_append.restype = ctypes.c_int

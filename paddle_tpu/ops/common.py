@@ -10,17 +10,26 @@ from __future__ import annotations
 
 import contextlib
 import os
-from typing import Optional
+from typing import Dict, List, Optional
 
 import jax
 
+FORCE_ENV = "PADDLE_TPU_KERNELS"
+
 # None = auto; "pallas" = force compiled; "interpret" = force interpreter;
 # "ref" = force pure-JAX reference implementation.
-_FORCED: Optional[str] = os.environ.get("PADDLE_TPU_KERNELS") or None
+_FORCED: Optional[str] = os.environ.get(FORCE_ENV) or None
 
-# VMEM budget used to decide whether a kernel's resident working set
-# (weights + a few time-step blocks) fits on-chip; conservative vs ~16MB.
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# VMEM budget a kernel's whole working set must fit, counted as Mosaic
+# allocates it: pipelined blocks twice (double-buffered), scratch and
+# in-kernel copies once. 14 MiB leaves an eighth of headroom under the
+# 16 MiB scoped-VMEM limit a TPU v5e compile runs with — the limit at
+# which the tiled LSTM was refused ("scoped allocation 17.84M, limit
+# 16.00M"; chip run, PERF.md). No kernel raises vmem_limit_bytes.
+VMEM_BUDGET_BYTES = 14 * 1024 * 1024
+
+# active record_dispatch() tallies; written at TRACE time only
+_RECORDERS: List[Dict[str, Dict[str, int]]] = []
 
 
 @contextlib.contextmanager
@@ -32,6 +41,13 @@ def force_mode(mode: Optional[str]):
         yield
     finally:
         _FORCED = prev
+
+
+def forced() -> Optional[str]:
+    """The forced dispatch mode (env ``PADDLE_TPU_KERNELS`` or an
+    enclosing ``force_mode``), None when dispatch follows the platform —
+    chip evidence (``chip_smoke.py``) refuses to run unless this is None."""
+    return _FORCED
 
 
 def mode() -> str:
@@ -50,8 +66,90 @@ def use_pallas(resident_bytes: int = 0) -> bool:
     return True
 
 
+@contextlib.contextmanager
+def record_dispatch():
+    """Tally which path every kernel entry takes while the scope is
+    open: ``{kernel: {path: count}}``, one count per TRACE of a dispatch
+    site (a cached jit re-uses its trace and records nothing). The shape
+    and budget gates stay silent dispatch; this is how a caller SEES
+    them (``chip_smoke.py``, ``tools/tpu_evidence.py``)."""
+    tally: Dict[str, Dict[str, int]] = {}
+    _RECORDERS.append(tally)
+    try:
+        yield tally
+    finally:
+        # by identity: list.remove compares dicts by VALUE and two empty
+        # nested tallies are equal
+        _RECORDERS[:] = [t for t in _RECORDERS if t is not tally]
+
+
+def note(kernel: str, path: str) -> str:
+    """Record one dispatch decision into every open tally; returns
+    ``path`` so a dispatch site can note and branch in one expression."""
+    for tally in _RECORDERS:
+        paths = tally.setdefault(kernel, {})
+        paths[path] = paths.get(path, 0) + 1
+    return path
+
+
+def pallas_path() -> str:
+    """Name of the non-reference path under the current mode."""
+    return "interpret" if interpret() else "pallas"
+
+
 def interpret() -> bool:
     return mode() == "interpret"
+
+
+# per-device batch ----------------------------------------------------------
+
+def partitioned(mesh) -> bool:
+    """Is a computation traced under ``mesh`` (outside any shard_map)
+    one that XLA will have to partition over several devices? A Mosaic
+    kernel cannot be: jax 0.9.0 refuses to lower it ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — four-chip run, PERF.md). So under such a mesh a kernel
+    either runs per device through ``batch_local`` or stands down to
+    its reference."""
+    return mesh is not None and mesh.size > 1
+
+
+def batch_split(mesh, B: int) -> int:
+    """How many ways the mesh's batch axes split a batch of ``B`` rows:
+    1 when nothing is partitioned, the data-parallel degree when it
+    divides ``B`` (the kernel then runs through ``batch_local``), and 0
+    when the mesh is partitioned but its batch axes cannot split ``B``
+    — the kernel entry must take its reference path."""
+    if not partitioned(mesh):
+        return 1
+    from paddle_tpu.parallel import mesh as mesh_lib  # lazy: import cycle
+    n = mesh_lib.data_parallel_degree(mesh)
+    return n if n > 1 and B % n == 0 else 0
+
+
+def batch_local(fn, mesh, split: int, in_dims, out_dims):
+    """``fn`` run by every device on its OWN batch rows: a ``shard_map``
+    over the mesh's batch axes — the only way a Mosaic kernel compiles
+    inside a partitioned step (see ``partitioned``); ``fn`` itself when
+    ``split`` (from ``batch_split``) is 1. ``in_dims`` and
+    ``out_dims`` give, per positional argument/result, the index of its
+    batch dimension, or None for an operand every device holds whole
+    (weights: their cotangents come back summed over the batch axes); a
+    bare ``out_dims`` is for a ``fn`` returning one array."""
+    if split <= 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel import mesh as mesh_lib  # lazy: import cycle
+    axes = mesh_lib.batch_axes(mesh)
+
+    def spec(d):
+        return P() if d is None else P(*([None] * d + [axes]))
+
+    return mesh_lib.shard_map_compat(
+        fn, mesh, in_specs=tuple(spec(d) for d in in_dims),
+        out_specs=(tuple(spec(d) for d in out_dims)
+                   if isinstance(out_dims, tuple) else spec(out_dims)))
 
 
 # shared kernel-layout vocabulary -------------------------------------------

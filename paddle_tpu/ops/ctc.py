@@ -189,18 +189,25 @@ _ctc_core.defvjp(_ctc_fwd, _ctc_bwd)
 
 # ---------------------------------------------------------------- public
 
-def ctc_ll(emit, in_mask, valid_s, can_skip, ext_lens):
+def ctc_ll(emit, in_mask, valid_s, can_skip, ext_lens, mesh=None):
     """Log-likelihood [B] of the CTC paths. Pallas on TPU (S padded to the
-    128-lane width by the caller or here), lax.scan elsewhere."""
+    128-lane width by the caller or here), lax.scan elsewhere. Under a
+    ``mesh`` whose batch axes divide B each device runs the kernel on
+    its own rows (``common.batch_local``)."""
     B, T, S = emit.shape
+    split = common.batch_split(mesh, B)
     Sp = ((S + LANE - 1) // LANE) * LANE
     itemsize = jnp.dtype(emit.dtype).itemsize
-    resident = itemsize * 6 * B * Sp
-    if not common.use_pallas(resident):
+    resident = itemsize * 6 * (B // max(split, 1)) * Sp
+    if split == 0 or not common.use_pallas(resident):
+        common.note("ctc", "ref")
         return ctc_ll_ref(emit, in_mask, valid_s, can_skip, ext_lens)
+    common.note("ctc", common.pallas_path())
     if Sp != S:
         pc = Sp - S
         emit = jnp.pad(emit, ((0, 0), (0, 0), (0, pc)), constant_values=NEG)
         valid_s = jnp.pad(valid_s, ((0, 0), (0, pc)))
         can_skip = jnp.pad(can_skip, ((0, 0), (0, pc)))
-    return _ctc_core(emit, in_mask, valid_s, can_skip, ext_lens)
+    core = common.batch_local(_ctc_core, mesh, split,
+                              in_dims=(0, 0, 0, 0, 0), out_dims=0)
+    return core(emit, in_mask, valid_s, can_skip, ext_lens)

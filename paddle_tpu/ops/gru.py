@@ -66,24 +66,27 @@ def _gru_kernel(with_residuals, xs_ref, mask_ref, wg_ref, ws_ref, h0_ref,
     def _():
         h_s[:] = h0_ref[:]
 
+    # gate math in f32 whatever the storage dtype (ops/lstm.py:
+    # _lstm_kernel); every cast is a no-op for f32 operands
+    f32 = jnp.float32
     h = h_s[:]
+    hf = h.astype(f32)
     H = h.shape[-1]
-    x = xs_ref[0]
-    zr = x[:, :2 * H] + jnp.dot(h, wg_ref[:],
-                                preferred_element_type=jnp.float32
-                                ).astype(h.dtype)
+    x = xs_ref[0].astype(f32)
+    zr = x[:, :2 * H] + jnp.dot(h, wg_ref[:], preferred_element_type=f32)
     z = jax.nn.sigmoid(zr[:, :H])
     r = jax.nn.sigmoid(zr[:, H:])
     c = jnp.tanh(x[:, 2 * H:] + jnp.dot(
-        r * h, ws_ref[:], preferred_element_type=jnp.float32).astype(h.dtype))
-    h_new = h - z * h + z * c
+        (r * hf).astype(h.dtype), ws_ref[:], preferred_element_type=f32))
+    h_new = hf - z * hf + z * c
     m = mask_ref[0]  # [B, 1] (mask fed as [T, B, 1] for tiling rules)
-    h_next = jnp.where(m > 0, h_new, h)
+    dt = ys_ref.dtype
+    h_next = jnp.where(m > 0, h_new, hf).astype(dt)
     h_s[:] = h_next
-    ys_ref[0] = h_new * m
+    ys_ref[0] = (h_new * m).astype(dt)
     if with_residuals:
         hs_ref[0] = h_next
-        gates_ref[0] = jnp.concatenate([z, r, c], axis=-1)
+        gates_ref[0] = jnp.concatenate([z, r, c], axis=-1).astype(dt)
     else:
         hT_ref[:] = h_next
 
@@ -138,14 +141,18 @@ def _bwd_rule(res, grads):
     T, B, H = hs.shape
     h_prev = jnp.concatenate([h0[None], hs[:-1]], axis=0)
 
+    dt = hs.dtype
+    f32 = jnp.float32
+
     def step(carry, inp):
         dh, dWg, dWs = carry
         dy_t, m_t, g_t, h_pv = inp
+        # f32 mask products cast back to the carry dtype (see ops/lstm.py)
         m = m_t[:, None]
         z = g_t[:, :H]
         r = g_t[:, H:2 * H]
         c = g_t[:, 2 * H:]
-        dh_new = m * (dh + dy_t)
+        dh_new = (m * (dh + dy_t)).astype(dt)
         dz = dh_new * (c - h_pv)
         da_c = (dh_new * z) * (1 - c * c)
         drh = da_c @ w_state.T
@@ -153,17 +160,19 @@ def _bwd_rule(res, grads):
         da_z = dz * z * (1 - z)
         da_r = dr * r * (1 - r)
         da_zr = jnp.concatenate([da_z, da_r], axis=-1)
-        dh_prev = ((1 - m) * dh + dh_new * (1 - z) + drh * r
+        dh_prev = (((1 - m) * dh).astype(dt) + dh_new * (1 - z) + drh * r
                    + da_zr @ w_gate.T)
-        dWg = dWg + h_pv.T @ da_zr
-        dWs = dWs + (r * h_pv).T @ da_c
+        dWg = dWg + jnp.dot(h_pv.T, da_zr, preferred_element_type=f32)
+        dWs = dWs + jnp.dot((r * h_pv).T, da_c, preferred_element_type=f32)
         dxs_t = jnp.concatenate([da_z, da_r, da_c], axis=-1)
         return (dh_prev, dWg, dWs), dxs_t
 
     (dh0, dWg, dWs), dxs = lax.scan(
-        step, (dhT, jnp.zeros_like(w_gate), jnp.zeros_like(w_state)),
+        step, (dhT, jnp.zeros(w_gate.shape, f32),
+               jnp.zeros(w_state.shape, f32)),
         (dys, mask, gates, h_prev), reverse=True)
-    return dxs, None, dWg, dWs, dh0
+    return (dxs, None, dWg.astype(w_gate.dtype),
+            dWs.astype(w_state.dtype), dh0)
 
 
 _gru_core.defvjp(_fwd_rule, _bwd_rule)
@@ -171,19 +180,29 @@ _gru_core.defvjp(_fwd_rule, _bwd_rule)
 
 # ---------------------------------------------------------------- public
 
-def gru_sequence(xs, mask, w_gate, w_state, bias, h0, reverse=False):
+def gru_sequence(xs, mask, w_gate, w_state, bias, h0, reverse=False,
+                 mesh=None):
     """Fused GRU over a padded [T,B,3H] gate-projection sequence.
     ``reverse=True`` runs back-to-front (outputs stay in input time order).
+    Under a ``mesh`` whose batch axes divide B each device runs the
+    kernel on its own rows (``common.batch_local``).
     Returns (ys [T,B,H], hT). Differentiable either way."""
     if reverse:
         ys, hT = gru_sequence(jnp.flip(xs, 0), jnp.flip(mask, 0), w_gate,
-                              w_state, bias, h0)
+                              w_state, bias, h0, mesh=mesh)
         return jnp.flip(ys, 0), hT
     T, B, H3 = xs.shape
     H = H3 // 3
+    split = common.batch_split(mesh, B)
+    Bl = B // max(split, 1)
     itemsize = jnp.dtype(xs.dtype).itemsize
-    resident = itemsize * (3 * H * H + 6 * B * H3)
-    if not common.use_pallas(resident):
+    # counted like the LSTM's (ops/lstm.py:_resident_bytes): constant-
+    # index weights once, per-step blocks twice, h scratch once, plus
+    # the lane-padded [B, 1] mask block
+    resident = (itemsize * (3 * H * H + 2 * 9 * Bl * H + Bl * H)
+                + 2 * 4 * Bl * common.LANE)
+    if split == 0 or not common.use_pallas(resident):
+        common.note("gru", "ref")
         # Big hidden sizes fall back to the scan reference. Unlike the
         # LSTM (ops/lstm.py:_lstm_pallas_tiled), a gate-column-tiled GRU
         # needs two phases per timestep (the candidate matmul consumes
@@ -191,4 +210,8 @@ def gru_sequence(xs, mask, w_gate, w_state, bias, h0, reverse=False):
         # benefit over XLA's scan is not established, and no BASELINE
         # benchmark shape exceeds the resident budget for GRU.
         return gru_sequence_ref(xs, mask, w_gate, w_state, bias, h0)
-    return _gru_core(xs + bias, mask, w_gate, w_state, h0)
+    common.note("gru", common.pallas_path())
+    core = common.batch_local(_gru_core, mesh, split,
+                              in_dims=(1, 1, None, None, 0),
+                              out_dims=(1, 0))
+    return core(xs + bias, mask, w_gate, w_state, h0)

@@ -83,33 +83,38 @@ def _lstm_kernel(with_residuals, xs_ref, mask_ref, w_ref, pI_ref, pF_ref,
         h_s[:] = h0_ref[:]
         c_s[:] = c0_ref[:]
 
+    # Gate math runs in f32 whatever the storage dtype: the MXU
+    # accumulates in f32 anyway, a v5e has no bf16 vector unit, and
+    # Mosaic's bf16 logistic fails its own verifier (chip run, PERF.md).
+    # Every cast below is a no-op for f32 operands.
+    f32 = jnp.float32
     h = h_s[:]
-    c = c_s[:]
+    c = c_s[:].astype(f32)
     H = c.shape[-1]
     m = mask_ref[0]  # [B, 1] (mask is fed as [T, B, 1] for tiling rules)
-    gates = xs_ref[0] + jnp.dot(h, w_ref[:],
-                                preferred_element_type=jnp.float32
-                                ).astype(h.dtype)
+    gates = xs_ref[0].astype(f32) + jnp.dot(h, w_ref[:],
+                                            preferred_element_type=f32)
     a_i = gates[:, :H]
     a_ig = gates[:, H:2 * H]
     a_fg = gates[:, 2 * H:3 * H]
     a_og = gates[:, 3 * H:]
     i = jnp.tanh(a_i)
-    ig = jax.nn.sigmoid(a_ig + c * pI_ref[0])
-    fg = jax.nn.sigmoid(a_fg + c * pF_ref[0])
+    ig = jax.nn.sigmoid(a_ig + c * pI_ref[0].astype(f32))
+    fg = jax.nn.sigmoid(a_fg + c * pF_ref[0].astype(f32))
     c_new = i * ig + c * fg
-    og = jax.nn.sigmoid(a_og + c_new * pO_ref[0])
+    og = jax.nn.sigmoid(a_og + c_new * pO_ref[0].astype(f32))
     h_new = og * jnp.tanh(c_new)
 
-    h_next = jnp.where(m > 0, h_new, h)
-    c_next = jnp.where(m > 0, c_new, c)
+    dt = ys_ref.dtype
+    h_next = jnp.where(m > 0, h_new, h.astype(f32)).astype(dt)
+    c_next = jnp.where(m > 0, c_new, c).astype(dt)
     h_s[:] = h_next
     c_s[:] = c_next
-    ys_ref[0] = h_new * m
+    ys_ref[0] = (h_new * m).astype(dt)
     if with_residuals:
         hs_ref[0] = h_next
         cs_ref[0] = c_next
-        gates_ref[0] = jnp.concatenate([i, ig, fg, og], axis=-1)
+        gates_ref[0] = jnp.concatenate([i, ig, fg, og], axis=-1).astype(dt)
     else:
         # final-state outputs use a constant index map; the last grid step's
         # write is what the caller sees
@@ -188,29 +193,31 @@ def _lstm_kernel_tiled(with_residuals, hb, xs_ref, mask_ref, w_ref, pI_ref,
     cols = pl.dslice(j * hb, hb)
     # every j block of this timestep must see the SAME h_{t-1}: h_s holds
     # the previous step all timestep long; new values buffer in hn_s and
-    # commit after the last block
+    # commit after the last block. Gate math in f32 (see _lstm_kernel).
+    f32 = jnp.float32
     h = h_s[:]                      # full [B, H] = h_{t-1}
-    c = c_s[:, cols]                # [B, hb]
+    c = c_s[:, cols].astype(f32)    # [B, hb]
     m = mask_ref[0]                 # [B, 1]
     B = h.shape[0]
     H = h.shape[1]
-    # w block [H, 4, hb] -> [H, 4*hb] (minor-axes merge, layout no-op)
+    # w block [H, 4, hb] -> [H, 4*hb]
     wb = w_ref[:].reshape(H, 4 * hb)
-    gates = (xs_ref[0].reshape(B, 4 * hb)
-             + jnp.dot(h, wb, preferred_element_type=jnp.float32
-                       ).astype(h.dtype)).reshape(B, 4, hb)
+    gates = (xs_ref[0].reshape(B, 4 * hb).astype(f32)
+             + jnp.dot(h, wb, preferred_element_type=f32)
+             ).reshape(B, 4, hb)
     a_i, a_ig, a_fg, a_og = (gates[:, 0], gates[:, 1], gates[:, 2],
                              gates[:, 3])
     i = jnp.tanh(a_i)
-    ig = jax.nn.sigmoid(a_ig + c * pI_ref[0])
-    fg = jax.nn.sigmoid(a_fg + c * pF_ref[0])
+    ig = jax.nn.sigmoid(a_ig + c * pI_ref[0].astype(f32))
+    fg = jax.nn.sigmoid(a_fg + c * pF_ref[0].astype(f32))
     c_new = i * ig + c * fg
-    og = jax.nn.sigmoid(a_og + c_new * pO_ref[0])
+    og = jax.nn.sigmoid(a_og + c_new * pO_ref[0].astype(f32))
     h_new = og * jnp.tanh(c_new)
 
-    h_prev = h_s[:, cols]
-    h_next = jnp.where(m > 0, h_new, h_prev)
-    c_next = jnp.where(m > 0, c_new, c)
+    dt = ys_ref.dtype
+    h_prev = h_s[:, cols].astype(f32)
+    h_next = jnp.where(m > 0, h_new, h_prev).astype(dt)
+    c_next = jnp.where(m > 0, c_new, c).astype(dt)
     hn_s[:, cols] = h_next
     c_s[:, cols] = c_next
 
@@ -218,11 +225,11 @@ def _lstm_kernel_tiled(with_residuals, hb, xs_ref, mask_ref, w_ref, pI_ref,
     def _():
         h_s[:] = hn_s[:]
 
-    ys_ref[0] = h_new * m
+    ys_ref[0] = (h_new * m).astype(dt)
     if with_residuals:
         hs_ref[0] = h_next
         cs_ref[0] = c_next
-        gates_ref[0] = jnp.stack([i, ig, fg, og], axis=1)
+        gates_ref[0] = jnp.stack([i, ig, fg, og], axis=1).astype(dt)
     else:
         hT_ref[:] = h_next
         cT_ref[:] = c_next
@@ -230,16 +237,21 @@ def _lstm_kernel_tiled(with_residuals, hb, xs_ref, mask_ref, w_ref, pI_ref,
 
 def _pick_hblock(H: int, B: int, itemsize: int) -> int:
     """Largest lane-aligned divisor of H whose per-block working set
-    (streamed weight block + step blocks + full state) fits the VMEM
-    budget; 0 if none."""
+    fits the VMEM budget; 0 if none. Counted as Mosaic allocates it
+    (chip run, PERF.md: H=1280 at hb=256 asked for 17.84 MiB against
+    the 16 MiB scoped limit): the streamed weight block three times
+    (two pipeline buffers plus the in-kernel [H,4,hb]->[H,4hb] copy),
+    every other block twice, the state scratch once."""
     for hb in (1024, 512, 256, 128):
         if H % hb:
             continue
         resident = itemsize * (
-            H * 4 * hb        # weight block
-            + 6 * B * 4 * hb  # xs/gates/ys blocks (double-buffered)
-            + 3 * B * H       # h (prev + commit buffer) / c scratch
-            + 4 * B * hb)     # residual blocks
+            3 * H * 4 * hb        # weight block
+            + 2 * 2 * B * 4 * hb  # xs in / activated gates out
+            + 2 * 3 * B * hb      # ys, hs, cs out
+            + 2 * 2 * B * H       # h0, c0 in
+            + 3 * B * H           # h (prev + commit buffer) / c scratch
+        ) + 2 * 4 * B * common.LANE   # lane-padded [B, 1] mask block
         if resident <= common.VMEM_BUDGET_BYTES:
             return hb
     return 0
@@ -363,37 +375,46 @@ def _bwd_rule(res, grads):
     h_prev = jnp.concatenate([h0[None], hs[:-1]], axis=0)
     c_prev = jnp.concatenate([c0[None], cs[:-1]], axis=0)
 
+    dt = hs.dtype
+    f32 = jnp.float32
+
     def step(carry, inp):
         dh, dc, dW, dpI, dpF, dpO = carry
         dy_t, m_t, g_t, c_new, c_pv, h_pv = inp
+        # the mask stays f32 (count data, never cast); under a bf16
+        # compute dtype its products promote, so each is cast back to
+        # the carry dtype — a no-op in f32
         m = m_t[:, None]
         i = g_t[:, :H]
         ig = g_t[:, H:2 * H]
         fg = g_t[:, 2 * H:3 * H]
         og = g_t[:, 3 * H:]
-        dh_new = m * (dh + dy_t)
-        dc_new = m * dc
+        dh_new = (m * (dh + dy_t)).astype(dt)
+        dc_new = (m * dc).astype(dt)
         tc = jnp.tanh(c_new)
         da_og = (dh_new * tc) * og * (1 - og)
         dc_tot = dc_new + dh_new * og * (1 - tc * tc) + da_og * pO
         da_i = dc_tot * ig * (1 - i * i)
         da_ig = (dc_tot * i) * ig * (1 - ig)
         da_fg = (dc_tot * c_pv) * fg * (1 - fg)
-        dc_prev = (1 - m) * dc + dc_tot * fg + da_ig * pI + da_fg * pF
+        dc_prev = (((1 - m) * dc).astype(dt) + dc_tot * fg + da_ig * pI
+                   + da_fg * pF)
         dgates = jnp.concatenate([da_i, da_ig, da_fg, da_og], axis=-1)
-        dh_prev = (1 - m) * dh + dgates @ w.T
-        dW = dW + h_pv.T @ dgates
-        dpI = dpI + jnp.sum(da_ig * c_pv, axis=0)
-        dpF = dpF + jnp.sum(da_fg * c_pv, axis=0)
-        dpO = dpO + jnp.sum(da_og * c_new, axis=0)
+        dh_prev = ((1 - m) * dh).astype(dt) + dgates @ w.T
+        # weight/peephole gradients accumulate over T steps in f32
+        dW = dW + jnp.dot(h_pv.T, dgates, preferred_element_type=f32)
+        dpI = dpI + jnp.sum((da_ig * c_pv).astype(f32), axis=0)
+        dpF = dpF + jnp.sum((da_fg * c_pv).astype(f32), axis=0)
+        dpO = dpO + jnp.sum((da_og * c_new).astype(f32), axis=0)
         return (dh_prev, dc_prev, dW, dpI, dpF, dpO), dgates
 
-    zW = jnp.zeros_like(w)
-    zH = jnp.zeros_like(pI)
+    zW = jnp.zeros(w.shape, f32)
+    zH = jnp.zeros(pI.shape, f32)
     (dh0, dc0, dW, dpI, dpF, dpO), dxs = lax.scan(
         step, (dhT, dcT, zW, zH, zH, zH),
         (dys, mask, gates, cs, c_prev, h_prev), reverse=True)
-    return dxs, None, dW, dpI, dpF, dpO, dh0, dc0
+    return (dxs, None, dW.astype(w.dtype), dpI.astype(pI.dtype),
+            dpF.astype(pF.dtype), dpO.astype(pO.dtype), dh0, dc0)
 
 
 _lstm_core.defvjp(_fwd_rule, _bwd_rule)
@@ -402,6 +423,20 @@ _lstm_core_tiled.defvjp(_fwd_rule_tiled, _bwd_rule)
 
 # ---------------------------------------------------------------- public
 
+def _resident_bytes(B: int, H: int, itemsize: int) -> int:
+    """VMEM the resident kernel's training spelling holds, calibrated on
+    the chip (PERF.md): the weight ONCE — its block index never changes
+    and (64, 640) compiles, which two 6.5 MB copies plus the step blocks
+    could not under the 16 MiB limit — every per-step block twice
+    (double-buffered), the h/c scratch once. The [B, 1] mask block pads
+    to a full lane tile."""
+    step_blocks = (2 * B * 4 * H    # xs in, activated gates out
+                   + 5 * B * H      # h0, c0 in; ys, hs, cs out
+                   + 3 * 8 * H)     # peepholes (one sublane tile each)
+    return (itemsize * (H * 4 * H + 2 * step_blocks + 2 * B * H)
+            + 2 * 4 * B * common.LANE)
+
+
 def lstm_dispatch(B: int, H: int, itemsize: int = 4) -> str:
     """Which implementation these shapes take: "resident" (weight stays
     in VMEM all T steps), "tiled" (big hidden sizes stream gate-column
@@ -409,8 +444,7 @@ def lstm_dispatch(B: int, H: int, itemsize: int = 4) -> str:
     can pin the benchmark shapes to their intended path."""
     if common.mode() == "ref":
         return "ref"
-    resident = itemsize * (H * 4 * H + 6 * B * 4 * H + 4 * B * H)
-    if resident <= common.VMEM_BUDGET_BYTES:
+    if _resident_bytes(B, H, itemsize) <= common.VMEM_BUDGET_BYTES:
         return "resident"
     if H % 128 == 0 and _pick_hblock(H, B, itemsize):
         return "tiled"
@@ -430,27 +464,34 @@ def kernel_dispatch_table():
 
 
 def lstm_sequence(xs, mask, w, gate_bias, check_i, check_f, check_o, h0, c0,
-                  reverse=False):
+                  reverse=False, mesh=None):
     """Fused LSTM over a padded [T,B,4H] gate-projection sequence.
 
     Dispatch (``lstm_dispatch``): the resident Pallas kernel when the
     recurrent weight fits VMEM for all T steps, the tiled Pallas kernel
     (weight streamed in gate-column blocks) for big hidden sizes, else
     the lax.scan reference. ``reverse=True`` runs the recurrence
-    back-to-front (outputs stay in input time order). Returns
+    back-to-front (outputs stay in input time order). Under a ``mesh``
+    whose batch axes divide B, each device runs the kernel on its own
+    rows (``common.batch_local``) and dispatch sees the per-device
+    batch; under one that cannot split B the reference runs. Returns
     (ys [T,B,H], hT, cT). Differentiable on every path.
     """
     if reverse:
         ys, hT, cT = lstm_sequence(jnp.flip(xs, 0), jnp.flip(mask, 0), w,
                                    gate_bias, check_i, check_f, check_o,
-                                   h0, c0)
+                                   h0, c0, mesh=mesh)
         return jnp.flip(ys, 0), hT, cT
     T, B, H4 = xs.shape
     H = H4 // 4
-    path = lstm_dispatch(B, H, jnp.dtype(xs.dtype).itemsize)
+    split = common.batch_split(mesh, B)
+    path = common.note("lstm", lstm_dispatch(
+        B // split, H, jnp.dtype(xs.dtype).itemsize) if split else "ref")
     if path == "ref":
         return lstm_sequence_ref(xs, mask, w, gate_bias, check_i, check_f,
                                  check_o, h0, c0)
     xs_b = xs + gate_bias  # fold bias into the pre-projected input once
-    core = _lstm_core if path == "resident" else _lstm_core_tiled
+    core = common.batch_local(
+        _lstm_core if path == "resident" else _lstm_core_tiled, mesh, split,
+        in_dims=(1, 1, None, None, None, None, 0, 0), out_dims=(1, 0, 0))
     return core(xs_b, mask, w, check_i, check_f, check_o, h0, c0)

@@ -171,19 +171,11 @@ def create_multislice_mesh(n_slices: Optional[int] = None,
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs,
                      check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: newer jax exports it
-    top-level with ``check_vma``; older jax has
-    ``jax.experimental.shard_map`` with the same knob named
-    ``check_rep``. One spelling for every shard_map consumer
-    (pipeline/moe/ring)."""
-    try:
-        from jax import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
+    """``jax.shard_map`` with this repo's default (``check_vma`` off):
+    one spelling for every shard_map consumer (pipeline/moe/ring/
+    zero1)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def batch_axes(mesh: Mesh):

@@ -31,7 +31,7 @@ Key discipline (one file per executable)::
   "generate") for which warmed bucket (e.g. ``b4_t32``, plus the pinned
   ``kK_lL`` pair for the search).
 - The jax / jaxlib / XLA backend fingerprint is recorded INSIDE the
-  entry, not in the filename: a cache written by an older jax resolves
+  entry, not in the filename: a cache written by another jax resolves
   to the same path, is detected as stale at load, warned about, and
   overwritten by the fresh compile — so upgrades self-heal instead of
   leaking orphaned files per version.
@@ -194,6 +194,7 @@ class AOTCache:
             entry = pickle.loads(payload)
             env, blob = entry["env"], entry["exe"]
             in_tree, out_tree = entry["in_tree"], entry["out_tree"]
+            device_ids = entry["devices"]
         except Exception as e:  # noqa: BLE001 — any unpickle failure
             self._quarantine(path, f"unpicklable: {e!r}")
             return None
@@ -207,8 +208,15 @@ class AOTCache:
                 "compile will overwrite it)", path, env, env_fingerprint())
             return None
         try:
+            import jax
             from jax.experimental import serialize_executable as se
-            compiled = se.deserialize_and_load(blob, in_tree, out_tree)
+            # load onto the devices it was compiled for: jax 0.9.0
+            # otherwise spreads it over EVERY device of the backend and
+            # a one-device program then demands one shard per device
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = se.deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
             if verify_args is not None:
                 compiled(*verify_args)  # trust only an exe that runs
         except Exception as e:  # noqa: BLE001 — deserialize/exec failure
@@ -228,8 +236,10 @@ class AOTCache:
             from jax.experimental import serialize_executable as se
             blob, in_tree, out_tree = se.serialize(compiled)
             buf = io.BytesIO()
+            devices = compiled.runtime_executable().local_devices()
             pickle.dump({"env": env_fingerprint(), "exe": blob,
-                         "in_tree": in_tree, "out_tree": out_tree},
+                         "in_tree": in_tree, "out_tree": out_tree,
+                         "devices": [d.id for d in devices]},
                         buf, protocol=pickle.HIGHEST_PROTOCOL)
             payload = buf.getvalue()
             # unique tmp per writer: replicas of a fleet share one

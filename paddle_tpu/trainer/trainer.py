@@ -646,7 +646,7 @@ class SGD:
                    else outputs[cost_name].value.shape[0])
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
-                num_passes=num_passes)
+                num_passes=num_passes, mesh=self.mesh)
             new_params.update(updates)
             health = self._health_metrics(
                 loss, params, grads, new_params, new_opt, num_passes,
@@ -728,7 +728,7 @@ class SGD:
                    else outputs[cost_name].value.shape[0])
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
-                num_passes=num_passes)
+                num_passes=num_passes, mesh=self.mesh)
             new_params.update(updates)  # moving statistics (batch_norm)
             health = self._health_metrics(
                 loss, params, grads, new_params, new_opt, num_passes,
@@ -831,7 +831,7 @@ class SGD:
             bsz = total_live if total_live is not None else full_bsz
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
-                num_passes=num_passes)
+                num_passes=num_passes, mesh=self.mesh)
             new_params.update(updates)
             act_table = None
             if with_stats and acts_k.shape[1] > 0:
@@ -1847,8 +1847,8 @@ class SGD:
                                             feed, step_rng,
                                             jnp.int32(pass_id),
                                             self._carried)
-                            # a real host fetch: on remote devices
-                            # block_until_ready returns before execution finishes
+                            # the host fetch waits for the step, so the
+                            # "compute" bracket closes on finished work
                             cost = float(metrics["cost"])
                         (self.stats_recompile_guard if stats_on
                          else self.recompile_guard).check()

@@ -38,16 +38,14 @@ class SGD(_SGD):
             from paddle_tpu.parallel import create_mesh
             want = int(flags["trainer_count"])
             have = len(_jax.devices())
-            n = min(want, have)
-            if n < want:
-                from paddle_tpu.utils.log import logger
-                logger.warning(
-                    "trainer_count=%d but only %d devices visible; "
-                    "using %d-way data parallelism", want, have, n)
-            if n > 1:
-                kwargs["mesh"] = create_mesh(
-                    n_data=n, devices=_jax.devices()[:n])
-                self._mesh_from_flags = True
+            if want > have:
+                raise ValueError(
+                    f"paddle.init(trainer_count={want}) but this process "
+                    f"has {have} device(s) — refusing to train narrower "
+                    "than asked")
+            kwargs["mesh"] = create_mesh(
+                n_data=want, devices=_jax.devices()[:want])
+            self._mesh_from_flags = True
         super().__init__(cost, parameters=parameters,
                          update_equation=update_equation, **kwargs)
 

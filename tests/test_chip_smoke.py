@@ -1,0 +1,72 @@
+"""``chip_smoke.py`` kept alive between chip runs: the same phases at a
+tiny width on the CPU with the kernels interpreted, its refusal to run
+anywhere but on a TPU, and where the compile cache goes."""
+
+import os
+
+import pytest
+
+import chip_smoke
+from paddle_tpu.ops import common
+from paddle_tpu.utils import runtime
+
+# hidden stays a lane multiple so the tiny model takes the same resident
+# kernel the full width does
+TINY = chip_smoke.Width(vocab=200, embed=16, hidden=128, layers=2, batch=8,
+                        seqlen=12, batches=2, passes=4, pool=8)
+
+
+def test_phases_run_tiny_with_interpreted_kernels():
+    with common.force_mode("interpret"):
+        trained = chip_smoke.train_phase(TINY, expect_mosaic=False)
+        assert trained["dispatch_tally"]["lstm"] == {"resident": 2}
+        assert trained["dispatch_tally"]["opt_update"]["fused"] >= 1
+        assert trained["loss_last_pass"] < trained["loss_first_pass"]
+        served = chip_smoke.serve_phase(TINY, trained)
+        assert served["repeat_byte_equal"] and served["fatal"] is None
+        assert {"b1_t12", "b4_t12"} <= set(served["bucket_hits"])
+        meshed = chip_smoke.mesh_phase(TINY, trained, expect_mosaic=False)
+        assert meshed["mesh"]["data"] == 4 and meshed["all_reduce_in_hlo"]
+        # on the mesh the LSTM kernels run per device (batch_local) and
+        # the plain optimizer's fused update stands down: XLA cannot
+        # partition a Mosaic kernel
+        assert meshed["dispatch_tally"]["lstm"] == {"resident": 2}
+        assert set(meshed["dispatch_tally"]["opt_update"]) == {"apply_one"}
+
+
+def test_real_entry_refuses_a_non_tpu_backend(capsys):
+    with pytest.raises(SystemExit) as exc:
+        chip_smoke.main()
+    assert exc.value.code not in (0, None)
+    assert "needs a TPU" in str(exc.value)
+    assert capsys.readouterr().out == ""  # no result line
+
+
+def test_real_entry_refuses_a_forced_kernel_path(capsys):
+    with common.force_mode("interpret"), pytest.raises(SystemExit) as exc:
+        chip_smoke.main()
+    assert common.FORCE_ENV in str(exc.value)
+    assert capsys.readouterr().out == ""
+
+
+def test_mosaic_call_parser_reads_operand_shapes():
+    # the line shape is the chip's (jax 0.9.0 / libtpu 0.0.34)
+    hlo = ('  %c.1 = (f32[100,16,256]{2,1,0:T(8,128)}, f32[16,256]{1,0}) '
+           'custom-call(%reshape.2, %copy-done), '
+           'custom_call_target="tpu_custom_call", '
+           'operand_layout_constraints={f32[100,16,1024]{2,1,0}, '
+           'f32[256,1024]{1,0}}, frontend_attributes={kernel_metadata={}}\n'
+           '  %other = f32[4] add(f32[4] %x, f32[4] %y)\n')
+    n, shapes = chip_smoke._mosaic_calls(hlo)
+    assert n == 1
+    assert shapes == [["f32[100,16,1024]", "f32[256,1024]"]]
+
+
+def test_compile_cache_dir_follows_env_else_fixed_checkout_path(monkeypatch):
+    monkeypatch.delenv(runtime.CACHE_ENV, raising=False)
+    path, from_env = runtime.compile_cache_dir()
+    checkout = os.path.dirname(os.path.abspath(chip_smoke.__file__))
+    assert (path, from_env) == (os.path.join(checkout, ".jax_cache"), False)
+    assert runtime.compile_cache_dir() == (path, False)  # nothing moves
+    monkeypatch.setenv(runtime.CACHE_ENV, "/somewhere/else")
+    assert runtime.compile_cache_dir() == ("/somewhere/else", True)

@@ -197,10 +197,12 @@ def test_pass5_mem_audit_clean_and_budget_pins_all_programs(
         serving.resident_bytes
     # the quantization win is a committed artifact too: the int8
     # scorer's pinned param residency beats its fp32 twin by >= 3x,
-    # and the matching temp bytes prove the dequant stayed fused
+    # and the temp bytes prove the dequant stayed fused: an f32 twin of
+    # the model would add the fp32 param bytes to them (under jax 0.9.0
+    # the two programs' temps are 64 B apart, no longer equal)
     quant = by_name["serving_quant"]
     assert quant.param_bytes * 3 <= serving.param_bytes
-    assert quant.temp_bytes == serving.temp_bytes
+    assert quant.temp_bytes - serving.temp_bytes < serving.param_bytes
 
 
 def test_pass2_jaxpr_audit_entry():

@@ -319,12 +319,74 @@ def test_ctc_ref_analytic_grad_matches_autodiff():
                                rtol=2e-4, atol=2e-5)
 
 
+# ------------------------------------------- kernels under a mesh
+def test_kernels_run_per_device_or_stand_down_under_a_mesh():
+    """XLA cannot partition a Mosaic kernel (jax 0.9.0 refuses to lower
+    it inside a multi-device step). Under a mesh whose batch axes divide
+    the batch the kernel runs per device through ``batch_local`` —
+    dispatch sees the per-device batch, values and gradients match the
+    reference; under one that cannot split it the reference runs."""
+    from paddle_tpu.ops import common
+    from paddle_tpu.ops.lstm import lstm_sequence
+    from paddle_tpu.parallel import create_mesh
+    mesh = create_mesh(n_data=4, devices=jax.devices()[:4])
+    assert common.partitioned(mesh) and not common.partitioned(None)
+    assert common.batch_split(mesh, 16) == 4
+    assert common.batch_split(mesh, 6) == 0     # must take the reference
+    assert common.batch_split(None, 6) == 1
+    rng = np.random.RandomState(0)
+    T, B, H = 3, 16, 128
+    xs = jnp.asarray(rng.randn(T, B, 4 * H).astype(np.float32) * 0.3)
+    mask = jnp.ones((T, B), jnp.float32).at[2:, 5].set(0.0)
+    w = jnp.asarray(rng.randn(H, 4 * H).astype(np.float32) * 0.1)
+    zb, zc = jnp.zeros((4 * H,)), jnp.zeros((H,))
+    h0 = jnp.zeros((B, H))
+
+    def loss(x, w_, mesh=None):
+        return jnp.sum(lstm_sequence(x, mask, w_, zb, zc, zc, zc, h0, h0,
+                                     mesh=mesh)[0] ** 2)
+
+    with common.force_mode("ref"):
+        want = jax.grad(loss, argnums=(0, 1))(xs, w)
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        got = jax.jit(jax.grad(lambda x, w_: loss(x, w_, mesh),
+                               argnums=(0, 1)))(xs, w)
+        # a batch the four-way axis cannot split: the reference runs
+        lstm_sequence(xs[:, :6], mask[:, :6], w, zb, zc, zc, zc,
+                      h0[:6], h0[:6], mesh=mesh)
+    assert tally == {"lstm": {"resident": 1, "ref": 1}}
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_fused_optimizer_stands_down_on_partitioned_operands():
+    from paddle_tpu.kernels import opt_update
+    from paddle_tpu.ops import common
+    from paddle_tpu.optim import Adam
+    opt = Adam(learning_rate=1e-3)
+    p = jnp.ones((8, 128)); g = jnp.full((8, 128), 0.5)
+    slots = {"mom": jnp.zeros((8, 128)), "v": jnp.zeros((8, 128))}
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        a = opt_update.apply_one(opt, p, g, slots, 0.01, 0.0, jnp.int32(1))
+        b = opt_update.apply_one(opt, p, g, slots, 0.01, 0.0, jnp.int32(1),
+                                 partitioned=True)
+    assert tally == {"opt_update": {"fused": 1, "apply_one": 1}}
+    np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b[0]),
+                               rtol=1e-6)
+
+
 # ------------------------------------------------- tiled-H LSTM (big H)
 def test_lstm_dispatch_pins_bench_shapes():
     """The benchmark shapes must take their intended kernel path
     (VERDICT r3 weak #5: the h=1280 BASELINE row silently lost the fused
     kernel). h=256 (headline bench) -> resident; h=1280 -> tiled, NOT
-    the scan fallback."""
+    the scan fallback — except where the chip refused: the working set
+    is counted as Mosaic allocates it (double-buffered blocks) against
+    the 16 MiB scoped-VMEM limit of a v5e, and the two widest batches
+    do not fit (PERF.md, "State of the chip path")."""
     from paddle_tpu.ops import common
     from paddle_tpu.ops.lstm import lstm_dispatch
     with common.force_mode("pallas"):
@@ -335,8 +397,19 @@ def test_lstm_dispatch_pins_bench_shapes():
         assert lstm_dispatch(128, 256) == "resident"
         assert lstm_dispatch(128, 1280) == "tiled"
         assert lstm_dispatch(256, 256) == "resident"
-        assert lstm_dispatch(256, 1280) == "tiled"
-        assert lstm_dispatch(512, 512) == "tiled"  # 4-GPU table row
+        # narrowed by the chip run: h0/c0 blocks + state scratch alone
+        # are ~9 MiB at B=256, H=1280; with the smallest weight block
+        # the kernel needs ~19 MiB
+        assert lstm_dispatch(256, 1280) == "ref"
+        assert lstm_dispatch(512, 512) == "ref"  # 4-GPU table row, ~16 MiB
+        # the largest resident shape the chip has compiled; one more
+        # lane tile of hidden width streams
+        assert lstm_dispatch(64, 640) == "resident"
+        assert lstm_dispatch(64, 768) == "tiled"
+        # H % 128 != 0 compiles on the chip (TPU_EVIDENCE.json): no
+        # alignment gate on the resident path
+        assert lstm_dispatch(8, 16) == "resident"
+        assert lstm_dispatch(8, 200) == "resident"
     with common.force_mode("ref"):
         assert lstm_dispatch(64, 256) == "ref"
 
@@ -344,15 +417,38 @@ def test_lstm_dispatch_pins_bench_shapes():
 def test_dispatch_table_matches_pins():
     """bench.py embeds ``kernel_dispatch_table()`` in its output so perf
     claims and dispatch can't drift apart (VERDICT r04 item #8); the
-    table must agree with the pins above."""
+    table must agree with the pins above — narrowing included."""
     from paddle_tpu.ops import common
     from paddle_tpu.ops.lstm import kernel_dispatch_table
     with common.force_mode("pallas"):
         table = kernel_dispatch_table()
-    assert table["lstm_bs64_h256"] == "resident"
-    assert table["lstm_bs64_h512"] == "resident"
-    assert table["lstm_bs512_h512"] == "tiled"
-    assert all(v in ("resident", "tiled") for v in table.values()), table
+    assert table == {
+        "lstm_bs64_h256": "resident", "lstm_bs64_h512": "resident",
+        "lstm_bs64_h1280": "tiled", "lstm_bs128_h256": "resident",
+        "lstm_bs128_h1280": "tiled", "lstm_bs256_h256": "resident",
+        "lstm_bs256_h1280": "ref", "lstm_bs512_h512": "ref"}
+
+
+def test_record_dispatch_shows_the_silent_gates():
+    """Shape and budget gates stay silent dispatch; ``record_dispatch``
+    is how a caller sees which path each kernel entry took."""
+    from paddle_tpu.ops import common
+    from paddle_tpu.ops.lstm import lstm_sequence
+    T, B, H = 2, 8, 128
+    xs = jnp.zeros((T, B, 4 * H), jnp.float32)
+    mask = jnp.ones((T, B), jnp.float32)
+    w = jnp.zeros((H, 4 * H), jnp.float32)
+    zb, zc = jnp.zeros((4 * H,)), jnp.zeros((H,))
+    h0 = jnp.zeros((B, H))
+    with common.record_dispatch() as outer:
+        with common.force_mode("interpret"), \
+                common.record_dispatch() as inner:
+            lstm_sequence(xs, mask, w, zb, zc, zc, zc, h0, h0)
+        with common.force_mode("ref"):
+            lstm_sequence(xs, mask, w, zb, zc, zc, zc, h0, h0)
+    assert inner == {"lstm": {"resident": 1}}
+    assert outer == {"lstm": {"resident": 1, "ref": 1}}
+    assert common.forced() is None
 
 
 def test_lstm_tiled_matches_ref_fwd_bwd():
@@ -364,7 +460,7 @@ def test_lstm_tiled_matches_ref_fwd_bwd():
                                      lstm_sequence_ref)
     rng = np.random.RandomState(0)
     T, B, H = 3, 8, 1280
-    assert _pick_hblock(H, B, 4) == 256  # streams 5 column blocks
+    assert _pick_hblock(H, B, 4) == 128  # streams 10 column blocks
     xs = jnp.asarray(rng.randn(T, B, 4 * H).astype(np.float32) * 0.1)
     mask = np.ones((T, B), np.float32)
     mask[1:, -2:] = 0.0  # ragged tail

@@ -315,15 +315,20 @@ def test_unbucketed_ragged_corpus_thrashes_and_guard_warns(caplog):
 
 def test_jit_cache_probe_counts_variants():
     f = jax.jit(lambda x: x * 2)
-    assert jit_cache_size(f) in (0, None)
+    assert jit_cache_size(f) == 0  # the probe exists on this jax
     f(jnp.ones((2,)))
     f(jnp.ones((3,)))
     assert jit_cache_size(f) == 2
     g = RecompileGuard(f, warn_after=1, name="probe")
     assert g.check() == 2
     assert g.warned
-    # no-probe objects disable the guard instead of breaking training
-    assert jit_cache_size(object()) is None
+    assert g.harden() == 2
+    # a callable without the probe leaves the advisory check quiet, but
+    # it cannot be HARDENED: that guard would never trip
+    blind = RecompileGuard(object(), name="blind")
+    assert jit_cache_size(object()) is None and blind.check() is None
+    with pytest.raises(RuntimeError, match="no jit-cache probe"):
+        blind.harden()
 
 
 # ----------------------------------------------------- trainer integration

@@ -72,7 +72,6 @@ def worker_main(args) -> int:
     os.environ.setdefault("XLA_FLAGS", "")
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
     import jax.numpy as jnp
 
