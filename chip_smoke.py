@@ -50,9 +50,12 @@ SEED = 20260926
 # allowed |loss difference| per step between the mesh and one-chip runs
 # (same data, same seed; f32 reduction order differs across devices)
 MESH_LOSS_TOL = 2e-2
-# served softmax vs the scan-reference forward (the chip's default f32
-# matmul precision differs between Mosaic and XLA; tools/tpu_evidence.py
-# holds the kernels to the same bound)
+# served softmax vs the scan-reference forward at batch 1. The kernel
+# multiplies at the chip's default f32 matmul precision; XLA computes
+# the reference's matrix-vector product at full f32, so the two differ
+# there (TPU_EVIDENCE.json "precision", PERF.md) and agree bitwise from
+# batch 2 up. A smoke check of the serving stack, not of kernel
+# precision: that evidence is tools/tpu_evidence.py's.
 REFERENCE_TOL = 2e-2
 
 
@@ -163,7 +166,8 @@ def train_phase(w: Width, *, mesh=None, expect_mosaic: bool = True,
     compile_s = time.perf_counter() - t0
     hlo = compiled.as_text()
     n_mosaic, operand_shapes = _mosaic_calls(hlo)
-    split = common.batch_split(mesh, w.batch)
+    with common.step_mesh(mesh):
+        split = common.batch_split(w.batch)
     local_batch = w.batch // max(split, 1)
     report = {
         "mesh": dict(mesh.shape) if mesh is not None else None,
@@ -184,14 +188,12 @@ def train_phase(w: Width, *, mesh=None, expect_mosaic: bool = True,
         if report["lstm_dispatch"] != "resident" or \
                 tally.get("lstm") != {"resident": w.layers}:
             raise AssertionError(f"LSTM left the resident kernel: {report}")
-        # one chip: the fused update. On a mesh the plain optimizer's
-        # operands are partitioned global arrays, where a Mosaic kernel
-        # cannot lower: every parameter must have stood down to
-        # _apply_one (kernels/opt_update.py)
-        want = "fused" if mesh is None else "apply_one"
-        if set(tally.get("opt_update", {})) != {want}:
+        # every dense f32 parameter takes the fused update: on one
+        # chip directly, on the data-parallel mesh on each device over
+        # its own replica (kernels/opt_update.py)
+        if set(tally.get("opt_update", {})) != {"fused"}:
             raise AssertionError(
-                f"optimizer update path is not all {want!r}: {tally}")
+                f"a parameter left the fused optimizer update: {tally}")
     if split > 1 and expect_mosaic:
         # the kernels must see the per-device batch, not the gathered one
         local = f"{w.seqlen},{local_batch},"

@@ -194,6 +194,7 @@ class Network:
         ctx = Context(train=train, rng=rng, carried=carried or {},
                       mesh=mesh)
         from paddle_tpu.layers.activations import apply_activation  # cycle-free
+        from paddle_tpu.ops.common import step_mesh  # cycle-free
         from paddle_tpu.utils.error_context import layer_scope
 
         for name in self.order:
@@ -214,8 +215,10 @@ class Network:
             ctx.in_infos = [self.shape_infos[i] for i in layer.input_names()]
             ctx.out_info = self.shape_infos[name]
             # layer_scope = CustomStackTrace push/pop + HLO named_scope
-            # (NeuralNetwork.cpp:244-252)
-            with layer_scope(name):
+            # (NeuralNetwork.cpp:244-252); step_mesh tells the Pallas
+            # kernels under this layer the mesh they are traced into
+            # (with no mesh given, the caller's declaration stands)
+            with layer_scope(name), step_mesh(mesh):
                 def compute(lp, ins_t, layer=layer, impl=impl, name=name):
                     # state updates thread through as explicit outputs so
                     # this stays pure enough for jax.checkpoint below

@@ -36,13 +36,13 @@ _FUSED_RNN = _env_flag("PADDLE_TPU_FUSED_RNN", False)
 _FUSED_OPT = _env_flag("PADDLE_TPU_FUSED_OPTIM", True)
 
 
-def rnn_cells_enabled(mesh=None) -> bool:
-    """Is the fused-RNN-cell switch on for a layer traced under
-    ``mesh``? Never inside a partitioned step: the cell kernels sit in
-    per-step scan bodies with no per-device wrapper, and a Mosaic
-    kernel cannot be partitioned (``ops/common.py:partitioned``)."""
+def rnn_cells_enabled() -> bool:
+    """Is the fused-RNN-cell switch on for the layer being traced? Never
+    inside a partitioned step: the cell kernels sit in per-step scan
+    bodies with no per-device wrapper, and a Mosaic kernel cannot be
+    partitioned (``ops/common.py``, "the step mesh")."""
     from paddle_tpu.ops.common import partitioned
-    return _FUSED_RNN and not partitioned(mesh)
+    return _FUSED_RNN and not partitioned()
 
 
 def fused_optimizer_enabled() -> bool:

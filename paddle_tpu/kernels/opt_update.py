@@ -26,7 +26,14 @@ Contract (``docs/kernels.md``):
   VMEM gate (the headline LSTM's 15 MB embedding among them);
 - the parameter and every slot alias their outputs
   (``input_output_aliases``), so the donated train step keeps its
-  in-place update through the custom call.
+  in-place update through the custom call;
+- XLA cannot partition a Mosaic kernel, so traced into a step that is
+  partitioned over a mesh (``ops/common.py``, "the step mesh") the
+  kernel runs on every device over its own replica of the parameter
+  (``common.replica_local``); inside the ZeRO-1/FSDP ``shard_map``s it
+  runs over the device's shard as on one chip; on a mesh with a model,
+  seq or pipe axis, where a parameter may be sharded, ``_apply_one``
+  runs (``record_dispatch`` counts it).
 
 Traced scalars (lr / Adam's bias-corrected alpha) ride SMEM ``(1, 1)``
 blocks; static hyper-parameters are kernel constants.
@@ -94,12 +101,18 @@ def _elementwise_call(kernel, tiles, scalars, aliases, n_out):
     )(*tiles, *scalars)
 
 
-def _eligible(arrays):
+def _per_device(fused, arrays):
+    """``fused`` as the step being traced can run it over ``arrays``
+    (the parameter, its gradient, its slots), or None where it cannot:
+    operands that are not same-shape f32, the reference mode, or a step
+    partitioned over a mesh on which a parameter may be sharded
+    (``common.replica_local``). On a data-parallel mesh every device
+    runs the kernel over its own replica."""
     shape = arrays[0].shape
     for a in arrays:
         if a.dtype != jnp.float32 or a.shape != shape:
-            return False
-    return common.use_pallas()
+            return None
+    return common.replica_local(fused) if common.use_pallas() else None
 
 
 # --------------------------------------------------------------- momentum
@@ -151,27 +164,29 @@ def _adam_fused(p, g, m, v, lr, t, b1, b2, eps, decay):
 
 # ---------------------------------------------------------------- routing
 
-def apply_one(opt, p, g, slots, lr, decay, t, partitioned=False):
+def apply_one(opt, p, g, slots, lr, decay, t):
     """Fused stand-in for ``opt._apply_one`` on the dense path. The slot
     dict may carry ``prune_mask`` (ignored here, re-attached by
-    ``_update_param``, matching ``_apply_one``'s contract).
-    ``partitioned`` operands (global arrays of a multi-device step)
-    always take ``_apply_one``: a Mosaic kernel cannot be partitioned,
-    so the fused update runs only where the update is already
-    per-device — one chip, or inside the ZeRO-1/FSDP shard_maps."""
+    ``_update_param``, matching ``_apply_one``'s contract)."""
     from paddle_tpu.kernels import dispatch
     kind = type(opt).__name__
     keys = set(slots) - {"prune_mask"}
-    fused = dispatch.fused_optimizer_enabled() and not partitioned
-    if (fused and kind == "Momentum"
-            and not getattr(opt, "nesterov", False)
-            and keys == {"mom"} and _eligible((p, g, slots["mom"]))):
-        common.note("opt_update", "fused")
-        return _momentum_fused(p, g, slots["mom"], lr, opt.momentum, decay)
-    if (fused and kind == "Adam" and keys == {"mom", "v"}
-            and _eligible((p, g, slots["mom"], slots["v"]))):
-        common.note("opt_update", "fused")
-        return _adam_fused(p, g, slots["mom"], slots["v"], lr, t,
-                           opt.beta1, opt.beta2, opt.epsilon, decay)
-    common.note("opt_update", "apply_one")
-    return opt._apply_one(p, g, slots, lr, decay, t)
+    fused = arrays = None
+    if (kind == "Momentum" and not getattr(opt, "nesterov", False)
+            and keys == {"mom"}):
+        arrays = (p, g, slots["mom"])
+        fused = lambda p, g, m, lr, decay, t: _momentum_fused(  # noqa: E731
+            p, g, m, lr, opt.momentum, decay)
+    elif kind == "Adam" and keys == {"mom", "v"}:
+        arrays = (p, g, slots["mom"], slots["v"])
+        fused = lambda p, g, m, v, lr, decay, t: _adam_fused(  # noqa: E731
+            p, g, m, v, lr, t, opt.beta1, opt.beta2, opt.epsilon, decay)
+    run = (_per_device(fused, arrays)
+           if fused and dispatch.fused_optimizer_enabled() else None)
+    if run is None:
+        common.note("opt_update", "apply_one")
+        return opt._apply_one(p, g, slots, lr, decay, t)
+    common.note("opt_update", "fused")
+    # traced scalars cross a shard_map as arrays
+    return run(*arrays, jnp.asarray(lr, jnp.float32),
+               jnp.asarray(decay, jnp.float32), jnp.asarray(t))

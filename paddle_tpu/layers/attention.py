@@ -79,8 +79,7 @@ class MultiHeadAttentionLayer(LayerImpl):
         else:
             # no mesh / no seq axis: same math on one device (the knob
             # degrades gracefully so configs run everywhere)
-            out = flash_attention(q, k, v, kv_mask, causal=causal,
-                                  mesh=ctx.mesh)
+            out = flash_attention(q, k, v, kv_mask, causal=causal)
         B, N, T, _ = out.shape
         out = out.transpose(0, 2, 1, 3).reshape(B, T, size) @ params["wo"]
         if "wbias" in params:

@@ -29,7 +29,7 @@ from paddle_tpu.core.registry import (LayerImpl, ParamSpec, ShapeInfo,
                                       register_layer)
 
 # --------------------------------------------------------------------- CRF
-def crf_log_likelihood(x, labels, mask, w, mesh=None):
+def crf_log_likelihood(x, labels, mask, w):
     """Per-sequence log P(labels | x) for a linear-chain CRF.
 
     x: [B, T, C] emission scores; labels: [B, T] int; mask: [B, T];
@@ -58,7 +58,7 @@ def crf_log_likelihood(x, labels, mask, w, mesh=None):
     # Dispatches to the Pallas exp-space-matmul kernel on TPU
     # (ops/crf.py), lax.scan elsewhere.
     from paddle_tpu.ops.crf import crf_log_z
-    log_z = crf_log_z(x, mask.astype(x.dtype), trans, a, b, mesh=mesh)
+    log_z = crf_log_z(x, mask.astype(x.dtype), trans, a, b)
     return gold - log_z
 
 
@@ -112,8 +112,7 @@ class CRFLayer(LayerImpl):
         x, label = ins[0], ins[1]
         mask = x.mask if x.mask is not None else \
             jnp.ones(x.value.shape[:2], x.value.dtype)
-        ll = crf_log_likelihood(x.value, label.value, mask, params["w0"],
-                                mesh=ctx.mesh)
+        ll = crf_log_likelihood(x.value, label.value, mask, params["w0"])
         cost = -ll
         if len(ins) > 2:
             cost = cost * ins[2].value.reshape(cost.shape)
@@ -155,7 +154,7 @@ class CRFDecodingLayer(LayerImpl):
 
 
 # --------------------------------------------------------------------- CTC
-def ctc_loss(log_probs, labels, in_mask, label_mask, blank, mesh=None):
+def ctc_loss(log_probs, labels, in_mask, label_mask, blank):
     """Per-sequence CTC negative log-likelihood.
 
     log_probs: [B, T, C] log softmax outputs; labels: [B, L] ints (no
@@ -190,7 +189,7 @@ def ctc_loss(log_probs, labels, in_mask, label_mask, blank, mesh=None):
         log_probs, jnp.broadcast_to(ext[:, None, :], (B, T, S)), axis=2)
     ll = ctc_ll(emit, in_mask.astype(log_probs.dtype),
                 valid_s.astype(log_probs.dtype),
-                can_skip.astype(log_probs.dtype), ext_lens, mesh=mesh)
+                can_skip.astype(log_probs.dtype), ext_lens)
     return -ll
 
 
@@ -215,8 +214,7 @@ class CTCLayer(LayerImpl):
             lab = lab[:, :, 0]
         log_probs = jax.nn.log_softmax(x.value, axis=-1)
         blank = cfg.attrs.get("blank", x.value.shape[-1] - 1)
-        cost = ctc_loss(log_probs, lab, in_mask, label_mask, blank,
-                        mesh=ctx.mesh)
+        cost = ctc_loss(log_probs, lab, in_mask, label_mask, blank)
         if cfg.attrs.get("norm_by_times", False):
             cost = cost / jnp.maximum(jnp.sum(in_mask, axis=1), 1.0)
         return Argument(value=cost[:, None])

@@ -212,10 +212,8 @@ class RecurrentLayerGroup(LayerImpl):
                 feed[k] = Argument(value=v, mask=inp["msub"][k])
             for mem in memories:
                 feed[mem["boundary"]] = Argument(value=carry[mem["boundary"]])
-            # the step net inherits the mesh: its layers must know when
-            # they are traced inside a partitioned step (kernel dispatch)
             outs = net.apply(sub_params, feed, train=train,
-                             rng=inp.get("rng"), mesh=ctx.mesh)
+                             rng=inp.get("rng"))
             m_t = inp["m"]
 
             def guard(new, old):

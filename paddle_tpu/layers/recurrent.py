@@ -112,7 +112,7 @@ class LstmLayer(LayerImpl):
             h0, c0 = carried if carried is not None else (z, z)
             ys, hT, cT = lstm_sequence(xs, mask, w, gate_bias, check_i,
                                        check_f, check_o, h0, c0,
-                                       reverse=reverse, mesh=ctx.mesh)
+                                       reverse=reverse)
             return Argument(value=jnp.swapaxes(ys, 0, 1), mask=a.mask,
                             state=(hT, cT))
 
@@ -123,7 +123,7 @@ class LstmLayer(LayerImpl):
         def step(carry, x_t):
             h, c = carry
             gates = x_t + h @ w + gate_bias
-            if _kernels.rnn_cells_enabled(ctx.mesh):
+            if _kernels.rnn_cells_enabled():
                 # fused cell (kernels/rnn_cells.py): the fallback
                 # spelling is this inline math verbatim, so the flag is
                 # bitwise-invisible off-TPU; no-grad serving takes the
@@ -187,7 +187,7 @@ class GruLayer(LayerImpl):
             h0 = carried if carried is not None \
                 else jnp.zeros((B, size), a.value.dtype)
             ys, hT = gru_sequence(xs, mask, w_gate, w_state, bias, h0,
-                                  reverse=reverse, mesh=ctx.mesh)
+                                  reverse=reverse)
             return Argument(value=jnp.swapaxes(ys, 0, 1), mask=a.mask,
                             state=hT)
 
@@ -197,7 +197,7 @@ class GruLayer(LayerImpl):
         def step(carry, x_t):
             (h,) = carry
             x_t = x_t + bias
-            if _kernels.rnn_cells_enabled(ctx.mesh):
+            if _kernels.rnn_cells_enabled():
                 cell = (_kernels.gru_cell if ctx.train
                         else _kernels.gru_cell_infer)
                 out = cell(x_t, h, w_gate, w_state,
@@ -283,7 +283,7 @@ class GruStepLayer(LayerImpl):
             x = x + params["wbias"]
         w_gate = params["w0"][:, : 2 * size]
         w_state = params["w0"][:, 2 * size:]
-        if _kernels.rnn_cells_enabled(ctx.mesh):
+        if _kernels.rnn_cells_enabled():
             cell = (_kernels.gru_cell if ctx.train
                     else _kernels.gru_cell_infer)
             return Argument(value=cell(
@@ -334,7 +334,7 @@ class LstmStepLayer(LayerImpl):
         else:
             z = jnp.zeros((size,), gates.dtype)
             check_i = check_f = check_o = z
-        if _kernels.rnn_cells_enabled(ctx.mesh):
+        if _kernels.rnn_cells_enabled():
             cell = (_kernels.lstm_cell if ctx.train
                     else _kernels.lstm_cell_infer)
             out, state = cell(

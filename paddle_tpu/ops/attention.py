@@ -218,15 +218,15 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, kv_mask=None, causal=False, scale=None,
-                    block_q=256, block_k=256, mesh=None):
-    """Flash attention. Pallas on TPU, blockwise-scan elsewhere. Under a
-    ``mesh`` whose batch axes divide B each device runs the kernel on
-    its own rows (``common.batch_local``)."""
+                    block_q=256, block_k=256):
+    """Flash attention. Pallas on TPU, blockwise-scan elsewhere. Traced
+    into a step partitioned over a mesh whose batch axes divide B, each
+    device runs the kernel on its own rows (``common.batch_local``)."""
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     resident = jnp.dtype(q.dtype).itemsize * (
         3 * min(block_k, k.shape[2]) * D + 2 * min(block_q, q.shape[2]) * D)
-    split = common.batch_split(mesh, q.shape[0])
+    split = common.batch_split(q.shape[0])
     if split == 0 or not common.use_pallas(resident):
         common.note("flash_attention", "ref")
         return blockwise_attention(q, k, v, kv_mask, causal=causal,
@@ -238,6 +238,6 @@ def flash_attention(q, k, v, kv_mask=None, causal=False, scale=None,
         core = common.batch_local(
             lambda q_, k_, v_, m_: _flash_core(
                 q_, k_, v_, m_, causal, scale, block_q, block_k),
-            mesh, split, in_dims=(0, 0, 0, 0), out_dims=0)
+            split, in_dims=(0, 0, 0, 0), out_dims=0)
         return core(q, k, v, kv_mask)
     return _flash_core(q, k, v, kv_mask, causal, scale, block_q, block_k)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import threading
 from typing import Dict, List, Optional
 
 import jax
@@ -101,46 +102,84 @@ def interpret() -> bool:
     return mode() == "interpret"
 
 
-# per-device batch ----------------------------------------------------------
+# the step mesh ---------------------------------------------------------------
+# XLA cannot partition a Mosaic kernel: jax 0.9.0 refuses to lower one
+# inside a multi-device step ("Mosaic kernels cannot be automatically
+# partitioned. Please wrap the call in a shard_map" — four-chip run,
+# PERF.md). So a kernel entry must know, at TRACE time, whether the step
+# it is traced into is partitioned over a mesh. That is declared in the
+# three places a mesh meets a trace — ``Network.apply`` given ``mesh=``,
+# the trainer's jitted step (``SGD._jit_step``), and
+# ``parallel.mesh.shard_map_compat``, whose body is per-device code — and
+# read here, so no layer or ops signature carries a mesh.
 
-def partitioned(mesh) -> bool:
-    """Is a computation traced under ``mesh`` (outside any shard_map)
-    one that XLA will have to partition over several devices? A Mosaic
-    kernel cannot be: jax 0.9.0 refuses to lower it ("Mosaic kernels
-    cannot be automatically partitioned. Please wrap the call in a
-    shard_map" — four-chip run, PERF.md). So under such a mesh a kernel
-    either runs per device through ``batch_local`` or stands down to
-    its reference."""
+_STEP = threading.local()   # .mesh: the declaration open on this thread
+
+
+@contextlib.contextmanager
+def _declare(mesh):
+    prev = current_mesh()
+    _STEP.mesh = mesh
+    try:
+        yield
+    finally:
+        _STEP.mesh = prev
+
+
+def step_mesh(mesh):
+    """Scope: what is traced inside belongs to a step partitioned over
+    ``mesh``. None declares nothing (an enclosing declaration stands),
+    so a sub-network applied without a mesh inherits its caller's."""
+    return _declare(mesh) if mesh is not None else contextlib.nullcontext()
+
+
+def per_device():
+    """Scope: what is traced inside is per-device code (a ``shard_map``
+    body) — every device already holds its own block, nothing is
+    partitioned, a kernel is called as on one chip."""
+    return _declare(None)
+
+
+def current_mesh():
+    return getattr(_STEP, "mesh", None)
+
+
+def partitioned() -> bool:
+    """Is the code being traced part of a step XLA has to partition over
+    several devices? A kernel there either runs per device
+    (``batch_local``, ``replica_local``) or stands down to its
+    reference."""
+    mesh = current_mesh()
     return mesh is not None and mesh.size > 1
 
 
-def batch_split(mesh, B: int) -> int:
-    """How many ways the mesh's batch axes split a batch of ``B`` rows:
-    1 when nothing is partitioned, the data-parallel degree when it
-    divides ``B`` (the kernel then runs through ``batch_local``), and 0
-    when the mesh is partitioned but its batch axes cannot split ``B``
+def batch_split(B: int) -> int:
+    """How many ways the step mesh's batch axes split a batch of ``B``
+    rows: 1 when nothing is partitioned, the data-parallel degree when
+    it divides ``B`` (the kernel then runs through ``batch_local``), and
+    0 when the step is partitioned but its batch axes cannot split ``B``
     — the kernel entry must take its reference path."""
-    if not partitioned(mesh):
+    if not partitioned():
         return 1
     from paddle_tpu.parallel import mesh as mesh_lib  # lazy: import cycle
-    n = mesh_lib.data_parallel_degree(mesh)
+    n = mesh_lib.data_parallel_degree(current_mesh())
     return n if n > 1 and B % n == 0 else 0
 
 
-def batch_local(fn, mesh, split: int, in_dims, out_dims):
+def batch_local(fn, split: int, in_dims, out_dims):
     """``fn`` run by every device on its OWN batch rows: a ``shard_map``
-    over the mesh's batch axes — the only way a Mosaic kernel compiles
-    inside a partitioned step (see ``partitioned``); ``fn`` itself when
-    ``split`` (from ``batch_split``) is 1. ``in_dims`` and
-    ``out_dims`` give, per positional argument/result, the index of its
-    batch dimension, or None for an operand every device holds whole
-    (weights: their cotangents come back summed over the batch axes); a
-    bare ``out_dims`` is for a ``fn`` returning one array."""
+    over the step mesh's batch axes; ``fn`` itself when ``split`` (from
+    ``batch_split``) is 1. ``in_dims`` and ``out_dims`` give, per
+    positional argument/result, the index of its batch dimension, or
+    None for an operand every device holds whole (weights: their
+    cotangents come back summed over the batch axes); a bare
+    ``out_dims`` is for a ``fn`` returning one array."""
     if split <= 1:
         return fn
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.parallel import mesh as mesh_lib  # lazy: import cycle
+    mesh = current_mesh()
     axes = mesh_lib.batch_axes(mesh)
 
     def spec(d):
@@ -150,6 +189,25 @@ def batch_local(fn, mesh, split: int, in_dims, out_dims):
         fn, mesh, in_specs=tuple(spec(d) for d in in_dims),
         out_specs=(tuple(spec(d) for d in out_dims)
                    if isinstance(out_dims, tuple) else spec(out_dims)))
+
+
+def replica_local(fn):
+    """``fn`` run whole by every device on operands each device holds
+    whole (a replicated parameter, its all-reduced gradient, its
+    slots): ``fn`` itself when nothing is partitioned, a replicated
+    ``shard_map`` when every device of the step mesh is a data-parallel
+    replica, and None — the caller stands down to what XLA can
+    partition — on a mesh with a model, seq or pipe axis, where a
+    parameter may be sharded and a replicated spec would gather it."""
+    if not partitioned():
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel import mesh as mesh_lib  # lazy: import cycle
+    mesh = current_mesh()
+    if mesh_lib.data_parallel_degree(mesh) != mesh.size:
+        return None
+    return mesh_lib.shard_map_compat(fn, mesh, in_specs=P(), out_specs=P())
 
 
 # shared kernel-layout vocabulary -------------------------------------------

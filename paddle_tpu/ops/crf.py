@@ -228,13 +228,13 @@ _crf_core.defvjp(_crf_fwd, _crf_bwd)
 
 # ---------------------------------------------------------------- public
 
-def crf_log_z(x, mask, trans, a, b, mesh=None):
+def crf_log_z(x, mask, trans, a, b):
     """log Z [B] for a batch of linear-chain CRFs. Pallas on TPU (class
-    axis padded to the 128-lane width), lax.scan elsewhere. Under a
-    ``mesh`` whose batch axes divide B each device runs the kernel on
-    its own rows (``common.batch_local``)."""
+    axis padded to the 128-lane width), lax.scan elsewhere. Traced into a
+    step partitioned over a mesh whose batch axes divide B, each device
+    runs the kernel on its own rows (``common.batch_local``)."""
     B, T, C = x.shape
-    split = common.batch_split(mesh, B)
+    split = common.batch_split(B)
     itemsize = jnp.dtype(x.dtype).itemsize
     Cp = ((C + LANE - 1) // LANE) * LANE
     resident = itemsize * (Cp * Cp + 4 * (B // max(split, 1)) * Cp)
@@ -243,6 +243,6 @@ def crf_log_z(x, mask, trans, a, b, mesh=None):
         return crf_log_z_ref(x, mask, trans, a, b)
     common.note("crf", common.pallas_path())
     xp, transp, ap, bp, _ = _pad_classes(x, trans, a, b)
-    core = common.batch_local(_crf_core, mesh, split,
+    core = common.batch_local(_crf_core, split,
                               in_dims=(0, 0, None, None, None), out_dims=0)
     return core(xp, mask, transp, ap, bp)

@@ -189,13 +189,13 @@ _ctc_core.defvjp(_ctc_fwd, _ctc_bwd)
 
 # ---------------------------------------------------------------- public
 
-def ctc_ll(emit, in_mask, valid_s, can_skip, ext_lens, mesh=None):
+def ctc_ll(emit, in_mask, valid_s, can_skip, ext_lens):
     """Log-likelihood [B] of the CTC paths. Pallas on TPU (S padded to the
-    128-lane width by the caller or here), lax.scan elsewhere. Under a
-    ``mesh`` whose batch axes divide B each device runs the kernel on
-    its own rows (``common.batch_local``)."""
+    128-lane width by the caller or here), lax.scan elsewhere. Traced into a
+    step partitioned over a mesh whose batch axes divide B, each device
+    runs the kernel on its own rows (``common.batch_local``)."""
     B, T, S = emit.shape
-    split = common.batch_split(mesh, B)
+    split = common.batch_split(B)
     Sp = ((S + LANE - 1) // LANE) * LANE
     itemsize = jnp.dtype(emit.dtype).itemsize
     resident = itemsize * 6 * (B // max(split, 1)) * Sp
@@ -208,6 +208,6 @@ def ctc_ll(emit, in_mask, valid_s, can_skip, ext_lens, mesh=None):
         emit = jnp.pad(emit, ((0, 0), (0, 0), (0, pc)), constant_values=NEG)
         valid_s = jnp.pad(valid_s, ((0, 0), (0, pc)))
         can_skip = jnp.pad(can_skip, ((0, 0), (0, pc)))
-    core = common.batch_local(_ctc_core, mesh, split,
+    core = common.batch_local(_ctc_core, split,
                               in_dims=(0, 0, 0, 0, 0), out_dims=0)
     return core(emit, in_mask, valid_s, can_skip, ext_lens)

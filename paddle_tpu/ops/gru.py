@@ -180,20 +180,20 @@ _gru_core.defvjp(_fwd_rule, _bwd_rule)
 
 # ---------------------------------------------------------------- public
 
-def gru_sequence(xs, mask, w_gate, w_state, bias, h0, reverse=False,
-                 mesh=None):
+def gru_sequence(xs, mask, w_gate, w_state, bias, h0, reverse=False):
     """Fused GRU over a padded [T,B,3H] gate-projection sequence.
     ``reverse=True`` runs back-to-front (outputs stay in input time order).
-    Under a ``mesh`` whose batch axes divide B each device runs the
-    kernel on its own rows (``common.batch_local``).
+    Traced into a step partitioned over a mesh whose batch axes divide
+    B, each device runs the kernel on its own rows
+    (``common.batch_local``).
     Returns (ys [T,B,H], hT). Differentiable either way."""
     if reverse:
         ys, hT = gru_sequence(jnp.flip(xs, 0), jnp.flip(mask, 0), w_gate,
-                              w_state, bias, h0, mesh=mesh)
+                              w_state, bias, h0)
         return jnp.flip(ys, 0), hT
     T, B, H3 = xs.shape
     H = H3 // 3
-    split = common.batch_split(mesh, B)
+    split = common.batch_split(B)
     Bl = B // max(split, 1)
     itemsize = jnp.dtype(xs.dtype).itemsize
     # counted like the LSTM's (ops/lstm.py:_resident_bytes): constant-
@@ -211,7 +211,7 @@ def gru_sequence(xs, mask, w_gate, w_state, bias, h0, reverse=False,
         # benchmark shape exceeds the resident budget for GRU.
         return gru_sequence_ref(xs, mask, w_gate, w_state, bias, h0)
     common.note("gru", common.pallas_path())
-    core = common.batch_local(_gru_core, mesh, split,
+    core = common.batch_local(_gru_core, split,
                               in_dims=(1, 1, None, None, 0),
                               out_dims=(1, 0))
     return core(xs + bias, mask, w_gate, w_state, h0)

@@ -464,27 +464,28 @@ def kernel_dispatch_table():
 
 
 def lstm_sequence(xs, mask, w, gate_bias, check_i, check_f, check_o, h0, c0,
-                  reverse=False, mesh=None):
+                  reverse=False):
     """Fused LSTM over a padded [T,B,4H] gate-projection sequence.
 
     Dispatch (``lstm_dispatch``): the resident Pallas kernel when the
     recurrent weight fits VMEM for all T steps, the tiled Pallas kernel
     (weight streamed in gate-column blocks) for big hidden sizes, else
     the lax.scan reference. ``reverse=True`` runs the recurrence
-    back-to-front (outputs stay in input time order). Under a ``mesh``
-    whose batch axes divide B, each device runs the kernel on its own
-    rows (``common.batch_local``) and dispatch sees the per-device
-    batch; under one that cannot split B the reference runs. Returns
+    back-to-front (outputs stay in input time order). Traced into a step
+    partitioned over a mesh (``common.step_mesh``) whose batch axes
+    divide B, each device runs the kernel on its own rows
+    (``common.batch_local``) and dispatch sees the per-device batch;
+    under one that cannot split B the reference runs. Returns
     (ys [T,B,H], hT, cT). Differentiable on every path.
     """
     if reverse:
         ys, hT, cT = lstm_sequence(jnp.flip(xs, 0), jnp.flip(mask, 0), w,
                                    gate_bias, check_i, check_f, check_o,
-                                   h0, c0, mesh=mesh)
+                                   h0, c0)
         return jnp.flip(ys, 0), hT, cT
     T, B, H4 = xs.shape
     H = H4 // 4
-    split = common.batch_split(mesh, B)
+    split = common.batch_split(B)
     path = common.note("lstm", lstm_dispatch(
         B // split, H, jnp.dtype(xs.dtype).itemsize) if split else "ref")
     if path == "ref":
@@ -492,6 +493,6 @@ def lstm_sequence(xs, mask, w, gate_bias, check_i, check_f, check_o, h0, c0,
                                  check_o, h0, c0)
     xs_b = xs + gate_bias  # fold bias into the pre-projected input once
     core = common.batch_local(
-        _lstm_core if path == "resident" else _lstm_core_tiled, mesh, split,
+        _lstm_core if path == "resident" else _lstm_core_tiled, split,
         in_dims=(1, 1, None, None, None, None, 0, 0), out_dims=(1, 0, 0))
     return core(xs_b, mask, w, check_i, check_f, check_o, h0, c0)

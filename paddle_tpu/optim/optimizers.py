@@ -108,13 +108,9 @@ class Optimizer:
                             if n in slots}
         return state
 
-    def _update_param(self, g, p, slots, spec, lr_t, t,
-                      partitioned: bool = False):
+    def _update_param(self, g, p, slots, spec, lr_t, t):
         """One parameter's update: clipping, l1/l2 resolution, the dense or
-        sparse apply, and the prune mask. ``partitioned``: the operands
-        are global arrays of a multi-device step (outside any
-        shard_map), where the fused Mosaic update cannot lower and the
-        XLA chain runs instead (``ops/common.py:partitioned``). Shape-agnostic and elementwise
+        sparse apply, and the prune mask. Shape-agnostic and elementwise
         (except the sparse lazy path), so the ZeRO-1 updater
         (``optim/zero1.py``) runs the same code on each device's 1/N flat
         shard — one source of truth for update semantics. Clipping happens
@@ -144,8 +140,7 @@ class Optimizer:
             # updates all share the one fused entry
             from paddle_tpu.kernels import opt_update as _fused
             p_new, slots_new = _fused.apply_one(
-                self, p, g, slots, lr_t * lr_mult, l2, t,
-                partitioned=partitioned)
+                self, p, g, slots, lr_t * lr_mult, l2, t)
             if l1 > 0:
                 shrink = l1 * lr_t * lr_mult
                 p_new = jnp.sign(p_new) * jnp.maximum(
@@ -157,15 +152,11 @@ class Optimizer:
 
     def update(self, grads, state, params,
                meta: Optional[Dict[str, ParamSpec]] = None,
-               batch_size=1, num_passes=0, mesh=None):
+               batch_size=1, num_passes=0):
         """(grads, state, params) -> (new_params, new_state). meta carries
         per-param lr multipliers / static flags / l1-l2 overrides;
-        ``num_passes`` (current pass id) drives the pass_manual schedule;
-        ``mesh`` is the mesh the calling step is partitioned over, if
-        any."""
-        from paddle_tpu.ops import common as kernel_common
+        ``num_passes`` (current pass id) drives the pass_manual schedule."""
         from paddle_tpu.optim.schedules import learning_rate_at
-        partitioned = kernel_common.partitioned(mesh)
 
         t = state["t"] + 1
         num_samples = state["num_samples"] + batch_size
@@ -190,8 +181,7 @@ class Optimizer:
                 continue
             spec = meta.get(name) if meta else None
             p_new, slots_new = self._update_param(
-                g, params[name], state["slots"][name], spec, lr_t, t,
-                partitioned=partitioned)
+                g, params[name], state["slots"][name], spec, lr_t, t)
             new_params[name] = p_new
             new_slots[name] = slots_new
 

@@ -276,12 +276,11 @@ class Zero1Updater:
     # --------------------------------------------------------------- update
     def update(self, grads, state, params,
                meta: Optional[Dict[str, ParamSpec]] = None,
-               batch_size=1, num_passes=0, mesh=None):
-        """Same contract as :meth:`Optimizer.update` (``mesh`` included;
-        this updater owns its mesh and ignores the argument). Planned
-        parameters update shard-wise under ``shard_map``; the rest run
-        the replicated per-parameter body. One shared t/num_samples/lr
-        computation keeps the two sub-paths on the same schedule step."""
+               batch_size=1, num_passes=0):
+        """Same contract as :meth:`Optimizer.update`. Planned parameters
+        update shard-wise under ``shard_map``; the rest run the replicated
+        per-parameter body. One shared t/num_samples/lr computation keeps
+        the two sub-paths on the same schedule step."""
         from paddle_tpu.optim.schedules import learning_rate_at
         opt = self.opt
         meta = meta if meta is not None else self.meta
@@ -311,10 +310,8 @@ class Zero1Updater:
                 new_params[name] = params[name]
                 continue
             spec = meta.get(name) if meta else None
-            # global arrays outside the shard_map below: no Mosaic kernel
             p_new, s_new = opt._update_param(
-                g, params[name], state["slots"][name], spec, lr_t, t,
-                partitioned=True)
+                g, params[name], state["slots"][name], spec, lr_t, t)
             new_params[name] = p_new
             new_slots[name] = s_new
 
@@ -603,7 +600,7 @@ class FsdpUpdater(Zero1Updater):
     # --------------------------------------------------------------- update
     def update(self, grads, state, params,
                meta: Optional[Dict[str, ParamSpec]] = None,
-               batch_size=1, num_passes=0, mesh=None):
+               batch_size=1, num_passes=0):
         """Shard-wise update on the packed storage: planned parameters
         and their gradients arrive ``(N, chunk)`` (the gather's
         transpose already reduced the cotangent into the packed
@@ -645,10 +642,8 @@ class FsdpUpdater(Zero1Updater):
                 new_params[name] = params[name]
                 continue
             spec = meta.get(name) if meta else None
-            # global arrays outside the shard_map below: no Mosaic kernel
             p_new, s_new = opt._update_param(
-                g, params[name], state["slots"][name], spec, lr_t, t,
-                partitioned=True)
+                g, params[name], state["slots"][name], spec, lr_t, t)
             new_params[name] = p_new
             new_slots[name] = s_new
 

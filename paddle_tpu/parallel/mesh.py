@@ -32,6 +32,7 @@ compatibility wrappers over it (``docs/spec_layout.md``).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -173,8 +174,17 @@ def shard_map_compat(f, mesh: Mesh, in_specs, out_specs,
                      check_vma: bool = False):
     """``jax.shard_map`` with this repo's default (``check_vma`` off):
     one spelling for every shard_map consumer (pipeline/moe/ring/
-    zero1)."""
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+    zero1/the kernels' per-device wrappers). The body is per-device
+    code, and is traced as such: a Pallas kernel inside it is called as
+    on one chip (``ops/common.py:per_device``)."""
+    from paddle_tpu.ops import common as kernel_common  # lazy: cycle
+
+    @functools.wraps(f)
+    def body(*args):
+        with kernel_common.per_device():
+            return f(*args)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=check_vma)
 
 

@@ -18,6 +18,7 @@ XLA inserts the gradient all-reduce, the ICI equivalent of
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Union
 
@@ -577,6 +578,21 @@ class SGD:
 
         return jax.tree_util.tree_map(split, feed)
 
+    def _jit_step(self, step):
+        """``jax.jit`` of a train step (params and optimizer state
+        donated) whose whole trace — forward, backward, optimizer update
+        — is declared partitioned over this trainer's mesh, which is how
+        the Pallas kernels inside know to run per device
+        (``ops/common.py:step_mesh``)."""
+        from paddle_tpu.ops import common as kernel_common
+
+        @functools.wraps(step)
+        def traced(*args, **kwargs):
+            with kernel_common.step_mesh(self.mesh):
+                return step(*args, **kwargs)
+
+        return jax.jit(traced, donate_argnums=(0, 1))
+
     def _build_pipe_step(self, with_stats=False):
         """The pipelined train step: body forward through the GPipe
         schedule (``PipelineTrainPlan.fwd`` — a shard_map'd scan whose
@@ -646,7 +662,7 @@ class SGD:
                    else outputs[cost_name].value.shape[0])
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
-                num_passes=num_passes, mesh=self.mesh)
+                num_passes=num_passes)
             new_params.update(updates)
             health = self._health_metrics(
                 loss, params, grads, new_params, new_opt, num_passes,
@@ -658,7 +674,7 @@ class SGD:
             metrics.update(health)
             return new_params, new_opt, metrics
 
-        return jax.jit(step, donate_argnums=(0, 1))
+        return self._jit_step(step)
 
     def _build_train_step(self, with_stats=False):
         if self._pipe is not None:
@@ -728,7 +744,7 @@ class SGD:
                    else outputs[cost_name].value.shape[0])
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
-                num_passes=num_passes, mesh=self.mesh)
+                num_passes=num_passes)
             new_params.update(updates)  # moving statistics (batch_norm)
             health = self._health_metrics(
                 loss, params, grads, new_params, new_opt, num_passes,
@@ -831,7 +847,7 @@ class SGD:
             bsz = total_live if total_live is not None else full_bsz
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
-                num_passes=num_passes, mesh=self.mesh)
+                num_passes=num_passes)
             new_params.update(updates)
             act_table = None
             if with_stats and acts_k.shape[1] > 0:
@@ -854,8 +870,7 @@ class SGD:
             metrics.update(health)
             return new_params, new_opt, metrics
 
-        return jax.jit(accum_step if accum_k > 1 else step,
-                       donate_argnums=(0, 1))
+        return self._jit_step(accum_step if accum_k > 1 else step)
 
     def _build_eval_step(self):
         network = self.network

@@ -27,11 +27,12 @@ def test_phases_run_tiny_with_interpreted_kernels():
         assert {"b1_t12", "b4_t12"} <= set(served["bucket_hits"])
         meshed = chip_smoke.mesh_phase(TINY, trained, expect_mosaic=False)
         assert meshed["mesh"]["data"] == 4 and meshed["all_reduce_in_hlo"]
-        # on the mesh the LSTM kernels run per device (batch_local) and
-        # the plain optimizer's fused update stands down: XLA cannot
-        # partition a Mosaic kernel
+        # XLA cannot partition a Mosaic kernel: on the mesh the LSTM
+        # kernels run per device on their own batch rows (batch_local),
+        # the fused optimizer update on each device's replica
+        # (replica_local)
         assert meshed["dispatch_tally"]["lstm"] == {"resident": 2}
-        assert set(meshed["dispatch_tally"]["opt_update"]) == {"apply_one"}
+        assert set(meshed["dispatch_tally"]["opt_update"]) == {"fused"}
 
 
 def test_real_entry_refuses_a_non_tpu_backend(capsys):

@@ -322,18 +322,32 @@ def test_ctc_ref_analytic_grad_matches_autodiff():
 # ------------------------------------------- kernels under a mesh
 def test_kernels_run_per_device_or_stand_down_under_a_mesh():
     """XLA cannot partition a Mosaic kernel (jax 0.9.0 refuses to lower
-    it inside a multi-device step). Under a mesh whose batch axes divide
-    the batch the kernel runs per device through ``batch_local`` —
-    dispatch sees the per-device batch, values and gradients match the
-    reference; under one that cannot split it the reference runs."""
+    it inside a multi-device step). Traced into a step declared
+    partitioned over a mesh (``common.step_mesh``) whose batch axes
+    divide the batch, the kernel runs per device through ``batch_local``
+    — dispatch sees the per-device batch, values and gradients match the
+    reference; under one that cannot split it the reference runs. A
+    ``shard_map`` body is per-device code: nothing is partitioned
+    there."""
     from paddle_tpu.ops import common
     from paddle_tpu.ops.lstm import lstm_sequence
     from paddle_tpu.parallel import create_mesh
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.parallel.mesh import shard_map_compat
     mesh = create_mesh(n_data=4, devices=jax.devices()[:4])
-    assert common.partitioned(mesh) and not common.partitioned(None)
-    assert common.batch_split(mesh, 16) == 4
-    assert common.batch_split(mesh, 6) == 0     # must take the reference
-    assert common.batch_split(None, 6) == 1
+    assert not common.partitioned() and common.batch_split(6) == 1
+    with common.step_mesh(mesh):
+        assert common.partitioned()
+        assert common.batch_split(16) == 4
+        assert common.batch_split(6) == 0   # must take the reference
+        with common.step_mesh(None):        # declares nothing: inherits
+            assert common.current_mesh() is mesh
+        inside = []
+        shard_map_compat(lambda x: inside.append(common.partitioned()) or x,
+                         mesh, in_specs=P("data"), out_specs=P("data"))(
+                             jnp.zeros((4,)))
+        assert inside == [False]
+    assert common.current_mesh() is None
     rng = np.random.RandomState(0)
     T, B, H = 3, 16, 128
     xs = jnp.asarray(rng.randn(T, B, 4 * H).astype(np.float32) * 0.3)
@@ -342,40 +356,60 @@ def test_kernels_run_per_device_or_stand_down_under_a_mesh():
     zb, zc = jnp.zeros((4 * H,)), jnp.zeros((H,))
     h0 = jnp.zeros((B, H))
 
-    def loss(x, w_, mesh=None):
-        return jnp.sum(lstm_sequence(x, mask, w_, zb, zc, zc, zc, h0, h0,
-                                     mesh=mesh)[0] ** 2)
+    def loss(x, w_):
+        return jnp.sum(lstm_sequence(x, mask, w_, zb, zc, zc, zc, h0,
+                                     h0)[0] ** 2)
 
     with common.force_mode("ref"):
         want = jax.grad(loss, argnums=(0, 1))(xs, w)
-    with common.force_mode("interpret"), \
+    with common.force_mode("interpret"), common.step_mesh(mesh), \
             common.record_dispatch() as tally:
-        got = jax.jit(jax.grad(lambda x, w_: loss(x, w_, mesh),
-                               argnums=(0, 1)))(xs, w)
+        got = jax.jit(jax.grad(loss, argnums=(0, 1)))(xs, w)
         # a batch the four-way axis cannot split: the reference runs
         lstm_sequence(xs[:, :6], mask[:, :6], w, zb, zc, zc, zc,
-                      h0[:6], h0[:6], mesh=mesh)
+                      h0[:6], h0[:6])
     assert tally == {"lstm": {"resident": 1, "ref": 1}}
     for g, r in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=2e-4, atol=2e-5)
 
 
-def test_fused_optimizer_stands_down_on_partitioned_operands():
+def test_fused_optimizer_runs_per_replica_under_a_data_parallel_mesh():
+    """Traced into a step partitioned over a data-parallel mesh, the
+    fused update runs on every device over its own replica
+    (``common.replica_local``) and equals the one-chip call; on a mesh
+    with a model axis — a parameter may be sharded there — it stands
+    down to ``_apply_one``, and ``record_dispatch`` counts it."""
     from paddle_tpu.kernels import opt_update
     from paddle_tpu.ops import common
-    from paddle_tpu.optim import Adam
-    opt = Adam(learning_rate=1e-3)
-    p = jnp.ones((8, 128)); g = jnp.full((8, 128), 0.5)
-    slots = {"mom": jnp.zeros((8, 128)), "v": jnp.zeros((8, 128))}
-    with common.force_mode("interpret"), \
-            common.record_dispatch() as tally:
-        a = opt_update.apply_one(opt, p, g, slots, 0.01, 0.0, jnp.int32(1))
-        b = opt_update.apply_one(opt, p, g, slots, 0.01, 0.0, jnp.int32(1),
-                                 partitioned=True)
-    assert tally == {"opt_update": {"fused": 1, "apply_one": 1}}
-    np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b[0]),
-                               rtol=1e-6)
+    from paddle_tpu.optim import Adam, Momentum
+    from paddle_tpu.parallel import create_mesh
+    dp = create_mesh(n_data=4, devices=jax.devices()[:4])
+    tp = create_mesh(n_data=2, n_model=2, devices=jax.devices()[:4])
+    rng = np.random.RandomState(0)
+    p, g, m, v = (jnp.asarray(rng.rand(7, 13).astype(np.float32))
+                  for _ in range(4))
+    for opt, slots in ((Adam(learning_rate=1e-3), {"mom": m, "v": v}),
+                       (Momentum(learning_rate=0.1, momentum=0.9),
+                        {"mom": m})):
+        def update(opt=opt):
+            # a fresh function per trace: the step mesh is read at trace
+            # time and is no part of jit's cache key
+            return jax.jit(lambda p_, g_, slots_: opt_update.apply_one(
+                opt, p_, g_, slots_, 0.01, 1e-4, jnp.int32(3)))
+        with common.force_mode("interpret"), \
+                common.record_dispatch() as tally:
+            one = update()(p, g, slots)
+            with common.step_mesh(dp):
+                meshed = update()(p, g, slots)
+            with common.step_mesh(tp):
+                stood_down = update()(p, g, slots)
+        assert tally == {"opt_update": {"fused": 2, "apply_one": 1}}
+        for got in (meshed, stood_down):
+            for a, b in zip(jax.tree_util.tree_leaves(one),
+                            jax.tree_util.tree_leaves(got)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-6, atol=1e-7)
 
 
 # ------------------------------------------------- tiled-H LSTM (big H)

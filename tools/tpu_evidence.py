@@ -135,6 +135,90 @@ def checkgrad(report, name, loss_fn, args, eps=1e-3):
     print(f"checkgrad[{name}]: {json.dumps(entry)[:300]}", flush=True)
 
 
+def _lstm_host_f64(xs, w, pI, pF, pO):
+    """The LSTM recurrence (``ops/lstm.py`` cell math, zero initial
+    state, no padding) in numpy float64 on the host: the arbiter when
+    two device spellings disagree."""
+    xs, w, pI, pF, pO = (np.asarray(a, np.float64)
+                         for a in (xs, w, pI, pF, pO))
+    T, B, H4 = xs.shape
+    h = c = np.zeros((B, H4 // 4))
+    sig = lambda z: 1.0 / (1.0 + np.exp(-z))  # noqa: E731
+    ys = []
+    for t in range(T):
+        a_i, a_ig, a_fg, a_og = np.split(xs[t] + h @ w, 4, axis=-1)
+        c = np.tanh(a_i) * sig(a_ig + c * pI) + c * sig(a_fg + c * pF)
+        h = sig(a_og + c * pO) * np.tanh(c)
+        ys.append(h)
+    return np.stack(ys)
+
+
+def lstm_precision(report, T=100, H=256, early=4):
+    """Which side deviates at batch 1? With weights drawn at scale 0.2
+    (gates saturate, the recurrence amplifies) the compiled kernel and
+    the scan reference agree bitwise at B >= 2 and differ at B = 1,
+    late in the sequence by as much as 0.6. The other cases tame the
+    weights so that parity means something; this one keeps them and
+    asks a float64 host recurrence which device spelling is off: the
+    kernel, the reference as XLA compiles it by default, or neither
+    once the reference's matmuls run at ``highest`` precision. Errors
+    are max |y - y_f64| (outputs lie in [-1, 1]) over the first
+    ``early`` steps — before the recurrence amplifies anything — and
+    over all ``T``. ``PERF.md`` records the answer (PR 21: the kernel
+    is as accurate at B=1 as both spellings are at B=2; it is XLA's
+    reference that changes, to full f32, for a matrix-vector product)."""
+    from paddle_tpu.ops.lstm import lstm_sequence
+    entry = {"shape": f"T{T} H{H} float32, xs and w at scale 0.2",
+             "early_steps": early}
+    report["precision"] = {"lstm_b1_original_weights": entry}
+    rng = np.random.RandomState(1)
+    draw = lambda *s, scale=0.2: (  # noqa: E731
+        rng.randn(*s).astype(np.float32) * scale)
+    w = draw(H, 4 * H)
+    pI, pF, pO = (draw(H, scale=0.1) for _ in range(3))
+    zb = jnp.zeros((4 * H,), jnp.float32)
+
+    def err(y, y64):
+        d = np.abs(np.asarray(y, np.float64) - y64)
+        return {"early": float(d[:early].max()), "all": float(d.max())}
+
+    try:
+        for B in (1, 2):
+            xs = draw(T, B, 4 * H)
+            mask = jnp.ones((T, B), jnp.float32)
+            z = jnp.zeros((B, H), jnp.float32)
+            fn = lambda xs_, w_: lstm_sequence(  # noqa: E731
+                xs_, mask, w_, zb, jnp.asarray(pI), jnp.asarray(pF),
+                jnp.asarray(pO), z, z)[0]
+            args = (jnp.asarray(xs), jnp.asarray(w))
+            kernel, _, tally, n_mosaic = _run(fn, args, (), None)
+            xla, _, _, _ = _run(fn, args, (), "ref")
+            with jax.default_matmul_precision("highest"):
+                xla_hi, _, _, _ = _run(fn, args, (), "ref")
+            y64 = _lstm_host_f64(xs, w, pI, pF, pO)
+            entry[f"B{B}"] = {
+                "kernel_dispatch": tally, "tpu_custom_calls": n_mosaic,
+                "kernel_vs_f64": err(kernel, y64),
+                "xla_default_vs_f64": err(xla, y64),
+                "xla_highest_vs_f64": err(xla_hi, y64),
+                "kernel_vs_xla_default": err(kernel, np.asarray(
+                    xla, np.float64)),
+            }
+        b1, b2 = entry["B1"], entry["B2"]
+        entry["closest_to_f64_at_B1"] = min(
+            ("kernel", "xla_default"),
+            key=lambda k: b1[f"{k}_vs_f64"]["early"])
+        # the kernel at B=1 must be no less accurate than the kernel
+        # and the default reference are at B=2, where they agree
+        bound = 2 * max(b2["kernel_vs_f64"]["early"],
+                        b2["xla_default_vs_f64"]["early"]) + 1e-6
+        entry["ok"] = bool(b1["kernel_vs_f64"]["early"] <= bound)
+    except Exception as e:  # noqa: BLE001 — a refusal is the evidence
+        entry.update(ok=False, error=f"{type(e).__name__}: {e}"[:1500])
+        traceback.print_exc()
+    print(f"precision[lstm_b1]: {json.dumps(entry)}", flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(
@@ -346,6 +430,8 @@ def main(argv=None) -> int:
                   jnp.ones((8, 3), jnp.float32), blank=5)),
               (clp,))
 
+    lstm_precision(report)
+
     files = sorted({c["file"] for c in report["cases"].values()})
     report["files_covered"] = files
     report["all_cases_ok"] = all(c["ok"] for c in report["cases"].values())
@@ -356,6 +442,8 @@ def main(argv=None) -> int:
         f.write("\n")
     failed = [n for n, c in report["cases"].items() if not c["ok"]] + \
         [f"checkgrad:{n}" for n, c in report["checkgrad"].items()
+         if not c["ok"]] + \
+        [f"precision:{n}" for n, c in report["precision"].items()
          if not c["ok"]]
     print(json.dumps({"out": args.out, "cases": len(report["cases"]),
                       "failed": failed}))
