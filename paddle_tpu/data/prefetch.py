@@ -45,7 +45,8 @@ from typing import Callable, Optional, Sequence
 
 from paddle_tpu.obs import flight as _flight
 from paddle_tpu.utils.log import get_logger
-from paddle_tpu.utils.stat import StatRegistry, global_stat, timer
+from paddle_tpu.utils.profiler import StepBreakdown
+from paddle_tpu.utils.stat import StatRegistry, global_stat
 
 logger = get_logger("prefetch")
 
@@ -115,16 +116,25 @@ class PrefetchPipeline:
     idempotent and safe mid-stream; the context manager and generator
     ``close`` call it.
 
-    Timing: decode and H2D seconds accumulate into the stat registry
-    (``prefetch/decode``, ``prefetch/h2d``); consumer-side blocked time
-    accumulates into ``prefetch/wait`` and :attr:`data_wait` — the
-    numerator of the bench's ``data_wait_frac``.
+    Timing: the worker times each batch's four parts through
+    ``breakdown`` (``utils/profiler.py:StepBreakdown``): ``prefetch.read``
+    (the reader's ``next``), ``prefetch.decode`` (the feeder),
+    ``prefetch.h2d`` (the ``device_put`` call: it returns before the copy
+    ends) and ``prefetch.put_wait`` (blocked on the full queue), each a
+    ``totals`` key, a ``Stat`` (``prefetch/decode`` ...) and a span that
+    carries the batch's sequence number as ``step``. ``SGD.train`` hands
+    in its own breakdown, so the trainer's spans for step n sit beside
+    the worker's for batch n; a pipeline built elsewhere keeps a private
+    one over ``registry``. Consumer-side blocked time accumulates into
+    ``prefetch/wait`` and :attr:`data_wait` — the numerator of the
+    bench's ``data_wait_frac``.
     """
 
     def __init__(self, reader: Callable, feeder: Optional[Callable] = None,
                  mesh=None, depth: int = 2,
                  registry: Optional[StatRegistry] = None,
-                 place: bool = True):
+                 place: bool = True,
+                 breakdown: Optional[StepBreakdown] = None):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self._reader = reader
@@ -132,6 +142,8 @@ class PrefetchPipeline:
         self._mesh = mesh
         self._place = place
         self._registry = registry or global_stat
+        self._bd = breakdown or StepBreakdown(self._registry)
+        self._bd.new_stream()
         self._q: Queue = Queue(maxsize=depth)
         self._stop = threading.Event()
         self._closed = False
@@ -143,12 +155,12 @@ class PrefetchPipeline:
         self._thread.start()
 
     # ------------------------------------------------------------- worker
-    def _prepare(self, raw):
+    def _prepare(self, raw, n: int):
         if self._feeder is not None:
-            with timer("prefetch/decode", self._registry):
+            with self._bd.measure("prefetch_decode", n):
                 raw = self._feeder(raw)
         if self._place:
-            with timer("prefetch/h2d", self._registry):
+            with self._bd.measure("prefetch_h2d", n):
                 raw = self._device_put(raw)
         return raw
 
@@ -161,13 +173,19 @@ class PrefetchPipeline:
 
     def _work(self):
         try:
-            for raw in self._reader():
-                if self._stop.is_set():
+            source = iter(self._reader())
+            n = 0       # the batch's sequence number: the trainer's step
+            while not self._stop.is_set():
+                with self._bd.measure("prefetch_read", n):
+                    raw = next(source, _END)
+                if raw is _END:
+                    self._put(_END)
                     return
-                item = self._prepare(raw)
-                if not self._put(item):
-                    return
-            self._put(_END)
+                item = self._prepare(raw, n)
+                with self._bd.measure("prefetch_put_wait", n):
+                    if not self._put(item):
+                        return
+                n += 1
         except BaseException as e:  # noqa: BLE001 — crosses the thread
             self._put(_Failure(e))
 
@@ -292,6 +310,10 @@ class RecompileGuard:
         self.name = name
         self.warned = False
         self.hard_baseline: Optional[int] = None
+        # did the cache grow between the last two checks: the step in
+        # between compiled (the first check only takes the baseline)
+        self.grew = False
+        self._seen: Optional[int] = None
 
     @property
     def count(self) -> Optional[int]:
@@ -314,6 +336,9 @@ class RecompileGuard:
 
     def check(self) -> Optional[int]:
         n = self.count
+        self.grew = (n is not None and self._seen is not None
+                     and n > self._seen)
+        self._seen = n
         if (self.hard_baseline is not None and n is not None
                 and n > self.hard_baseline):
             if _flight._ACTIVE is not None:

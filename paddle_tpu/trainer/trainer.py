@@ -1527,9 +1527,13 @@ class SGD:
         device compute. A reader already wrapped by ``prefetch_reader``
         (``is_prefetched``) yields ready feeds and is consumed as such.
         ``show_step_breakdown`` logs the per-step host-time split
-        {data_wait, h2d, compute, callback} at each log_period and pass
-        end (``utils/profiler.py:StepBreakdown``; always accumulated —
-        the flag only controls logging) plus the per-device
+        {data_wait, h2d, compute, callback}, compute's own split into
+        {dispatch, device_wait} and the prefetch thread's parts at each
+        log_period and pass end (``utils/profiler.py:StepBreakdown``;
+        always accumulated — the flag only controls logging — and
+        always written as ``train.*`` / ``prefetch.*`` spans into a
+        running profiler session and an armed ``obs.trace`` Tracer)
+        plus the per-device
         parameter/optimizer-slot byte accounting
         (``utils/profiler.py:memory_stats``).
 
@@ -1599,7 +1603,7 @@ class SGD:
         sets it, ``False`` disables (unstacking the body back to flat
         parameters), ``None`` keeps the current mode. Configs or meshes
         the schedule cannot honor warn and stand down cleanly."""
-        from paddle_tpu.utils import global_stat, logger, timer
+        from paddle_tpu.utils import global_stat, logger
         self._configure_step(zero1, grad_accum_steps, pipeline, fsdp,
                              fsdp_overlap)
         self._configure_health(health, show_parameter_stats_period)
@@ -1785,11 +1789,14 @@ class SGD:
                     from paddle_tpu.data.prefetch import PrefetchPipeline
                     pipe = PrefetchPipeline(
                         lambda: _call_reader(reader, pass_id), feeder=feeder,
-                        mesh=self.mesh, depth=prefetch_depth)
+                        mesh=self.mesh, depth=prefetch_depth, breakdown=bd)
                     stream = iter(pipe)
                 else:
                     stream = iter(_call_reader(reader, pass_id))
                 batch_id = -1
+                # the batch's place in this pass's stream: the `step` of
+                # the trainer's spans and of the prefetch thread's
+                seq = -1
                 if resuming:
                     # exact-resume replay: discard the already-trained prefix
                     # (plain readers; a ledger-restored master reader yields
@@ -1799,55 +1806,61 @@ class SGD:
                     for _ in range(resume_skip):
                         if next(stream, _END_OF_PASS) is _END_OF_PASS:
                             break
+                        seq += 1
                     batch_id = resume_base - 1
                 try:
                     while True:
-                        t_step = time.perf_counter()
+                        seq += 1
+                        bd.step_begin(seq)
                         # blocked-on-data time: the sync reader's own cost, or
                         # the prefetch queue wait (near zero once it keeps up)
                         with bd.measure("data_wait"):
                             data = next(stream, _END_OF_PASS)
                         if data is _END_OF_PASS:
+                            bd.step_abandon()
                             break
                         batch_id += 1
                         event_handler(ev.BeginIteration(pass_id, batch_id))
                         if pipe is not None or pre_prepared:
                             feed = data  # decoded + sharded by the worker thread
                         else:
-                            with bd.measure("h2d"), timer("prepareBatchData"):
+                            with bd.measure("h2d"):
                                 feed = feeder(data) if feeder is not None else data
                                 if self.mesh is not None:
                                     feed = mesh_lib.shard_batch(feed, self.mesh)
-                        prev_rng = self._rng  # skip_batch rolls back here
-                        self._rng, step_rng = jax.random.split(self._rng)
-                        if self._carried is not None:
-                            # a batch-size change (e.g. smaller final batch) makes
-                            # the carried state unusable: reset, like the
-                            # reference's resetState on shape change
-                            b_feed = next(iter(feed.values())).value.shape[0]
-                            b_carry = jax.tree_util.tree_leaves(
-                                self._carried)[0].shape[0]
-                            if b_carry != b_feed:
-                                self._carried = None
-                        stats_on = self._train_step_stats is not None and (
-                            (batch_id + 1) % self._health_cfg.period == 0
-                            or self._stats_warm_pending)
-                        self._stats_warm_pending = False
-                        poison = None
-                        if hm is not None and self._health_cfg.sentry:
-                            fired = ()
-                            if _chaos._ACTIVE is not None:
-                                # the health plane's own chaos site: a
-                                # `corrupt` fault here poisons one
-                                # gradient leaf IN-GRAPH (the traced
-                                # `poison` scalar), the divergence-
-                                # sentry drill
-                                fired = _chaos._ACTIVE.hit(
-                                    "step_stats", pass_id=pass_id,
-                                    batch_id=batch_id) or ()
-                            poison = jnp.float32(
-                                1.0 if "corrupt" in fired else 0.0)
-                        with bd.measure("compute"), timer("trainBatch"):
+                        with bd.measure("dispatch"):
+                            prev_rng = self._rng  # skip_batch rolls back here
+                            self._rng, step_rng = jax.random.split(self._rng)
+                            if self._carried is not None:
+                                # a batch-size change (e.g. smaller final
+                                # batch) makes the carried state unusable:
+                                # reset, like the reference's resetState on
+                                # shape change
+                                b_feed = next(
+                                    iter(feed.values())).value.shape[0]
+                                b_carry = jax.tree_util.tree_leaves(
+                                    self._carried)[0].shape[0]
+                                if b_carry != b_feed:
+                                    self._carried = None
+                            stats_on = self._train_step_stats is not None and (
+                                (batch_id + 1) % self._health_cfg.period == 0
+                                or self._stats_warm_pending)
+                            self._stats_warm_pending = False
+                            poison = None
+                            if hm is not None and self._health_cfg.sentry:
+                                fired = ()
+                                if _chaos._ACTIVE is not None:
+                                    # the health plane's own chaos site: a
+                                    # `corrupt` fault here poisons one
+                                    # gradient leaf IN-GRAPH (the traced
+                                    # `poison` scalar), the divergence-
+                                    # sentry drill
+                                    fired = _chaos._ACTIVE.hit(
+                                        "step_stats", pass_id=pass_id,
+                                        batch_id=batch_id) or ()
+                                poison = jnp.float32(
+                                    1.0 if "corrupt" in fired else 0.0)
+                            t_compute = time.perf_counter()
                             step_fn = (self._train_step_stats if stats_on
                                        else self._train_step)
                             if hm is not None:
@@ -1862,12 +1875,21 @@ class SGD:
                                             feed, step_rng,
                                             jnp.int32(pass_id),
                                             self._carried)
-                            # the host fetch waits for the step, so the
-                            # "compute" bracket closes on finished work
+                        # the host fetch waits for the step: the trainer's
+                        # thread blocked on the device, and the `compute`
+                        # bracket (the reference's trainBatch) closes on
+                        # finished work
+                        with bd.measure("device_wait") as waited:
                             cost = float(metrics["cost"])
-                        (self.stats_recompile_guard if stats_on
-                         else self.recompile_guard).check()
-                        t_cb = time.perf_counter()
+                        bd.add("compute",
+                               waited.t0 + waited.seconds - t_compute)
+                        guard = (self.stats_recompile_guard if stats_on
+                                 else self.recompile_guard)
+                        guard.check()
+                        if guard.grew:
+                            # a slow step in a dump says that it compiled
+                            bd.mark_step(recompiled=True)
+                        in_callback = bd.measure("callback").start()
                         sentry_host = metrics.pop("sentry", None)
                         health_raw = metrics.pop("health", None)
                         health_lr = metrics.pop("health_lr", None)
@@ -1952,17 +1974,18 @@ class SGD:
                             # restores the generation just written
                             _chaos._ACTIVE.hit("step_done", pass_id=pass_id,
                                                batch_id=batch_id)
-                        bd.add("callback", time.perf_counter() - t_cb)
-                        # true wall denominator: work outside the four
-                        # brackets (BeginIteration handlers, rng split) shows
+                        in_callback.stop()
+                        # true wall denominator: work outside the brackets
+                        # (BeginIteration handlers, the guard's check) shows
                         # as a shortfall from 1.0 instead of inflating steps/s
-                        bd.step_done(time.perf_counter() - t_step)
+                        bd.step_done()
                 finally:
                     # the worker must not outlive this pass — a raising
                     # event handler / step / checkpointer (or Ctrl-C)
                     # would otherwise leak a thread holding `depth`
                     # device batches until GC (and a traceback pinning the
                     # frame defeats GC entirely)
+                    bd.step_abandon()   # a step that raised is no step
                     if pipe is not None:
                         pipe.close()
                     close = getattr(stream, "close", None)

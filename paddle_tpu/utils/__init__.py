@@ -12,11 +12,11 @@ from paddle_tpu.utils.stat import (Stat, StatRegistry, global_stat, timer,
 from paddle_tpu.utils.log import get_logger, logger
 from paddle_tpu.utils.error_context import (current_layer_stack, layer_scope,
                                             LayerStackError)
-from paddle_tpu.utils.profiler import StepBreakdown, profiler_trace
+from paddle_tpu.utils.profiler import StepBreakdown
 
 __all__ = [
     "Stat", "StatRegistry", "global_stat", "timer", "timer_guard",
     "get_logger", "logger",
     "current_layer_stack", "layer_scope", "LayerStackError",
-    "profiler_trace", "StepBreakdown",
+    "StepBreakdown",
 ]

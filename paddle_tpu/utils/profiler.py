@@ -1,37 +1,34 @@
-"""Device profiling bracket + host-side step-time breakdown.
+"""Host-side step-time breakdown, its spans, and device-memory accounting.
 
 The reference brackets regions with ``hl_profiler_start/end`` +
 ``GpuProfiler`` (``paddle/utils/Stat.h:282-300``, ``WITH_PROFILER``); the
-TPU-native equivalent is a jax profiler trace: every op inside the bracket
-lands in a TensorBoard-loadable trace with the per-layer ``named_scope``
-annotations from the graph executor.
+TPU-native equivalent is a jax profiler session
+(``jax.profiler.start_trace`` / ``stop_trace``, or the benchmark's
+``--trace 1``): every op lands in a TensorBoard-loadable trace with the
+per-layer ``named_scope`` annotations from the graph executor, and every
+site of :class:`StepBreakdown` lands beside them as a host span on the
+same clock.
 
-:class:`StepBreakdown` is the coarse host-side complement: per-step wall
-time split into {data-wait, h2d, compute, callback} so the first-order
-utilization question — is the chip waiting on the host? — is answerable
-without a trace. The trainer feeds it (``--show_step_breakdown``), the
-bench emits its summary as the CPU-side input-pipeline metric.
+:class:`StepBreakdown` is the one span site of the train path: the
+trainer's loop and the prefetch thread time each part of a step through
+``measure``, which feeds a counter (always), a span in the profiler's
+trace (when a session runs) and a span in ``obs.trace``'s buffer (when a
+``Tracer`` is armed), so the first-order utilization question — is the
+chip waiting on the host, and for which part of it? — is answerable from
+whichever of the three is at hand. The trainer feeds it
+(``--show_step_breakdown``), the benchmark reads its ``totals``.
 """
 
 from __future__ import annotations
 
+import threading
 import time
-from contextlib import contextmanager
+from typing import Optional
 
 import jax
 
+from paddle_tpu.obs import trace as _trace
 from paddle_tpu.utils.stat import StatRegistry, global_stat
-
-
-@contextmanager
-def profiler_trace(log_dir: str):
-    """``with profiler_trace("/tmp/trace"): step()`` — the
-    ``REGISTER_GPU_PROFILER`` bracket."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def _leaf_device_bytes(leaf) -> int:
@@ -203,41 +200,134 @@ def fsdp_overlap_stats(n_gathers: int, overlap: bool) -> dict:
     }
 
 
-class StepBreakdown:
-    """Per-step host-side wall-time split.
+# part -> (span name, Stat name). The span name is what the profiler's
+# trace and the Tracer's buffer show; its prefix tells the thread (both
+# host lines of a trace are called "python"). The Stat is what the
+# ``log_period`` dump prints: the reference's own timer names where it
+# had one (``prepareBatchData``, ``trainBatch``: ``TrainerInternal.cpp``).
+# ``compute`` is a counter with no span of its own: dispatch's and
+# device_wait's cover it.
+SITES = {
+    "data_wait": ("train.data_wait", "step/data_wait"),
+    "h2d": ("train.h2d", "prepareBatchData"),
+    "dispatch": ("train.dispatch", "step/dispatch"),
+    "device_wait": ("train.device_wait", "step/device_wait"),
+    "compute": (None, "trainBatch"),
+    "callback": ("train.callback", "step/callback"),
+    "prefetch_read": ("prefetch.read", "prefetch/read"),
+    "prefetch_decode": ("prefetch.decode", "prefetch/decode"),
+    "prefetch_h2d": ("prefetch.h2d", "prefetch/h2d"),
+    "prefetch_put_wait": ("prefetch.put_wait", "prefetch/put_wait"),
+}
 
-    Parts:
+# perf_counter -> wall clock, for the ``ts`` of a Tracer span: one
+# offset for the process, so a site reads one clock, once at each end
+_EPOCH = time.time() - time.perf_counter()
+
+
+class _Site:
+    """One timed site: ``with bd.measure(part): ...``. A class, not a
+    generator, because it runs ten times a step."""
+
+    __slots__ = ("bd", "part", "name", "step", "t0", "seconds", "_ann")
+
+    def __init__(self, bd, part, step):
+        self.bd, self.part, self.step = bd, part, step
+        self.name = SITES[part][0]
+        self.seconds = 0.0
+
+    def start(self):
+        # records nothing (and reads no clock) with no profiler session
+        self._ann = jax.profiler.TraceAnnotation(self.name, step=self.step)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def stop(self, *exc):
+        self.seconds = time.perf_counter() - self.t0
+        self._ann.__exit__(None, None, None)
+        bd = self.bd
+        bd.add(self.part, self.seconds)
+        if _trace._TRACER is not None:
+            bd._span(self.name, self.step, self.t0, self.seconds)
+
+    # ``with`` where the bracket is a block; start/stop where it spans
+    # most of a loop's body (a bracket left open by an exception is lost
+    # with its step: ``step_abandon``)
+    __enter__, __exit__ = start, stop
+
+
+class StepBreakdown:
+    """Per-step host-side wall-time split, and the train path's span site.
+
+    Parts timed on the trainer's thread (span ``train.<part>``):
 
     - ``data_wait`` — blocked pulling the next batch (the reader's own
       cost when synchronous; queue-wait when the async pipeline runs —
       near zero once prefetch keeps up).
     - ``h2d``      — feed conversion + device placement done on the
       trainer thread (``prepareBatchData``); with prefetch on this moves
-      into the worker (``prefetch/decode`` / ``prefetch/h2d`` stats) and
-      the trainer-side number collapses.
-    - ``compute``  — step dispatch through the device fetch
-      (``block_until_ready``-equivalent: a host read of the cost).
+      into the worker and the trainer-side number collapses.
+    - ``dispatch`` — from the feed in hand to the jitted step's return:
+      the rng split, the step's scalars, the jit call's argument
+      handling and enqueue. The device may already be running.
+    - ``device_wait`` — the host read of the cost alone: the trainer's
+      thread blocked on the device.
+    - ``compute``  — the jitted step's call through the cost fetch, the
+      bracket the reference calls ``trainBatch``: the tail of
+      ``dispatch`` plus ``device_wait``. A counter, no span.
     - ``callback`` — host evaluators, event handlers, periodic logging.
 
-    Every ``add`` also lands in the stat registry (``step/<part>``) so
-    the existing ``log_period`` dump shows the same numbers. ``summary``
-    yields the bench metrics: ``steps_per_sec`` and ``data_wait_frac``.
+    Parts timed on the prefetch thread (span ``prefetch.<part>``, key
+    ``prefetch_<part>``), concurrent with the trainer's and so outside
+    every sum over a step: ``read`` (the reader's ``next``), ``decode``
+    (the feeder), ``h2d`` (the ``device_put`` *call*: it returns before
+    the copy ends, the rest of the copy shows as the step's
+    ``device_wait``) and ``put_wait`` (blocked on the full queue: the
+    room the pipeline has over the trainer).
+
+    ``measure(part)`` does three things with one pair of clock reads:
+    adds the seconds to ``totals[part]`` and the part's ``Stat`` (so the
+    ``log_period`` dump shows the same numbers); runs the body under
+    ``jax.profiler.TraceAnnotation(<span>, step=n)``, which lands on the
+    host line of a profiler session's trace, on the clock of the
+    device's ``XLA Ops``, and records nothing without a session; and,
+    only when ``obs.trace`` has a ``Tracer`` armed
+    (``$PADDLE_TPU_TRACE_DIR``), keeps the span until the step is done
+    and then records it under that step's ``train.step`` span, one trace
+    per step. ``n`` is the batch's sequence number in its pass: the
+    prefetch thread's spans for batch n and the trainer's for step n
+    carry the same ``step``. Spans of a step that never finished are
+    dropped, never left with a dangling parent.
     """
 
+    # the four that partition a step; the other keys of ``totals`` are
+    # finer or concurrent brackets and stay outside ``total``
     PARTS = ("data_wait", "h2d", "compute", "callback")
+    PENDING_STEPS = 256     # steps whose spans the Tracer's sink holds
 
     def __init__(self, registry: StatRegistry = None):
         self.registry = registry or global_stat
+        # a registry's reset() zeroes its Stats in place, so they can be
+        # looked up once
+        self._stats = {part: self.registry.get(stat)
+                       for part, (_span, stat) in SITES.items()}
+        # Tracer sink only: spans waiting for their step to finish
+        self._lock = threading.Lock()
         self.reset()
 
     def reset(self):
         self.steps = 0
         self.wall = 0.0  # true per-step wall time, when the caller times it
-        self.totals = {p: 0.0 for p in self.PARTS}
+        # every key is here from the start: a reader that snapshots the
+        # totals (the benchmark's window) may subtract any of them
+        self.totals = dict.fromkeys(SITES, 0.0)
         # most recent single measurement per part: the health plane's
         # per-step timeline reads {data_wait, compute} from here
         # without having to delta the cumulative totals
-        self.last = {p: 0.0 for p in self.PARTS}
+        self.last = dict(self.totals)
+        self._step = None   # the open step: (n, t0, annotations, attrs)
+        self.new_stream()
         # set by SGD.enable_pipeline; reset() survives it (a pass reset
         # must not silently drop the schedule identity from summaries)
         if not hasattr(self, "pipeline"):
@@ -259,37 +349,117 @@ class StepBreakdown:
         self.fsdp = ((int(n_gathers), bool(overlap))
                      if n_gathers else None)
 
+    # ------------------------------------------------------------ sites
     def add(self, part: str, seconds: float):
         self.totals[part] += seconds
         self.last[part] = seconds
-        self.registry.get(f"step/{part}").add(seconds)
+        self._stats[part].add(seconds)
 
-    @contextmanager
-    def measure(self, part: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(part, time.perf_counter() - t0)
+    def measure(self, part: str, step: Optional[int] = None) -> _Site:
+        """Time one part of step ``step`` (default: the open step)."""
+        if step is None and self._step is not None:
+            step = self._step[0]
+        return _Site(self, part, step)
 
-    def step_done(self, wall_seconds: float = None):
-        """Count a finished step; pass the step's true wall time so
-        throughput and fractions use it as the denominator — work outside
-        the four measured brackets then shows up as a shortfall from 1.0
-        instead of silently inflating steps/s."""
+    def step_begin(self, n: int):
+        """Open step ``n`` of the pass: ``train.step`` in the traces,
+        under a ``StepTraceAnnotation`` so that TensorBoard's step
+        analysis finds it."""
+        anns = (jax.profiler.StepTraceAnnotation("train", step_num=n),
+                jax.profiler.TraceAnnotation("train.step", step=n))
+        for a in anns:
+            a.__enter__()
+        self._step = (n, time.perf_counter(), anns, {})
+
+    def mark_step(self, **attrs):
+        """Attributes of the open step's span (``recompiled=True``)."""
+        self._step[3].update(attrs)
+        self._step[2][1].set_metadata(**attrs)
+
+    def step_done(self):
+        """Count the open step as finished. Its true wall time, since
+        ``step_begin``, is the denominator of throughput and fractions —
+        work outside the measured brackets then shows up as a shortfall
+        from 1.0 instead of silently inflating steps/s."""
+        n, t0, _anns, attrs = self._close_step()
+        wall = time.perf_counter() - t0
         self.steps += 1
-        if wall_seconds is not None:
-            self.wall += wall_seconds
+        self.wall += wall
+        tracer = _trace._TRACER
+        if tracer is not None:
+            self._flush(tracer, n, t0, wall, attrs)
 
+    def step_abandon(self):
+        """Close an open step without counting it (end of pass found, or
+        the step raised); its spans are dropped. Idempotent."""
+        step = self._close_step()
+        if step is not None and _trace._TRACER is not None:
+            with self._lock:
+                self._pending.pop(step[0], None)
+
+    def _close_step(self):
+        step, self._step = self._step, None
+        if step is not None:
+            for a in reversed(step[2]):
+                a.__exit__(None, None, None)
+        return step
+
+    # ------------------------------------------------- the Tracer's sink
+    def new_stream(self):
+        """A new stream numbers its batches from 0 (each pass's
+        ``PrefetchPipeline`` calls this): spans the last one left
+        waiting belong to steps that never finished, and go."""
+        with self._lock:
+            self._pending = {}      # step -> [(name, t0, seconds)]
+            self._flushed = None    # (step, trace_id, span_id), the last
+
+    def _span(self, name, step, t0, seconds):
+        """Keep a finished span until its step is done. The prefetch
+        thread's ``put_wait`` for batch n can end just after step n
+        did: that one goes straight under the step's recorded span."""
+        with self._lock:
+            late = self._flushed
+            if late is None or late[0] != step:
+                late = None
+                self._pending.setdefault(step, []).append(
+                    (name, t0, seconds))
+                if len(self._pending) > self.PENDING_STEPS:
+                    # steps nobody finishes (batches skipped on a
+                    # resume, a pipeline with no trainer behind it)
+                    del self._pending[next(iter(self._pending))]
+        tracer = _trace._TRACER
+        if late is not None and tracer is not None:
+            self._emit(tracer, late[1], late[2], name, step, t0, seconds)
+
+    @staticmethod
+    def _emit(tracer, trace_id, parent_id, name, step, t0, seconds):
+        tracer.record_span(name, trace_id=trace_id, parent_id=parent_id,
+                           ts=_EPOCH + t0, dur_ms=1e3 * seconds, step=step)
+
+    def _flush(self, tracer, n, t0, wall, attrs):
+        """Record step ``n``: its children first, so that the bounded
+        buffer never evicts a parent before its child."""
+        trace_id, span_id = _trace.new_trace_id(), _trace.new_span_id()
+        with self._lock:
+            spans = self._pending.pop(n, ())
+            self._flushed = (n, trace_id, span_id)
+        for name, s0, seconds in spans:
+            self._emit(tracer, trace_id, span_id, name, n, s0, seconds)
+        tracer.record("train.step",
+                      _trace.TraceContext(trace_id, span_id, None),
+                      ts=_EPOCH + t0, dur_ms=1e3 * wall, step=n, **attrs)
+
+    # ---------------------------------------------------------- readings
     @property
     def total(self) -> float:
-        return self.wall if self.wall > 0 else sum(self.totals.values())
+        return self.wall if self.wall > 0 else sum(
+            self.totals[p] for p in self.PARTS)
 
     def summary(self) -> dict:
         total = self.total
         out = {"steps": self.steps,
                "steps_per_sec": (self.steps / total) if total > 0 else 0.0}
-        for p in self.PARTS:
+        for p in self.totals:
             out[f"{p}_frac"] = (self.totals[p] / total) if total > 0 else 0.0
             out[f"{p}_ms_per_step"] = (
                 1e3 * self.totals[p] / self.steps if self.steps else 0.0)
@@ -303,7 +473,7 @@ class StepBreakdown:
         s = self.summary()
         parts = " ".join(
             f"{p}={s[f'{p}_ms_per_step']:.2f}ms({s[f'{p}_frac'] * 100:.1f}%)"
-            for p in self.PARTS)
+            for p in self.totals)
         pipe = ""
         if self.pipeline is not None:
             pipe = (f" pipeline=S{s['pipeline_stages']}/M"

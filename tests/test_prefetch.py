@@ -105,6 +105,85 @@ def test_prefetch_records_wait_and_decode_stats():
     assert pipe.data_wait >= 0.0
 
 
+def test_prefetch_times_its_four_parts_into_a_handed_breakdown():
+    from paddle_tpu.utils.profiler import StepBreakdown
+    reg = StatRegistry("t")
+    bd = StepBreakdown(reg)
+    def reader():
+        for i in range(4):
+            time.sleep(0.002)
+            yield [i]
+
+    def feeder(b):
+        time.sleep(0.002)
+        return b
+
+    pipe = PrefetchPipeline(reader, feeder=feeder, place=False, depth=1,
+                            breakdown=bd)
+    time.sleep(0.1)     # the worker fills the queue and blocks on it
+    assert list(pipe) == [[0], [1], [2], [3]]
+    assert bd.totals["prefetch_read"] >= 4 * 0.002
+    assert bd.totals["prefetch_decode"] >= 4 * 0.002
+    assert bd.totals["prefetch_put_wait"] > 0.05    # it waited for us
+    assert bd.totals["prefetch_h2d"] == 0.0         # place=False
+    # the worker adds to its own keys alone
+    assert all(bd.totals[p] == 0.0 for p in
+               ("data_wait", "h2d", "compute", "callback", "dispatch",
+                "device_wait"))
+    assert reg.get("prefetch/read").count == 5      # the last finds the end
+    assert reg.get("prefetch/decode").count == 4
+    assert reg.get("prefetch/put_wait").count == 4
+    assert reg.get("prefetch/h2d").count == 0
+
+
+def test_prefetch_built_elsewhere_keeps_a_private_breakdown():
+    reg = StatRegistry("t")
+    a = PrefetchPipeline(lambda: iter([[1]]), feeder=lambda b: b,
+                         place=False, registry=reg)
+    b = PrefetchPipeline(lambda: iter([[2]]), feeder=lambda b: b,
+                         place=False, registry=reg)
+    assert list(a) == [[1]] and list(b) == [[2]]
+    assert a._bd is not b._bd and a._bd.registry is reg
+    assert a._bd.totals["prefetch_decode"] > 0
+    assert reg.get("prefetch/decode").count == 2
+
+
+def test_prefetch_spans_carry_the_batchs_sequence_number():
+    from paddle_tpu.obs import trace
+    from paddle_tpu.utils.profiler import StepBreakdown
+    bd = StepBreakdown(StatRegistry("t"))
+    trace.install(trace.Tracer("test"))
+    try:
+        pipe = PrefetchPipeline(lambda: iter("abc"), feeder=lambda b: b,
+                                place=False, breakdown=bd)
+        assert list(pipe) == ["a", "b", "c"]
+        pipe._thread.join(timeout=5.0)
+        assert not pipe._thread.is_alive()
+    finally:
+        trace.install(None)
+    # no step finished: the spans wait, keyed by the batch's number
+    waiting = {n: sorted(name for name, _t0, _s in spans)
+               for n, spans in bd._pending.items()}
+    batch = ["prefetch.decode", "prefetch.put_wait", "prefetch.read"]
+    assert waiting == {0: batch, 1: batch, 2: batch, 3: ["prefetch.read"]}
+
+
+def test_recompile_guard_says_when_the_cache_grew():
+    f = jax.jit(lambda x: x + 1)
+    guard = RecompileGuard(f)
+    f(jnp.zeros(2))
+    guard.check()
+    assert guard.grew is False      # the first check takes the baseline
+    f(jnp.zeros(2))
+    guard.check()
+    assert guard.grew is False
+    f(jnp.zeros(3))
+    guard.check()
+    assert guard.grew is True
+    guard.check()
+    assert guard.grew is False
+
+
 def test_prefetch_reader_wrapper_marks_and_streams():
     r = prefetch_reader(lambda: iter([1, 2, 3]), place=False)
     assert r.is_prefetched
