@@ -1,4 +1,4 @@
-"""DataFeeder: python samples -> device Arguments.
+"""DataFeeder: python samples -> host Arguments.
 
 Replaces ``py_paddle.DataProviderConverter`` (``paddle/py_paddle/
 dataprovider_converter.py``) + the SWIG ``Arguments`` assembly: given input
@@ -10,14 +10,21 @@ padded lengths, and ``batch_buckets`` pads short (e.g. final partial)
 batches up to a bucketed row count with all-masked rows plus a
 ``ROW_MASK_KEY`` feed entry the trainer uses to ignore them exactly
 (zero loss, zero grad — see ``trainer/trainer.py:_total_cost``).
+
+Every leaf of the feed is a ``numpy.ndarray``: the feeder builds on the
+host and places nothing. Placement belongs to whoever times it: the
+prefetch worker's ``prefetch.h2d`` (``data/prefetch.py``), the trainer's
+``train.h2d`` on the synchronous path, or the jitted function a feed is
+handed to, which takes numpy leaves as they are.
 """
 
 from __future__ import annotations
 
+import collections
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
-import jax.numpy as jnp
 
 from paddle_tpu.core.argument import Argument
 from paddle_tpu.data import types as T
@@ -44,6 +51,56 @@ def _zero_sample(itype: T.InputType):
     if itype.type in (T.SPARSE_BINARY, T.SPARSE_FLOAT):
         return []
     return np.zeros(itype.dim, dtype=np.float32)
+
+
+class _Staging:
+    """Host memory for the dense batches of one feeder, handed out again
+    once nothing refers to the batch that had it.
+
+    A 154 MB batch in fresh memory costs ten times its copy: the
+    allocator maps new pages for every array of that size and the first
+    write faults each one in (PERF.md, PR 26: 167 ms fresh against 15 ms
+    in memory written before, on the chip's host). So ``empty`` hands
+    out arrays over blocks it keeps, and takes a block back at the moment
+    numpy would have freed it: when the array and every view of it are
+    gone. A batch therefore never changes under anyone who can still
+    read it, on any backend and from any thread. That covers a transfer
+    in flight: the runtime keeps the host array it reads from alive until
+    the copy is done (or, where a CPU device array aliases it, for that
+    array's life), exactly as it must for memory numpy frees.
+
+    No lock: the release runs wherever the last reference drops, maybe
+    inside another call of this class on the same thread. Every step is
+    one atomic deque operation; two callers racing for one block cost a
+    fresh allocation, never a shared block."""
+
+    KEEP = 4        # free blocks kept; one more is freed as numpy would
+
+    def __init__(self):
+        self._free = collections.deque(maxlen=self.KEEP)
+        self.allocated = 0      # blocks ever made: a steady stream stops
+
+    def empty(self, shape: Tuple[int, ...]) -> np.ndarray:
+        """An uninitialised float32 array of ``shape``."""
+        block = self._take(4 * int(np.prod(shape, dtype=np.int64)))
+        # the array over a block is no view of another array (its base is
+        # the block's memoryview), so every view of it keeps *it* alive
+        # and its end is the end of all of them
+        root = np.frombuffer(block, dtype=np.float32)
+        weakref.finalize(root, self._free.append, block).atexit = False
+        return root.reshape(shape)
+
+    def _take(self, nbytes: int) -> memoryview:
+        for _ in range(len(self._free)):
+            try:
+                block = self._free.popleft()
+            except IndexError:
+                break
+            if block.nbytes == nbytes:
+                return block
+            self._free.append(block)    # another shape's: back in line
+        self.allocated += 1
+        return memoryview(np.empty(nbytes, dtype=np.uint8))
 
 
 class DataFeeder:
@@ -91,6 +148,7 @@ class DataFeeder:
         self.batch_buckets = (sorted(int(b) for b in batch_buckets)
                               if batch_buckets else None)
         self.shared_length_bucket = bool(shared_length_bucket)
+        self._staging = _Staging()
 
     def _pad_len(self, raw_max: int) -> int:
         if self.length_buckets is not None:
@@ -139,7 +197,7 @@ class DataFeeder:
             feed[name] = self._convert_one(self.feeding[name], col, name,
                                            pad_to=pad_to)
         if row_mask is not None:
-            feed[ROW_MASK_KEY] = Argument(value=jnp.asarray(row_mask))
+            feed[ROW_MASK_KEY] = Argument(value=row_mask)
         return feed
 
     __call__ = convert
@@ -164,6 +222,25 @@ class DataFeeder:
                 "jitted table lookup maps such ids to zero rows instead "
                 "of raising — fix the data or the declared dimension.")
 
+    def _stack_dense(self, col: Sequence, name: str) -> np.ndarray:
+        """Rows -> one float32 array, a copy a row. Array assignment
+        runs without the GIL and writes into memory the feeder has used
+        before; ``np.asarray(col)`` holds neither promise. Values, shape
+        and dtype are what ``np.asarray(col, dtype=np.float32)`` gives."""
+        shape = np.shape(col[0])
+        out = self._staging.empty((len(col),) + shape)
+        for i, row in enumerate(col):
+            if not isinstance(row, np.ndarray):
+                row = np.asarray(row)
+            if row.shape != shape:
+                # assignment would broadcast a short row in silence
+                raise ValueError(
+                    f"input {name!r}: row {i} has shape {row.shape}, "
+                    f"row 0 has {shape}; a dense_vector batch needs "
+                    "rows of one shape")
+            out[i] = row
+        return out
+
     def _convert_one(self, itype: T.InputType, col: Sequence,
                      name: str = "?",
                      pad_to: Optional[int] = None) -> Argument:
@@ -171,10 +248,9 @@ class DataFeeder:
             if itype.type == T.INDEX:
                 arr = np.asarray(col, dtype=np.int32)
                 self._check_ids(name, itype, arr)
-                return Argument(value=jnp.asarray(arr))
+                return Argument(value=arr)
             if itype.type == T.DENSE:
-                return Argument(value=jnp.asarray(
-                    np.asarray(col, dtype=np.float32)))
+                return Argument(value=self._stack_dense(col, name))
             if itype.type in (T.SPARSE_BINARY, T.SPARSE_FLOAT):
                 dense = np.zeros((len(col), itype.dim), dtype=np.float32)
                 for i, idxs in enumerate(col):
@@ -183,7 +259,7 @@ class DataFeeder:
                     else:
                         for j, v in idxs:
                             dense[i, j] = v
-                return Argument(value=jnp.asarray(dense))
+                return Argument(value=dense)
             raise KeyError(itype.type)
         if itype.seq_type == T.SUB_SEQUENCE:
             # nested: sample = list of sub-sequences -> [B, S, T(, D)]
@@ -222,8 +298,7 @@ class DataFeeder:
                                 for k, v in idxs:
                                     value[i, j, t, k] = v
                             mask[i, j, t] = 1.0
-            return Argument(value=jnp.asarray(value),
-                            mask=jnp.asarray(mask))
+            return Argument(value=value, mask=mask)
         # sequences: pad to multiple / bucket edge for shape bucketing
         # (pad_to = the batch-wide shared bucket, shared_length_bucket)
         max_len = pad_to or self._pad_len(max(len(s) for s in col))
@@ -254,4 +329,4 @@ class DataFeeder:
                 arr = np.asarray(s, dtype=np.float32).reshape(len(s), itype.dim)
                 value[i, : len(s)] = arr
                 mask[i, : len(s)] = 1.0
-        return Argument(value=jnp.asarray(value), mask=jnp.asarray(mask))
+        return Argument(value=value, mask=mask)

@@ -8,7 +8,10 @@ bounded background-thread pipeline that finishes each batch with a
 **sharded ``jax.device_put``** so the H2D copy (and any cross-device
 scatter) is already in flight when the trainer asks for the batch; XLA's
 async dispatch does the rest (the jitted step for batch N executes while
-the host prepares N+1).
+the host prepares N+1). The feeder hands the worker host arrays and the
+worker starts the one transfer: the call returns at once and the copy
+runs beside the assembly of the next batch, so a batch costs the worker
+the larger of the two, not their sum.
 
 Three pieces:
 
@@ -103,10 +106,12 @@ class PrefetchPipeline:
     ``reader``: zero-arg callable returning an iterable of raw batches
     (the trainer's usual minibatch reader). ``feeder``: optional
     batch -> feed-dict converter (``DataFeeder`` or any callable) run in
-    the worker — this is where decode/pad/bucket cost lives. ``mesh``:
-    when given, batches land sharded over the data axis
+    the worker — this is where decode/pad/bucket cost lives; it returns
+    host arrays. ``mesh``: when given, batches go from the host straight
+    to each device's shard of the data axis
     (``parallel/mesh.py:shard_batch``); otherwise a plain
-    ``jax.device_put`` starts the H2D copy early. ``depth``: batches in
+    ``jax.device_put`` starts the H2D copy early. ``place=False`` hands
+    the feeder's host arrays on as they are. ``depth``: batches in
     flight (2 = the reference's double buffer).
 
     Iterate it (or call :meth:`get`) to consume; iteration ends at the
@@ -118,9 +123,12 @@ class PrefetchPipeline:
 
     Timing: the worker times each batch's four parts through
     ``breakdown`` (``utils/profiler.py:StepBreakdown``): ``prefetch.read``
-    (the reader's ``next``), ``prefetch.decode`` (the feeder),
-    ``prefetch.h2d`` (the ``device_put`` call: it returns before the copy
-    ends) and ``prefetch.put_wait`` (blocked on the full queue), each a
+    (the reader's ``next``), ``prefetch.decode`` (the feeder: building
+    the batch in host memory, nothing else), ``prefetch.h2d`` (the
+    ``device_put`` call that starts the copy of those host arrays; on
+    the v5e's host it returns in 0.4-0.6 ms for 154 MB and the copy takes
+    another 17 ms beside the worker, PERF.md PR 26) and
+    ``prefetch.put_wait`` (blocked on the full queue), each a
     ``totals`` key, a ``Stat`` (``prefetch/decode`` ...) and a span that
     carries the batch's sequence number as ``step``. ``SGD.train`` hands
     in its own breakdown, so the trainer's spans for step n sit beside
@@ -254,8 +262,9 @@ def prefetch_reader(reader: Callable, feeder: Optional[Callable] = None,
                     place: bool = True) -> Callable:
     """Decorator form: wrap a batched reader so each call streams through
     a fresh :class:`PrefetchPipeline`. The result yields *prepared feeds*
-    (already through the feeder and on device), so it marks itself
-    ``is_prefetched`` — the trainer skips its own feeder/shard step."""
+    (already through the feeder and, unless ``place=False``, on device),
+    so it marks itself ``is_prefetched`` — the trainer skips its own
+    feeder/shard step."""
 
     pass_aware = getattr(reader, "pass_aware", False)
 

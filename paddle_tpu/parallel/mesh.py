@@ -226,7 +226,8 @@ def batch_sharding(mesh: Mesh, ndim: int = 1) -> NamedSharding:
 
 def shard_batch(feed: Dict[str, Argument], mesh: Mesh) -> Dict[str, Argument]:
     """Place a feed dict with the batch dim split over the data axis (and
-    the dcn axis on a multi-slice mesh)."""
+    the dcn axis on a multi-slice mesh). Host leaves (a ``DataFeeder``'s)
+    go from the host straight to each device's shard."""
 
     n_data = data_parallel_degree(mesh)
 

@@ -509,8 +509,6 @@ class ServingPredictor:
         """rows -> feed dict through the bucketing feeder. ``lane_valid``
         (bool per row) zeroes the row mask of known-bad lanes so they are
         exact padding."""
-        import jax.numpy as jnp
-
         from paddle_tpu.data.feeder import ROW_MASK_KEY
         feed = self.feeder(list(rows))
         # runtime twin of graftlint PT102: every mask the feeder built
@@ -520,8 +518,10 @@ class ServingPredictor:
             mask = feed[ROW_MASK_KEY]
             lv = np.ones(mask.value.shape[0], dtype=np.float32)
             lv[:len(lane_valid)] = np.asarray(lane_valid, np.float32)
-            feed[ROW_MASK_KEY] = mask.replace(
-                value=mask.value * jnp.asarray(lv))
+            # host arithmetic: every leaf stays the feeder's kind (a
+            # device array here would be a new entry in the jit cache
+            # the hardened guard counts)
+            feed[ROW_MASK_KEY] = mask.replace(value=mask.value * lv)
         return feed
 
     def _bucket_key(self, feed) -> Tuple[str, int]:

@@ -710,6 +710,8 @@ def cmd_time(ns, args):
     sig0 = None
     for i, data in enumerate(batches):
         feed = feeder(data) if feeder is not None else data
+        # the feeder's host arrays, placed outside the timed step
+        feed = jax.device_put(feed)
         sig = shape_sig(feed)
         sig0 = sig0 or sig
         trainer._rng, step_rng = jax.random.split(trainer._rng)

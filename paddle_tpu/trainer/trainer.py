@@ -1826,8 +1826,13 @@ class SGD:
                         else:
                             with bd.measure("h2d"):
                                 feed = feeder(data) if feeder is not None else data
+                                # the feeder's leaves are host arrays: the
+                                # copy starts here, under its own bracket,
+                                # not inside the step's dispatch
                                 if self.mesh is not None:
                                     feed = mesh_lib.shard_batch(feed, self.mesh)
+                                else:
+                                    feed = jax.device_put(feed)
                         with bd.measure("dispatch"):
                             prev_rng = self._rng  # skip_batch rolls back here
                             self._rng, step_rng = jax.random.split(self._rng)

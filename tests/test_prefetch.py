@@ -168,6 +168,197 @@ def test_prefetch_spans_carry_the_batchs_sequence_number():
     assert waiting == {0: batch, 1: batch, 2: batch, 3: ["prefetch.read"]}
 
 
+# ------------------------------------- the worker as built: one stage,
+# read -> decode (host arrays) -> h2d (the one placement) -> put
+def _pull(pipe, timeout=10.0):
+    """``pipe.get()`` that cannot hang the suite."""
+    box = []
+
+    def run():
+        try:
+            box.append((True, pipe.get()))
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            box.append((False, e))
+
+    th = threading.Thread(target=run, daemon=True)
+    th.start()
+    th.join(timeout)
+    assert box, f"pipe.get() still blocked after {timeout} s"
+    ok, value = box[0]
+    if not ok:
+        raise value
+    return value
+
+
+def _workers():
+    return [t for t in threading.enumerate()
+            if t.name == "prefetch-worker" and t.is_alive()]
+
+
+def _host_feed(i):
+    return {"x": np.full((2, 3), i, np.float32),
+            "n": np.asarray([i], np.int32)}
+
+
+def test_order_holds_over_50_placed_batches_with_a_feeder_of_random_delay():
+    rng = np.random.RandomState(0)
+    delays = rng.uniform(0.0, 0.004, size=50)
+
+    def feeder(i):
+        time.sleep(delays[i])
+        return _host_feed(i)
+
+    pipe = PrefetchPipeline(lambda: iter(range(50)), feeder=feeder,
+                            depth=3)
+    got = []
+    for i in range(50):
+        if i % 7 == 0:
+            time.sleep(0.003)       # a consumer that is late at times
+        got.append(_pull(pipe))
+    with pytest.raises(StopIteration):
+        _pull(pipe)
+    assert [int(f["n"][0]) for f in got] == list(range(50))
+    for i, f in enumerate(got):
+        assert isinstance(f["x"], jax.Array)
+        np.testing.assert_array_equal(np.asarray(f["x"]),
+                                      _host_feed(i)["x"])
+    pipe._thread.join(timeout=5.0)
+    assert not pipe._thread.is_alive()
+
+
+@pytest.mark.parametrize("where", ["feeder", "placement"])
+def test_a_failure_re_raises_at_its_queue_position(where):
+    def feeder(i):
+        if i == 3:
+            if where == "feeder":
+                raise KeyError("batch 3 is bad")
+            return {"x": object()}      # nothing device_put can place
+        return _host_feed(i)
+
+    pipe = PrefetchPipeline(lambda: iter(range(6)), feeder=feeder, depth=2)
+    time.sleep(0.2)         # the worker runs into the failure first
+    for i in range(3):      # the batches before it still drain, in order
+        assert int(_pull(pipe)["n"][0]) == i
+    with pytest.raises(KeyError if where == "feeder" else TypeError):
+        _pull(pipe)
+    with pytest.raises(StopIteration):
+        _pull(pipe)
+    pipe._thread.join(timeout=5.0)
+    assert not _workers()
+
+
+@pytest.mark.parametrize("stuck_in", ["put_wait", "feeder", "reader"])
+def test_close_mid_stream_returns_in_time_with_every_thread_gone(stuck_in):
+    entered = threading.Event()
+
+    def reader():
+        for i in range(1000):
+            if stuck_in == "reader" and i == 4:
+                entered.set()
+                time.sleep(0.5)
+            yield i
+
+    def feeder(i):
+        if stuck_in == "feeder" and i == 4:
+            entered.set()
+            time.sleep(0.5)
+        return _host_feed(i)
+
+    assert not _workers()
+    pipe = PrefetchPipeline(reader, feeder=feeder, depth=2)
+    assert int(_pull(pipe)["n"][0]) == 0
+    if stuck_in == "put_wait":
+        time.sleep(0.2)     # the queue fills, the worker blocks on it
+    else:
+        assert _pull(pipe) is not None and _pull(pipe) is not None
+        assert entered.wait(timeout=5.0)
+    t0 = time.perf_counter()
+    pipe.close()
+    assert time.perf_counter() - t0 < 5.0       # close()'s join timeout
+    assert not pipe._thread.is_alive() and not _workers()
+    with pytest.raises(StopIteration):
+        _pull(pipe)
+
+
+def test_batches_in_flight_never_exceed_depth_plus_the_one_stage():
+    depth = 2
+    made, taken, worst = [], [], []
+
+    def feeder(i):
+        made.append(i)
+        worst.append(len(made) - len(taken))
+        return _host_feed(i)
+
+    pipe = PrefetchPipeline(lambda: iter(range(40)), feeder=feeder,
+                            depth=depth)
+    for i in range(40):
+        if i % 5 == 0:
+            time.sleep(0.02)    # let the worker run as far ahead as it may
+        _pull(pipe)
+        taken.append(i)
+    # the queue's `depth`, one in the worker's hands, and the one the
+    # consumer has pulled but not yet counted
+    assert max(worst) <= depth + 1 + 1
+    assert max(worst) >= depth      # it did run ahead
+    pipe.close()
+    assert not _workers()
+
+
+def test_spans_of_batch_n_carry_step_n_in_every_stage():
+    from paddle_tpu.obs import trace
+    from paddle_tpu.utils.profiler import StepBreakdown
+    bd = StepBreakdown(StatRegistry("t"))
+    trace.install(trace.Tracer("test"))
+    try:
+        pipe = PrefetchPipeline(lambda: iter(range(5)), feeder=_host_feed,
+                                breakdown=bd)
+        got = [int(_pull(pipe)["n"][0]) for _ in range(5)]
+        with pytest.raises(StopIteration):
+            _pull(pipe)
+        pipe._thread.join(timeout=5.0)
+        assert not pipe._thread.is_alive()
+    finally:
+        trace.install(None)
+    assert got == list(range(5))
+    waiting = {n: sorted(name for name, _t0, _s in spans)
+               for n, spans in bd._pending.items()}
+    stages = ["prefetch.decode", "prefetch.h2d", "prefetch.put_wait",
+              "prefetch.read"]
+    assert waiting == {**{n: stages for n in range(5)},
+                       5: ["prefetch.read"]}
+    # and in its own order within a batch: read, decode, h2d, put_wait
+    for n in range(5):
+        by_start = sorted(bd._pending[n], key=lambda s: s[1])
+        assert [name for name, _t0, _s in by_start] == [
+            "prefetch.read", "prefetch.decode", "prefetch.h2d",
+            "prefetch.put_wait"]
+
+
+def test_the_worker_places_what_a_real_feeder_built_on_the_host():
+    feeder = DataFeeder({"x": dense_vector(4), "y": integer_value(3)})
+    rng = np.random.RandomState(2)
+    batches = [[(rng.randn(4).astype(np.float32), int(rng.randint(3)))
+                for _ in range(4)] for _ in range(12)]
+    built = []
+
+    def spy(rows):
+        feed = feeder(rows)
+        built.append({k: (type(a.value), a.value.copy())
+                      for k, a in feed.items()})
+        return feed
+
+    pipe = PrefetchPipeline(lambda: iter(batches), feeder=spy, depth=2)
+    got = [_pull(pipe) for _ in batches]
+    for feed, host in zip(got, built):
+        for k, (kind, value) in host.items():
+            assert kind is np.ndarray
+            assert isinstance(feed[k].value, jax.Array)
+            np.testing.assert_array_equal(np.asarray(feed[k].value), value)
+    pipe.close()
+    # twelve batches went through the memory of a few
+    assert feeder._staging.allocated <= 2 + 1 + 1
+
+
 def test_recompile_guard_says_when_the_cache_grew():
     f = jax.jit(lambda x: x + 1)
     guard = RecompileGuard(f)

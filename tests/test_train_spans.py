@@ -53,6 +53,15 @@ def _train(t, batches, **kw):
     return t.breakdown
 
 
+def _ours(spans):
+    """The train path's spans. A thread that another test file left
+    behind in this worker (a master client's heartbeat) records into
+    whatever Tracer is installed; which files share a worker changes
+    with every file the suite gains."""
+    return [s for s in spans
+            if s["name"].startswith(("train", "prefetch."))]
+
+
 @pytest.fixture
 def tracer():
     t = trace.install(trace.Tracer("test"))
@@ -158,6 +167,59 @@ def test_counters_nest_and_cover_the_step(async_load_data):
     assert (t["h2d"] > 0) == (not async_load_data)
 
 
+@pytest.mark.parametrize("async_load_data", [True, False])
+def test_h2d_brackets_the_call_that_places_the_feeders_host_arrays(
+        tracer, monkeypatch, async_load_data):
+    """The feeder hands over numpy; ``train.h2d`` (synchronous) or
+    ``prefetch.h2d`` (the worker) holds the ``device_put`` of exactly
+    those arrays; the step is called with device arrays."""
+    from paddle_tpu.utils import profiler
+    t = _trainer()
+    fed, placed, stepped = [], [], []
+    leaves = jax.tree_util.tree_leaves
+
+    def feeder(rows):
+        feed = FEEDER(rows)
+        fed.append({type(leaf) for leaf in leaves(feed)})
+        return feed
+
+    put = jax.device_put
+
+    def spying_put(tree, *a, **kw):
+        t0 = time.perf_counter()
+        out = put(tree, *a, **kw)
+        if isinstance(tree, dict):      # a feed, not the step's scalars
+            placed.append(({type(leaf) for leaf in leaves(tree)},
+                           threading.current_thread().name,
+                           profiler._EPOCH + t0,
+                           profiler._EPOCH + time.perf_counter()))
+        return out
+
+    step = t._train_step
+
+    def spying_step(params, opt_state, feed, *rest):
+        stepped.append({isinstance(leaf, jax.Array)
+                        for leaf in leaves(feed)})
+        return step(params, opt_state, feed, *rest)
+
+    monkeypatch.setattr(jax, "device_put", spying_put)
+    t._train_step = spying_step
+    t.train(lambda: iter(_batches()), feeder=feeder, num_passes=1,
+            async_load_data=async_load_data)
+    assert fed == [{np.ndarray}] * STEPS
+    assert stepped == [{True}] * STEPS
+    assert [p[0] for p in placed] == [{np.ndarray}] * STEPS
+    name = "prefetch.h2d" if async_load_data else "train.h2d"
+    spans = {s["attrs"]["step"]: s for s in _ours(tracer.spans())
+             if s["name"] == name}
+    assert sorted(spans) == list(range(STEPS))
+    for n, (_types, thread, t0, t1) in enumerate(placed):
+        assert (thread == "prefetch-worker") == async_load_data
+        span = spans[n]
+        assert span["ts"] <= t0 + 1e-6
+        assert t1 <= span["ts"] + span["dur_ms"] / 1e3 + 1e-6
+
+
 def test_new_keys_are_zero_from_reset_and_outside_total():
     bd = StepBreakdown()
     assert set(bd.totals) == set(SITES)
@@ -194,7 +256,7 @@ def _check_pt401(tracer, tmp_path):
     from paddle_tpu.analysis.bench_schema import check_bench_file
     findings = check_bench_file(str(artifact), "TRACE_train.json")
     assert findings == [], [f.message for f in findings]
-    return dumped
+    return _ours(dumped)
 
 
 @pytest.mark.parametrize("async_load_data", [True, False])
@@ -238,7 +300,7 @@ def test_unarmed_step_makes_no_id_and_reaches_no_tracer(monkeypatch):
 def test_a_shape_change_marks_exactly_that_step_recompiled(tracer):
     sizes = (8, 8, 8, 4, 4, 8)
     _train(_trainer(), _batches(sizes), async_load_data=True)
-    steps = [s for s in tracer.spans() if s["name"] == "train.step"]
+    steps = [s for s in _ours(tracer.spans()) if s["name"] == "train.step"]
     assert [s["attrs"].get("recompiled", False) for s in steps] == \
         [False, False, False, True, False, False]
 
@@ -281,7 +343,7 @@ def test_a_step_that_raised_leaves_no_span(tracer, tmp_path):
     # the next run starts clean: what the worker had in flight is gone
     tracer.clear()
     _train(t, _batches((8, 8)), async_load_data=True)
-    assert [s["attrs"]["step"] for s in tracer.spans()
+    assert [s["attrs"]["step"] for s in _ours(tracer.spans())
             if s["name"] == "train.step"] == [0, 1]
 
 
@@ -296,7 +358,7 @@ def test_a_span_that_ends_after_its_step_goes_under_it(tracer):
     site.__exit__(None, None, None)
     with bd.measure("prefetch_read", 1):        # the next step's: waits
         pass
-    by_name = {s["name"]: s for s in tracer.spans()}
+    by_name = {s["name"]: s for s in _ours(tracer.spans())}
     assert set(by_name) == {"train.step", "train.dispatch",
                             "prefetch.put_wait"}
     step = by_name["train.step"]
@@ -311,7 +373,7 @@ def test_spans_nobody_finishes_are_bounded(tracer):
         with bd.measure("prefetch_read", n):
             pass
     assert len(bd._pending) == bd.PENDING_STEPS
-    assert tracer.spans() == []
+    assert _ours(tracer.spans()) == []
 
 
 def test_worker_and_trainer_share_the_sink_without_losing_a_span():
@@ -358,7 +420,7 @@ def test_worker_and_trainer_share_the_sink_without_losing_a_span():
             th.join(timeout=10)
     assert not any(th.is_alive() for th in noisy)
     assert n == n_batches
-    spans = tracer.spans()
+    spans = _ours(tracer.spans())
     steps = {s["span_id"]: s for s in spans if s["name"] == "train.step"}
     assert len(steps) == n_batches
     for name in PREFETCH + ["train.data_wait"]:
