@@ -127,18 +127,51 @@ def fc(input, size: int, *, act: str = "tanh", name: str = None,
     return _add(ldef)
 
 
-def moe(input, *, expert_hidden: int, num_experts: int,
-        capacity: int = None, name: str = None) -> LayerOutput:
-    """Top-1 mixture-of-experts FFN (TPU-native capability-add; output
-    size = input size). Expert weights are ordinary parameters —
-    shard them over the model axis via shard_rules for expert
-    parallelism (`parallel/moe.py` documents the shard_map form)."""
+def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
+        experts_held: int = None, expert_offset: int = 0,
+        shared_hidden: int = 0, routed_scaling_factor: float = 1.0,
+        name: str = None, layer_attr: dict = None) -> LayerOutput:
+    """Mixture-of-experts FFN (TPU-native capability-add; output size =
+    input size): sigmoid top-``top_k`` routing over ``num_experts``,
+    SwiGLU experts, a shared expert of width ``shared_hidden`` (0: none).
+    The layer holds experts ``expert_offset .. + experts_held`` (all of
+    them by default) and computes their part of the sum: the chip's share
+    of an expert-parallel group (`parallel/moe.py`)."""
     src = _in(input)[0]
+    extra = _layer_attr(layer_attr)
+    attrs = {"num_experts": num_experts, "expert_hidden": expert_hidden,
+             "top_k": top_k, "experts_held": experts_held or num_experts,
+             "expert_offset": expert_offset, "shared_hidden": shared_hidden,
+             "routed_scaling_factor": routed_scaling_factor,
+             **extra.pop("attrs", {})}
     ldef = LayerDef(name=name or _auto_name("moe"), type="moe",
-                    inputs=[Input(src.name)], bias=False,
-                    attrs={"num_experts": num_experts,
-                           "expert_hidden": expert_hidden,
-                           "capacity": capacity})
+                    inputs=[Input(src.name)], bias=False, attrs=attrs,
+                    **extra)
+    return _add(ldef)
+
+
+def swiglu(input, *, hidden: int, name: str = None,
+           layer_attr: dict = None) -> LayerOutput:
+    """``(silu(x W_g) * (x W_u)) W_d``, no bias: a decoder block's dense
+    feed-forward half."""
+    extra = _layer_attr(layer_attr)
+    ldef = LayerDef(name=name or _auto_name("swiglu"), type="swiglu",
+                    inputs=[Input(_in(input)[0].name)], bias=False,
+                    attrs={"hidden": hidden, **extra.pop("attrs", {})},
+                    **extra)
+    return _add(ldef)
+
+
+def rms_norm(input, *, epsilon: float = 1e-6, name: str = None,
+             param_attr=None, layer_attr: dict = None) -> LayerOutput:
+    """``x / sqrt(mean(x^2) + epsilon) * g`` over the feature dim."""
+    extra = _layer_attr(layer_attr)
+    ldef = LayerDef(name=name or _auto_name("rms_norm"), type="rms_norm",
+                    inputs=[Input(_in(input)[0].name,
+                                  param_attr=_param(param_attr))],
+                    bias=False,
+                    attrs={"epsilon": epsilon, **extra.pop("attrs", {})},
+                    **extra)
     return _add(ldef)
 
 
@@ -304,6 +337,49 @@ def multi_head_attention(query, key_value=None, *, size: int = None,
                     attrs={"num_heads": num_heads, "causal": causal,
                            "seq_parallel": seq_parallel,
                            "seq_axis": seq_axis})
+    return _add(ldef)
+
+
+def mla_attention(input, *, num_heads: int, q_lora_rank: int,
+                  kv_lora_rank: int, qk_nope_head_dim: int,
+                  qk_rope_head_dim: int, v_head_dim: int,
+                  rope_theta: float = 10000.0, epsilon: float = 1e-6,
+                  name: str = None, layer_attr: dict = None) -> LayerOutput:
+    """Causal multi-head latent attention (low-rank q and kv paths with an
+    RMSNorm inside, a decoupled rotary part, one rotary key for all the
+    heads; `layers/attention.py`); no bias, output size = input size."""
+    extra = _layer_attr(layer_attr)
+    attrs = {"num_heads": num_heads, "q_lora_rank": q_lora_rank,
+             "kv_lora_rank": kv_lora_rank,
+             "qk_nope_head_dim": qk_nope_head_dim,
+             "qk_rope_head_dim": qk_rope_head_dim, "v_head_dim": v_head_dim,
+             "rope_theta": rope_theta, "epsilon": epsilon,
+             **extra.pop("attrs", {})}
+    ldef = LayerDef(name=name or _auto_name("mla"), type="mla_attention",
+                    inputs=[Input(_in(input)[0].name)], bias=False,
+                    attrs=attrs, **extra)
+    return _add(ldef)
+
+
+def seq_shift(input, *, offset: int, name: str = None) -> LayerOutput:
+    """Position i of the output holds position ``i + offset`` of the
+    input (zeros and a dead mask on the last ``offset``)."""
+    return _simple("seq_shift", input, name, attrs={"offset": offset})
+
+
+def lm_cost(input, ids, *, vocab_size: int, shift: int = 1,
+            coeff: float = 1.0, chunk: int = 2048, name: str = None,
+            param_attr=None) -> LayerOutput:
+    """The output head fused with its cross-entropy: position i's logits
+    against the id at ``i + shift``, each row's mean over the positions
+    that have a target, times ``coeff`` (`layers/lm.py`). The head's
+    weight is ``param_attr``'s to share (``ParamAttr(name=...)``)."""
+    ldef = LayerDef(name=name or _auto_name("lm_cost"), type="lm_cost",
+                    inputs=[Input(_in(input)[0].name,
+                                  param_attr=_param(param_attr)),
+                            Input(_in(ids)[0].name)], bias=False,
+                    attrs={"vocab_size": vocab_size, "shift": shift,
+                           "coeff": coeff, "chunk": chunk})
     return _add(ldef)
 
 

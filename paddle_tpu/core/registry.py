@@ -72,6 +72,10 @@ class ParamSpec:
     # wire-format dims override where the reference's recorded layout
     # differs from the physical shape (conv shared biases: [size, 1])
     wire_dims: Optional[Tuple[int, ...]] = None
+    # the parameter stays float32 under a lower ``compute_dtype`` (the
+    # trainer's cast leaves it alone): an MoE router's scores decide a
+    # discontinuous choice
+    compute_f32: bool = False
     # True only when the USER requested sparse_update (ParamAttr); the
     # engine's sparse_grad default (embedding touched-rows updates) is an
     # internal optimization the reference wire format doesn't record

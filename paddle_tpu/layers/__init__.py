@@ -19,3 +19,4 @@ from paddle_tpu.layers import sampling  # noqa: F401
 from paddle_tpu.layers import detection  # noqa: F401
 from paddle_tpu.layers import attention  # noqa: F401
 from paddle_tpu.layers import moe  # noqa: F401
+from paddle_tpu.layers import lm  # noqa: F401

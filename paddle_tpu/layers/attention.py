@@ -11,15 +11,25 @@ fused path is the difference between MXU-bound and HBM-bound attention.
 only query is given. Heads live in one [S, S] projection per q/k/v plus an
 output projection, scaled-dot-product core with the sequence mask taken
 from the key/value Argument; optional causal masking for decoder use.
+
+``mla_attention``: multi-head latent attention (DeepSeek-V2,
+arXiv:2405.04434 §2.1, as DeepSeek-V3's ``config.json`` keys carry it):
+queries and keys/values each pass through a low-rank path with an
+RMSNorm inside, a ``rope``-wide part of every query and ONE key of that
+width shared by all heads carry the position (rotary, pairs interleaved),
+so a head's query and key are ``nope + rope`` wide and its value
+``v_head_dim``; the core is the same ``flash_attention``.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.argument import Argument
 from paddle_tpu.core.registry import (LayerImpl, ParamSpec, ShapeInfo,
                                       register_layer)
+from paddle_tpu.layers.norm import rms_normalize
 from paddle_tpu.ops.attention import flash_attention
 
 
@@ -87,3 +97,81 @@ class MultiHeadAttentionLayer(LayerImpl):
         if q_arg.mask is not None:
             out = out * q_arg.mask[..., None]
         return Argument(value=out, mask=q_arg.mask)
+
+
+def rotary_interleaved(x, theta: float):
+    """Rotary position embedding over the last axis of ``x [..., T, d]``
+    (positions 0..T-1 along axis -2), the pairs interleaved: elements
+    ``(2i, 2i+1)`` turn by ``pos * theta ** (-2i / d)``. Computed in
+    float32; the result takes ``x``'s type."""
+    T, d = x.shape[-2], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+@register_layer("mla_attention")
+class MlaAttentionLayer(LayerImpl):
+    """Causal self-attention through latent (low-rank) paths; no bias.
+    Output size = input size."""
+
+    @staticmethod
+    def _dims(cfg):
+        a = cfg.attrs
+        return (int(a["num_heads"]), int(a["q_lora_rank"]),
+                int(a["kv_lora_rank"]), int(a["qk_nope_head_dim"]),
+                int(a["qk_rope_head_dim"]), int(a["v_head_dim"]))
+
+    def infer(self, cfg, in_infos):
+        return ShapeInfo(size=in_infos[0].size, is_sequence=True)
+
+    def params(self, cfg, in_infos):
+        d = in_infos[0].size
+        heads, qr, kvr, nope, rope, dv = self._dims(cfg)
+        ones = dict(init="const", initial_mean=1.0, initial_std=0.0)
+        return {
+            "wqa": ParamSpec(shape=(d, qr)),
+            "qnorm": ParamSpec(shape=(qr,), **ones),
+            "wqb": ParamSpec(shape=(qr, heads * (nope + rope))),
+            "wkva": ParamSpec(shape=(d, kvr + rope)),
+            "kvnorm": ParamSpec(shape=(kvr,), **ones),
+            "wkvb": ParamSpec(shape=(kvr, heads * (nope + dv))),
+            "wo": ParamSpec(shape=(heads * dv, d)),
+        }
+
+    def apply(self, cfg, params, ins, ctx):
+        u = ins[0].value
+        B, T, _ = u.shape
+        heads, _qr, kvr, nope, rope, dv = self._dims(cfg)
+        eps = cfg.attrs.get("epsilon", 1e-6)
+        theta = float(cfg.attrs.get("rope_theta", 10000.0))
+
+        def split(x, width):  # [B,T,H*w] -> [B,H,T,w]
+            return x.reshape(B, T, heads, width).transpose(0, 2, 1, 3)
+
+        q = split(rms_normalize(u @ params["wqa"], params["qnorm"], eps)
+                  @ params["wqb"], nope + rope)
+        kva = u @ params["wkva"]
+        kv = split(rms_normalize(kva[..., :kvr], params["kvnorm"], eps)
+                   @ params["wkvb"], nope + dv)
+        # one rotary key for all the heads
+        k_rope = rotary_interleaved(kva[..., kvr:], theta)[:, None]
+        q = jnp.concatenate(
+            [q[..., :nope], rotary_interleaved(q[..., nope:], theta)], -1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope, (B, heads, T, rope))], -1)
+        with jax.named_scope("mla_core"):
+            # 512 x 512 tiles: 168 MFLOP a grid step, a few times a
+            # step's fixed cost (the kernels' default 256 is a quarter)
+            out = flash_attention(q, k, kv[..., nope:], ins[0].mask,
+                                  causal=True, block_q=512, block_k=512)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, heads * dv) \
+            @ params["wo"]
+        if ins[0].mask is not None:
+            out = out * ins[0].mask[..., None].astype(out.dtype)
+        return Argument(value=out, mask=ins[0].mask)

@@ -1,59 +1,95 @@
-"""MoE as a first-class layer type (TPU-native capability-add).
+"""The feed-forward halves of a decoder block as layer types
+(TPU-native capability-add): ``swiglu``, the dense FFN, and ``moe``, the
+expert layer (``parallel/moe.py`` has the mathematics).
 
-``dsl.moe(input, expert_hidden=..., num_experts=..., capacity=...)``
-registers a ``moe`` layer whose parameters live in the ordinary
-parameter table (so SGD/optimizers/checkpoints/shard_rules all apply):
-a top-1-routed expert FFN (``parallel/moe.py:moe_ffn`` math inline,
-batched [E, capacity, d] MXU matmuls, static shapes). Expert weights
-shard over the model axis with ``shard_rules={"_<name>.w1": P('model'),
-...}`` or automatically through ``parallel.moe.make_moe`` for the
-shard_map formulation.
+``dsl.moe(input, expert_hidden=..., num_experts=..., top_k=...,
+experts_held=..., expert_offset=...)`` registers a layer whose parameters
+live in the ordinary parameter table: the router ``wr`` over all
+``num_experts`` (kept float32 under a lower ``compute_dtype``) with its
+selection bias ``br`` (static: no gradient trains it), the held experts
+stacked expert-major (``wg wu`` [held, d, h], ``wd`` [held, h, d]) and the
+shared expert (``sg su sd``). The layer is told which experts it holds:
+it routes over all of them, computes the chosen held experts' part of the
+sum and leaves the rest out; no token routed to a held expert is ever
+dropped. Its output's ``state["counters"]`` names what the step counted
+here (the trainer hands every layer's counters back with the cost, into
+``StepBreakdown.totals``): ``moe_rows_max`` and ``moe_rows_mean``, the
+rows the fullest held expert got and the mean held expert's, and
+``moe_experts_active``, how many held experts got any row.
 """
 
 from __future__ import annotations
 
 from typing import Dict
 
-import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.argument import Argument
 from paddle_tpu.core.registry import (LayerImpl, ParamSpec, ShapeInfo,
                                       register_layer)
+from paddle_tpu.parallel.moe import moe_ffn, swiglu
+
+
+def _same_shape(in_infos):
+    return ShapeInfo(size=in_infos[0].size,
+                     is_sequence=in_infos[0].is_sequence)
+
+
+@register_layer("swiglu")
+class SwigluLayer(LayerImpl):
+    """``(silu(x W_g) * (x W_u)) W_d``, no bias; output size = input's."""
+
+    def infer(self, cfg, in_infos):
+        return _same_shape(in_infos)
+
+    def params(self, cfg, in_infos) -> Dict[str, ParamSpec]:
+        d, h = in_infos[0].size, int(cfg.attrs["hidden"])
+        return {"wg": ParamSpec(shape=(d, h)), "wu": ParamSpec(shape=(d, h)),
+                "wd": ParamSpec(shape=(h, d))}
+
+    def apply(self, cfg, params, ins, ctx):
+        y = swiglu(ins[0].value, params["wg"], params["wu"], params["wd"])
+        return Argument(value=y, mask=ins[0].mask)
 
 
 @register_layer("moe")
 class MoELayer(LayerImpl):
-    """Top-1 mixture-of-experts FFN over the feature dim; output size =
-    input size. Capacity-clipped static dispatch (overflow tokens pass
-    through with a zero expert contribution, as in the library form)."""
-
     def infer(self, cfg, in_infos):
-        return ShapeInfo(size=in_infos[0].size,
-                         is_sequence=in_infos[0].is_sequence)
+        return _same_shape(in_infos)
 
     def params(self, cfg, in_infos) -> Dict[str, ParamSpec]:
         d = in_infos[0].size
         e = int(cfg.attrs["num_experts"])
+        held = int(cfg.attrs.get("experts_held") or e)
         h = int(cfg.attrs["expert_hidden"])
-        return {
-            "wg": ParamSpec(shape=(d, e)),
-            "w1": ParamSpec(shape=(e, d, h)),
-            "b1": ParamSpec(shape=(e, h), init="zeros", is_bias=True),
-            "w2": ParamSpec(shape=(e, h, d)),
-            "b2": ParamSpec(shape=(e, d), init="zeros", is_bias=True),
+        hs = int(cfg.attrs.get("shared_hidden") or 0)
+        specs = {
+            "wr": ParamSpec(shape=(d, e), compute_f32=True),
+            "br": ParamSpec(shape=(e,), init="zeros", is_static=True,
+                            compute_f32=True),
+            "wg": ParamSpec(shape=(held, d, h)),
+            "wu": ParamSpec(shape=(held, d, h)),
+            "wd": ParamSpec(shape=(held, h, d)),
         }
+        if hs:
+            specs.update(sg=ParamSpec(shape=(d, hs)),
+                         su=ParamSpec(shape=(d, hs)),
+                         sd=ParamSpec(shape=(hs, d)))
+        return specs
 
     def apply(self, cfg, params, ins, ctx):
-        from paddle_tpu.parallel.moe import moe_ffn
         a = ins[0]
-        v = a.value
-        shape = v.shape
-        flat = v.reshape(-1, shape[-1])
-        cap = int(cfg.attrs.get("capacity") or flat.shape[0])
-        # Dead (padded) positions must not claim capacity slots — a
-        # padded batch would otherwise crowd out live tokens and the
-        # output would change with padding amount (ragged invariant).
-        live = a.mask.reshape(-1) if a.mask is not None else None
-        y = moe_ffn(params, flat, cap, live=live)
-        return Argument(value=y.reshape(shape), mask=a.mask)
+        shape = a.value.shape
+        y, rows = moe_ffn(
+            params, a.value.reshape(-1, shape[-1]),
+            top_k=int(cfg.attrs["top_k"]),
+            scale=float(cfg.attrs.get("routed_scaling_factor", 1.0)),
+            offset=int(cfg.attrs.get("expert_offset") or 0),
+            # padding is routed nowhere: it takes no expert's rows
+            live=a.mask.reshape(-1) if a.mask is not None else None)
+        rows = rows.astype(jnp.float32)     # [held]
+        return Argument(value=y.reshape(shape), mask=a.mask,
+                        state={"counters": {
+                            "moe_rows_max": rows.max(),
+                            "moe_rows_mean": rows.mean(),
+                            "moe_experts_active": (rows > 0).sum()}})

@@ -1,4 +1,4 @@
-"""Normalization layers: batch_norm and cross-map response norm.
+"""Normalization layers: batch_norm, rms_norm and cross-map response norm.
 
 ``BatchNormalizationLayer``/``CudnnBatchNormLayer`` (``paddle/gserver/layers/
 BatchNorm*Layer.cpp``): scale+shift per channel, batch statistics in
@@ -10,6 +10,11 @@ applies those updates functionally (no mutation inside jit).
 
 ``CMRProjectionNormLayer`` ("norm" with norm_type cmrnorm-projection):
 AlexNet-style local response normalization across channel windows.
+
+``rms_norm`` (capability-add; today's decoder blocks normalise so):
+``x / sqrt(mean(x^2) + eps) * g`` over the feature dim, no shift, no
+statistics kept; ``rms_normalize`` is the function, for layers that
+normalise inside (latent attention's two low-rank paths).
 """
 
 from __future__ import annotations
@@ -67,6 +72,30 @@ class BatchNormLayer(LayerImpl):
                 momentum * params["w1"] + (1.0 - momentum) * mean)
             ctx.state_updates[f"_{lname}.w2"] = (
                 momentum * params["w2"] + (1.0 - momentum) * var)
+        return Argument(value=y, mask=ins[0].mask)
+
+
+def rms_normalize(x, scale, eps: float):
+    """RMSNorm over the last axis. The statistic and the division are
+    float32 whatever ``x`` is stored in; the result takes ``x``'s type."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                       + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer("rms_norm")
+class RmsNormLayer(LayerImpl):
+    def infer(self, cfg, in_infos):
+        return in_infos[0]
+
+    def params(self, cfg, in_infos):
+        return {"w0": ParamSpec(shape=(in_infos[0].size,), init="const",
+                                initial_mean=1.0, initial_std=0.0)}
+
+    def apply(self, cfg, params, ins, ctx):
+        y = rms_normalize(ins[0].value, params["w0"],
+                          cfg.attrs.get("epsilon", 1e-6))
         return Argument(value=y, mask=ins[0].mask)
 
 

@@ -1,160 +1,302 @@
-"""Expert parallelism: a mixture-of-experts FFN sharded expert-per-device.
+"""Mixture of experts: sigmoid routing with a selection bias, top-k of all
+the experts, SwiGLU experts, a shared expert, and a layer that is told
+which experts it holds.
 
-The 2017 reference has no MoE (SURVEY §2: no expert parallelism), so —
-like ``parallel/ring.py`` — this is a pure capability-add designed
-TPU-first. The canonical recipe (the public Switch/GShard pattern):
+The 2017 reference has no MoE (SURVEY §2), so this is a capability-add.
+The mathematics is DeepSeek-V3's (arXiv:2412.19437 §2.1.2, the form
+today's large open models carry in their ``config.json``):
 
-- router: per-token top-1 expert choice from a learned projection,
-  with capacity clipping (static shapes: each expert processes exactly
-  ``capacity`` token slots; overflow drops, underflow pads).
-- dispatch: each device builds the capacity buffers from its replicated
-  token batch and keeps its local experts' slice; the expert FFN runs
-  dense (batched [capacity, d] matmuls on the MXU); results return with
-  an ``all_gather`` over the expert axis and scatter back weighted by
-  the router gate. (With a batch additionally sharded over the expert
-  axis this becomes the classic all_to_all pair; the replicated-batch
-  form keeps one collective.)
-- gradients flow through gates and expert weights (straight-through on
-  the routing choice, the standard top-1 formulation); everything is
-  pure lax inside ``shard_map``, so XLA lowers dispatch to ICI
-  collectives.
-
-``moe_ffn`` is the single-device (unsharded) reference; ``make_moe``
-returns the expert-parallel version over a mesh axis. Parity between
-the two is pinned in ``tests/test_moe.py``.
+- router: ``s = sigmoid(u W_r)`` over all ``E`` experts in float32; the
+  ``k`` chosen are the top ``k`` of ``s + b`` (``b``: a selection bias
+  that no gradient trains); their weights are ``s[chosen] /
+  sum(s[chosen]) * scale``. The gradient reaches ``W_r`` through the
+  weights; the choice itself is piecewise constant.
+- experts: ``E(u) = (silu(u W_g) * (u W_u)) W_d``, no bias; the routed
+  part of the result is ``sum_i w_i E_i(u)`` over the chosen experts.
+- expert parallelism: a device holds experts ``offset .. offset + held``.
+  It routes over all ``E``, computes the sum over the chosen experts it
+  holds and leaves the rest out (``routed_experts``). Over a mesh axis
+  the partial sums are added (``make_moe``); on one chip that stands for
+  one of a group the layer runs without any exchange, and what the
+  absent experts would have added is simply not there.
+- dispatch: the (token, choice) pairs that name a held expert are sorted
+  by expert and gathered ``R`` at a time into an ``[R, d]`` buffer, over
+  which the three products run as grouped matrix products
+  (``grouped_matmul``: megablox on the TPU, whose grid follows the rows
+  really there; ``lax.ragged_dot`` elsewhere). ``R`` is twice what a
+  uniform router sends here, so the usual batch is one buffer. **No
+  capacity and no drop**: a loop takes as many buffers as the rows need
+  (``lax.fori_loop`` with a traced bound), so a batch in which every
+  token chooses held experts comes out exact, and both time and memory
+  follow the rows really routed here, not the worst case ``T * min(k,
+  held)``. The loop sits inside a ``custom_vjp`` whose backward makes
+  the same turns, recomputing each buffer from the layer's input (a
+  loop of traced length has no transpose of its own).
 """
 
 from __future__ import annotations
 
-from typing import Dict
+import functools
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddle_tpu.ops import common
 
-def _route(x, wg, n_experts):
-    """Top-1 routing: (expert_id[B], gate[B]) with softmax gates."""
-    logits = x @ wg                       # [B, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    eid = jnp.argmax(probs, axis=-1)
-    gate = jnp.take_along_axis(probs, eid[:, None], axis=-1)[:, 0]
-    return eid, gate
+_HIGHEST = lax.Precision.HIGHEST
+_ROW_TILE = 512     # rows a grouped-product tile holds on the TPU
 
 
-def _expert_ffn(x, w1, b1, w2, b2):
-    return jax.nn.relu(x @ w1 + b1) @ w2 + b2
-
-
-def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int
+def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int,
+                    d_shared: Optional[int] = None
                     ) -> Dict[str, jnp.ndarray]:
-    k1, k2, k3 = jax.random.split(key, 3)
-    s1 = 1.0 / jnp.sqrt(d_model)
-    s2 = 1.0 / jnp.sqrt(d_hidden)
-    return {
-        "wg": jax.random.normal(k1, (d_model, n_experts)) * s1,
-        "w1": jax.random.normal(k2, (n_experts, d_model, d_hidden)) * s1,
-        "b1": jnp.zeros((n_experts, d_hidden)),
-        "w2": jax.random.normal(k3, (n_experts, d_hidden, d_model)) * s2,
-        "b2": jnp.zeros((n_experts, d_model)),
+    """Router ``wr``/``br``, routed experts ``wg wu wd`` (stacked, expert
+    major), shared expert ``sg su sd`` (``d_shared`` 0: none)."""
+    d_shared = d_hidden if d_shared is None else d_shared
+    ks = jax.random.split(key, 7)
+    s1, s2 = d_model ** -0.5, d_hidden ** -0.5
+    out = {
+        "wr": jax.random.normal(ks[0], (d_model, n_experts)) * s1,
+        "br": jnp.zeros((n_experts,)),
+        "wg": jax.random.normal(ks[1], (n_experts, d_model, d_hidden)) * s1,
+        "wu": jax.random.normal(ks[2], (n_experts, d_model, d_hidden)) * s1,
+        "wd": jax.random.normal(ks[3], (n_experts, d_hidden, d_model)) * s2,
     }
+    if d_shared:
+        out.update(
+            sg=jax.random.normal(ks[4], (d_model, d_shared)) * s1,
+            su=jax.random.normal(ks[5], (d_model, d_shared)) * s1,
+            sd=jax.random.normal(ks[6], (d_shared, d_model))
+            * d_shared ** -0.5)
+    return out
 
 
-def _dispatch_plan(eid, n_experts, capacity, live=None):
-    """Position of each token within its expert's capacity slots, and a
-    keep-mask for tokens under capacity (static shapes throughout).
+def swiglu(x, wg, wu, wd):
+    """``(silu(x W_g) * (x W_u)) W_d``: the dense FFN and every expert."""
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
 
-    ``live`` ([B] bool/0-1, optional) marks real tokens: dead (padded)
-    positions claim no capacity slot and are excluded from ``keep``, so
-    a padded batch routes identically to its unpadded equivalent."""
-    onehot = jax.nn.one_hot(eid, n_experts, dtype=jnp.int32)   # [B, E]
+
+def route(x, wr, br, top_k: int, scale: float):
+    """``(ids [T,k] int32, weights [T,k] float32)``: sigmoid scores over
+    all the experts in float32 (the product at ``highest``: a TPU's
+    default float32 product rounds its operands to bfloat16), the choice
+    by ``s + b``, the weights by ``s``, normalised and scaled."""
+    with jax.named_scope("moe_route"):
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), wr.astype(jnp.float32),
+            precision=_HIGHEST))
+        _, ids = lax.top_k(s + lax.stop_gradient(br.astype(jnp.float32)),
+                           top_k)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+    return ids.astype(jnp.int32), w
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs [R, k]`` whose rows lie sorted by group, ``rhs [G, k, n]``,
+    ``group_sizes [G]`` (their sum may fall short of ``R``): row r times
+    its group's matrix; rows past the sum come out zero. (The kernel
+    leaves them unwritten, and what lies there may be no number at all:
+    they are zeroed by a select, never by a product, here and, by the
+    select's own transpose, in the backward pass.)"""
+    R, k = lhs.shape
+    n = rhs.shape[-1]
+    group_sizes = group_sizes.astype(jnp.int32)
+    if common.partitioned() or not common.use_pallas():
+        common.note("moe_grouped_matmul", "ref")
+        return lax.ragged_dot(lhs, rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    common.note("moe_grouped_matmul", common.pallas_path())
+
+    def tile(size, cap):
+        for t in (cap, 512, 256, 128):
+            if t <= cap and size % t == 0:
+                return t
+        return size
+
+    out = megablox.gmm(
+        lhs, rhs, group_sizes, lhs.dtype,
+        (tile(R, _ROW_TILE), tile(k, 1024), tile(n, 1024)),
+        interpret=common.interpret())
+    written = jnp.arange(R)[:, None] < jnp.sum(group_sizes)
+    return jnp.where(written, out, 0)
+
+
+def _plan(ids, offset, held: int, live):
+    """For every (token, choice) pair the local index of its expert, or
+    ``held`` where that expert is not here or the token is padding; and
+    the rows each held expert gets."""
+    local = ids - offset
+    here = (local >= 0) & (local < held)
     if live is not None:
-        onehot = onehot * live.astype(jnp.int32)[:, None]
-    pos = jnp.cumsum(onehot, axis=0) * onehot                  # 1-based
-    slot = jnp.sum(pos, axis=-1) - 1                           # [B]
-    keep = (slot < capacity) & (slot >= 0)
-    return slot, keep
+        here = here & (live.reshape(-1, 1) > 0)
+    key = jnp.where(here, local, held).reshape(-1)
+    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype),
+                     axis=0, dtype=jnp.int32)
+    return key, counts
 
 
-def moe_ffn(params, x, capacity: int, live=None):
-    """Single-device reference: identical math to the sharded version
-    (capacity clipping included), dense per-expert batches. ``live``
-    excludes masked/padded tokens from dispatch (they produce zeros)."""
-    n_experts = params["wg"].shape[-1]
-    eid, gate = _route(x, params["wg"], n_experts)
-    slot, keep = _dispatch_plan(eid, n_experts, capacity, live)
-    d = x.shape[-1]
-    # scatter tokens into [E, capacity, d] buffers
-    buf = jnp.zeros((n_experts, capacity, d), x.dtype)
-    buf = buf.at[eid, jnp.clip(slot, 0, capacity - 1)].add(
-        x * keep[:, None].astype(x.dtype))
-    out_buf = jax.vmap(_expert_ffn)(buf, params["w1"], params["b1"],
-                                    params["w2"], params["b2"])
-    y = out_buf[eid, jnp.clip(slot, 0, capacity - 1)]
-    return y * (gate * keep.astype(x.dtype))[:, None]
+def _chunk_rows(T: int, K: int, E: int, held: int) -> int:
+    """Rows of the dispatch buffer: twice what a uniform router sends
+    here, or the worst case ``T * min(K, held)`` where that is less."""
+    def up(n):
+        n = max(int(n), 8)
+        q = _ROW_TILE if n >= _ROW_TILE else 8
+        return -(-n // q) * q
+    return up(min(2 * T * K * held / E, T * min(K, held)))
 
 
-def make_moe(mesh: Mesh, axis: str, n_experts: int, capacity: int):
-    """Expert-parallel MoE over ``axis`` (one or more experts per device;
-    ``n_experts`` must be divisible by the axis size). Returns
-    ``fn(params, x) -> y`` with params sharded expert-major on ``axis``
-    and ``x`` fully REPLICATED (in_specs pins it): every device routes
-    the whole batch and keeps only its experts' buffers. Shard the batch
-    upstream over the data axis and call this per data-shard if DP is
-    also in play. ``fn(params, x, live)`` takes a [B] 0-1 live mask
-    (pass ones for fully-dense batches): dead/padded tokens claim no
-    capacity slot, matching ``moe_ffn``'s ragged semantics exactly."""
+def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into):
+    """``into`` ([T, d] float32) plus the routed sum of the sorted pairs
+    ``first .. first + R`` (``pairs [R]``: their indices into the
+    flattened [T * K] choices). ``counts [held]`` are the whole batch's
+    rows per expert; the chunk's own follow from where it starts."""
+    R, K = pairs.shape[0], gates.shape[1]
+    ends = jnp.cumsum(counts)
+    mine = jnp.clip(jnp.minimum(ends, first + R)
+                    - jnp.maximum(ends - counts, first), 0, R)
+    token = pairs // K
+    valid = jnp.arange(R) < jnp.sum(mine)
+    xg = jnp.where(valid[:, None], x[token], 0)
+    with jax.named_scope("moe_experts"):
+        a = (jax.nn.silu(grouped_matmul(xg, wg, mine))
+             * grouped_matmul(xg, wu, mine))
+        o = grouped_matmul(a, wd, mine)
+    with jax.named_scope("moe_combine"):
+        # a token's up to k contributions are added in float32; rows
+        # past the last expert's are zero and add nothing
+        g = gates.reshape(-1)[pairs]
+        return into.at[token].add(o.astype(jnp.float32) * g[:, None])
+
+
+def _sorted_pairs(key, R: int):
+    """The (token, choice) pairs sorted by expert, held experts' first,
+    padded to whole chunks of ``R``."""
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    return jnp.pad(order, (0, (-order.shape[0]) % R))
+
+
+def _over_chunks(R, counts, init, step):
+    """``step(c, carry)`` for every chunk of ``R`` sorted rows that holds
+    a held expert's row: as many turns as the rows need, no more."""
+    turns = (jnp.sum(counts) + R - 1) // R
+    return lax.fori_loop(0, turns, step, init)
+
+
+def _routed_sum(R, order, x, wg, wu, wd, gates, counts):
+    def step(c, y):
+        pairs = lax.dynamic_slice(order, (c * R,), (R,))
+        return _chunk(x, wg, wu, wd, gates, pairs, counts, c * R, y)
+
+    return _over_chunks(R, counts, jnp.zeros(x.shape, jnp.float32), step)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(R, x, wg, wu, wd, gates, key, counts):
+    return _routed_sum(R, _sorted_pairs(key, R), x, wg, wu, wd, gates,
+                       counts)
+
+
+def _routed_fwd(R, x, wg, wu, wd, gates, key, counts):
+    order = _sorted_pairs(key, R)       # sorted once, kept for the backward
+    return (_routed_sum(R, order, x, wg, wu, wd, gates, counts),
+            (x, wg, wu, wd, gates, order, counts))
+
+
+def _routed_bwd(R, saved, dy):
+    *floats, order, counts = saved
+    zero = jnp.zeros(floats[0].shape, jnp.float32)
+
+    def step(c, grads):
+        pairs = lax.dynamic_slice(order, (c * R,), (R,))
+        _, vjp = jax.vjp(
+            lambda *f: _chunk(*f, pairs, counts, c * R, zero), *floats)
+        return tuple(g + d.astype(jnp.float32)
+                     for g, d in zip(grads, vjp(dy)))
+
+    grads = _over_chunks(
+        R, counts, tuple(jnp.zeros(f.shape, jnp.float32) for f in floats),
+        step)
+    return (*(g.astype(f.dtype) for g, f in zip(grads, floats)), None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def routed_experts(x, wg, wu, wd, ids, gates, *, n_experts: int,
+                   offset=0, live=None):
+    """``sum_i w_i E_i(x)`` over the chosen experts that are held here:
+    experts ``offset .. offset + wg.shape[0]`` of ``n_experts``. Returns
+    ``(y [T, d], rows [held] int32)``, the rows each held expert got."""
+    held = wg.shape[0]
+    key, counts = _plan(ids, offset, held, live)
+    R = _chunk_rows(x.shape[0], ids.shape[1], n_experts, held)
+    y = _routed(R, x, wg, wu, wd, gates.astype(jnp.float32), key, counts)
+    return y.astype(x.dtype), counts
+
+
+def moe_ffn(params, x, *, top_k: int, scale: float = 1.0, offset=0,
+            live=None, shared: bool = True):
+    """The layer on one device: ``x [T, d]`` -> ``(y [T, d], rows)``.
+    ``params["wg"]`` holds ``held`` experts, those from ``offset`` on, of
+    the ``params["wr"].shape[-1]`` the router scores. ``live`` ([T], 0 or
+    1) marks real tokens: padding is routed nowhere. ``shared`` False
+    leaves the shared expert out (``make_moe`` adds it once)."""
+    ids, w = route(x, params["wr"], params["br"], top_k, scale)
+    y, rows = routed_experts(
+        x, params["wg"], params["wu"], params["wd"], ids, w,
+        n_experts=params["wr"].shape[-1], offset=offset, live=live)
+    if shared and "sg" in params:
+        y = y + swiglu(x, params["sg"], params["su"], params["sd"])
+    return y, rows
+
+
+_EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
+def make_moe(mesh: Mesh, axis: str, *, top_k: int, scale: float = 1.0):
+    """Expert-parallel MoE over ``axis``: the experts' leaves split expert
+    major, router and shared expert replicated, ``x`` replicated. Every
+    device routes the whole batch over all the experts, computes the
+    partial sum of the experts it holds, and the partial sums are added
+    over the axis (one ``psum``); the shared expert is counted once.
+    Returns ``fn(params, x, live=None) -> y``."""
+    from paddle_tpu.parallel.mesh import shard_map_compat
     n_dev = mesh.shape[axis]
-    if n_experts % n_dev:
-        raise ValueError(f"{n_experts} experts over {n_dev} devices")
-    e_local = n_experts // n_dev
 
     def local(params, x, live):
-        # x: the full (replicated-over-axis) token batch [B, d]
-        eid, gate = _route(x, params["wg"], n_experts)
-        slot, keep = _dispatch_plan(eid, n_experts, capacity, live)
-        d = x.shape[-1]
-        # build every expert's capacity buffer locally (the batch is
-        # replicated, so all copies agree); keep this device's slice —
-        # the only collective is the all_gather of expert outputs below
-        buf = jnp.zeros((n_experts, capacity, d), x.dtype)
-        buf = buf.at[eid, jnp.clip(slot, 0, capacity - 1)].add(
-            x * keep[:, None].astype(x.dtype))
-        # [E, cap, d] -> [n_dev, e_local, cap, d]; device i keeps slice i
-        buf = buf.reshape(n_dev, e_local, capacity, d)
-        # psum-of-scatter: every device built the full buffer from ITS
-        # replicated batch copy; they are identical, so just slice
-        idx = lax.axis_index(axis)
-        mine = lax.dynamic_index_in_dim(buf, idx, axis=0, keepdims=False)
-        out_local = jax.vmap(_expert_ffn)(
-            mine, params["w1"], params["b1"], params["w2"], params["b2"])
-        # gather every expert's outputs back to every device
-        out_all = lax.all_gather(out_local, axis)  # [n_dev, e_local, cap, d]
-        out_all = out_all.reshape(n_experts, capacity, d)
-        y = out_all[eid, jnp.clip(slot, 0, capacity - 1)]
-        return y * (gate * keep.astype(x.dtype))[:, None]
+        held = params["wg"].shape[0]
+        y, _ = moe_ffn(params, x, top_k=top_k, scale=scale,
+                       offset=lax.axis_index(axis) * held, live=live,
+                       shared=False)
+        y = lax.psum(y, axis)
+        if "sg" in params:
+            y = y + swiglu(x, params["sg"], params["su"], params["sd"])
+        return y
 
-    from paddle_tpu.parallel.mesh import shard_map_compat
-    fn = shard_map_compat(
-        local, mesh=mesh,
-        in_specs=({"wg": P(), "w1": P(axis), "b1": P(axis),
-                   "w2": P(axis), "b2": P(axis)}, P(), P()),
-        out_specs=P(), check_vma=False)
-    jitted = jax.jit(fn)
+    @functools.lru_cache(maxsize=None)
+    def jitted(names):
+        specs = {k: P(axis) if k in _EXPERT_LEAVES else P() for k in names}
+        return jax.jit(shard_map_compat(
+            local, mesh=mesh, in_specs=(specs, P(), P()), out_specs=P(),
+            check_vma=False))
 
     def call(params, x, live=None):
+        if params["wg"].shape[0] % n_dev:
+            raise ValueError(f"{params['wg'].shape[0]} experts over "
+                             f"{n_dev} devices")
         if live is None:
-            live = jnp.ones((x.shape[0],), x.dtype)
-        return jitted(params, x, live)
+            live = jnp.ones((x.shape[0],), jnp.float32)
+        return jitted(tuple(sorted(params)))(params, x, live)
 
     return call
 
 
 def shard_moe_params(params, mesh: Mesh, axis: str):
-    """Place MoE params: router replicated, experts split over ``axis``."""
-    out = {}
-    for k, v in params.items():
-        spec = P() if k == "wg" else P(axis)
-        out[k] = jax.device_put(v, NamedSharding(mesh, spec))
-    return out
+    """Place MoE params: experts split over ``axis``, the rest whole."""
+    return {k: jax.device_put(v, NamedSharding(
+        mesh, P(axis) if k in _EXPERT_LEAVES else P()))
+        for k, v in params.items()}

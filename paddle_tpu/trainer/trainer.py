@@ -266,6 +266,19 @@ class SGD:
         return jax.tree_util.tree_map(
             go, tree, is_leaf=lambda x: isinstance(x, Argument))
 
+    def _cast_params(self, params):
+        """``_cast_compute`` of a parameter table, but for the parameters
+        whose spec says ``compute_f32`` (an MoE router's weight: its
+        scores decide a discontinuous choice): those stay float32."""
+        if self.compute_dtype is None:
+            return params
+        keep = {n for n in params
+                if getattr(self.meta.get(n), "compute_f32", False)}
+        out = self._cast_compute(
+            {n: v for n, v in params.items() if n not in keep})
+        out.update((n, params[n]) for n in keep)
+        return out
+
     def _cast_f32(self, tree):
         if self.compute_dtype is None:
             return tree
@@ -326,6 +339,18 @@ class SGD:
             errs, cnt = classification_error(outputs[out_l], outputs[lab_l],
                                              row_mask=row_mask)
             metrics["classification_error"] = (errs, cnt)
+        counters = {}
+        for a in outputs.values():
+            state = getattr(a, "state", None)
+            if isinstance(state, dict):
+                for name, v in state.get("counters", {}).items():
+                    counters.setdefault(name, []).append(v)
+        if counters:
+            # what layers counted this step, by name (the mean over the
+            # layers that report the name), fetched with the cost
+            metrics["counters"] = {
+                name: jnp.mean(jnp.stack(v).astype(jnp.float32))
+                for name, v in counters.items()}
         if self._eval_layers:
             # layer outputs the config-declared evaluators consume; fetched
             # to host once per batch (dict values are skipped by
@@ -640,7 +665,7 @@ class SGD:
                     # from their fsdp shards (stage-stacked body keys
                     # are excluded from the plan by their P(pipe) pins)
                     params = fsdp.full_params(params)
-                cast_params = self._cast_compute(params)
+                cast_params = self._cast_params(params)
                 cast_feed = self._cast_compute(feed)
                 x = cast_feed[plan.body_in].value
                 body = {k: cast_params[k] for k in body_names}
@@ -708,7 +733,7 @@ class SGD:
             if fsdp is not None:
                 params = fsdp.full_params(params)
             outputs, updates = network.apply_with_state(
-                self._cast_compute(params), self._cast_compute(feed),
+                self._cast_params(params), self._cast_compute(feed),
                 train=True, rng=rng, carried=carried, probes=probes,
                 mesh=self.mesh)
             return (self._total_cost(outputs, self._row_mask(feed)),
@@ -800,7 +825,7 @@ class SGD:
                     # so only one microbatch's full params are live
                     params = fsdp.full_params(params)
                 outputs, updates = network.apply_with_state(
-                    self._cast_compute(params), self._cast_compute(mfeed),
+                    self._cast_params(params), self._cast_compute(mfeed),
                     train=True, rng=mrng, mesh=self.mesh)
                 return (self._total_cost(outputs, self._row_mask(mfeed),
                                          accum_k=k_eff,
@@ -844,6 +869,9 @@ class SGD:
                     # live-prefix slice stays exact
                     metrics[key] = jax.tree_util.tree_map(
                         lambda x: x.reshape((-1,) + x.shape[2:]), val)
+                elif key == "counters":
+                    metrics[key] = jax.tree_util.tree_map(
+                        lambda x: jnp.mean(x, axis=0), val)
             bsz = total_live if total_live is not None else full_bsz
             new_params, new_opt = updater.update(
                 grads, opt_state, params, meta, batch_size=bsz,
@@ -880,7 +908,7 @@ class SGD:
             # eval forward runs the plain (unpipelined) graph on the flat
             # view — jnp slicing, free at trace time
             outputs = network.apply(
-                self._cast_compute(self._flat_params_view(params)),
+                self._cast_params(self._flat_params_view(params)),
                 self._cast_compute(feed), train=False,
                 mesh=self.mesh)
             return self._metrics(outputs, feed)
@@ -1885,7 +1913,12 @@ class SGD:
                         # bracket (the reference's trainBatch) closes on
                         # finished work
                         with bd.measure("device_wait") as waited:
-                            cost = float(metrics["cost"])
+                            # the layers' counters come in the same fetch
+                            cost, counters = jax.device_get(
+                                (metrics["cost"],
+                                 metrics.pop("counters", {})))
+                            cost = float(cost)
+                        bd.add_counters(counters)
                         bd.add("compute",
                                waited.t0 + waited.seconds - t_compute)
                         guard = (self.stats_recompile_guard if stats_on
@@ -2329,7 +2362,7 @@ class SGD:
 
             @jax.jit
             def stat_fn(params, feed):
-                outs = net.apply(self._cast_compute(params),
+                outs = net.apply(self._cast_params(params),
                                  self._cast_compute(feed), train=False)
                 return {n: _arg_abs_stats(a)[:2]
                         for n, a in outs.items()

@@ -360,6 +360,18 @@ class StepBreakdown:
         self.last[part] = seconds
         self._stats[part].add(seconds)
 
+    def add_counters(self, counters):
+        """What the layers counted in the open step, by name (a layer
+        reports through its output's ``state["counters"]``; the step
+        hands back each name's mean over the layers that report it):
+        summed into ``totals`` and, with a ``Tracer`` armed, attributes
+        of the step's span."""
+        counters = {name: float(v) for name, v in counters.items()}
+        for name, value in counters.items():
+            self.totals[name] = self.totals.get(name, 0.0) + value
+        if _trace._TRACER is not None and self._step is not None:
+            self._step[3].update(counters)
+
     def measure(self, part: str, step: Optional[int] = None) -> _Site:
         """Time one part of step ``step`` (default: the open step)."""
         if step is None and self._step is not None:

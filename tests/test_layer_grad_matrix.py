@@ -375,6 +375,37 @@ def _case_multi_head_attention():
             {"x": _seq(d=8)})
 
 
+def _case_mla_attention():
+    return ([("x", 8, {"is_sequence": True})],
+            L("out", "mla_attention", ["x"], num_heads=2, q_lora_rank=6,
+              kv_lora_rank=4, qk_nope_head_dim=4, qk_rope_head_dim=2,
+              v_head_dim=4, rope_theta=100.0),
+            {"x": _seq(d=8)})
+
+
+def _case_rms_norm():
+    return ([("x", 6, {"is_sequence": True})],
+            L("out", "rms_norm", ["x"]), {"x": _seq()})
+
+
+def _case_swiglu():
+    return ([("x", 6, {"is_sequence": True})],
+            L("out", "swiglu", ["x"], hidden=5), {"x": _seq()})
+
+
+def _case_seq_shift():
+    return ([("x", 6, {"is_sequence": True})],
+            L("out", "seq_shift", ["x"], offset=1), {"x": _seq(full=True)})
+
+
+def _case_lm_cost():
+    return ([("x", 6, {"is_sequence": True}),
+             ("ids", 4, {"is_sequence": True})],
+            L("out", "lm_cost", ["x", "ids"], vocab_size=4, shift=1,
+              coeff=0.7, chunk=4),
+            {"x": _seq(full=True), "ids": _seq_ids()})
+
+
 def _case_agent():
     return ([("x", 6, {})], L("out", "agent", ["x"]), {"x": _dense()})
 
@@ -611,6 +642,9 @@ GRAD_CASES = {
     "conv_shift": _case_conv_shift, "tensor": _case_tensor,
     "selective_fc": _case_selective_fc, "prelu": _case_prelu,
     "multi_head_attention": _case_multi_head_attention,
+    "mla_attention": _case_mla_attention, "rms_norm": _case_rms_norm,
+    "swiglu": _case_swiglu, "seq_shift": _case_seq_shift,
+    "lm_cost": _case_lm_cost,
     "agent": _case_agent,
     "scatter_agent": _case_scatter_agent,
     "gather_agent": _case_gather_agent,
@@ -640,8 +674,10 @@ FWD_CASES = {
 # points at the dedicated test file exercising it
 COVERED_ELSEWHERE = {
     "data": "fed directly by every test",
-    "moe": "tests/test_moe.py (routing boundaries break numeric diff; "
-           "gradient flow + sharded parity tested there)",
+    "moe": "tests/test_moe.py (the top-k choice is piecewise constant, "
+           "so a numeric difference can cross a boundary; the layer's "
+           "gradients are compared there with the plain reference's, "
+           "leaf by leaf, with sharded parity and the no-drop case)",
     "recurrent_layer_group": "tests/test_recurrent_group.py",
     "beam_search_group": "tests/test_generation.py, tests/test_seq_models.py",
     "group_output": "tests/test_recurrent_group.py",

@@ -1,6 +1,11 @@
-"""Expert parallelism: the sharded MoE equals the single-device
-reference exactly, trains (gradients flow through gates + experts), and
-the sharded program contains the expert-axis collective."""
+"""The expert layer (``parallel/moe.py``, ``layers/moe.py``): sigmoid
+top-k routing with a selection bias, SwiGLU experts, a shared expert, a
+layer told which experts it holds. Compared with the plain reference
+(``benchmark/reference/joyai_llm_flash_ep32.py:_experts``, a dense
+one-hot combine with no sort), with itself over a CPU mesh, and with
+itself on the kernel's path (megablox in interpret mode)."""
+
+import importlib
 
 import numpy as np
 import pytest
@@ -9,103 +14,196 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from benchmark.reference import plain
+from paddle_tpu.ops import common
 from paddle_tpu.parallel import create_mesh
+from paddle_tpu.parallel import moe as moe_lib
 from paddle_tpu.parallel.moe import (init_moe_params, make_moe, moe_ffn,
                                      shard_moe_params)
 
-D, H, E, CAP, B = 16, 32, 4, 16, 32
+ref = importlib.import_module("benchmark.reference.joyai_llm_flash_ep32")
+
+D, H, E, K, T = 16, 32, 8, 2, 64
+SCALE = 2.5
 
 
 @pytest.fixture()
 def setup():
     params = init_moe_params(jax.random.PRNGKey(0), D, H, E)
-    x = jax.random.normal(jax.random.PRNGKey(1), (B, D))
+    params["br"] = 0.3 * jax.random.normal(jax.random.PRNGKey(9), (E,))
+    x = jax.random.normal(jax.random.PRNGKey(1), (T, D))
     return params, x
 
 
-def test_sharded_matches_reference(setup):
+def reference(params, x, held=None, offset=0, shared=True):
+    """The plain reference's expert layer on the same leaves."""
+    e = params["wr"].shape[-1]
+    m = {"n_routed_experts": e, "experts_held": held or e,
+         "expert_offset": offset, "num_experts_per_tok": K,
+         "routed_scaling_factor": SCALE}
+    p = {f"_l_moe.{k}": v for k, v in params.items()
+         if shared or k not in ("sg", "su", "sd")}
+    with jax.default_matmul_precision("highest"):
+        return ref._experts(p, "l", x, m, plain.Arith())
+
+
+def share(params, lo, hi):
+    """The leaves a device holding experts lo..hi has."""
+    return {k: v[lo:hi] if k in ("wg", "wu", "wd") else v
+            for k, v in params.items()}
+
+
+def test_layer_matches_plain_reference_and_trains(setup):
+    """Output and every leaf's gradient equal the reference's (router
+    and selection bias included: chosen by s + b, weighted by s), and a
+    gradient step lowers the loss."""
     params, x = setup
-    ref = moe_ffn(params, x, CAP)
+    target = jax.random.normal(jax.random.PRNGKey(2), (T, D))
+
+    def loss(f):
+        return lambda p, x_: jnp.mean((f(p, x_) - target) ** 2)
+
+    ours = loss(lambda p, x_: moe_ffn(p, x_, top_k=K, scale=SCALE)[0])
+    theirs = loss(reference)
+    (l0, g0), (l1, g1) = (jax.value_and_grad(f, argnums=(0, 1))(params, x)
+                          for f in (ours, theirs))
+    assert float(l0) == pytest.approx(float(l1), rel=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(g0),
+                    jax.tree_util.tree_leaves(g1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-6)
+    assert float(jnp.abs(g0[0]["wr"]).sum()) > 0      # the router learns
+    assert not np.any(np.asarray(g0[0]["br"]))        # its bias does not
+    stepped = {k: v - 0.1 * g0[0][k] for k, v in params.items()}
+    assert float(ours(stepped, x)) < float(l0)
+
+
+def test_selection_bias_chooses_but_does_not_weigh(setup):
+    params, x = setup
+    ids0, w0 = moe_lib.route(x, params["wr"], jnp.zeros((E,)), K, SCALE)
+    ids1, w1 = moe_lib.route(x, params["wr"], params["br"], K, SCALE)
+    assert np.any(np.asarray(ids0) != np.asarray(ids1))
+    s = jax.nn.sigmoid(jnp.matmul(x, params["wr"], precision="highest"))
+    chosen = jnp.take_along_axis(s, ids1, axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(w1), np.asarray(chosen / chosen.sum(-1, keepdims=True)
+                                   * SCALE), rtol=1e-6)
+    # top-k of s + b, not of s
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(ids1), axis=-1),
+        np.sort(np.asarray(jax.lax.top_k(s + params["br"], K)[1]), axis=-1))
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_no_token_is_dropped_at_any_skew(setup, mode):
+    """Every token chooses the same two experts, both held: the worst
+    case T * k rows, two buffers' worth. Exact, on both paths."""
+    params, x = setup
+    params = dict(params, br=jnp.zeros((E,)).at[jnp.array([2, 3])].set(50.))
+    held = share(params, 2, 4)
+    with common.force_mode(mode), common.record_dispatch() as tally:
+        y, rows = moe_ffn(held, x, top_k=K, scale=SCALE, offset=2)
+    assert list(np.asarray(rows)) == [T, T]
+    assert mode in tally["moe_grouped_matmul"]
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(reference(held, x, held=2, offset=2)),
+        rtol=2e-5, atol=2e-6)
+    # the buffer holds twice a uniform router's rows: this took 2 turns
+    assert moe_lib._chunk_rows(T, K, E, 2) * 2 == T * K
+
+
+def test_shares_add_up():
+    """32 experts over 4 shares of 8: the four partial sums, with the
+    shared expert counted once, equal the uncut layer of the uncut
+    reference; each share equals the reference given the same share."""
+    params = init_moe_params(jax.random.PRNGKey(3), D, H, 32)
+    params["br"] = 0.2 * jax.random.normal(jax.random.PRNGKey(4), (32,))
+    x = jax.random.normal(jax.random.PRNGKey(5), (T, D))
+    whole = reference(params, x)
+    shared = moe_lib.swiglu(x, params["sg"], params["su"], params["sd"])
+    total = shared
+    for i in range(4):
+        mine = share(params, 8 * i, 8 * i + 8)
+        part, rows = moe_ffn(mine, x, top_k=K, scale=SCALE, offset=8 * i,
+                             shared=False)
+        np.testing.assert_allclose(
+            np.asarray(part), np.asarray(reference(
+                mine, x, held=8, offset=8 * i, shared=False)),
+            rtol=2e-5, atol=2e-6)
+        total = total + part
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=2e-5, atol=3e-6)
+
+
+def test_sharded_matches_one_device_layer(setup):
+    """``make_moe`` over the model axis of a CPU mesh: partial sums
+    added, shared expert once; with and without padding."""
+    params, x = setup
     mesh = create_mesh(n_data=2, n_model=4)
-    fn = make_moe(mesh, "model", E, CAP)
-    got = fn(shard_moe_params(params, mesh, "model"), x)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
-                               rtol=2e-5, atol=2e-6)
-    # live-mask parity: the sharded dispatch honors the same ragged
-    # semantics as the reference (dead tokens claim no capacity)
-    live = (jnp.arange(B) % 3 != 0).astype(x.dtype)
-    ref_l = moe_ffn(params, x, CAP, live=live)
-    got_l = fn(shard_moe_params(params, mesh, "model"), x, live)
-    np.testing.assert_allclose(np.asarray(ref_l), np.asarray(got_l),
-                               rtol=2e-5, atol=2e-6)
+    fn = make_moe(mesh, "model", top_k=K, scale=SCALE)
+    sharded = shard_moe_params(params, mesh, "model")
+    np.testing.assert_allclose(
+        np.asarray(fn(sharded, x)),
+        np.asarray(moe_ffn(params, x, top_k=K, scale=SCALE)[0]),
+        rtol=2e-5, atol=2e-6)
+    live = (jnp.arange(T) % 3 != 0).astype(x.dtype)
+    np.testing.assert_allclose(
+        np.asarray(fn(sharded, x, live)),
+        np.asarray(moe_ffn(params, x, top_k=K, scale=SCALE, live=live)[0]),
+        rtol=2e-5, atol=2e-6)
 
 
-def test_gradients_flow_and_train(setup):
+def test_sharded_program_has_collective(setup):
     params, x = setup
-    y_target = jax.random.normal(jax.random.PRNGKey(2), (B, D))
-
-    def loss(p):
-        return jnp.mean((moe_ffn(p, x, CAP) - y_target) ** 2)
-
-    grads = jax.grad(loss)(params)
-    assert float(jnp.abs(grads["wg"]).sum()) > 0      # router learns
-    assert float(jnp.abs(grads["w1"]).sum()) > 0      # experts learn
-    l0 = float(loss(params))
-    p2 = jax.tree_util.tree_map(lambda p, g: p - 0.1 * g, params, grads)
-    assert float(loss(p2)) < l0
+    mesh = create_mesh(n_data=2, n_model=4)
+    fn = make_moe(mesh, "model", top_k=K, scale=SCALE)
+    sp = shard_moe_params(params, mesh, "model")
+    hlo = jax.jit(lambda p, x_: fn(p, x_)).lower(sp, x).compile().as_text()
+    assert "all-reduce" in hlo
 
 
-def test_capacity_clipping_is_static_and_effective():
-    params = init_moe_params(jax.random.PRNGKey(0), D, H, E)
-    # force every token to one expert: only `capacity` survive
-    params = dict(params)
-    params["wg"] = params["wg"] * 0.0 + jnp.eye(D, E) * 100.0
-    x = jnp.ones((B, D))
-    y = moe_ffn(params, x, capacity=4)
-    live = jnp.sum(jnp.any(y != 0.0, axis=-1))
-    assert int(live) == 4  # overflow dropped, shapes static
-
-
-def test_masked_tokens_claim_no_capacity():
-    """Ragged invariant (advisor r04 medium): dead/padded positions must
-    not claim capacity slots — the live tokens' outputs are identical
-    whatever amount of padding follows them."""
-    params = init_moe_params(jax.random.PRNGKey(0), D, H, E)
-    x = jax.random.normal(jax.random.PRNGKey(2), (8, D))
-    cap = 3  # tight: padding would crowd out live tokens without `live`
-    y_ref = moe_ffn(params, x, cap, live=jnp.ones(8))
-    # same live tokens + 24 padded rows interleaved ahead in flat order
+def test_masked_tokens_take_no_rows(setup):
+    """Padding is routed nowhere: it takes no expert's rows, gets the
+    shared expert only, and leaves the live tokens' outputs alone."""
+    params, x = setup
+    y_ref, rows_ref = moe_ffn(params, x[:8], top_k=K, scale=SCALE)
     pad = jax.random.normal(jax.random.PRNGKey(3), (24, D))
-    xp = jnp.concatenate([pad, x], axis=0)
     live = jnp.concatenate([jnp.zeros(24), jnp.ones(8)])
-    y_pad = moe_ffn(params, xp, cap, live=live)
+    y_pad, rows = moe_ffn(params, jnp.concatenate([pad, x[:8]]), top_k=K,
+                          scale=SCALE, live=live)
     np.testing.assert_allclose(np.asarray(y_pad[24:]), np.asarray(y_ref),
-                               rtol=1e-6, atol=1e-6)
-    assert not np.any(np.asarray(y_pad[:24]))  # dead rows produce zeros
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_ref))
+    assert int(rows.sum()) == 8 * K
+
+
+def _layer(name="mx", **kw):
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.registry import get_layer_impl
+    dsl.reset()
+    x = dsl.data(name="x", size=D, is_sequence=True)
+    dsl.moe(input=x, expert_hidden=H, num_experts=E, top_k=K,
+            shared_hidden=H, routed_scaling_factor=SCALE, name=name, **kw)
+    cfg = dsl.current_graph().layers[name]
+    impl = get_layer_impl("moe")
+    infos = [type("I", (), {"size": D, "is_sequence": True})()]
+    return cfg, impl, impl.params(cfg, infos)
 
 
 def test_moe_layer_respects_sequence_mask():
-    """The registered `moe` layer threads Argument.mask into dispatch:
-    growing the pad length leaves live positions' outputs unchanged."""
+    """The registered ``moe`` layer threads Argument.mask into dispatch:
+    growing the pad length leaves live positions' outputs and the rows
+    it reports unchanged."""
     from paddle_tpu.core.argument import Argument
-    from paddle_tpu.core.registry import get_layer_impl
-    from paddle_tpu.config import dsl
-
-    dsl.reset()
-    x = dsl.data(name="x", size=D)
-    m = dsl.moe(input=x, expert_hidden=H, num_experts=E, capacity=6,
-                name="mx")
-    cfg = dsl.current_graph().layers["mx"]
-    impl = get_layer_impl("moe")
-    infos = [type("I", (), {"size": D, "is_sequence": True})()]
+    cfg, impl, specs = _layer(experts_held=4, expert_offset=2)
+    assert specs["wg"].shape == (4, D, H) and specs["wr"].shape == (D, E)
+    assert specs["wr"].compute_f32 and specs["br"].is_static
     key = jax.random.PRNGKey(0)
-    params = {k: jax.random.normal(key, s.shape) * 0.1
-              for k, s in impl.params(cfg, infos).items()}
+    params = {k: jax.random.normal(jax.random.fold_in(key, i), s.shape) * 0.1
+              for i, (k, s) in enumerate(specs.items())}
     v = jax.random.normal(jax.random.PRNGKey(1), (2, 4, D))
     mask = jnp.asarray([[1, 1, 1, 0], [1, 1, 0, 0]], jnp.float32)
-    a_short = Argument(value=v, mask=mask)
-    out_short = impl.apply(cfg, params, [a_short], None)
-    # re-pad to T=9 with garbage values in the dead tail
+    out_short = impl.apply(cfg, params, [Argument(value=v, mask=mask)], None)
     v_long = jnp.concatenate(
         [v, jax.random.normal(jax.random.PRNGKey(2), (2, 5, D))], axis=1)
     mask_long = jnp.concatenate([mask, jnp.zeros((2, 5))], axis=1)
@@ -114,50 +212,139 @@ def test_moe_layer_respects_sequence_mask():
     np.testing.assert_allclose(np.asarray(out_long.value[:, :4]),
                                np.asarray(out_short.value),
                                rtol=1e-5, atol=1e-5)
+    short, long = out_short.state["counters"], out_long.state["counters"]
+    assert set(short) == {"moe_rows_max", "moe_rows_mean",
+                          "moe_experts_active"}
+    for name in short:
+        assert float(long[name]) == float(short[name]), name
+    # 5 live tokens choose K of E experts each; 4 of the E are held
+    assert 0 < float(short["moe_rows_mean"]) * 4 <= 5 * K
+    assert 1 <= float(short["moe_experts_active"]) <= 4
 
 
 def test_moe_layer_trains_and_shards():
-    """`dsl.moe`: the registered layer type trains through SGD and its
-    expert weights shard over the model axis via shard_rules."""
+    """``dsl.moe``: the registered layer type trains through SGD, its
+    expert weights shard over the model axis via shard_rules, and the
+    step hands back the rows of each held expert with the cost."""
     from paddle_tpu.config import dsl
     from paddle_tpu.data import DataFeeder, dense_vector, integer_value
     from paddle_tpu.optim import Momentum
     from paddle_tpu.trainer import SGD
 
-    def model():
-        dsl.reset()
-        x = dsl.data(name="x", size=D)
-        lab = dsl.data(name="label", size=4)
-        m = dsl.moe(input=x, expert_hidden=H, num_experts=E,
-                    capacity=CAP, name="mx")
-        out = dsl.fc(input=m, size=4, act="softmax", name="out")
-        return dsl.classification_cost(input=out, label=lab)
-
+    dsl.reset()
+    x = dsl.data(name="x", size=D)
+    lab = dsl.data(name="label", size=4)
+    m = dsl.moe(input=x, expert_hidden=H, num_experts=E, top_k=K,
+                shared_hidden=H, name="mx")
+    out = dsl.fc(input=m, size=4, act="softmax", name="out")
+    cost = dsl.classification_cost(input=out, label=lab)
     rng = np.random.RandomState(0)
     X = rng.randn(64, D).astype(np.float32)
     Y = rng.randint(0, 4, 64)
     feeder = DataFeeder({"x": dense_vector(D), "label": integer_value(4)})
-
     mesh = create_mesh(n_data=2, n_model=4)
-    tr = SGD(cost=model(), update_equation=Momentum(learning_rate=0.1),
-             mesh=mesh,
-             shard_rules={"_mx.w1": P("model"), "_mx.b1": P("model"),
-                          "_mx.w2": P("model"), "_mx.b2": P("model")})
-    assert tr.params["_mx.w1"].sharding.spec == P("model")
-    errs = []
+    tr = SGD(cost=cost, update_equation=Momentum(learning_rate=0.1),
+             mesh=mesh, shard_rules={"_mx.wg": P("model"),
+                                     "_mx.wu": P("model"),
+                                     "_mx.wd": P("model")})
+    assert tr.params["_mx.wg"].sharding.spec == P("model")
+    before = np.asarray(tr.params["_mx.wg"]).copy()
     tr.train(lambda: iter([[(X[i], int(Y[i])) for i in range(64)]]),
-             feeder=feeder, num_passes=3,
-             event_handler=lambda e: errs.append(e) if hasattr(
-                 e, "evaluator") and e.evaluator else None)
-    assert np.isfinite(float(np.asarray(
-        tr.params["_mx.w1"]).sum()))  # trained, still sharded
-    assert tr.params["_mx.w1"].sharding.spec == P("model")
+             feeder=feeder, num_passes=3)
+    after = np.asarray(tr.params["_mx.wg"])
+    assert np.isfinite(after.sum()) and np.any(after != before)
+    assert not np.any(np.asarray(tr.params["_mx.br"]))   # static
+    assert tr.params["_mx.wg"].sharding.spec == P("model")
+    totals = tr.breakdown.totals
+    assert totals["moe_rows_mean"] == pytest.approx(3 * 64 * K / E)
+    assert totals["moe_rows_max"] >= totals["moe_rows_mean"]
+    assert 3 <= totals["moe_experts_active"] <= 3 * E
 
 
-def test_sharded_program_has_collective(setup):
+def test_layer_counters_reach_totals_and_the_armed_steps_span():
+    """What a layer puts in ``state["counters"]`` comes back with the
+    cost: summed into ``StepBreakdown.totals`` under its own name and,
+    with a Tracer armed, attributes of each step's span. The channel
+    knows no layer kind: a name nobody registered goes the same way."""
+    from paddle_tpu.config import dsl
+    from paddle_tpu.data import DataFeeder, dense_vector, integer_value
+    from paddle_tpu.obs import trace
+    from paddle_tpu.optim import Momentum
+    from paddle_tpu.trainer import SGD
+    from paddle_tpu.utils.profiler import StepBreakdown
+
+    dsl.reset()
+    x = dsl.data(name="x", size=D)
+    lab = dsl.data(name="label", size=4)
+    m = dsl.moe(input=x, expert_hidden=H, num_experts=E, top_k=K, name="mx")
+    out = dsl.fc(input=m, size=4, act="softmax", name="out")
+    cost = dsl.classification_cost(input=out, label=lab)
+    rng = np.random.RandomState(1)
+    X = rng.randn(16, D).astype(np.float32)
+    Y = rng.randint(0, 4, 16)
+    feeder = DataFeeder({"x": dense_vector(D), "label": integer_value(4)})
+    tr = SGD(cost=cost, update_equation=Momentum(learning_rate=0.1))
+    tracer = trace.install(trace.Tracer("test"))
+    try:
+        tr.train(lambda: iter([[(X[i], int(Y[i])) for i in range(16)]] * 2),
+                 feeder=feeder, num_passes=1)
+    finally:
+        trace.install(None)
+    steps = [s for s in tracer.spans() if s["name"] == "train.step"]
+    assert len(steps) == 2
+    for name in ("moe_rows_max", "moe_rows_mean", "moe_experts_active"):
+        assert tr.breakdown.totals[name] == pytest.approx(
+            sum(s["attrs"][name] for s in steps))
+    assert steps[0]["attrs"]["moe_rows_mean"] == pytest.approx(16 * K / E)
+    bd = StepBreakdown()
+    bd.add_counters({"anything": np.float32(2.5)})
+    bd.add_counters({"anything": 1})
+    assert bd.totals["anything"] == 3.5
+
+
+def test_unwritten_rows_of_the_kernel_poison_nothing(setup, monkeypatch):
+    """The grouped-product kernel leaves the buffer's rows past the last
+    expert's unwritten, forward and in its left operand's gradient, and
+    on the chip what lies there may be no number (it was, PR 27: a zero
+    cotangent times such a row made every gradient upstream NaN on some
+    seeds). With NaN planted there, output and gradients are those of
+    the other path."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     params, x = setup
-    mesh = create_mesh(n_data=2, n_model=4)
-    fn = make_moe(mesh, "model", E, CAP)
-    sp = shard_moe_params(params, mesh, "model")
-    hlo = jax.jit(fn).lower(sp, x).compile().as_text()
-    assert "all-gather" in hlo or "all-to-all" in hlo
+
+    def written(rows, sizes):
+        return jnp.arange(rows)[:, None] < jnp.sum(sizes)
+
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, sizes):
+        out = jax.lax.ragged_dot(lhs, rhs, sizes)
+        return jnp.where(written(lhs.shape[0], sizes), out, jnp.nan)
+
+    def fwd(lhs, rhs, sizes):
+        return poisoned(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def bwd(saved, g):
+        lhs, rhs, sizes = saved
+        dl, dr = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes),
+                         lhs, rhs)[1](g)
+        return jnp.where(written(lhs.shape[0], sizes), dl, jnp.nan), dr, None
+
+    poisoned.defvjp(fwd, bwd)
+    monkeypatch.setattr(megablox, "gmm",
+                        lambda lhs, rhs, sizes, *a, **kw: poisoned(
+                            lhs, rhs, sizes))
+    held = share(params, 2, 6)
+    target = jax.random.normal(jax.random.PRNGKey(2), (T, D))
+
+    def loss(p, x_):
+        y, _ = moe_ffn(p, x_, top_k=K, scale=SCALE, offset=2)
+        return jnp.mean((y - target) ** 2)
+
+    want = jax.value_and_grad(loss, argnums=(0, 1))(held, x)
+    with common.force_mode("interpret"):
+        got = jax.value_and_grad(loss, argnums=(0, 1))(held, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-6)

@@ -124,13 +124,52 @@ def test_flash_attention_kernel(causal):
         out = flash_attention(q, k, v, kv_mask, causal=causal,
                               block_q=16, block_k=16)
         np.testing.assert_allclose(out, out_ref, rtol=1e-5, atol=1e-5)
-        # grads route through the blockwise recompute backward
+        # grads come from the two backward kernels
         g = jax.grad(lambda q_: jnp.sum(flash_attention(
             q_, k, v, kv_mask, causal=causal, block_q=16, block_k=16) ** 2)
         )(q)
     g_ref = jax.grad(lambda q_: jnp.sum(
         mha_reference(q_, k, v, kv_mask, causal=causal) ** 2))(q)
     np.testing.assert_allclose(g, g_ref, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", [
+    # B, N, Tq, Tk, Dqk, Dv, block_q, block_k, ragged mask
+    (2, 2, 64, 64, 24, 16, 16, 16, False),    # latent attention's 3:2
+    (2, 3, 40, 72, 24, 16, 16, 16, True),     # Tq != Tk, padded, masked
+    (1, 2, 64, 64, 12, 8, 32, 16, False),     # block_q != block_k
+    (1, 2, 64, 64, 12, 8, 16, 32, False),
+])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_qk_and_v_widths_differ(shape, causal):
+    """q, k of one head size and v of another, forward and the backward
+    kernels (dQ; dK, dV) against ``mha_reference``; ``blockwise_attention``
+    takes the same shapes. Causal blocks above the diagonal are skipped:
+    the result must not notice."""
+    B, N, Tq, Tk, Dqk, Dv, bq, bk, ragged = shape
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.normal(size=(B, N, Tq, Dqk)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, N, Tk, Dqk)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, N, Tk, Dv)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, N, Tq, Dv)), jnp.float32)
+    kv_mask = jnp.asarray(_ragged_mask(Tk, B, rng).T) if ragged else None
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_) * w), (0, 1, 2))(q, k, v)
+
+    want = run(lambda *a: mha_reference(*a, kv_mask, causal=causal))
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        got = run(lambda *a: flash_attention(
+            *a, kv_mask, causal=causal, block_q=bq, block_k=bk))
+    assert tally["flash_attention"] == {"interpret": 1}
+    blk = run(lambda *a: blockwise_attention(*a, kv_mask, causal=causal,
+                                             block_k=bk))
+    for have in (got, blk):
+        assert float(have[0]) == pytest.approx(float(want[0]), rel=1e-5)
+        for g, rg in zip(have[1], want[1]):
+            np.testing.assert_allclose(g, rg, rtol=1e-4, atol=2e-5)
 
 
 def test_lstm_layer_uses_fused_path():

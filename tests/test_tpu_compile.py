@@ -1,0 +1,78 @@
+"""The new cell's kernels compiled for a described TPU v5e at the
+widths the cell runs them, with no chip: Mosaic refuses here what it
+would refuse there (a slice off the tiling, too much VMEM). Nothing
+runs; a compile that passes is not a chip run. All in this one file:
+the worker that gets it is the one process that loads the TPU's
+compiler."""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import common
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops the describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with common.force_mode("pallas"):
+            return jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_latent_attention_core_forward_and_backward(one_chip):
+    """q, k of 192 and v of 128, 32 heads, 4,096 positions, causal,
+    512 x 512 tiles: the forward and the two backward kernels."""
+    from paddle_tpu.ops.attention import flash_attention
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def step(q, k, v, g):
+        return jax.vjp(lambda *a: flash_attention(
+            *a, None, causal=True, block_q=512, block_k=512), q, k, v)[1](g)
+
+    compiled = _compile(step, sd(2, 32, 4096, 192), sd(2, 32, 4096, 192),
+                        sd(2, 32, 4096, 128), sd(2, 32, 4096, 128))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_routed_experts_forward_and_backward(one_chip):
+    """8 held experts of 256, 8 a token, 8,192 tokens of 2,048, width
+    768: the loop over buffers of 4,096 rows, forward and backward."""
+    from paddle_tpu.parallel.moe import routed_experts
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(x, wg, wu, wd, ids, gates, dy):
+        def f(x, wg, wu, wd, gates):
+            return routed_experts(x, wg, wu, wd, ids, gates,
+                                  n_experts=256, offset=8)[0]
+        return jax.vjp(f, x, wg, wu, wd, gates)[1](dy)
+
+    compiled = _compile(
+        step, sd((8192, 2048)), sd((8, 2048, 768)), sd((8, 2048, 768)),
+        sd((8, 768, 2048)), sd((8192, 8), jnp.int32),
+        sd((8192, 8), jnp.float32), sd((8192, 2048)))
+    assert compiled.as_text().count("tpu_custom_call") >= 9
