@@ -194,7 +194,8 @@ class Network:
         ctx = Context(train=train, rng=rng, carried=carried or {},
                       mesh=mesh)
         from paddle_tpu.layers.activations import apply_activation  # cycle-free
-        from paddle_tpu.ops.common import step_mesh  # cycle-free
+        from paddle_tpu.ops.common import (  # cycle-free
+            KEPT_RESIDUAL, step_mesh)
         from paddle_tpu.utils.error_context import layer_scope
 
         for name in self.order:
@@ -239,7 +240,13 @@ class Network:
                 if layer.attrs.get("recompute") and train:
                     # per-layer rematerialization: trade recompute FLOPs
                     # for activation HBM (jax.checkpoint; the TPU-native
-                    # render of memory-pressure knobs). Static Python
+                    # render of memory-pressure knobs). Kept across the
+                    # forward pass: the layer's inputs, and whatever a
+                    # kernel's forward rule named common.KEPT_RESIDUAL
+                    # (the attention core's output and log-sum-exp, so
+                    # its forward kernel is not run again); a layer in
+                    # which nothing is named keeps its inputs only. The
+                    # kernel decides, where its cost is known. Static Python
                     # metadata in Argument.state (e.g. a nested group's
                     # shape ints) must NOT pass through checkpoint as
                     # pytree leaves — it would come back as tracers and
@@ -258,7 +265,10 @@ class Network:
                         cell["is_arr"] = is_arr
                         return [v for v, a in zip(leaves, is_arr) if a]
 
-                    arrs = jax.checkpoint(arrays_only)(lparams, ins)
+                    arrs = jax.checkpoint(
+                        arrays_only,
+                        policy=jax.checkpoint_policies.save_only_these_names(
+                            KEPT_RESIDUAL))(lparams, ins)
                     it = iter(arrs)
                     leaves = [next(it) if a else s
                               for a, s in zip(cell["is_arr"],

@@ -42,8 +42,12 @@ def joyai_llm_flash(*, vocab_size: int = 129280, hidden_size: int = 2048,
     """Returns (cost, softmax_output, data_names). ``recompute`` marks
     the attention and dense feed-forward layers for rematerialisation
     (an expert layer keeps little: its routed part recomputes from the
-    layer's input by itself); the softmax output is for inference and no
-    part of the cost's graph."""
+    layer's input by itself). A feed-forward layer then keeps its input
+    alone; an attention layer keeps its core's output and log-sum-exp
+    besides (the kernel names them, ``ops/attention.py``: 68 MB a layer
+    at 2 x 4,096 tokens, 32 heads of 128), so the backward pass
+    recomputes the projections and not the core. The softmax output is
+    for inference and no part of the cost's graph."""
     if num_nextn_predict_layers not in (0, 1):
         raise ValueError("one multi-token-prediction module at most")
     remat = {"recompute": True} if recompute else None

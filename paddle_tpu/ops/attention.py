@@ -20,7 +20,11 @@ Three tiers:
   the kv sweep innermost): memory linear in T, nothing of size T_q·T_k
   is ever held. Under ``causal`` the blocks that lie wholly above the
   diagonal are skipped, in all three (their index maps repeat the last
-  needed block, so nothing is fetched for them either).
+  needed block, so nothing is fetched for them either). The forward rule
+  names its output and the log-sum-exp ``common.KEPT_RESIDUAL``: a
+  layer under the executor's checkpoint keeps those two (T·Dv and T a
+  head) and recomputes what led to q, k and v, so the forward kernel
+  runs once a step, not again in the backward pass.
 
 All take q, k of [B, N, T, Dqk] and v of [B, N, T_k, Dv] (the two head
 sizes may differ: latent attention has 192 and 128), an optional kv
@@ -34,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -363,7 +368,10 @@ def _flash_core(cfg, qf, kf, vf, mask):
 
 
 def _flash_fwd(cfg, qf, kf, vf, mask):
-    out, lse = _flash_forward(cfg, qf, kf, vf, mask)
+    # named as they leave the kernel, so that the layer's own use of `out`
+    # (the output projection) reads the kept array too
+    out, lse = checkpoint_name(_flash_forward(cfg, qf, kf, vf, mask),
+                               common.KEPT_RESIDUAL)
     return out, (qf, kf, vf, mask, out, lse)
 
 

@@ -215,6 +215,12 @@ def replica_local(fn):
 NEG = -1e30     # finite -inf stand-in (log-space padding)
 LANE = 128      # TPU vector lane width; minor axes pad to a multiple
 
+# The name (``jax.ad_checkpoint.checkpoint_name``) a kernel's forward rule
+# gives the residuals that are dearer to recompute than to keep. The
+# executor's per-layer checkpoint (``core/network.py``) keeps exactly the
+# arrays under this name; outside a checkpoint the name is an identity.
+KEPT_RESIDUAL = "kept_residual"
+
 
 def time_block(*shape):
     """BlockSpec for a [T, ...]-shaped operand consumed one step per grid

@@ -57,6 +57,45 @@ def test_latent_attention_core_forward_and_backward(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+def test_recomputed_latent_layer_runs_the_forward_kernel_once(one_chip):
+    """The cell's attention layer (2 x 4,096 tokens of 2,048, 32 heads,
+    192/128 core at 512 x 512 tiles) marked `recompute`, through the
+    executor: its forward and backward hold the forward kernel, dK/dV
+    and dQ, and no second forward kernel for the recomputation."""
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.argument import Argument
+    from paddle_tpu.core.network import Network
+
+    dsl.reset()
+    x = dsl.data(name="x", size=2048, is_sequence=True)
+    attn = dsl.mla_attention(
+        x, num_heads=32, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_theta=32e6, name="attn", layer_attr={"recompute": True})
+    net = Network(dsl.current_graph(), outputs=[attn.name])
+
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape.shape, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        sd, jax.eval_shape(net.init_params, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16,
+                                  sharding=one_chip)
+
+    def step(params, xv, g):
+        def layer(params, xv):
+            return net.apply(params, {"x": Argument(value=xv)},
+                             train=True)[attn.name].value
+        # the output too, or a forward pass nobody reads would be dropped
+        out, back = jax.vjp(layer, params, xv)
+        return out, back(g)
+
+    compiled = _compile(step, params, tokens, tokens)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+
+
 def test_routed_experts_forward_and_backward(one_chip):
     """8 held experts of 256, 8 a token, 8,192 tokens of 2,048, width
     768: the loop over buffers of 4,096 rows, forward and backward."""
