@@ -190,7 +190,7 @@ def train_phase(w: Width, *, mesh=None, expect_mosaic: bool = True,
             raise AssertionError(f"LSTM left the resident kernel: {report}")
         # every dense f32 parameter takes the fused update: on one
         # chip directly, on the data-parallel mesh on each device over
-        # its own replica (kernels/opt_update.py)
+        # its own replica (ops/opt_update.py)
         if set(tally.get("opt_update", {})) != {"fused"}:
             raise AssertionError(
                 f"a parameter left the fused optimizer update: {tally}")

@@ -884,9 +884,6 @@ def _iter_source_files(root: str,
             for fname in sorted(files):
                 if fname.endswith((".py", ".sh")):
                     yield os.path.join(dirpath, fname)
-    extra = os.path.join(root, "bench.py")
-    if os.path.exists(extra):
-        yield extra
 
 
 def run_pass1(root: str,

@@ -2,8 +2,12 @@
 ``MULTICHIP_*.json``, ``ACCURACY_*.json``, ``MEM_*.json`` and
 ``HEALTH_*.json``.
 
-These artifacts are the evidence trail (perf best-of-R discipline,
-multichip dryruns, real-corpus accuracy runs). A malformed artifact —
+These artifacts are the evidence trail of the rounds before the chip
+(CPU-side structure runs, multichip dryruns, real-corpus accuracy
+runs); the program that wrote the ``BENCH_*`` / ``WORKLOAD_*`` families
+is gone and nothing regenerates them (ROADMAP D1b) — the numbers users
+are told live in ``PERF_LEDGER.jsonl``, written by ``python3 -m
+benchmark.run``. A malformed artifact —
 truncated JSON, a NaN ratio, an A/B metric missing its sides — should
 fail at *lint* time, not at ROADMAP-review time when the run that
 produced it is long gone.
@@ -19,16 +23,15 @@ looser schema):
 - ``ACCURACY_*``: ``{"platform": str, ...}`` plus at least one named
   run section (a dict) — an accuracy artifact with no run sections
   recorded nothing.
-- ``TRACE_*`` (committed distributed-trace evidence, e.g. the
-  ``bench.py --fleet`` failover trace): ``{"spans": [...]}`` with a
+- ``TRACE_*`` (distributed-trace evidence, an ``obs.trace``
+  ``dump_jsonl`` bundled as one object): ``{"spans": [...]}`` with a
   NON-EMPTY span list, every span carrying string ``trace_id`` /
   ``span_id`` / ``name``, numeric ``ts`` and ``dur_ms >= 0``, spans
   sorted by ``ts`` (monotone file order), and every non-null
   ``parent_id`` resolving to another span's ``span_id`` in the same
   file — a trace whose parents dangle reconstructs nothing.
-- ``HEALTH_*`` (committed training-health timelines: the sampled
-  run `bench.py --health` writes, or a snapshot of an
-  ``obs/events.py`` JSONL bundled as one object):
+- ``HEALTH_*`` (committed training-health timelines: a snapshot of
+  an ``obs/events.py`` JSONL bundled as one object):
   ``{"run": str, "period": int >= 0, "events": [...]}`` with a
   NON-EMPTY events list, every event carrying an int ``step >= 0``
   in monotone non-decreasing order and a finite numeric ``loss`` —
@@ -40,8 +43,8 @@ looser schema):
   ``{"programs": {name: {field: int >= 0, ...}, ...}}`` with a
   non-empty programs map — a malformed snapshot is a finding, not a
   silently unplottable file.
-- ``WORKLOAD_*`` (committed request traces, the ``bench.py
-  --autotune`` record / ``tests/test_workload_replay.py`` replay pair):
+- ``WORKLOAD_*`` (committed request traces, replayed by
+  ``tests/test_workload_replay.py``):
   ``{"workload": str, "version": 1, "n_events": int, "duration_s":
   num >= 0, "events": [...]}`` with a NON-EMPTY events list whose
   length matches ``n_events``, every event carrying the full replay

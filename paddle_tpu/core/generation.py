@@ -46,14 +46,10 @@ and per-lane ``[B, 1]`` / ``[B]`` arrays inside a :class:`DecodeSession`
 Python branches, and they work identically in both.
 
 Compile-key policy (``docs/generation.md``): one executable per
-``(beam_size, max_length, decode_chunk-or-full_scan, hooks,
-fused-RNN-flag)`` key, the cache LRU-bounded at ``_JIT_CACHE_CAP`` —
-per-call hook *lambdas* mint fresh keys every call and would otherwise
-leak compiled executables; pin hooks at module level (or in the config)
-to reuse the cache. The fused-RNN inference-cell switch
-(``kernels.dispatch.rnn_cells_enabled``) is folded into the key inside
-``_jit_for`` itself: the step net resolves it at trace time, so two
-flag states are two programs.
+``(beam_size, max_length, decode_chunk-or-full_scan, hooks)`` key, the
+cache LRU-bounded at ``_JIT_CACHE_CAP`` — per-call hook *lambdas* mint
+fresh keys every call and would otherwise leak compiled executables; pin
+hooks at module level (or in the config) to reuse the cache.
 """
 
 from __future__ import annotations
@@ -67,7 +63,6 @@ import numpy as np
 from jax import lax
 
 from paddle_tpu.core.argument import Argument
-from paddle_tpu.kernels.dispatch import rnn_cells_enabled
 from paddle_tpu.utils.log import get_logger
 
 logger = get_logger("generation")
@@ -248,13 +243,6 @@ class SequenceGenerator:
 
     def _jit_for(self, key, K, L, hooks, chunk):
         """LRU-bounded lookup of the compiled search for ``key``."""
-        # the fused-RNN-cell switch is resolved at TRACE time inside
-        # net.apply (layers/recurrent.py picks lstm_cell/_infer per
-        # rnn_cells_enabled()), so it is part of the compiled program's
-        # identity: appended HERE — the one funnel both generate() and
-        # the serving warmup's direct _jit_for call pass through — so
-        # toggling the flag can never serve a stale compiled search
-        key = key + (rnn_cells_enabled(),)
         fn = self._jitted.get(key)
         if fn is not None:
             self._jitted.move_to_end(key)

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddle_tpu import kernels as _kernels
 from paddle_tpu.core.argument import Argument
 from paddle_tpu.core.registry import (LayerImpl, ParamSpec, ShapeInfo,
                                       register_layer)
@@ -123,17 +122,6 @@ class LstmLayer(LayerImpl):
         def step(carry, x_t):
             h, c = carry
             gates = x_t + h @ w + gate_bias
-            if _kernels.rnn_cells_enabled():
-                # fused cell (kernels/rnn_cells.py): the fallback
-                # spelling is this inline math verbatim, so the flag is
-                # bitwise-invisible off-TPU; no-grad serving takes the
-                # primal-only inference spelling (no residual plumbing)
-                cell = (_kernels.lstm_cell if ctx.train
-                        else _kernels.lstm_cell_infer)
-                out, state = cell(
-                    gates, c, check_i, check_f, check_o,
-                    act_in_name, act_gate_name, act_state_name)
-                return (out, state), out
             g_in, g_ig, g_fg, g_og = jnp.split(gates, 4, axis=-1)
             g_in = act_in(g_in)
             g_ig = act_gate(g_ig + c * check_i)
@@ -197,12 +185,6 @@ class GruLayer(LayerImpl):
         def step(carry, x_t):
             (h,) = carry
             x_t = x_t + bias
-            if _kernels.rnn_cells_enabled():
-                cell = (_kernels.gru_cell if ctx.train
-                        else _kernels.gru_cell_infer)
-                out = cell(x_t, h, w_gate, w_state,
-                           act_in_name, act_gate_name)
-                return (out,), out
             zr = x_t[:, : 2 * size] + h @ w_gate
             z = act_gate(zr[:, :size])
             r = act_gate(zr[:, size:])
@@ -283,13 +265,6 @@ class GruStepLayer(LayerImpl):
             x = x + params["wbias"]
         w_gate = params["w0"][:, : 2 * size]
         w_state = params["w0"][:, 2 * size:]
-        if _kernels.rnn_cells_enabled():
-            cell = (_kernels.gru_cell if ctx.train
-                    else _kernels.gru_cell_infer)
-            return Argument(value=cell(
-                x, h, w_gate, w_state,
-                cfg.attrs.get("active_type", "tanh"),
-                cfg.attrs.get("active_gate_type", "sigmoid")))
         zr = x[:, : 2 * size] + h @ w_gate
         z = act_gate(zr[:, :size])
         r = act_gate(zr[:, size:])
@@ -334,15 +309,6 @@ class LstmStepLayer(LayerImpl):
         else:
             z = jnp.zeros((size,), gates.dtype)
             check_i = check_f = check_o = z
-        if _kernels.rnn_cells_enabled():
-            cell = (_kernels.lstm_cell if ctx.train
-                    else _kernels.lstm_cell_infer)
-            out, state = cell(
-                gates, c_prev, check_i, check_f, check_o,
-                cfg.attrs.get("active_type", "tanh"),
-                cfg.attrs.get("active_gate_type", "sigmoid"),
-                cfg.attrs.get("active_state_type", "tanh"))
-            return Argument(value=out, state={"state": state})
         g_in, g_ig, g_fg, g_og = jnp.split(gates, 4, axis=-1)
         g_in = act_in(g_in)
         g_ig = act_gate(g_ig + c_prev * check_i)

@@ -29,8 +29,8 @@ Span taxonomy (``docs/observability.md`` is the catalog):
 Zero-cost discipline: recording guards on the module global
 ``_TRACER`` (None == off). Context/id *generation* is NOT gated — the
 ``X-Trace-Id`` echo contract needs ids whether or not anyone records —
-but it is plain ``os.urandom`` string work, and the A/B in
-``bench.py --fleet`` pins the on-vs-off overhead.
+but it is plain ``os.urandom`` string work (its cost on the chip:
+not measured).
 
 Buffers are bounded (deque, default 4096 spans; evictions counted in
 ``Tracer.dropped``); ``dump_jsonl`` writes spans sorted by wall-clock

@@ -12,7 +12,10 @@ by Pallas TPU kernels with two fallback tiers:
   are also the ground truth the kernels are unit-tested against.
 
 Selection is automatic (see ``common.use_pallas``); nothing else in the
-framework needs to know which tier ran.
+framework needs to know which tier ran. ``common`` is the one policy for
+every kernel in the tree (``docs/kernels.md``): the entries here, the
+fused optimizer update (``ops/opt_update.py``) and the grouped products
+of ``parallel/moe.py``.
 """
 
 from paddle_tpu.ops.common import use_pallas, force_mode

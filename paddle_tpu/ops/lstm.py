@@ -457,8 +457,8 @@ BENCH_SHAPES = [(64, 256), (64, 512), (64, 1280), (128, 256), (128, 1280),
 
 def kernel_dispatch_table():
     """{"lstm_bs{B}_h{H}": path} for every BASELINE.md rnn-table shape
-    (benchmark/README.md:108-161). bench.py embeds this in its output so
-    perf claims and dispatch can never drift apart silently."""
+    (benchmark/README.md:108-161): what ``lstm_dispatch`` decides at
+    each, in one place (``tests/test_ops_pallas.py`` pins it)."""
     return {f"lstm_bs{b}_h{h}": lstm_dispatch(b, h)
             for b, h in BENCH_SHAPES}
 

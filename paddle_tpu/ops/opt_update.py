@@ -9,9 +9,10 @@ ZeRO-1 shard-wise update and the packed FSDP update all reuse it.
 
 Contract (``docs/kernels.md``):
 
-- the fallback IS ``Optimizer._apply_one`` — off-TPU (or for any
-  optimizer/slot/dtype shape the kernels don't cover) the routing is
-  the identity, bitwise by construction;
+- the fallback IS ``Optimizer._apply_one`` — off-TPU, in the reference
+  mode (``common.force_mode("ref")`` / ``PADDLE_TPU_KERNELS=ref``, the
+  one switch) or for any optimizer/slot/dtype shape the kernels don't
+  cover the routing is the identity, bitwise by construction;
 - the Pallas spelling is numerically the same chain; its outputs feed
   the same slot dict shape ``_update_param`` expects (``prune_mask``
   re-attachment happens in the caller, as for ``_apply_one``);
@@ -168,7 +169,6 @@ def apply_one(opt, p, g, slots, lr, decay, t):
     """Fused stand-in for ``opt._apply_one`` on the dense path. The slot
     dict may carry ``prune_mask`` (ignored here, re-attached by
     ``_update_param``, matching ``_apply_one``'s contract)."""
-    from paddle_tpu.kernels import dispatch
     kind = type(opt).__name__
     keys = set(slots) - {"prune_mask"}
     fused = arrays = None
@@ -181,8 +181,7 @@ def apply_one(opt, p, g, slots, lr, decay, t):
         arrays = (p, g, slots["mom"], slots["v"])
         fused = lambda p, g, m, v, lr, decay, t: _adam_fused(  # noqa: E731
             p, g, m, v, lr, t, opt.beta1, opt.beta2, opt.epsilon, decay)
-    run = (_per_device(fused, arrays)
-           if fused and dispatch.fused_optimizer_enabled() else None)
+    run = _per_device(fused, arrays) if fused else None
     if run is None:
         common.note("opt_update", "apply_one")
         return opt._apply_one(p, g, slots, lr, decay, t)

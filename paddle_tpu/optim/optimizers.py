@@ -133,12 +133,12 @@ class Optimizer:
             p_new, slots_new = self._apply_sparse(
                 p, g, slots, lr_t * lr_mult, l1, l2, t)
         else:
-            # the dense elementwise chain routes through the fused-kernel
-            # plane (kernels/opt_update.py): Pallas-on-TPU for the
+            # the dense elementwise chain routes through the fused
+            # update (ops/opt_update.py): Pallas-on-TPU for the
             # Momentum/Adam chains, _apply_one itself everywhere else —
             # so the replicated, ZeRO-1 shard-wise and packed FSDP
             # updates all share the one fused entry
-            from paddle_tpu.kernels import opt_update as _fused
+            from paddle_tpu.ops import opt_update as _fused
             p_new, slots_new = _fused.apply_one(
                 self, p, g, slots, lr_t * lr_mult, l2, t)
             if l1 > 0:

@@ -37,8 +37,8 @@ hedge wait included — the number the kill-and-respawn bench reports as
 fleet p99).
 
 Exported two ways: :meth:`ServingMetrics.snapshot` (the ``/metrics``
-JSON + ``bench.py --serving``) and :meth:`to_prometheus` (text format,
-``# TYPE`` lines included, for scrapers).
+JSON) and :meth:`to_prometheus` (text format, ``# TYPE`` lines
+included, for scrapers).
 
 Quantiles come from a bounded reservoir of the most recent samples
 (deque, default 4096) — honest recent-window p50/p95/p99 without

@@ -1,12 +1,12 @@
 """Canonical workload mixes for the self-tuning loop.
 
 The committed ``WORKLOAD_r21_*.json`` traces pin the REQUEST stream;
-this module pins the fleet they were recorded against. ``bench.py
---autotune`` (which records the traces and runs the defaults-vs-tuned
-A/B) and ``tests/test_workload_replay.py`` (which replays the committed
-traces and asserts the determinism contract) both build their engines
-HERE, so a drifted model or knob default shows up as a test failure,
-not as a silently unreplayable artifact.
+this module pins the fleet they were recorded against.
+``tests/test_workload_replay.py`` (which replays the committed traces
+and asserts the determinism contract) builds its engines HERE, so a
+drifted model or knob default shows up as a test failure, not as a
+silently unreplayable artifact. Nothing records such a trace any more
+(ROADMAP D1b): the module serves the tests only.
 
 Two mixes, chosen to stress different knobs:
 
@@ -31,8 +31,8 @@ from typing import List, Optional
 
 from paddle_tpu.serving.workload import Workload
 
-# shrunk r10 decode-convoy geometry (bench.py:bench_decode is the
-# full-size original); small enough that warmup compiles fit tier-1
+# shrunk r10 decode-convoy geometry; small enough that warmup compiles
+# fit tier-1
 CONVOY_V, CONVOY_E, CONVOY_H = 64, 8, 16
 CONVOY_K, CONVOY_L, CONVOY_CHUNK = 2, 16, 4
 

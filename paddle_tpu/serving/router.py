@@ -51,8 +51,8 @@ The health loop polls every replica's readiness (``/healthz`` payload /
 worker died (liveness false) is DEAD and — when a ``spawn`` factory is
 configured — respawned in place (chaos site ``replica_spawn``). With the
 AOT warmup cache a respawned replica deserializes its whole bucket menu
-instead of re-tracing it, which is what makes kill-and-respawn under
-load a non-event (``bench.py --fleet``).
+instead of re-tracing it, which is what keeps kill-and-respawn under
+load from dropping requests (``tests/test_serving_fleet.py``).
 
 Rolling reload (:meth:`ReplicaRouter.rolling_reload`) hot-swaps model
 versions replica by replica: mark DRAINING (router dispatch stops

@@ -182,12 +182,6 @@ def parse_args(argv=None):
                         "backends only (audit compiles on CPU keep the "
                         "sync spelling), 'force' engages everywhere, "
                         "'off' keeps the sync spelling")
-    p.add_argument("--fused_rnn", action="store_true",
-                   help="route LSTM/GRU cell math through the fused "
-                        "kernel plane (paddle_tpu/kernels/): one Pallas "
-                        "kernel per cell step on TPU, the bitwise-"
-                        "identical jnp spelling elsewhere "
-                        "(docs/kernels.md)")
     p.add_argument("--grad_accum_steps", type=int, default=1,
                    help="split each batch into k microbatches scanned "
                         "inside the jitted step, applying the optimizer "
@@ -485,9 +479,6 @@ def _build_trainer(ns, args):
         mesh = create_mesh(n_data=args.trainer_count)
     optimizer = ns.get("optimizer") or Momentum(learning_rate=0.01,
                                                 momentum=0.9)
-    if getattr(args, "fused_rnn", False):
-        from paddle_tpu import kernels
-        kernels.set_fused_rnn(True)
     dtype = getattr(args, "compute_dtype", None)
     trainer = SGD(cost=topo, update_equation=optimizer, mesh=mesh,
                   seed=args.seed, evaluators=ns.get("evaluators"),

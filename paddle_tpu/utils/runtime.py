@@ -1,6 +1,6 @@
 """What a process runs on, and where its compiled programs are kept.
 
-Every entry point that compiles (``trainer/cli.py:main``, ``bench.py``,
+Every entry point that compiles (``trainer/cli.py:main``,
 ``chip_smoke.py``, ``tools/tpu_evidence.py``) calls ``start`` once,
 before the first jit: it places JAX's persistent compilation cache and
 logs platform / device kind / count, so no run's log leaves in doubt
@@ -115,7 +115,7 @@ def start(what: str) -> Dict[str, object]:
 
 def require_tpu(what: str) -> Dict[str, object]:
     """``start`` for the entry points whose output means nothing off the
-    chip (``chip_smoke.py``, ``bench.py``, ``tools/tpu_evidence.py``):
+    chip (``chip_smoke.py``, ``tools/tpu_evidence.py``):
     ``SystemExit`` — before anything is placed or printed — unless the
     default backend is a TPU."""
     import jax

@@ -1,17 +1,15 @@
-"""Bitwise neutrality of the FSDP gather-overlap chain and the fused
-kernel plane: overlap-on x fused-on training IS baseline training.
+"""Bitwise neutrality of the FSDP gather-overlap chain: overlap-on
+training IS baseline training.
 
 The r18 tentpole's acceptance bar: the double-buffered all-gather
 spelling (``optim/zero1.py:FsdpUpdater.full_params`` — an
-``optimization_barrier`` prefetch chain, identity on values) and the
-``--fused_rnn`` / fused-optimizer routing (``paddle_tpu/kernels/`` —
-off-TPU the fallback IS the inline math) must not change a single
-trained bit. Closure-enforced matrix (the ``test_exact_resume_matrix``
-pattern): every overlap-relevant composition feature — {fsdp,
-pipeline, grad_accum, telemetry, rnn} — appears in at least one cell,
-and each cell trains all four {overlap, fused} arms on the 8-device
-virtual mesh and demands final params, optimizer state and RNG
-bit-identical to the (off, off) arm. The overlap arm uses
+``optimization_barrier`` prefetch chain, identity on values) must not
+change a single trained bit. Closure-enforced matrix (the
+``test_exact_resume_matrix`` pattern): every overlap-relevant
+composition feature — {fsdp, pipeline, grad_accum, telemetry, rnn} —
+appears in at least one cell, and each cell trains both overlap arms on
+the 8-device virtual mesh and demands final params, optimizer state and
+RNG bit-identical to the off arm. The overlap arm uses
 ``fsdp_overlap="force"`` so the chain is actually staged on CPU (the
 auto mode stands down off-TPU to keep audit compiles sync-spelled).
 """
@@ -22,7 +20,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu import kernels
 from paddle_tpu.config import dsl
 from paddle_tpu.core.argument import Argument
 from paddle_tpu.optim import Adam
@@ -44,9 +41,6 @@ MATRIX = {
 REQUIRED_FEATURES = {"fsdp", "pipeline", "grad_accum", "telemetry",
                      "rnn"}
 
-# the four {overlap, fused} arms; (False, False) is the pinned baseline
-ARMS = [(False, False), (True, False), (False, True), (True, True)]
-
 HEALTH = {"period": 2, "sentry": True, "policy": "skip_batch"}
 
 
@@ -63,9 +57,8 @@ def test_matrix_closure():
 def _build(features, seed=5):
     dsl.reset()
     if "rnn" in features:
-        # non-default activation: the lstmemory layer takes its INLINE
-        # step (not ops/lstm.py), which is exactly where --fused_rnn
-        # reroutes the cell math through kernels/rnn_cells.py
+        # non-default activation: the lstmemory layer takes its inline
+        # scan step, not ops/lstm.py
         x = dsl.data(name="x", size=4 * HID, is_sequence=True)
         lbl = dsl.data(name="label", size=CLASSES)
         r = dsl.lstmemory(input=x, act="relu")
@@ -137,18 +130,12 @@ def _final_state(tr):
     return params, opt, np.asarray(jax.device_get(tr._rng))
 
 
-def _run_arm(features, overlap, fused):
+def _run_arm(features, overlap):
     tr = _build(features)
     reader = _reader(features)
     kw = _train_kwargs(features, overlap)
-    if fused:
-        with kernels.fused_rnn(True), kernels.fused_optimizer(True):
-            for _ in range(PASSES):
-                tr.train(reader, num_passes=1, **kw)
-    else:
-        with kernels.fused_rnn(False), kernels.fused_optimizer(False):
-            for _ in range(PASSES):
-                tr.train(reader, num_passes=1, **kw)
+    for _ in range(PASSES):
+        tr.train(reader, num_passes=1, **kw)
     assert tr._fsdp is not None, "fsdp stood down in-matrix"
     assert len(tr._fsdp.plan) >= 2, \
         "nothing to double-buffer — the cell no longer tests the chain"
@@ -165,22 +152,21 @@ def _run_arm(features, overlap, fused):
 
 
 @pytest.mark.parametrize("cell", sorted(MATRIX), ids=sorted(MATRIX))
-def test_overlap_and_fused_are_bitwise_neutral(cell):
+def test_overlap_is_bitwise_neutral(cell):
     features = MATRIX[cell]
-    want_params, want_opt, want_rng = _run_arm(features, False, False)
-    for overlap, fused in ARMS[1:]:
-        got_params, got_opt, got_rng = _run_arm(features, overlap, fused)
-        tag = f"{cell}[overlap={overlap} fused={fused}]"
-        assert set(got_params) == set(want_params), tag
-        for k in want_params:
-            np.testing.assert_array_equal(
-                got_params[k], want_params[k],
-                err_msg=f"{tag}: param {k} diverged")
-        assert set(got_opt) == set(want_opt), tag
-        for k in want_opt:
-            np.testing.assert_array_equal(
-                np.asarray(jax.device_get(got_opt[k])),
-                np.asarray(jax.device_get(want_opt[k])),
-                err_msg=f"{tag}: opt slot {k} diverged")
-        np.testing.assert_array_equal(got_rng, want_rng,
-                                      err_msg=f"{tag}: rng diverged")
+    want_params, want_opt, want_rng = _run_arm(features, False)
+    got_params, got_opt, got_rng = _run_arm(features, True)
+    tag = f"{cell}[overlap=True]"
+    assert set(got_params) == set(want_params), tag
+    for k in want_params:
+        np.testing.assert_array_equal(
+            got_params[k], want_params[k],
+            err_msg=f"{tag}: param {k} diverged")
+    assert set(got_opt) == set(want_opt), tag
+    for k in want_opt:
+        np.testing.assert_array_equal(
+            np.asarray(jax.device_get(got_opt[k])),
+            np.asarray(jax.device_get(want_opt[k])),
+            err_msg=f"{tag}: opt slot {k} diverged")
+    np.testing.assert_array_equal(got_rng, want_rng,
+                                  err_msg=f"{tag}: rng diverged")

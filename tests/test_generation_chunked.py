@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.generation import (DEFAULT_DECODE_CHUNK, _HOOK_NAMES,
                                         SequenceGenerator)
-from paddle_tpu.kernels.dispatch import fused_rnn
 from tests.test_generation_callbacks import (EOS, H, K, L, V, _boost_eos,
                                              _build, _drop_token,
                                              _min_len_4, _outer, _params,
@@ -183,8 +182,7 @@ def test_session_matches_dedicated_search_with_staggered_admission():
 
 
 def _build_cell_decoder(cell):
-    """Beam-search config whose step net runs a real recurrent cell —
-    the no-grad decode loop the fused inference cells serve."""
+    """Beam-search config whose step net runs a real recurrent cell."""
     from paddle_tpu.config import dsl
     dsl.reset()
     src = dsl.data("src", size=H)
@@ -218,34 +216,26 @@ def _build_cell_decoder(cell):
 
 
 @pytest.mark.parametrize("cell", ["gru", "lstm"])
-def test_fused_infer_cells_bitwise_and_distinct_program(cell):
-    """The generation-matrix fused-RNN row: the no-grad decode loop
-    routes through ``lstm_cell_infer``/``gru_cell_infer`` when the
-    fused switch is on, and (a) the toggle is BITWISE-invisible off-TPU
-    — the fallback spelling is the step's inline math verbatim (the
-    three-spelling contract, ``docs/kernels.md``) — while (b) each flag
-    state is its own compiled program: the switch resolves at trace
-    time inside the step net, so ``_jit_for`` folds it into the compile
-    key (a stale hit would silently serve the wrong spelling after a
-    toggle)."""
+def test_recurrent_cell_decoders_chunked_byte_identical(cell):
+    """The decode loop over a real recurrent cell (``gru_step``;
+    ``lstm_step`` with its state carried through a second memory):
+    chunked search byte-identical to the full scan, one compiled program
+    per compile key and none for a repeated call."""
     graph = _build_cell_decoder(cell)
     net, params = _params(graph)
     outer = _outer(net, params, B=3)
     gen = SequenceGenerator(graph, "gen")
-
-    base = [np.asarray(x) for x in gen.generate(params, outer,
-                                                beam_size=K)]
-    n0 = len(gen._jitted)
-    with fused_rnn(True):
-        fused = [np.asarray(x) for x in gen.generate(params, outer,
-                                                     beam_size=K)]
-    # distinct program identity per flag state, same everything else
-    assert len(gen._jitted) == n0 + 1
-    for name, a, b in zip(("tokens", "scores", "lengths"), base, fused):
+    full = [np.asarray(x) for x in gen.generate(params, outer, beam_size=K,
+                                                full_scan=True)]
+    chunked = [np.asarray(x) for x in gen.generate(params, outer,
+                                                   beam_size=K,
+                                                   decode_chunk=3)]
+    n = len(gen._jitted)
+    assert n == 2
+    again = [np.asarray(x) for x in gen.generate(params, outer, beam_size=K,
+                                                 decode_chunk=3)]
+    assert len(gen._jitted) == n
+    for name, a, b, c in zip(("tokens", "scores", "lengths"), full, chunked,
+                             again):
         assert np.array_equal(a, b), (cell, name)
-    # toggling back reuses the original entry — no third compile
-    again = [np.asarray(x) for x in gen.generate(params, outer,
-                                                 beam_size=K)]
-    assert len(gen._jitted) == n0 + 1
-    for name, a, b in zip(("tokens", "scores", "lengths"), base, again):
-        assert np.array_equal(a, b), (cell, name)
+        assert np.array_equal(a, c), (cell, name)

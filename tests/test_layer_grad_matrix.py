@@ -781,41 +781,3 @@ def test_layer_forward(type_name):
     if type_name != "priorbox":  # priorbox emits per-prior rows, no batch
         assert v.shape[0] == next(iter(feed.values())).value.shape[0]
     assert np.all(np.isfinite(v.astype(np.float64)))
-
-
-# ------------------------------------------------- fused cell parity rows
-# the r18 kernel plane (paddle_tpu/kernels/rnn_cells.py): --fused_rnn
-# routes these types' cell math through kernels.lstm_cell/gru_cell. The
-# contract is bitwise neutrality off-TPU — the fallback spelling IS the
-# inline math — so each row re-runs the registered layer with the flag
-# on and demands the forward AND every parameter gradient unchanged bit
-# for bit (the Pallas-vs-fallback numerics live in tests/test_kernels.py).
-FUSED_RNN_TYPES = ("lstmemory", "gated_recurrent", "lstm_step", "gru_step")
-
-
-@pytest.mark.parametrize("type_name", FUSED_RNN_TYPES)
-def test_fused_rnn_cell_row_bitwise_vs_inline(type_name):
-    from paddle_tpu import kernels
-
-    net, ld, params, feed = _build(GRAD_CASES[type_name])
-    out0 = net.apply(params, feed, train=False,
-                     rng=jax.random.PRNGKey(0))[ld.name]
-    w = jnp.asarray(_rng(7).randn(*out0.value.shape).astype(np.float32))
-
-    def loss_fn(p):
-        out = net.apply(p, feed, train=False, rng=jax.random.PRNGKey(0))
-        return jnp.sum(out[ld.name].value * w)
-
-    base_grads = jax.grad(loss_fn)(params)
-    assert not kernels.rnn_cells_enabled()
-    with kernels.fused_rnn(True):
-        fused_out = net.apply(params, feed, train=False,
-                              rng=jax.random.PRNGKey(0))[ld.name]
-        fused_grads = jax.grad(loss_fn)(params)
-    assert np.array_equal(np.asarray(out0.value),
-                          np.asarray(fused_out.value)), \
-        f"{type_name}: fused forward diverged from the inline spelling"
-    for name, g in base_grads.items():
-        assert np.array_equal(np.asarray(g),
-                              np.asarray(fused_grads[name])), \
-            f"{type_name}: fused grad diverged for param {name}"

@@ -108,6 +108,23 @@ def test_bench_schema_clean():
         findings, "BENCH artifact schema violations:")
 
 
+def test_the_tree_has_one_benchmark():
+    """``BENCHMARK.json`` + ``benchmark/`` is the one yardstick (``python3
+    -m benchmark.run``): the root has no second bench program, and no
+    source, tool or document sends a reader to one."""
+    import glob
+
+    from paddle_tpu.analysis.ast_lints import _iter_source_files
+    assert not os.path.exists(os.path.join(ROOT, "bench.py"))
+    naming = []
+    for path in (list(_iter_source_files(ROOT, ("paddle_tpu", "tools")))
+                 + glob.glob(os.path.join(ROOT, "docs", "*.md"))):
+        with open(path, encoding="utf-8") as f:
+            if "bench.py" in f.read():
+                naming.append(os.path.relpath(path, ROOT))
+    assert not naming, f"these still name bench.py: {sorted(naming)}"
+
+
 def test_baseline_is_empty():
     """Policy: the baseline only parks findings while a new rule lands,
     and this tree is clean — any entry here needs a shrinking plan, and

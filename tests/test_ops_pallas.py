@@ -419,7 +419,7 @@ def test_fused_optimizer_runs_per_replica_under_a_data_parallel_mesh():
     (``common.replica_local``) and equals the one-chip call; on a mesh
     with a model axis — a parameter may be sharded there — it stands
     down to ``_apply_one``, and ``record_dispatch`` counts it."""
-    from paddle_tpu.kernels import opt_update
+    from paddle_tpu.ops import opt_update
     from paddle_tpu.ops import common
     from paddle_tpu.optim import Adam, Momentum
     from paddle_tpu.parallel import create_mesh
@@ -488,9 +488,9 @@ def test_lstm_dispatch_pins_bench_shapes():
 
 
 def test_dispatch_table_matches_pins():
-    """bench.py embeds ``kernel_dispatch_table()`` in its output so perf
-    claims and dispatch can't drift apart (VERDICT r04 item #8); the
-    table must agree with the pins above — narrowing included."""
+    """``kernel_dispatch_table()`` says in one place what the LSTM takes
+    at every BASELINE.md shape (VERDICT r04 item #8); the table must
+    agree with the pins above — narrowing included."""
     from paddle_tpu.ops import common
     from paddle_tpu.ops.lstm import kernel_dispatch_table
     with common.force_mode("pallas"):

@@ -5,11 +5,10 @@ The determinism claim is structural, per ``serving/workload.py``: the
 same trace against the same (generously provisioned) engine yields the
 same outcome COUNTS exactly, every event accounted for once, and a
 score within ``SCORE_DRIFT_BOUND`` (absolute latencies drift +-50% on
-this shared host; counts do not). The committed traces are the same
-artifacts ``bench.py --autotune`` records and tunes against, rebuilt
-here via the shared ``serving/mixes.py`` builders — if the model or
-knob defaults drift from what the traces were recorded on, these tests
-fail instead of the bench quietly scoring a different fleet.
+this shared host; counts do not). The engines the committed traces
+were recorded on are rebuilt here via the ``serving/mixes.py`` builders
+— if the model or knob defaults drift from what the traces were
+recorded on, these tests fail.
 """
 
 import os
@@ -121,8 +120,8 @@ def test_replay_accounts_every_event_under_shed(classifier_eng):
 
 def _assert_deterministic(eng, trace_path, slo):
     assert os.path.exists(trace_path), (
-        f"missing committed trace {trace_path} — regenerate with "
-        "`python bench.py --autotune`")
+        f"missing committed trace {trace_path} (nothing regenerates "
+        "it: ROADMAP D1b)")
     w = Workload.load(trace_path)
     disp = engine_dispatch(eng)
     a = replay_score(w, disp, slo, rounds=1)

@@ -12,8 +12,7 @@ Accepts both timeline shapes the health plane produces:
   (``--health_log`` / ``HealthConfig.log_path``): one record per line,
   torn tail lines tolerated;
 - the committed ``HEALTH_*.json`` artifact family (PT401): one object
-  ``{"run", "period", "events": [...]}`` as ``bench.py --health``
-  writes.
+  ``{"run", "period", "events": [...]}``.
 
 Rendering shows one line per step — loss, lr, max|grad|, the
 data_wait/compute split — with ``!! divergence`` markers on sentry

@@ -2,9 +2,8 @@
 
 Runs only on a TPU backend, with dispatch following the platform (no
 forced kernel mode), and rewrites ``TPU_EVIDENCE.json`` at the repo
-root. For every kernel file under ``paddle_tpu/ops`` and
-``paddle_tpu/kernels`` it records one or more *cases* — the public entry
-called at one shape and dtype:
+root. For every kernel file under ``paddle_tpu/ops`` it records one or
+more *cases* — the public entry called at one shape and dtype:
 
 - which path dispatch took (``ops/common.py:record_dispatch``) and how
   many Mosaic custom calls the compiled forward holds — ``compiled`` is
@@ -346,31 +345,10 @@ def main(argv=None) -> int:
          lambda lp_: ctc_loss(lp_, lab, in_m, lab_m, blank=11),
          (lp,), (0,), shape="B32 T40 C12 L8 (S=17 padded to 128)")
 
-    # ---------------------------------------------- kernels/rnn_cells.py
-    from paddle_tpu.kernels import (gru_cell, gru_cell_infer, lstm_cell,
-                                    lstm_cell_infer)
-    for nm, B, H in (("b64_h256", 64, 256), ("b5_h48", 5, 48)):
-        gates, c_prev = arr(B, 4 * H), arr(B, H)
-        pI, pF, pO = arr(H, scale=0.1), arr(H, scale=0.1), arr(H, scale=0.1)
-        case(report, f"lstm_cell_{nm}", "kernels/rnn_cells.py",
-             lambda g_, c_: lstm_cell(g_, c_, pI, pF, pO),
-             (gates, c_prev), (0, 1), shape=f"B{B} H{H} float32")
-        case(report, f"lstm_cell_infer_{nm}", "kernels/rnn_cells.py",
-             lambda g_, c_: lstm_cell_infer(g_, c_, pI, pF, pO),
-             (gates, c_prev), shape=f"B{B} H{H} float32")
-        x3, h = arr(B, 3 * H), arr(B, H)
-        wg, ws = arr(H, 2 * H), arr(H, H)
-        case(report, f"gru_cell_{nm}", "kernels/rnn_cells.py",
-             lambda x_, h_, wg_, ws_: gru_cell(x_, h_, wg_, ws_),
-             (x3, h, wg, ws), (0, 1, 2, 3), shape=f"B{B} H{H} float32")
-        case(report, f"gru_cell_infer_{nm}", "kernels/rnn_cells.py",
-             lambda x_, h_: gru_cell_infer(x_, h_, wg, ws),
-             (x3, h), shape=f"B{B} H{H} float32")
-
-    # --------------------------------------------- kernels/opt_update.py
+    # ------------------------------------------------- ops/opt_update.py
     # the fused entry against Optimizer._apply_one (its own fallback):
     # "ref" mode routes apply_one straight to it
-    from paddle_tpu.kernels import opt_update
+    from paddle_tpu.ops import opt_update
     from paddle_tpu.optim import Adam, Momentum
     shapes = {"lstm_w_1MiB": (256, 1024), "w_1p5MiB": (384, 1024),
               "embedding_15MB": (30000, 128), "bias": (1024,),
@@ -392,7 +370,7 @@ def main(argv=None) -> int:
                                         for k in sorted(s2)])
 
             case(report, f"opt_update_{oname}_{sname}",
-                 "kernels/opt_update.py", update, (p, g),
+                 "ops/opt_update.py", update, (p, g),
                  shape=f"{shp} float32", fwd_tol=1e-5)
 
     # ------------------------------ on-device checkgrad of the custom VJPs
