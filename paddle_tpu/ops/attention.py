@@ -13,18 +13,24 @@ Three tiers:
   online softmax (max/sum running stats). Memory O(T_q·block) instead of
   O(T_q·T_k); differentiable by autodiff; runs anywhere.
 - ``flash_attention`` — Pallas kernels: forward on a grid (batch·heads,
-  q-blocks, kv-blocks), kv innermost so the accumulator lives in VMEM
-  scratch across the kv sweep; it also hands back each row's
-  log-sum-exp. Backward = two kernels that recompute the scores block by
-  block from that log-sum-exp (dK/dV with the q sweep innermost, dQ with
-  the kv sweep innermost): memory linear in T, nothing of size T_q·T_k
-  is ever held. Under ``causal`` the blocks that lie wholly above the
-  diagonal are skipped, in all three (their index maps repeat the last
-  needed block, so nothing is fetched for them either). The forward rule
-  names its output and the log-sum-exp ``common.KEPT_RESIDUAL``: a
-  layer under the executor's checkpoint keeps those two (T·Dv and T a
-  head) and recomputes what led to q, k and v, so the forward kernel
-  runs once a step, not again in the backward pass.
+  steps), a step one (q block, kv block) tile and a q block's kv tiles
+  consecutive, so the accumulator lives in VMEM scratch across that
+  sweep; it also hands back each row's log-sum-exp. Backward = two
+  kernels that recompute the scores tile by tile from that log-sum-exp
+  (dK/dV walking a kv block's q tiles, dQ a q block's kv tiles): memory
+  linear in T, nothing of size T_q·T_k is ever held. The grid holds the
+  tiles in which a query sees a key and no others (``_Tiles``): under
+  ``causal`` the pairs wholly above the diagonal are not skipped steps
+  but no steps, named by a table of steps in SMEM; a grid step costs
+  about 0.4 us on a v5e even when it computes nothing, and a sweep
+  that starts right after skipped steps waits for its first blocks. A
+  tile's own vector work (scale, both masks, the exponential) is done
+  on every tile alike: on the chip it hides under the matrix products
+  (``tools/flash_tile_times.py``; PERF.md §5). The forward rule names
+  its output and the log-sum-exp ``common.KEPT_RESIDUAL``: a layer
+  under the executor's checkpoint keeps those two (T·Dv and T a head)
+  and recomputes what led to q, k and v, so the forward kernel runs
+  once a step, not again in the backward pass.
 
 All take q, k of [B, N, T, Dqk] and v of [B, N, T_k, Dv] (the two head
 sizes may differ: latent attention has 192 and 128), an optional kv
@@ -37,6 +43,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
@@ -118,6 +125,8 @@ def blockwise_attention(q, k, v, kv_mask=None, causal=False, scale=None,
 
 _TRANS_B = (((1,), (1,)), ((), ()))     # a [M,K] x b [N,K] -> [M,N]
 _STAT_LANES = 128     # a row statistic is kept broadcast over one lane tile
+_WALK_TABLE_BYTES = 512 * 1024      # of SMEM, for a walk's table of steps
+_FIRST, _LAST = 1, 2      # a step's place in its sweep, in the walk's table
 
 
 def _scores(off, scale, causal, q, k, msk, qb, kb):
@@ -134,48 +143,30 @@ def _scores(off, scale, causal, q, k, msk, qb, kb):
     return s
 
 
-def _visible(off, causal, Bq, Bk, qb, kb):
-    """Does q block ``qb`` see any key of kv block ``kb``?"""
-    if not causal:
-        return None
-    return kb * Bk <= qb * Bq + Bq - 1 + off
-
-
-def _when(cond):
-    """``pl.when`` that is no condition at all for ``None``."""
-    return (lambda f: f()) if cond is None else pl.when(cond)
-
-
-def _flash_kernel(off, scale, causal, q_ref, k_ref, v_ref, mask_ref,
+def _flash_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                   o_ref, lse_ref, acc_s, m_s, l_s):
-    qb, kb = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    off, causal = tiles.off, tiles.causal
+    qb, kb, first, last = tiles.step(walk_ref)
 
-    @pl.when(kb == 0)
+    @pl.when(first)
     def _():
         acc_s[:] = jnp.zeros_like(acc_s)
         m_s[:] = jnp.full_like(m_s, _NEG)
         l_s[:] = jnp.zeros_like(l_s)
 
-    Bq, Bk = q_ref.shape[1], k_ref.shape[1]
+    v = v_ref[0]
+    s = _scores(off, scale, causal, q_ref[0], k_ref[0], mask_ref[0], qb, kb)
+    m_prev = m_s[:, 0:1]                                         # [Bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)                              # [Bq, 1]
+    l_s[:, 0:1] = l_s[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_s[:] = (acc_s[:] * alpha
+                + jnp.dot(p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32))
+    m_s[:, 0:1] = m_new
 
-    @_when(_visible(off, causal, Bq, Bk, qb, kb))
-    def _():
-        v = v_ref[0]
-        s = _scores(off, scale, causal, q_ref[0], k_ref[0], mask_ref[0],
-                    qb, kb)
-        m_prev = m_s[:, 0:1]                                     # [Bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)                          # [Bq, 1]
-        l_s[:, 0:1] = l_s[:, 0:1] * alpha + jnp.sum(p, axis=-1,
-                                                    keepdims=True)
-        acc_s[:] = (acc_s[:] * alpha
-                    + jnp.dot(p.astype(v.dtype), v,
-                              preferred_element_type=jnp.float32))
-        m_s[:, 0:1] = m_new
-
-    @pl.when(kb == nk - 1)
+    @pl.when(last)
     def _():
         o_ref[0] = (acc_s[:] / l_s[:, 0:1]).astype(o_ref.dtype)
         lse_ref[0] = jnp.broadcast_to(m_s[:, 0:1] + jnp.log(l_s[:, 0:1]),
@@ -197,100 +188,156 @@ def _tile_terms(off, scale, causal, q_ref, k_ref, v_ref, mask_ref, do_ref,
     return q, k, do, p, ds
 
 
-def _flash_dkv_kernel(off, scale, causal, q_ref, k_ref, v_ref, mask_ref,
+def _flash_dkv_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                       do_ref, st_ref, dk_ref, dv_ref, dk_s, dv_s):
-    kb, qb = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    off, causal = tiles.off, tiles.causal
+    qb, kb, first, last = tiles.step(walk_ref)
 
-    @pl.when(qb == 0)
+    @pl.when(first)
     def _():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    Bq, Bk = q_ref.shape[1], k_ref.shape[1]
+    q, _k, do, p, ds = _tile_terms(off, scale, causal, q_ref, k_ref, v_ref,
+                                   mask_ref, do_ref, st_ref, qb, kb)
+    dv_s[:] += jnp.dot(p.T.astype(do.dtype), do,
+                       preferred_element_type=jnp.float32)
+    dk_s[:] += jnp.dot(ds.T.astype(q.dtype), q,
+                       preferred_element_type=jnp.float32)
 
-    @_when(_visible(off, causal, Bq, Bk, qb, kb))
-    def _():
-        q, _k, do, p, ds = _tile_terms(off, scale, causal, q_ref, k_ref,
-                                       v_ref, mask_ref, do_ref, st_ref,
-                                       qb, kb)
-        dv_s[:] += jnp.dot(p.T.astype(do.dtype), do,
-                           preferred_element_type=jnp.float32)
-        dk_s[:] += jnp.dot(ds.T.astype(q.dtype), q,
-                           preferred_element_type=jnp.float32)
-
-    @pl.when(qb == nq - 1)
+    @pl.when(last)
     def _():
         dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
 
 
-def _flash_dq_kernel(off, scale, causal, q_ref, k_ref, v_ref, mask_ref,
+def _flash_dq_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                      do_ref, st_ref, dq_ref, dq_s):
-    qb, kb = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    off, causal = tiles.off, tiles.causal
+    qb, kb, first, last = tiles.step(walk_ref)
 
-    @pl.when(kb == 0)
+    @pl.when(first)
     def _():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    Bq, Bk = q_ref.shape[1], k_ref.shape[1]
+    _q, k, _do, _p, ds = _tile_terms(off, scale, causal, q_ref, k_ref,
+                                     v_ref, mask_ref, do_ref, st_ref,
+                                     qb, kb)
+    dq_s[:] += jnp.dot(ds.astype(k.dtype), k,
+                       preferred_element_type=jnp.float32)
 
-    @_when(_visible(off, causal, Bq, Bk, qb, kb))
-    def _():
-        _q, k, _do, _p, ds = _tile_terms(off, scale, causal, q_ref, k_ref,
-                                         v_ref, mask_ref, do_ref, st_ref,
-                                         qb, kb)
-        dq_s[:] += jnp.dot(ds.astype(k.dtype), k,
-                           preferred_element_type=jnp.float32)
-
-    @pl.when(kb == nk - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
 class _Tiles:
-    """The block specs the three kernels share, for q-major grids
-    (bn, qb, kb) and the kv-major one (bn, kb, qb). Under ``causal`` a
-    step that is skipped names the block of the last step that was not,
-    so the pipeline fetches nothing for it."""
+    """The tiles of one head that a kernel's grid walks, and the block
+    specs the three kernels share over that walk. The grid is (batch x
+    heads, steps): one step for every (q block, kv block) pair in which
+    a query sees a key, q-major (a sweep is one q block's kv blocks: the
+    forward and dQ kernels) or kv-major (one kv block's q blocks:
+    dK/dV). Under ``causal`` the pairs wholly above the diagonal are no
+    steps at all: nothing is fetched, tested or waited for on their
+    behalf, and a sweep's first blocks arrive while the sweep before it
+    still computes. A sweep that sees nothing keeps one tile, all of it
+    masked, so that its output block is still written. The steps' tiles
+    are a table in SMEM (the calls' scalar prefetch, read by the index
+    maps and the kernels); where every pair is a step there is no
+    table: the grid is (batch x heads, sweeps, steps of a sweep) and a
+    step's tile is its two indices."""
 
-    def __init__(self, heads, off, causal, block_q, block_k, nq, nk):
+    def __init__(self, heads, off, causal, block_q, block_k, nq, nk,
+                 kv_major):
         self.N, self.off, self.causal = heads, off, causal
-        self.bq, self.bk, self.nq, self.nk = block_q, block_k, nq, nk
-
-    def _kv(self, qb, kb):      # q-major: the last kv block qb sees
-        if not self.causal:
-            return kb
-        last = (qb * self.bq + self.bq - 1 + self.off) // self.bk
-        return jnp.minimum(kb, jnp.clip(last, 0, self.nk - 1))
-
-    def _q(self, kb, qb):       # kv-major: the first q block that sees kb
-        if not self.causal:
-            return qb
-        first = (kb * self.bk - self.off) // self.bq
-        return jnp.maximum(qb, jnp.clip(first, 0, self.nq - 1))
-
-    def specs(self, kv_major):
-        """``(q-like(d), kv-like(d), mask)`` block-spec makers."""
+        self.bq, self.bk, self.kv_major = block_q, block_k, kv_major
+        qb, kb = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
+        seen = np.ones((nq, nk), bool)
+        if causal:
+            seen = kb * block_k <= qb * block_q + block_q - 1 + off
         if kv_major:
-            qi = lambda bn, kb, qb: (bn, self._q(kb, qb), 0)
-            ki = lambda bn, kb, qb: (bn, kb, 0)
-            mi = lambda bn, kb, qb: (bn // self.N, 0, kb)
+            seen[nq - 1, ~seen.any(axis=0)] = True
+            order = np.lexsort((qb[seen], kb[seen]))
+            sweep = kb[seen][order]
         else:
-            qi = lambda bn, qb, kb: (bn, qb, 0)
-            ki = lambda bn, qb, kb: (bn, self._kv(qb, kb), 0)
-            mi = lambda bn, qb, kb: (bn // self.N, 0, self._kv(qb, kb))
+            seen[~seen.any(axis=1), 0] = True
+            order = np.lexsort((kb[seen], qb[seen]))
+            sweep = qb[seen][order]
+        # every pair a step: the grid is (rows, sweeps, steps of a sweep)
+        # and needs no table
+        self.whole = ((nk, nq) if kv_major else (nq, nk)) \
+            if seen.all() else None
+        if self.whole:
+            return
+        edge = np.flatnonzero(np.diff(sweep)) + 1     # where a sweep starts
+        ends = np.zeros(len(sweep), np.int32)
+        ends[np.r_[0, edge]] |= _FIRST
+        ends[np.r_[edge - 1, len(sweep) - 1]] |= _LAST
+        self.steps = len(sweep)
+        # [qb of every step | kb of every step | its place in its sweep]
+        self.walk = jnp.asarray(np.concatenate(
+            [qb[seen][order], kb[seen][order], ends]), jnp.int32)
+
+    def _qb(self, *at):
+        """The q block of the grid step ``at``: (sweep, step of it) where
+        every pair is a step, else (step, the table)."""
+        if self.whole:
+            return at[1] if self.kv_major else at[0]
+        t, walk = at
+        return walk[t]
+
+    def _kb(self, *at):
+        if self.whole:
+            return at[0] if self.kv_major else at[1]
+        t, walk = at
+        return walk[self.steps + t]
+
+    def step(self, walk):
+        """``(qb, kb, first of its sweep?, last of it?)`` of the grid
+        step a kernel is in."""
+        if self.whole:
+            at = pl.program_id(1), pl.program_id(2)
+            first, last = at[1] == 0, at[1] == self.whole[1] - 1
+        else:
+            at = pl.program_id(1), walk
+            ends = walk[2 * self.steps + at[0]]
+            first, last = (ends & _FIRST) != 0, (ends & _LAST) != 0
+        return self._qb(*at), self._kb(*at), first, last
+
+    def specs(self):
+        """``(q-like(d), kv-like(d), mask)`` block-spec makers."""
         vmem = pltpu.VMEM
+        qi = lambda bn, *at: (bn, self._qb(*at), 0)
+        ki = lambda bn, *at: (bn, self._kb(*at), 0)
+        mi = lambda bn, *at: (bn // self.N, 0, self._kb(*at))
         return (lambda d: pl.BlockSpec((1, self.bq, d), qi,
                                        memory_space=vmem),
                 lambda d: pl.BlockSpec((1, self.bk, d), ki,
                                        memory_space=vmem),
                 pl.BlockSpec((1, 1, self.bk), mi, memory_space=vmem))
 
-
-_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+    def call(self, kernel, scale, rows, in_specs, out_specs, out_shape,
+             scratch, *operands):
+        """``kernel(self, scale, the table or None, *references)`` over
+        the walk, ``rows`` (batch x heads) times."""
+        kernel = functools.partial(kernel, self, scale)
+        if self.whole:              # no table among its references
+            table, grid = (), (rows,) + self.whole
+            kernel = functools.partial(kernel, None)
+        else:
+            table, grid = (self.walk,), (rows, self.steps)
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(table), grid=grid,
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",) * (len(grid) - 1)
+                + ("arbitrary",)),
+            interpret=common.interpret(),
+        )(*table, *operands)
 
 
 def _flash_forward(cfg, qf, kf, vf, mask):
@@ -300,25 +347,19 @@ def _flash_forward(cfg, qf, kf, vf, mask):
     heads, off, scale, causal, block_q, block_k = cfg
     BN, Tq, Dqk = qf.shape
     Tk, Dv = vf.shape[1], vf.shape[2]
-    nq, nk = Tq // block_q, Tk // block_k
-    q_like, kv_like, mask_spec = _Tiles(
-        heads, off, causal, block_q, block_k, nq, nk).specs(False)
-    out, lse = pl.pallas_call(
-        functools.partial(_flash_kernel, off, scale, causal),
-        grid=(BN, nq, nk),
-        in_specs=[q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec],
-        out_specs=[q_like(Dv), q_like(_STAT_LANES)],
-        out_shape=[jax.ShapeDtypeStruct((BN, Tq, Dv), qf.dtype),
-                   jax.ShapeDtypeStruct((BN, Tq, _STAT_LANES),
-                                        jnp.float32)],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-        ],
-        compiler_params=_SEMANTICS,
-        interpret=common.interpret(),
-    )(qf, kf, vf, mask)
+    tiles = _Tiles(heads, off, causal, block_q, block_k, Tq // block_q,
+                   Tk // block_k, kv_major=False)
+    q_like, kv_like, mask_spec = tiles.specs()
+    out, lse = tiles.call(
+        _flash_kernel, scale, BN,
+        [q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec],
+        [q_like(Dv), q_like(_STAT_LANES)],
+        [jax.ShapeDtypeStruct((BN, Tq, Dv), qf.dtype),
+         jax.ShapeDtypeStruct((BN, Tq, _STAT_LANES), jnp.float32)],
+        [pltpu.VMEM((block_q, Dv), jnp.float32),
+         pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+         pltpu.VMEM((block_q, _STAT_LANES), jnp.float32)],
+        qf, kf, vf, mask)
     return out, lse[..., 0]
 
 
@@ -332,33 +373,31 @@ def _flash_backward(cfg, qf, kf, vf, mask, out, lse, do):
     # lane 0 the log-sum-exp, lane 1 delta: one operand, one fetch a tile
     stats = jnp.pad(jnp.stack([lse, delta], axis=-1),
                     ((0, 0), (0, 0), (0, _STAT_LANES - 2)))
-    tiles = _Tiles(heads, off, causal, block_q, block_k, nq, nk)
-    q_like, kv_like, mask_spec = tiles.specs(True)
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, off, scale, causal),
-        grid=(BN, nk, nq),
-        in_specs=[q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec,
-                  q_like(Dv), q_like(_STAT_LANES)],
-        out_specs=[kv_like(Dqk), kv_like(Dv)],
-        out_shape=[jax.ShapeDtypeStruct(kf.shape, kf.dtype),
-                   jax.ShapeDtypeStruct(vf.shape, vf.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, Dqk), jnp.float32),
-                        pltpu.VMEM((block_k, Dv), jnp.float32)],
-        compiler_params=_SEMANTICS,
-        interpret=common.interpret(),
-    )(qf, kf, vf, mask, do, stats)
-    q_like, kv_like, mask_spec = tiles.specs(False)
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, off, scale, causal),
-        grid=(BN, nq, nk),
-        in_specs=[q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec,
-                  q_like(Dv), q_like(_STAT_LANES)],
-        out_specs=q_like(Dqk),
-        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, Dqk), jnp.float32)],
-        compiler_params=_SEMANTICS,
-        interpret=common.interpret(),
-    )(qf, kf, vf, mask, do, stats)
+    operands = (qf, kf, vf, mask, do, stats)
+    tiles = _Tiles(heads, off, causal, block_q, block_k, nq, nk,
+                   kv_major=True)
+    q_like, kv_like, mask_spec = tiles.specs()
+    dk, dv = tiles.call(
+        _flash_dkv_kernel, scale, BN,
+        [q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec,
+         q_like(Dv), q_like(_STAT_LANES)],
+        [kv_like(Dqk), kv_like(Dv)],
+        [jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+         jax.ShapeDtypeStruct(vf.shape, vf.dtype)],
+        [pltpu.VMEM((block_k, Dqk), jnp.float32),
+         pltpu.VMEM((block_k, Dv), jnp.float32)],
+        *operands)
+    tiles = _Tiles(heads, off, causal, block_q, block_k, nq, nk,
+                   kv_major=False)
+    q_like, kv_like, mask_spec = tiles.specs()
+    dq = tiles.call(
+        _flash_dq_kernel, scale, BN,
+        [q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec,
+         q_like(Dv), q_like(_STAT_LANES)],
+        q_like(Dqk),
+        jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+        [pltpu.VMEM((block_q, Dqk), jnp.float32)],
+        *operands)
     return dq, dk, dv
 
 
@@ -421,8 +460,12 @@ def flash_attention(q, k, v, kv_mask=None, causal=False, scale=None,
     resident = (2 * item * (bq * (Dqk + Dv) + bk * (Dqk + Dv))
                 + 2 * 4 * bq * _STAT_LANES + 4 * bk * (Dqk + Dv)
                 + 4 * 4 * bq * bk)
+    # the causal walk's table of steps lives in SMEM (1 MiB on a v5e),
+    # 12 bytes a (q block, kv block) pair at most
+    pairs = -(-q.shape[2] // bq) * -(-k.shape[2] // bk)
     split = common.batch_split(q.shape[0])
-    if split == 0 or not common.use_pallas(resident):
+    if split == 0 or not common.use_pallas(resident) \
+            or (causal and 12 * pairs > _WALK_TABLE_BYTES):
         common.note("flash_attention", "ref")
         return blockwise_attention(q, k, v, kv_mask, causal=causal,
                                    scale=scale, block_k=block_k)

@@ -172,6 +172,132 @@ def test_flash_attention_qk_and_v_widths_differ(shape, causal):
             np.testing.assert_allclose(g, rg, rtol=1e-4, atol=2e-5)
 
 
+def _keys(kind, B, Tk, rng):
+    """Key masks for the walk's cases: none, all ones, one invalid key
+    in a block that is otherwise below the diagonal, a padded tail."""
+    if kind == "none":
+        return None
+    m = np.ones((B, Tk), np.float32)
+    if kind == "one_invalid":
+        m[0, 5] = 0.0
+    elif kind == "padded_tail":
+        m[:, Tk - 7:] = 0.0
+        m[1, Tk - 20:] = 0.0
+    return jnp.asarray(m)
+
+
+@pytest.mark.parametrize("widths", [(24, 16), (16, 16)])
+@pytest.mark.parametrize("keys", ["none", "ones", "one_invalid",
+                                  "padded_tail"])
+@pytest.mark.parametrize("Tq,Tk", [(64, 64), (40, 72)])
+def test_flash_attention_walks_only_the_tiles_a_query_sees(Tq, Tk, keys,
+                                                           widths):
+    """Causal, blocks of 16: the kernels' grids hold one step a (q block,
+    kv block) pair in which a query sees a key and none for the pairs
+    above the diagonal (T_k = T_q, and T_k > T_q where every query sees
+    T_k - T_q keys more; the second pads both lengths). Output and all
+    three gradients against ``mha_reference``, whatever the key mask."""
+    from paddle_tpu.ops.attention import _Tiles
+    B, N, (Dqk, Dv) = 2, 2, widths
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(B, N, Tq, Dqk)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, N, Tk, Dqk)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, N, Tk, Dv)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, N, Tq, Dv)), jnp.float32)
+    kv_mask = _keys(keys, B, Tk, rng)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_) * w), (0, 1, 2))(q, k, v)
+
+    want = run(lambda *a: mha_reference(*a, kv_mask, causal=True))
+    with common.force_mode("interpret"):
+        got = run(lambda *a: flash_attention(*a, kv_mask, causal=True,
+                                             block_q=16, block_k=16))
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for g, rg in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, rg, rtol=1e-4, atol=2e-5)
+    # the grids: fewer steps than pairs, the same tiles in both orders
+    nq, nk = -(-Tq // 16), -(-Tk // 16)
+    walks = [_Tiles(N, Tk - Tq, True, 16, 16, nq, nk, kv_major)
+             for kv_major in (False, True)]
+    assert walks[0].steps == walks[1].steps < nq * nk
+    assert walks[0].whole is None
+
+
+def _walk(tiles):
+    qb, kb, ends = np.asarray(tiles.walk).reshape(3, tiles.steps)
+    return list(zip(qb.tolist(), kb.tolist())), ends.tolist()
+
+
+def test_the_walk_of_a_causal_grid_and_of_a_whole_one():
+    from paddle_tpu.ops.attention import _FIRST, _LAST, _Tiles
+    both = _FIRST | _LAST
+    # three q blocks of 16 over three kv blocks of 16, T_k = T_q
+    pairs, ends = _walk(_Tiles(1, 0, True, 16, 16, 3, 3, kv_major=False))
+    assert pairs == [(0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2)]
+    assert ends == [both, _FIRST, _LAST, _FIRST, 0, _LAST]
+    pairs, ends = _walk(_Tiles(1, 0, True, 16, 16, 3, 3, kv_major=True))
+    assert pairs == [(0, 0), (1, 0), (2, 0), (1, 1), (2, 1), (2, 2)]
+    assert ends == [_FIRST, 0, _LAST, _FIRST, _LAST, both]
+    # q blocks of 32 over kv blocks of 16: the diagonal crosses two
+    pairs, _ = _walk(_Tiles(1, 0, True, 32, 16, 2, 4, kv_major=False))
+    assert pairs == [(0, 0), (0, 1), (1, 0), (1, 1), (1, 2), (1, 3)]
+    # 16 more keys than queries: every query sees one kv block more
+    pairs, _ = _walk(_Tiles(1, 16, True, 16, 16, 2, 3, kv_major=False))
+    assert pairs == [(0, 0), (0, 1), (1, 0), (1, 1), (1, 2)]
+    # every pair a step: no table, the grid's own two indices
+    assert _Tiles(1, 0, False, 16, 16, 3, 2, kv_major=False).whole == (3, 2)
+    assert _Tiles(1, 64, True, 16, 16, 3, 2, kv_major=True).whole == (2, 3)
+
+
+def test_a_sweep_that_sees_nothing_still_writes_its_block():
+    """32 queries more than keys, causal: the first two q blocks see no
+    key and the walk keeps one masked tile for each (their rows are
+    whatever a fully masked row is, but finite, and no gradient leaves
+    them into a kv block that nothing sees); the rows that do see keys
+    are the reference's."""
+    from paddle_tpu.ops.attention import _Tiles
+    B, N, Tq, Tk, D = 1, 2, 64, 32, 8
+    rng = np.random.default_rng(13)
+    q = jnp.asarray(rng.normal(size=(B, N, Tq, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, N, Tk, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, N, Tk, D)), jnp.float32)
+    live = jnp.asarray((np.arange(Tq) >= Tq - Tk)[None, None, :, None],
+                       jnp.float32)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda q_, k_, v_: jnp.sum((fn(q_, k_, v_) * live) ** 2),
+            (0, 1, 2))(q, k, v)
+
+    want = run(lambda *a: mha_reference(*a, causal=True))
+    with common.force_mode("interpret"):
+        got = run(lambda *a: flash_attention(*a, causal=True, block_q=16,
+                                             block_k=16))
+        out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16)
+    assert np.isfinite(np.asarray(out)).all()
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for g, rg in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, rg, rtol=1e-4, atol=2e-5)
+    pairs, _ = _walk(_Tiles(N, Tk - Tq, True, 16, 16, 4, 2, kv_major=False))
+    assert pairs == [(0, 0), (1, 0), (2, 0), (3, 0), (3, 1)]
+
+
+def test_a_walk_too_long_for_its_table_takes_the_reference_path():
+    """The causal walk's table of steps lives in SMEM; a call whose grid
+    has more pairs than the table's budget holds stands down, and says
+    so in the tally. A grid in which every pair is a step has no table."""
+    T = 8 * 210          # 210 x 210 blocks of 8: 44,100 pairs
+    x = jax.ShapeDtypeStruct((1, 1, T, 8), jnp.float32)
+    for causal, path in ((True, "ref"), (False, "interpret")):
+        with common.force_mode("interpret"), \
+                common.record_dispatch() as tally:
+            jax.eval_shape(lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, block_q=8, block_k=8), x, x, x)
+        assert tally["flash_attention"] == {path: 1}
+
+
 def test_lstm_layer_uses_fused_path():
     """lstmemory layer output must be identical with kernels forced to the
     reference tier vs the fused tier (the layer auto-dispatches)."""
