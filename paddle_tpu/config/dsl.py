@@ -361,6 +361,32 @@ def mla_attention(input, *, num_heads: int, q_lora_rank: int,
     return _add(ldef)
 
 
+def gqa_attention(input, *, num_heads: int, num_kv_heads: int,
+                  head_dim: int, window: int = None, rotary_dim: int = None,
+                  rope_theta: float = 10000.0, yarn: dict = None,
+                  gate: bool = True, block: int = 512, name: str = None,
+                  layer_attr: dict = None) -> LayerOutput:
+    """Causal grouped-query self-attention (`layers/attention.py`):
+    ``num_heads`` query heads over ``num_kv_heads`` key-value heads of
+    ``head_dim``, a sliding ``window`` (None: the whole sequence), rotary
+    by halves over the first ``rotary_dim`` of a head (None: all of it)
+    with ``yarn``'s frequencies where given (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``), a per-head sigmoid ``gate`` on the core's
+    output, the flash kernels' tiles ``block`` x ``block``; no bias,
+    output size = input size."""
+    extra = _layer_attr(layer_attr)
+    attrs = {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
+             "head_dim": head_dim, "window": window,
+             "rotary_dim": rotary_dim, "rope_theta": rope_theta,
+             "yarn": yarn, "gate": gate, "block": block,
+             **extra.pop("attrs", {})}
+    ldef = LayerDef(name=name or _auto_name("gqa"), type="gqa_attention",
+                    inputs=[Input(_in(input)[0].name)], bias=False,
+                    attrs=attrs, **extra)
+    return _add(ldef)
+
+
 def seq_shift(input, *, offset: int, name: str = None) -> LayerOutput:
     """Position i of the output holds position ``i + offset`` of the
     input (zeros and a dead mask on the last ``offset``)."""

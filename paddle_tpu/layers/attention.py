@@ -19,18 +19,37 @@ RMSNorm inside, a ``rope``-wide part of every query and ONE key of that
 width shared by all heads carry the position (rotary, pairs interleaved),
 so a head's query and key are ``nope + rope`` wide and its value
 ``v_head_dim``; the core is the same ``flash_attention``.
+
+``gqa_attention``: grouped-query causal self-attention with a head
+count of its own (a decoder's layers may differ in it), ``num_kv_heads``
+key-value heads shared by groups of query heads (K and V reach the
+kernels at their own heads), an optional sliding ``window``, rotary by
+halves (Hugging Face's ``rotate_half``: element ``j`` pairs with ``j +
+r/2``) over the first ``rotary_dim`` elements of a head with plain or
+YaRN frequencies (``yarn_inv_freq``; cos and sin times YaRN's
+``attention_factor``), and a per-head sigmoid gate on the core's output
+(``sigmoid(u W_g)``, one scalar a head and token; arXiv:2505.06708's
+head-wise form). One layer type for windowed and full layers: the core
+runs under the inner scope ``attn_core`` in both, and a windowed layer's
+``state["counters"]`` names what its band cost the tiling:
+``swa_pairs_visited`` (query-key pairs inside the tiles the forward
+kernel's grid walks, a head) and ``swa_pairs_visible`` (pairs the mask
+lets see).
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from paddle_tpu.core.argument import Argument
 from paddle_tpu.core.registry import (LayerImpl, ParamSpec, ShapeInfo,
                                       register_layer)
 from paddle_tpu.layers.norm import rms_normalize
-from paddle_tpu.ops.attention import flash_attention
+from paddle_tpu.ops.attention import flash_attention, walked_pairs
 
 
 @register_layer("multi_head_attention")
@@ -175,3 +194,123 @@ class MlaAttentionLayer(LayerImpl):
         if ins[0].mask is not None:
             out = out * ins[0].mask[..., None].astype(out.dtype)
         return Argument(value=out, mask=ins[0].mask)
+
+
+def yarn_inv_freq(rotary_dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's rotary frequencies (arXiv:2309.00071 §3.2, as Hugging
+    Face's ``_compute_yarn_parameters`` blends them), ``[rotary_dim/2]``:
+    the pairs that turn more than ``beta_fast`` times over the original
+    context keep ``theta^(-2i/r)``, those that turn less than
+    ``beta_slow`` times are divided by ``factor``, a linear ramp
+    between."""
+    r = rotary_dim
+
+    def dim_of(turns):
+        return r * math.log(original_max_position / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), r - 1)
+    i = np.arange(r // 2, dtype=np.float64)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    plain = theta ** (-2.0 * i / r)
+    return ((1.0 - ramp) * plain + ramp * plain / factor).astype(np.float32)
+
+
+def rotary_halves(x, inv_freq, factor: float = 1.0):
+    """Rotary position embedding over the first ``2 * len(inv_freq)``
+    elements of the last axis of ``x [..., T, d]`` (positions 0..T-1
+    along axis -2), by halves: element ``j`` of the turned part pairs
+    with ``j + r/2`` and both turn by ``pos * inv_freq[j]``; cos and sin
+    are multiplied by ``factor`` (YaRN's attention factor), the rest of
+    the head is left as it is. Computed in float32; the result takes
+    ``x``'s type."""
+    T, half = x.shape[-2], len(inv_freq)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    xf = x.astype(jnp.float32)
+    a, b, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest], -1)
+    return out.astype(x.dtype)
+
+
+@register_layer("gqa_attention")
+class GqaAttentionLayer(LayerImpl):
+    """Causal grouped-query self-attention, windowed or full, with
+    partial rotary and a per-head output gate; no bias. Output size =
+    input size."""
+
+    @staticmethod
+    def _dims(cfg):
+        a = cfg.attrs
+        return int(a["num_heads"]), int(a["num_kv_heads"]), int(a["head_dim"])
+
+    def infer(self, cfg, in_infos):
+        heads, kv, _ = self._dims(cfg)
+        assert heads % kv == 0, "num_kv_heads must divide num_heads"
+        return ShapeInfo(size=in_infos[0].size, is_sequence=True)
+
+    def params(self, cfg, in_infos):
+        d = in_infos[0].size
+        heads, kv, hd = self._dims(cfg)
+        specs = {"wq": ParamSpec(shape=(d, heads * hd)),
+                 "wk": ParamSpec(shape=(d, kv * hd)),
+                 "wv": ParamSpec(shape=(d, kv * hd)),
+                 "wo": ParamSpec(shape=(heads * hd, d))}
+        if cfg.attrs.get("gate", True):
+            specs["wg"] = ParamSpec(shape=(d, heads))
+        return specs
+
+    @staticmethod
+    def _rotary(cfg, hd):
+        """``(inv_freq, factor)`` of this layer's rotary scheme."""
+        a = cfg.attrs
+        r = int(a.get("rotary_dim") or hd)
+        theta = float(a.get("rope_theta", 10000.0))
+        yarn = a.get("yarn")
+        if not yarn:
+            return theta ** (-2.0 * np.arange(r // 2) / r), 1.0
+        return (yarn_inv_freq(r, theta, float(yarn["factor"]),
+                              int(yarn["original_max_position_embeddings"]),
+                              float(yarn.get("beta_fast", 32.0)),
+                              float(yarn.get("beta_slow", 1.0))),
+                float(yarn.get("attention_factor")
+                      or 0.1 * math.log(float(yarn["factor"])) + 1.0))
+
+    def apply(self, cfg, params, ins, ctx):
+        u = ins[0].value
+        B, T, _ = u.shape
+        heads, kv, hd = self._dims(cfg)
+        window = cfg.attrs.get("window")
+        window = int(window) if window else None
+        block = int(cfg.attrs.get("block", 512))
+        inv_freq, factor = self._rotary(cfg, hd)
+
+        def split(x, n):  # [B,T,n*hd] -> [B,n,T,hd]
+            return x.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
+
+        q = rotary_halves(split(u @ params["wq"], heads), inv_freq, factor)
+        k = rotary_halves(split(u @ params["wk"], kv), inv_freq, factor)
+        v = split(u @ params["wv"], kv)
+        with jax.named_scope("attn_core"):
+            out = flash_attention(q, k, v, ins[0].mask, causal=True,
+                                  block_q=block, block_k=block,
+                                  window=window)
+        if "wg" in params:
+            gate = jax.nn.sigmoid((u @ params["wg"]).astype(jnp.float32))
+            out = (out * gate.transpose(0, 2, 1)[..., None]) \
+                .astype(out.dtype)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, heads * hd) \
+            @ params["wo"]
+        if ins[0].mask is not None:
+            out = out * ins[0].mask[..., None].astype(out.dtype)
+        state = None
+        if window:
+            visited, visible = walked_pairs(T, T, True, window, block, block)
+            state = {"counters": {
+                "swa_pairs_visited": jnp.asarray(visited, jnp.float32),
+                "swa_pairs_visible": jnp.asarray(visible, jnp.float32)}}
+        return Argument(value=out, mask=ins[0].mask, state=state)

@@ -1,6 +1,20 @@
+"""Model builders: each returns what a trainer needs from the DSL graph
+it writes (a cost layer first; see the builder's docstring for the
+rest). The 2017 families (``lenet_mnist``, ``resnet``,
+``lstm_text_classifier``, ``seq2seq_attention``, ``bilstm_crf_tagger``,
+``ctr_model``, ``build_gan``, ``vae``) and two decoder-only language
+models of today's kind, every size an argument named by its published
+``config.json`` key: ``joyai_llm_flash`` (latent attention, every layer
+alike, a multi-token-prediction module) and ``laguna`` (grouped-query
+attention whose layers differ in kind: sliding-window and full, with
+per-layer head counts and two rotary schemes). Both share the expert
+layer (``dsl.moe``), ``swiglu``, ``rms_norm`` and ``lm_cost``.
+"""
+
 from paddle_tpu.models.ctr import ctr_model  # noqa: F401
 from paddle_tpu.models.gan import GANTrainer, build_gan  # noqa: F401
 from paddle_tpu.models.joyai import joyai_llm_flash  # noqa: F401
+from paddle_tpu.models.laguna import laguna  # noqa: F401
 from paddle_tpu.models.lenet import lenet_mnist  # noqa: F401
 from paddle_tpu.models.resnet import resnet  # noqa: F401
 from paddle_tpu.models.lstm_text import lstm_text_classifier  # noqa: F401

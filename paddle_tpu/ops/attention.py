@@ -32,9 +32,26 @@ Three tiers:
   and recomputes what led to q, k and v, so the forward kernel runs
   once a step, not again in the backward pass.
 
-All take q, k of [B, N, T, Dqk] and v of [B, N, T_k, Dv] (the two head
-sizes may differ: latent attention has 192 and 128), an optional kv
-validity mask [B, T_k] and a ``causal`` flag.
+All take q of [B, N, T, Dqk], k of [B, N_kv, T_k, Dqk] and v of [B, N_kv,
+T_k, Dv] (the two head sizes may differ: latent attention has 192 and
+128), an optional kv validity mask [B, T_k], a ``causal`` flag and a
+``window``.
+
+- **Grouped queries.** ``N_kv`` divides ``N``: query head ``n`` attends
+  key-value head ``n // (N / N_kv)``. The kernels never see K and V
+  repeated to the query heads: the forward and dQ kernels walk a query
+  head's tiles and their index maps name its group's K and V blocks;
+  the dK/dV kernel walks a KEY-VALUE head's tiles and, inside one kv
+  block's sweep, the group's query heads in turn, so the sums over the
+  group stay in the VMEM accumulators and dK, dV leave at ``N_kv`` heads
+  (per-query-head results summed outside cost a write and a read of
+  ``N / N_kv`` times the bytes: PERF.md §5 has both timings).
+- **A window.** With ``causal``, ``window=W`` lets query ``i`` see key
+  ``j`` iff ``j <= i + off`` and ``i + off - j < W`` (``off = T_k -
+  T_q``): a band. ``_Tiles`` leaves out the pairs wholly below the band
+  as it leaves out those above the diagonal, and a tile on either edge
+  is masked inside; ``walked_pairs`` says how many pairs the forward
+  grid's tiles hold against how many the mask lets see.
 """
 
 from __future__ import annotations
@@ -54,10 +71,29 @@ from paddle_tpu.ops import common
 _NEG = -1e9
 
 
-def mha_reference(q, k, v, kv_mask=None, causal=False, scale=None):
-    """Plain attention. q [B,N,Tq,Dqk], k [B,N,Tk,Dqk], v [B,N,Tk,Dv],
-    kv_mask [B,Tk]."""
+def _group(q, k):
+    """Query heads a key-value head: ``k`` has a divisor of ``q``'s."""
+    N, Nkv = q.shape[1], k.shape[1]
+    if N % Nkv:
+        raise ValueError(f"{Nkv} key-value heads do not divide {N} "
+                         "query heads")
+    return N // Nkv
+
+
+def _check_window(causal, window):
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is a causal band of at least one key")
+
+
+def mha_reference(q, k, v, kv_mask=None, causal=False, scale=None,
+                  window=None):
+    """Plain attention. q [B,N,Tq,Dqk], k [B,Nkv,Tk,Dqk], v
+    [B,Nkv,Tk,Dv], kv_mask [B,Tk]."""
+    _check_window(causal, window)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
+    group = _group(q, k)
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bnqd,bnkd->bnqk", q, k) * scale
     if kv_mask is not None:
         s = jnp.where(kv_mask[:, None, None, :] > 0, s, _NEG)
@@ -66,17 +102,25 @@ def mha_reference(q, k, v, kv_mask=None, causal=False, scale=None):
         qi = jnp.arange(Tq)[:, None] + (Tk - Tq)
         kj = jnp.arange(Tk)[None, :]
         s = jnp.where(kj <= qi, s, _NEG)
+        if window is not None:
+            s = jnp.where(qi - kj < window, s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bnqk,bnkd->bnqd", p, v)
 
 
 def blockwise_attention(q, k, v, kv_mask=None, causal=False, scale=None,
-                        block_k=512):
+                        block_k=512, window=None):
     """Memory-efficient attention: lax.scan over KV blocks with online
-    softmax. Differentiable; the ground-truth backward for flash."""
+    softmax. Differentiable; the ground-truth backward for flash. A
+    group's query heads ride one more axis of q (``g``), so K and V
+    keep their own heads; every kv block is visited, whatever the
+    window."""
+    _check_window(causal, window)
+    group = _group(q, k)
     B, N, Tq, D = q.shape
     Tk, Dv = k.shape[2], v.shape[-1]
     scale = scale if scale is not None else D ** -0.5
+    q = q.reshape(B, N // group, group, Tq, D)
     block_k = min(block_k, Tk)
     pad = (-Tk) % block_k
     if pad:
@@ -86,8 +130,8 @@ def blockwise_attention(q, k, v, kv_mask=None, causal=False, scale=None,
                 else jnp.ones((B, Tk), q.dtype))
         kv_mask = jnp.pad(base, ((0, 0), (0, pad)))
     nk = k.shape[2] // block_k
-    kb = k.reshape(B, N, nk, block_k, D).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(B, N, nk, block_k, Dv).transpose(2, 0, 1, 3, 4)
+    kb = k.reshape(B, N // group, nk, block_k, D).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(B, N // group, nk, block_k, Dv).transpose(2, 0, 1, 3, 4)
     mb = (kv_mask.reshape(B, nk, block_k).transpose(1, 0, 2)
           if kv_mask is not None else None)
     qi = jnp.arange(Tq)[:, None] + (Tk - Tq)
@@ -95,22 +139,25 @@ def blockwise_attention(q, k, v, kv_mask=None, causal=False, scale=None,
     def body(carry, inp):
         acc, m_run, l_run = carry
         idx, k_t, v_t, msk = inp
-        s = jnp.einsum("bnqd,bnkd->bnqk", q, k_t) * scale
+        s = jnp.einsum("bngqd,bnkd->bngqk", q, k_t) * scale
         if msk is not None:
-            s = jnp.where(msk[:, None, None, :] > 0, s, _NEG)
+            s = jnp.where(msk[:, None, None, None, :] > 0, s, _NEG)
         if causal:
             kj = idx * block_k + jnp.arange(block_k)[None, :]
             s = jnp.where(kj <= qi, s, _NEG)
+            if window is not None:
+                s = jnp.where(qi - kj < window, s, _NEG)
         m_new = jnp.maximum(m_run, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m_run - m_new)
         l_new = l_run * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum("bnqk,bnkd->bnqd", p, v_t)
+        acc = acc * alpha[..., None] + jnp.einsum("bngqk,bnkd->bngqd", p,
+                                                  v_t)
         return (acc, m_new, l_new), None
 
-    acc0 = jnp.zeros((B, N, Tq, Dv), jnp.float32)
-    m0 = jnp.full((B, N, Tq), _NEG, jnp.float32)
-    l0 = jnp.zeros((B, N, Tq), jnp.float32)
+    acc0 = jnp.zeros(q.shape[:-1] + (Dv,), jnp.float32)
+    m0 = jnp.full(q.shape[:-1], _NEG, jnp.float32)
+    l0 = jnp.zeros(q.shape[:-1], jnp.float32)
     if mb is None:
         (acc, m_run, l_run), _ = lax.scan(
             lambda c, i: body(c, (i[0], i[1], i[2], None)), (acc0, m0, l0),
@@ -118,7 +165,7 @@ def blockwise_attention(q, k, v, kv_mask=None, causal=False, scale=None,
     else:
         (acc, m_run, l_run), _ = lax.scan(body, (acc0, m0, l0),
                                           (jnp.arange(nk), kb, vb, mb))
-    return (acc / l_run[..., None]).astype(q.dtype)
+    return (acc / l_run[..., None]).astype(q.dtype).reshape(B, N, Tq, Dv)
 
 
 # ---------------------------------------------------------------- pallas
@@ -129,23 +176,26 @@ _WALK_TABLE_BYTES = 512 * 1024      # of SMEM, for a walk's table of steps
 _FIRST, _LAST = 1, 2      # a step's place in its sweep, in the walk's table
 
 
-def _scores(off, scale, causal, q, k, msk, qb, kb):
-    """The masked, scaled score tile [Bq, Bk] in float32. ``off`` is
-    T_k - T_q: query row i sees keys up to i + off."""
+def _scores(tiles, scale, q, k, msk, qb, kb):
+    """The masked, scaled score tile [Bq, Bk] in float32. ``tiles.off``
+    is T_k - T_q: query row i sees keys up to i + off, and under a
+    window no key more than ``window - 1`` before that."""
     Bq, Bk = q.shape[0], k.shape[0]
     s = lax.dot_general(q, k, _TRANS_B,
                         preferred_element_type=jnp.float32) * scale
     s = jnp.where(msk > 0, s, _NEG)
-    if causal:
-        qi = qb * Bq + lax.broadcasted_iota(jnp.int32, (Bq, Bk), 0) + off
+    if tiles.causal:
+        qi = (qb * Bq + lax.broadcasted_iota(jnp.int32, (Bq, Bk), 0)
+              + tiles.off)
         kj = kb * Bk + lax.broadcasted_iota(jnp.int32, (Bq, Bk), 1)
         s = jnp.where(kj <= qi, s, _NEG)
+        if tiles.window is not None:
+            s = jnp.where(qi - kj < tiles.window, s, _NEG)
     return s
 
 
 def _flash_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                   o_ref, lse_ref, acc_s, m_s, l_s):
-    off, causal = tiles.off, tiles.causal
     qb, kb, first, last = tiles.step(walk_ref)
 
     @pl.when(first)
@@ -155,7 +205,7 @@ def _flash_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
         l_s[:] = jnp.zeros_like(l_s)
 
     v = v_ref[0]
-    s = _scores(off, scale, causal, q_ref[0], k_ref[0], mask_ref[0], qb, kb)
+    s = _scores(tiles, scale, q_ref[0], k_ref[0], mask_ref[0], qb, kb)
     m_prev = m_s[:, 0:1]                                         # [Bq, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -173,14 +223,14 @@ def _flash_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                                       lse_ref.shape[1:])
 
 
-def _tile_terms(off, scale, causal, q_ref, k_ref, v_ref, mask_ref, do_ref,
+def _tile_terms(tiles, scale, q_ref, k_ref, v_ref, mask_ref, do_ref,
                 st_ref, qb, kb):
     """What both backward kernels recompute for one tile: the
     probabilities from the saved log-sum-exp (lane 0 of ``st``) and the
     scores' gradient, with delta = rowsum(dO * O) in lane 1."""
     q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
     st = st_ref[0]
-    s = _scores(off, scale, causal, q, k, mask_ref[0], qb, kb)
+    s = _scores(tiles, scale, q, k, mask_ref[0], qb, kb)
     p = jnp.exp(s - st[:, 0:1])
     dp = lax.dot_general(do, v, _TRANS_B,
                          preferred_element_type=jnp.float32)
@@ -190,7 +240,6 @@ def _tile_terms(off, scale, causal, q_ref, k_ref, v_ref, mask_ref, do_ref,
 
 def _flash_dkv_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                       do_ref, st_ref, dk_ref, dv_ref, dk_s, dv_s):
-    off, causal = tiles.off, tiles.causal
     qb, kb, first, last = tiles.step(walk_ref)
 
     @pl.when(first)
@@ -198,7 +247,7 @@ def _flash_dkv_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    q, _k, do, p, ds = _tile_terms(off, scale, causal, q_ref, k_ref, v_ref,
+    q, _k, do, p, ds = _tile_terms(tiles, scale, q_ref, k_ref, v_ref,
                                    mask_ref, do_ref, st_ref, qb, kb)
     dv_s[:] += jnp.dot(p.T.astype(do.dtype), do,
                        preferred_element_type=jnp.float32)
@@ -213,16 +262,14 @@ def _flash_dkv_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
 
 def _flash_dq_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
                      do_ref, st_ref, dq_ref, dq_s):
-    off, causal = tiles.off, tiles.causal
     qb, kb, first, last = tiles.step(walk_ref)
 
     @pl.when(first)
     def _():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    _q, k, _do, _p, ds = _tile_terms(off, scale, causal, q_ref, k_ref,
-                                     v_ref, mask_ref, do_ref, st_ref,
-                                     qb, kb)
+    _q, k, _do, _p, ds = _tile_terms(tiles, scale, q_ref, k_ref, v_ref,
+                                     mask_ref, do_ref, st_ref, qb, kb)
     dq_s[:] += jnp.dot(ds.astype(k.dtype), k,
                        preferred_element_type=jnp.float32)
 
@@ -231,58 +278,79 @@ def _flash_dq_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
+def _seen(off, causal, window, block_q, block_k, nq, nk):
+    """``[nq, nk]``: does some query of q block ``qb`` see some key of kv
+    block ``kb``? A block is a rectangle and what the mask lets see a
+    band between two diagonals, so it is enough that the rectangle lies
+    neither wholly above the upper one nor wholly below the lower."""
+    qb, kb = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
+    seen = np.ones((nq, nk), bool)
+    if causal:
+        seen = kb * block_k <= qb * block_q + block_q - 1 + off
+        if window is not None:
+            seen &= kb * block_k + block_k - 1 > qb * block_q + off - window
+    return qb, kb, seen
+
+
 class _Tiles:
     """The tiles of one head that a kernel's grid walks, and the block
-    specs the three kernels share over that walk. The grid is (batch x
-    heads, steps): one step for every (q block, kv block) pair in which
-    a query sees a key, q-major (a sweep is one q block's kv blocks: the
-    forward and dQ kernels) or kv-major (one kv block's q blocks:
-    dK/dV). Under ``causal`` the pairs wholly above the diagonal are no
-    steps at all: nothing is fetched, tested or waited for on their
-    behalf, and a sweep's first blocks arrive while the sweep before it
-    still computes. A sweep that sees nothing keeps one tile, all of it
+    specs the three kernels share over that walk. The grid is (rows,
+    steps): one step for every (q block, kv block) pair in which a query
+    sees a key, q-major (a row is a query head, a sweep one q block's kv
+    blocks: the forward and dQ kernels) or kv-major (a row is a
+    KEY-VALUE head, a sweep one kv block's q blocks over each of the
+    ``group`` query heads that share it in turn: dK/dV). Under
+    ``causal`` the pairs wholly above the diagonal are no steps at all,
+    and under a ``window`` neither are those wholly below the band:
+    nothing is fetched, tested or waited for on their behalf, and a
+    sweep's first blocks arrive while the sweep before it still
+    computes. A sweep that sees nothing keeps one tile, all of it
     masked, so that its output block is still written. The steps' tiles
     are a table in SMEM (the calls' scalar prefetch, read by the index
     maps and the kernels); where every pair is a step there is no
-    table: the grid is (batch x heads, sweeps, steps of a sweep) and a
-    step's tile is its two indices."""
+    table: the grid is (rows, sweeps, steps of a sweep) and a step's
+    tile is its two indices."""
 
     def __init__(self, heads, off, causal, block_q, block_k, nq, nk,
-                 kv_major):
+                 kv_major, window=None, group=1):
         self.N, self.off, self.causal = heads, off, causal
+        self.window, self.group, self.nq = window, group, nq
         self.bq, self.bk, self.kv_major = block_q, block_k, kv_major
-        qb, kb = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
-        seen = np.ones((nq, nk), bool)
-        if causal:
-            seen = kb * block_k <= qb * block_q + block_q - 1 + off
+        qb, kb, seen = _seen(off, causal, window, block_q, block_k, nq, nk)
         if kv_major:
             seen[nq - 1, ~seen.any(axis=0)] = True
-            order = np.lexsort((qb[seen], kb[seen]))
-            sweep = kb[seen][order]
         else:
             seen[~seen.any(axis=1), 0] = True
-            order = np.lexsort((kb[seen], qb[seen]))
-            sweep = qb[seen][order]
+        # the group's query heads share a kv block's sweep (dK/dV alone)
+        share = group if kv_major else 1
         # every pair a step: the grid is (rows, sweeps, steps of a sweep)
         # and needs no table
-        self.whole = ((nk, nq) if kv_major else (nq, nk)) \
+        self.whole = ((nk, share * nq) if kv_major else (nq, nk)) \
             if seen.all() else None
         if self.whole:
             return
+        qs, ks = np.tile(qb[seen], share), np.tile(kb[seen], share)
+        head = np.repeat(np.arange(share), seen.sum())
+        order = np.lexsort((qs, head, ks) if kv_major else (ks, qs))
+        sweep = (ks if kv_major else qs)[order]
         edge = np.flatnonzero(np.diff(sweep)) + 1     # where a sweep starts
         ends = np.zeros(len(sweep), np.int32)
         ends[np.r_[0, edge]] |= _FIRST
         ends[np.r_[edge - 1, len(sweep) - 1]] |= _LAST
         self.steps = len(sweep)
-        # [qb of every step | kb of every step | its place in its sweep]
+        # [qb of every step | kb of every step | its place in its sweep
+        #  | under a group, the query head of it the step is for]
         self.walk = jnp.asarray(np.concatenate(
-            [qb[seen][order], kb[seen][order], ends]), jnp.int32)
+            [qs[order], ks[order], ends]
+            + ([head[order]] if share > 1 else [])), jnp.int32)
 
     def _qb(self, *at):
         """The q block of the grid step ``at``: (sweep, step of it) where
         every pair is a step, else (step, the table)."""
         if self.whole:
-            return at[1] if self.kv_major else at[0]
+            if not self.kv_major:
+                return at[0]
+            return at[1] % self.nq if self.group > 1 else at[1]
         t, walk = at
         return walk[t]
 
@@ -291,6 +359,13 @@ class _Tiles:
             return at[0] if self.kv_major else at[1]
         t, walk = at
         return walk[self.steps + t]
+
+    def _head(self, *at):
+        """Which of its group's query heads a kv-major step is for."""
+        if self.whole:
+            return at[1] // self.nq
+        t, walk = at
+        return walk[3 * self.steps + t]
 
     def step(self, walk):
         """``(qb, kb, first of its sweep?, last of it?)`` of the grid
@@ -305,11 +380,23 @@ class _Tiles:
         return self._qb(*at), self._kb(*at), first, last
 
     def specs(self):
-        """``(q-like(d), kv-like(d), mask)`` block-spec makers."""
+        """``(q-like(d), kv-like(d), mask)`` block-spec makers. A grid
+        row is a query head (q-major) or a key-value head (kv-major);
+        the other operand's row follows from the group."""
         vmem = pltpu.VMEM
-        qi = lambda bn, *at: (bn, self._qb(*at), 0)
-        ki = lambda bn, *at: (bn, self._kb(*at), 0)
-        mi = lambda bn, *at: (bn // self.N, 0, self._kb(*at))
+        G = self.group
+        if G == 1:
+            q_row = kv_row = lambda r, *at: r
+        elif self.kv_major:
+            q_row = lambda r, *at: r * G + self._head(*at)
+            kv_row = lambda r, *at: r
+        else:
+            q_row = lambda r, *at: r
+            kv_row = lambda r, *at: r // G
+        rows = self.N // G if self.kv_major else self.N     # a batch row's
+        qi = lambda r, *at: (q_row(r, *at), self._qb(*at), 0)
+        ki = lambda r, *at: (kv_row(r, *at), self._kb(*at), 0)
+        mi = lambda r, *at: (r // rows, 0, self._kb(*at))
         return (lambda d: pl.BlockSpec((1, self.bq, d), qi,
                                        memory_space=vmem),
                 lambda d: pl.BlockSpec((1, self.bk, d), ki,
@@ -319,7 +406,7 @@ class _Tiles:
     def call(self, kernel, scale, rows, in_specs, out_specs, out_shape,
              scratch, *operands):
         """``kernel(self, scale, the table or None, *references)`` over
-        the walk, ``rows`` (batch x heads) times."""
+        the walk, ``rows`` (batch x the heads a row stands for) times."""
         kernel = functools.partial(kernel, self, scale)
         if self.whole:              # no table among its references
             table, grid = (), (rows,) + self.whole
@@ -340,15 +427,38 @@ class _Tiles:
         )(*table, *operands)
 
 
+def walked_pairs(Tq, Tk, causal=False, window=None, block_q=256,
+                 block_k=256):
+    """``(visited, visible)`` of one head: the query-key pairs inside
+    the tiles the forward kernel's grid walks at these blocks, and the
+    pairs the mask lets see. Their ratio is what the tiling wastes on
+    the band's two edges (2.0 for a window of 512 in 512 x 512 tiles:
+    every q block walks the diagonal tile and the one before it, each
+    half visible)."""
+    _check_window(causal, window)
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    _, _, seen = _seen(Tk - Tq, causal, window, bq, bk, -(-Tq // bq),
+                       -(-Tk // bk))
+    seen[~seen.any(axis=1), 0] = True       # the tile a blind sweep keeps
+    # query i sees the keys in (i + off - window, i + off], cut to [0, Tk)
+    qi = np.arange(Tq) + (Tk - Tq)
+    upper = np.minimum(qi, Tk - 1) if causal else np.full(Tq, Tk - 1)
+    lower = np.maximum(qi - window + 1, 0) if window is not None \
+        else np.zeros(Tq, np.int64)
+    visible = int(np.maximum(upper - lower + 1, 0).sum())
+    return int(seen.sum()) * bq * bk, visible
+
+
 def _flash_forward(cfg, qf, kf, vf, mask):
-    """qf [BN,Tq,Dqk], kf [BN,Tk,Dqk], vf [BN,Tk,Dv] (lengths already
-    multiples of the blocks), mask [B,1,Tk] -> (out [BN,Tq,Dv], the rows'
-    log-sum-exp [BN,Tq])."""
-    heads, off, scale, causal, block_q, block_k = cfg
+    """qf [B*N,Tq,Dqk], kf [B*Nkv,Tk,Dqk], vf [B*Nkv,Tk,Dv] (lengths
+    already multiples of the blocks), mask [B,1,Tk] -> (out [B*N,Tq,Dv],
+    the rows' log-sum-exp [B*N,Tq])."""
+    heads, off, scale, causal, block_q, block_k, window, group = cfg
     BN, Tq, Dqk = qf.shape
     Tk, Dv = vf.shape[1], vf.shape[2]
     tiles = _Tiles(heads, off, causal, block_q, block_k, Tq // block_q,
-                   Tk // block_k, kv_major=False)
+                   Tk // block_k, kv_major=False, window=window,
+                   group=group)
     q_like, kv_like, mask_spec = tiles.specs()
     out, lse = tiles.call(
         _flash_kernel, scale, BN,
@@ -364,7 +474,7 @@ def _flash_forward(cfg, qf, kf, vf, mask):
 
 
 def _flash_backward(cfg, qf, kf, vf, mask, out, lse, do):
-    heads, off, scale, causal, block_q, block_k = cfg
+    heads, off, scale, causal, block_q, block_k, window, group = cfg
     BN, Tq, Dqk = qf.shape
     Tk, Dv = vf.shape[1], vf.shape[2]
     nq, nk = Tq // block_q, Tk // block_k
@@ -374,11 +484,13 @@ def _flash_backward(cfg, qf, kf, vf, mask, out, lse, do):
     stats = jnp.pad(jnp.stack([lse, delta], axis=-1),
                     ((0, 0), (0, 0), (0, _STAT_LANES - 2)))
     operands = (qf, kf, vf, mask, do, stats)
+    # a row of dK/dV's grid is a key-value head: its group's query heads
+    # are swept inside, so dk and dv leave at kf's and vf's own heads
     tiles = _Tiles(heads, off, causal, block_q, block_k, nq, nk,
-                   kv_major=True)
+                   kv_major=True, window=window, group=group)
     q_like, kv_like, mask_spec = tiles.specs()
     dk, dv = tiles.call(
-        _flash_dkv_kernel, scale, BN,
+        _flash_dkv_kernel, scale, BN // group,
         [q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec,
          q_like(Dv), q_like(_STAT_LANES)],
         [kv_like(Dqk), kv_like(Dv)],
@@ -388,7 +500,7 @@ def _flash_backward(cfg, qf, kf, vf, mask, out, lse, do):
          pltpu.VMEM((block_k, Dv), jnp.float32)],
         *operands)
     tiles = _Tiles(heads, off, causal, block_q, block_k, nq, nk,
-                   kv_major=False)
+                   kv_major=False, window=window, group=group)
     q_like, kv_like, mask_spec = tiles.specs()
     dq = tiles.call(
         _flash_dq_kernel, scale, BN,
@@ -422,11 +534,12 @@ def _flash_bwd(cfg, res, g):
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_padded(q, k, v, kv_mask, causal, scale, block_q, block_k):
+def _flash_padded(q, k, v, kv_mask, causal, scale, block_q, block_k,
+                  window):
     """Pad the lengths to whole blocks (padded keys are masked, padded
     query rows cut off again), fold batch and heads, call the kernels."""
     B, N, Tq, Dqk = q.shape
-    Tk, Dv = k.shape[2], v.shape[-1]
+    Nkv, Tk, Dv = k.shape[1], k.shape[2], v.shape[-1]
     block_q, block_k = min(block_q, Tq), min(block_k, Tk)
     pad_q, pad_k = (-Tq) % block_q, (-Tk) % block_k
     if kv_mask is None:
@@ -437,19 +550,24 @@ def _flash_padded(q, k, v, kv_mask, causal, scale, block_q, block_k):
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         kv_mask = jnp.pad(kv_mask, ((0, 0), (0, pad_k)))
-    cfg = (N, Tk - Tq, float(scale), bool(causal), block_q, block_k)
+    cfg = (N, Tk - Tq, float(scale), bool(causal), block_q, block_k,
+           window, N // Nkv)
     out = _flash_core(cfg, q.reshape(B * N, Tq + pad_q, Dqk),
-                      k.reshape(B * N, Tk + pad_k, Dqk),
-                      v.reshape(B * N, Tk + pad_k, Dv),
+                      k.reshape(B * Nkv, Tk + pad_k, Dqk),
+                      v.reshape(B * Nkv, Tk + pad_k, Dv),
                       kv_mask.astype(jnp.float32)[:, None, :])
     return out.reshape(B, N, Tq + pad_q, Dv)[:, :, :Tq]
 
 
 def flash_attention(q, k, v, kv_mask=None, causal=False, scale=None,
-                    block_q=256, block_k=256):
-    """Flash attention. Pallas on TPU, blockwise-scan elsewhere. Traced
-    into a step partitioned over a mesh whose batch axes divide B, each
-    device runs the kernels on its own rows (``common.batch_local``)."""
+                    block_q=256, block_k=256, window=None):
+    """Flash attention. Pallas on TPU, blockwise-scan elsewhere. ``k``
+    and ``v`` may have fewer heads than ``q`` (a divisor: grouped
+    queries); ``window`` makes ``causal`` a band. Traced into a step
+    partitioned over a mesh whose batch axes divide B, each device runs
+    the kernels on its own rows (``common.batch_local``)."""
+    _check_window(causal, window)
+    group = _group(q, k)
     Dqk, Dv = q.shape[-1], v.shape[-1]
     scale = scale if scale is not None else Dqk ** -0.5
     bq, bk = min(block_q, q.shape[2]), min(block_k, k.shape[2])
@@ -461,21 +579,25 @@ def flash_attention(q, k, v, kv_mask=None, causal=False, scale=None,
                 + 2 * 4 * bq * _STAT_LANES + 4 * bk * (Dqk + Dv)
                 + 4 * 4 * bq * bk)
     # the causal walk's table of steps lives in SMEM (1 MiB on a v5e),
-    # 12 bytes a (q block, kv block) pair at most
+    # 12 bytes a (q block, kv block) pair at most, and 16 for each of a
+    # group's query heads in dK/dV's
     pairs = -(-q.shape[2] // bq) * -(-k.shape[2] // bk)
+    table = (12 if group == 1 else 16 * group) * pairs
     split = common.batch_split(q.shape[0])
     if split == 0 or not common.use_pallas(resident) \
-            or (causal and 12 * pairs > _WALK_TABLE_BYTES):
+            or (causal and table > _WALK_TABLE_BYTES):
         common.note("flash_attention", "ref")
         return blockwise_attention(q, k, v, kv_mask, causal=causal,
-                                   scale=scale, block_k=block_k)
+                                   scale=scale, block_k=block_k,
+                                   window=window)
     common.note("flash_attention", common.pallas_path())
     if split > 1:
         if kv_mask is None:
             kv_mask = jnp.ones((k.shape[0], k.shape[2]), jnp.float32)
         core = common.batch_local(
             lambda q_, k_, v_, m_: _flash_padded(
-                q_, k_, v_, m_, causal, scale, block_q, block_k),
+                q_, k_, v_, m_, causal, scale, block_q, block_k, window),
             split, in_dims=(0, 0, 0, 0), out_dims=0)
         return core(q, k, v, kv_mask)
-    return _flash_padded(q, k, v, kv_mask, causal, scale, block_q, block_k)
+    return _flash_padded(q, k, v, kv_mask, causal, scale, block_q, block_k,
+                         window)

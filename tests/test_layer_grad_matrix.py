@@ -383,6 +383,15 @@ def _case_mla_attention():
             {"x": _seq(d=8)})
 
 
+def _case_gqa_attention():
+    return ([("x", 8, {"is_sequence": True})],
+            L("out", "gqa_attention", ["x"], num_heads=4, num_kv_heads=2,
+              head_dim=4, window=3, rotary_dim=2, rope_theta=100.0,
+              yarn={"factor": 8.0, "original_max_position_embeddings": 4,
+                    "beta_fast": 4.0, "beta_slow": 1.0}),
+            {"x": _seq(d=8)})
+
+
 def _case_rms_norm():
     return ([("x", 6, {"is_sequence": True})],
             L("out", "rms_norm", ["x"]), {"x": _seq()})
@@ -642,7 +651,8 @@ GRAD_CASES = {
     "conv_shift": _case_conv_shift, "tensor": _case_tensor,
     "selective_fc": _case_selective_fc, "prelu": _case_prelu,
     "multi_head_attention": _case_multi_head_attention,
-    "mla_attention": _case_mla_attention, "rms_norm": _case_rms_norm,
+    "mla_attention": _case_mla_attention,
+    "gqa_attention": _case_gqa_attention, "rms_norm": _case_rms_norm,
     "swiglu": _case_swiglu, "seq_shift": _case_seq_shift,
     "lm_cost": _case_lm_cost,
     "agent": _case_agent,

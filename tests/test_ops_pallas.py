@@ -298,6 +298,167 @@ def test_a_walk_too_long_for_its_table_takes_the_reference_path():
         assert tally["flash_attention"] == {path: 1}
 
 
+# window (a causal band) and grouped queries ---------------------------
+
+def _band_case(B, N, Nkv, Tq, Tk, D, seed, masked=True):
+    """q, k, v, the loss's weights and a ragged key mask (or None). A
+    query whose own position is masked is padding: what it reads is
+    whatever a row without a key is, so the loss gives it no weight."""
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(B, N, Tq, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Nkv, Tk, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Nkv, Tk, D)), jnp.float32)
+    w = rng.normal(size=(B, N, Tq, D)).astype(np.float32)
+    if not masked:
+        return q, k, v, jnp.asarray(w), None
+    kv_mask = _ragged_mask(Tk, B, rng).T
+    w = w * kv_mask[:, None, Tk - Tq:, None]
+    return q, k, v, jnp.asarray(w), jnp.asarray(kv_mask)
+
+
+def _value_and_grads(fn, q, k, v, w):
+    return jax.value_and_grad(
+        lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_) * w), (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("window", [5, 16, 24, 40, 200])
+@pytest.mark.parametrize("Tq,Tk,masked", [(64, 64, False), (40, 40, True),
+                                          (40, 72, True)])
+def test_flash_attention_window(Tq, Tk, masked, window):
+    """A window below, at, above and not a multiple of the block of 16,
+    and wider than the sequence; lengths a multiple of the block or not,
+    more keys than queries, with and without a key mask: the forward and
+    all three gradients against ``mha_reference`` (which masks a plain
+    score matrix), and ``blockwise_attention`` the same."""
+    q, k, v, w, kv_mask = _band_case(2, 2, 2, Tq, Tk, 8, 17, masked)
+    want = _value_and_grads(lambda *a: mha_reference(
+        *a, kv_mask, causal=True, window=window), q, k, v, w)
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        got = _value_and_grads(lambda *a: flash_attention(
+            *a, kv_mask, causal=True, block_q=16, block_k=16,
+            window=window), q, k, v, w)
+    assert tally["flash_attention"] == {"interpret": 1}
+    blk = _value_and_grads(lambda *a: blockwise_attention(
+        *a, kv_mask, causal=True, block_k=16, window=window), q, k, v, w)
+    for have in (got, blk):
+        assert float(have[0]) == pytest.approx(float(want[0]), rel=1e-5,
+                                                abs=2e-5)
+        for g, rg in zip(have[1], want[1]):
+            np.testing.assert_allclose(g, rg, rtol=1e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal,window", [(False, None), (True, None),
+                                           (True, 24)])
+@pytest.mark.parametrize("N,Nkv", [(2, 2), (6, 1), (12, 2), (8, 1),
+                                   (16, 2)])
+def test_flash_attention_grouped_queries(N, Nkv, causal, window):
+    """Groups of 1, 6 and 8 query heads a key-value head (one and two
+    key-value heads), with a ragged key mask and a length (40) that is
+    no multiple of the block: K and V keep their own heads into the
+    kernels, dK and dV come back at those heads, summed over the group
+    inside the dK/dV kernel. A whole grid (no ``causal``), the causal
+    walk and the band."""
+    q, k, v, w, kv_mask = _band_case(2, N, Nkv, 40, 40, 8, 19)
+    want = _value_and_grads(lambda *a: mha_reference(
+        *a, kv_mask, causal=causal, window=window), q, k, v, w)
+    with common.force_mode("interpret"):
+        got = _value_and_grads(lambda *a: flash_attention(
+            *a, kv_mask, causal=causal, block_q=16, block_k=16,
+            window=window), q, k, v, w)
+    blk = _value_and_grads(lambda *a: blockwise_attention(
+        *a, kv_mask, causal=causal, block_k=16, window=window), q, k, v, w)
+    assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
+    for have in (got, blk):
+        assert float(have[0]) == pytest.approx(float(want[0]), rel=1e-5,
+                                                abs=2e-5)
+        for g, rg in zip(have[1], want[1]):
+            np.testing.assert_allclose(g, rg, rtol=1e-4, atol=3e-5)
+
+
+def test_grouped_queries_need_a_divisor_and_a_window_needs_causal():
+    x = jnp.zeros((1, 6, 16, 8))
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(x, x[:, :4], x[:, :4])
+    with pytest.raises(ValueError, match="causal band"):
+        flash_attention(x, x, x, causal=False, window=4)
+    with pytest.raises(ValueError, match="causal band"):
+        mha_reference(x, x, x, causal=True, window=0)
+
+
+@pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16), (16, 32), (8, 24)])
+@pytest.mark.parametrize("window", [None, 1, 7, 16, 17, 40, 1000])
+@pytest.mark.parametrize("Tq,Tk", [(96, 96), (48, 96), (96, 48)])
+def test_the_walk_of_a_band_holds_every_visible_pair_once(Tq, Tk, window,
+                                                          bq, bk):
+    """A property of ``_Tiles`` under ``causal`` with and without a
+    window, in both orders and under a group: every pair the mask lets
+    see lies in exactly one walked tile (of every query head of the
+    group, in dK/dV's order), and no walked tile is without a visible
+    pair but the one that a sweep which sees nothing keeps; a kv-major
+    sweep holds its kv block's tiles of the group's heads in turn, first
+    and last marked once a sweep."""
+    from paddle_tpu.ops.attention import _FIRST, _LAST, _Tiles, walked_pairs
+    off = Tk - Tq
+    nq, nk = -(-Tq // bq), -(-Tk // bk)
+    qi = np.arange(nq * bq)[:, None] + off
+    kj = np.arange(nk * bk)[None, :]
+    sees = kj <= qi
+    if window is not None:
+        sees &= qi - kj < window
+    # by tile: how many visible pairs it holds
+    held = sees.reshape(nq, bq, nk, bk).sum(axis=(1, 3))
+    for kv_major, group in ((False, 1), (True, 1), (True, 3)):
+        t = _Tiles(group, off, True, bq, bk, nq, nk, kv_major,
+                   window=window, group=group)
+        if t.whole:
+            assert (held > 0).all()
+            assert t.whole == ((nk, group * nq) if kv_major else (nq, nk))
+            continue
+        cols = np.asarray(t.walk).reshape(-1, t.steps)
+        qb, kb, ends = cols[:3]
+        head = cols[3] if group > 1 else np.zeros_like(qb)
+        walked = np.zeros((group, nq, nk), int)
+        np.add.at(walked, (head, qb, kb), 1)
+        assert walked.max() == 1                     # no tile twice
+        for g in range(group):
+            assert (walked[g][held > 0] == 1).all()  # every visible pair
+            blind = (walked[g] == 1) & (held == 0)
+            # only where a whole sweep sees nothing, one tile of it
+            if kv_major:
+                assert (blind.sum(axis=0) <= (held.sum(axis=0) == 0)).all()
+            else:
+                assert (blind.sum(axis=1) <= (held.sum(axis=1) == 0)).all()
+        sweep = kb if kv_major else qb
+        assert (np.diff(sweep) >= 0).all()           # sweeps in order
+        starts = np.r_[True, np.diff(sweep) != 0]
+        stops = np.r_[np.diff(sweep) != 0, True]
+        assert ((ends & _FIRST) != 0).tolist() == starts.tolist()
+        assert ((ends & _LAST) != 0).tolist() == stops.tolist()
+        if kv_major and group > 1:      # within a sweep, head by head
+            for b in np.unique(kb):
+                assert (np.diff(head[kb == b]) >= 0).all()
+    if Tq * Tk == nq * bq * nk * bk:        # nothing padded
+        visited, visible = walked_pairs(Tq, Tk, True, window, bq, bk)
+        t = _Tiles(1, off, True, bq, bk, nq, nk, False, window=window)
+        steps = nq * nk if t.whole else t.steps
+        assert visited == steps * bq * bk and visible == sees.sum()
+
+
+def test_walked_pairs_of_the_cells_sliding_layer():
+    """8,192 positions, a window of 512 in 512 x 512 tiles: 31 tiles a
+    head (the causal walk has 136, the whole grid 256), twice the pairs
+    the mask lets see."""
+    from paddle_tpu.ops.attention import _Tiles, walked_pairs
+    assert walked_pairs(8192, 8192, True, 512, 512, 512) == (
+        31 * 512 * 512, 4_063_488)
+    assert _Tiles(64, 0, True, 512, 512, 16, 16, False, window=512,
+                  group=8).steps == 31
+    assert _Tiles(64, 0, True, 512, 512, 16, 16, True, window=512,
+                  group=8).steps == 8 * 31
+    assert _Tiles(48, 0, True, 512, 512, 16, 16, False, group=6).steps == 136
+
+
 def test_lstm_layer_uses_fused_path():
     """lstmemory layer output must be identical with kernels forced to the
     reference tier vs the fused tier (the layer auto-dispatches)."""

@@ -285,3 +285,58 @@ def test_recomputed_swiglu_saves_what_it_saved(monkeypatch):
         bare, _ = _loss_of(build, True)
         assert saved == _saved(bare, params, xv)
     assert saved and all("from the argument" in s[2] for s in saved), saved
+
+
+@pytest.mark.parametrize("window", [32, None])
+def test_recomputed_grouped_query_layer_keeps_the_cores_output(
+        window, monkeypatch):
+    """A ``gqa_attention`` layer (4 query heads over 2 key-value heads of
+    16, sliding or full) under ``recompute``: beside its arguments it
+    keeps the core's output and log-sum-exp and nothing else, so the
+    gradient holds 3 ``pallas_call``s (forward, dK/dV, dQ) where the bare
+    checkpoint runs the forward kernel again; gradients as without
+    ``recompute``. A sliding layer's counters come through the
+    checkpoint."""
+    heads, kv, hd = 4, 2, 16
+
+    def build(x):
+        return dsl.gqa_attention(x, num_heads=heads, num_kv_heads=kv,
+                                 head_dim=hd, window=window, block=32,
+                                 name="swa")
+
+    xv = _x()
+    with common.force_mode("interpret"):
+        plain, params = _loss_of(build, False)
+        remat, _ = _loss_of(build, True)
+        assert _kernels(remat, params, xv) == 3
+        assert _kernels(plain, params, xv) == 3
+        g_plain = _grad(plain)(params, xv)
+        g_remat = _grad(remat)(params, xv)
+        saved = _saved(remat, params, xv)
+        with monkeypatch.context() as m:
+            _bare_checkpoint(m)
+            bare, _ = _loss_of(build, True)
+            assert _kernels(bare, params, xv) == 4
+    for a, b in zip(jax.tree_util.tree_leaves(g_remat),
+                    jax.tree_util.tree_leaves(g_plain)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    inner = [s for s in saved if "from the argument" not in s[2]]
+    assert sorted(s[0] for s in inner) == sorted(
+        [(_B * heads, _T), (_B * heads, _T, hd)]), saved    # lse, out
+    assert any(common.KEPT_RESIDUAL in s[2] for s in inner), saved
+    # the layer's counters are arrays of the checkpointed function
+    dsl.reset()
+    x = dsl.data(name="x", size=_D, is_sequence=True)
+    layer = build(x)
+    dsl.current_graph().layers[layer.name].attrs["recompute"] = True
+    net = Network(dsl.current_graph(), outputs=[layer.name])
+    out = net.apply(params, {"x": Argument(value=xv)}, train=True,
+                    rng=jax.random.PRNGKey(1))[layer.name]
+    if window:
+        # 128 tokens, a window of 32 in tiles of 32: 7 of 16 tiles
+        assert float(out.state["counters"]["swa_pairs_visited"]) \
+            == 7 * 32 * 32
+        assert float(out.state["counters"]["swa_pairs_visible"]) \
+            == 32 * 33 // 2 + 96 * 32
+    else:
+        assert not out.state

@@ -96,6 +96,70 @@ def test_recomputed_latent_layer_runs_the_forward_kernel_once(one_chip):
         'custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
+def test_grouped_query_cores_forward_and_backward(one_chip, heads, window):
+    """The Laguna cell's two cores: 64 query heads with a window of 512
+    and 48 without, over 8 key-value heads of 128, 8,192 positions,
+    512 x 512 tiles: the forward kernel, dK/dV with its sweep over a
+    group's 8 or 6 query heads, and dQ; K and V go in at 8 heads."""
+    from paddle_tpu.ops.attention import flash_attention
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def step(q, k, v, g):
+        return jax.vjp(lambda *a: flash_attention(
+            *a, None, causal=True, block_q=512, block_k=512,
+            window=window), q, k, v)[1](g)
+
+    with common.record_dispatch() as tally:
+        compiled = _compile(step, sd(1, heads, 8192, 128),
+                            sd(1, 8, 8192, 128), sd(1, 8, 8192, 128),
+                            sd(1, heads, 8192, 128))
+    assert tally["flash_attention"] == {"pallas": 1}
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    dq, dk, dv = compiled.out_info
+    assert dq.shape == (1, heads, 8192, 128)
+    assert dk.shape == dv.shape == (1, 8, 8192, 128)
+
+
+def test_recomputed_sliding_layer_runs_the_forward_kernel_once(one_chip):
+    """The cell's sliding layer (8,192 tokens of 2,048, 64 heads over 8
+    key-value heads, a window of 512) marked `recompute`, through the
+    executor: forward kernel, dK/dV and dQ, no second forward kernel."""
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.argument import Argument
+    from paddle_tpu.core.network import Network
+
+    dsl.reset()
+    x = dsl.data(name="x", size=2048, is_sequence=True)
+    attn = dsl.gqa_attention(x, num_heads=64, num_kv_heads=8, head_dim=128,
+                             window=512, name="swa",
+                             layer_attr={"recompute": True})
+    net = Network(dsl.current_graph(), outputs=[attn.name])
+
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape.shape, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        sd, jax.eval_shape(net.init_params, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
+                                  sharding=one_chip)
+
+    def step(params, xv, g):
+        def layer(params, xv):
+            return net.apply(params, {"x": Argument(value=xv)},
+                             train=True)[attn.name].value
+        out, back = jax.vjp(layer, params, xv)
+        return out, back(g)
+
+    compiled = _compile(step, params, tokens, tokens)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+
+
 def test_routed_experts_forward_and_backward(one_chip):
     """8 held experts of 256, 8 a token, 8,192 tokens of 2,048, width
     768: the loop over buffers of 4,096 rows, forward and backward."""

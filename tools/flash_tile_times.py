@@ -1,16 +1,25 @@
 """What a pass of the flash attention kernels costs on the chip, by kernel
 and by what the score tile does (TPU only; `chiprun -- python3
-tools/flash_tile_times.py [variant ...]`).
+tools/flash_tile_times.py [--window W] [--kv-heads N] [--batch B --heads
+H --seq T --dqk D --dv D] [variant ...]`).
 
-At the JoyAI cell's shape (batch x heads 64, 4,096 positions, q/k 192,
-v 128, bf16, 512 x 512 tiles, causal, a key mask of ones) it times the
-forward, the dK/dV and the dQ kernel alone, each jitted by itself, as
-`ops/attention.py` has them. A variant is one of
+At the JoyAI cell's shape by default (batch x heads 2 x 32, 4,096
+positions, q/k 192, v 128, bf16, 512 x 512 tiles, causal, a key mask of
+ones) it times the forward, the dK/dV and the dQ kernel alone, each
+jitted by itself, as `ops/attention.py` has them. `--window` makes the
+causal pass a band, `--kv-heads` gives K and V fewer heads than q (the
+Laguna cell's sliding layers: `--batch 1 --heads 64 --kv-heads 8 --seq
+8192 --dqk 128 --window 512`; its full layers: `--heads 48` and no
+window). A variant is one of
 
 - a name of `VARIANTS`: parts of the tile's vector work taken out by
   patching the module's tile functions. These compute WRONG outputs:
   ceilings that say what a part costs, not candidates (PR 30: none of
   them moves a pass);
+- `dkv_outside`: dK/dV per QUERY head (K and V repeated to the query
+  heads beforehand, outside the timing; the kernel reads the same bytes)
+  and summed over each group outside the kernel, against `as_is`'s sweep
+  over the group inside it (PR 31's choice: PERF.md section 5);
 - `shape:<block_q>x<block_k>`: the kernels as they are at other blocks;
   `shape:<bq>x<bk>:full` without `causal`, every pair of the grid a tile
   (a causal pass over a whole pass says what the grid's walk costs
@@ -21,12 +30,13 @@ and in `chiprun_out/flash_tile_times.jsonl`; `ms` is the median of
 `REPEATS` host-clock timings of `CALLS` calls (XLA's copies of the
 operands into the kernels' layout, about 0.7 ms, are in it, and the
 backward kernels' are less `beside_ms`, the statistics operand's
-making), `layers6_ms` six layers of it, `us_a_tile` over the causal
-512 x 512 grid's 2,304 visible tiles.
+making), `layers6_ms` six layers of it, `us_a_tile` over the tiles the
+forward grid walks at 512 x 512 (2,304 at the default shape).
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import statistics
@@ -43,6 +53,7 @@ from jax import lax  # noqa: E402
 from paddle_tpu.ops import attention as A  # noqa: E402
 
 B, N, T, DQK, DV, BLOCK = 2, 32, 4096, 192, 128, 512
+NKV, WINDOW = N, None       # --kv-heads, --window
 CALLS, REPEATS = 10, 5
 
 
@@ -59,25 +70,27 @@ def _iotas(q, k, qb, kb, off):
 
 
 def _scores_variant(scale_on, key_on, causal_on):
-    def scores(off, scale, causal, q, k, msk, qb, kb):
+    def scores(tiles, scale, q, k, msk, qb, kb):
         s = _product(q, k)
         if scale_on:
             s = s * scale
         if key_on:
             s = jnp.where(msk > 0, s, A._NEG)
-        if causal_on and causal:
-            qi, kj = _iotas(q, k, qb, kb, off)
+        if causal_on and tiles.causal:
+            qi, kj = _iotas(q, k, qb, kb, tiles.off)
             s = jnp.where(kj <= qi, s, A._NEG)
+            if tiles.window is not None:
+                s = jnp.where(qi - kj < tiles.window, s, A._NEG)
         return s
     return scores
 
 
 def _terms_variant(ds_scale, exp_on):
-    def terms(off, scale, causal, q_ref, k_ref, v_ref, mask_ref, do_ref,
-              st_ref, qb, kb):
+    def terms(tiles, scale, q_ref, k_ref, v_ref, mask_ref, do_ref, st_ref,
+              qb, kb):
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         st = st_ref[0]
-        s = A._scores(off, scale, causal, q, k, mask_ref[0], qb, kb)
+        s = A._scores(tiles, scale, q, k, mask_ref[0], qb, kb)
         p = s - st[:, 0:1]
         if exp_on:
             p = jnp.exp(p)
@@ -101,6 +114,7 @@ VARIANTS = {
                  _terms_variant(False, True)),
     "bare_no_exp": (_scores_variant(False, False, False),
                     _terms_variant(False, False)),
+    "dkv_outside": (None, None),
 }
 
 
@@ -112,8 +126,8 @@ def _operands():
             jnp.bfloat16)
 
     q = rnd(keys[0], B * N, T, DQK)
-    k = rnd(keys[1], B * N, T, DQK)
-    v = rnd(keys[2], B * N, T, DV)
+    k = rnd(keys[1], B * NKV, T, DQK)
+    v = rnd(keys[2], B * NKV, T, DV)
     do = rnd(keys[3], B * N, T, DV)
     mask = jnp.ones((B, 1, T), jnp.float32)
     return q, k, v, do, mask
@@ -139,14 +153,28 @@ def measure(name, bq=BLOCK, bk=BLOCK, causal=True):
     if terms is not None:
         A._tile_terms = terms
     try:
-        cfg = (N, 0, DQK ** -0.5, causal, bq, bk)
+        group = N // NKV
+        window = WINDOW if causal else None
+        cfg = (N, 0, DQK ** -0.5, causal, bq, bk, window, group)
         q, k, v, do, mask = _operands()
         fwd = jax.jit(lambda *a: A._flash_forward(cfg, *a))
         out, lse = jax.block_until_ready(fwd(q, k, v, mask))
+        res = (q, k, v, mask, out, lse, do)
+        if name == "dkv_outside":
+            # a query head a grid row, its group's K and V beside it;
+            # the group's dK and dV summed by XLA afterwards
+            one = cfg[:-1] + (1,)
+            rep = [jnp.repeat(x.reshape(B, NKV, T, -1), group, axis=1)
+                   .reshape(B * N, T, -1) for x in (k, v)]
+
+            def outside(q, k, v, *rest):
+                return [g.reshape(B, NKV, group, T, -1).sum(axis=2)
+                        for g in A._flash_backward(one, q, k, v, *rest)[1:]]
+
+            return {"dkv": _time(jax.jit(outside), q, *rep, *res[3:])}
         # one backward kernel each: the other's call is dead code
         dkv = jax.jit(lambda *a: A._flash_backward(cfg, *a)[1:])
         dq = jax.jit(lambda *a: A._flash_backward(cfg, *a)[0])
-        res = (q, k, v, mask, out, lse, do)
         times = {"fwd": _time(fwd, q, k, v, mask),
                  "dkv": _time(dkv, *res), "dq": _time(dq, *res)}
     finally:
@@ -170,13 +198,27 @@ def beside():
 
 
 def main(argv) -> int:
+    global B, N, NKV, T, DQK, DV, WINDOW
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("variants", nargs="*")
+    ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--kv-heads", type=int, default=None)
+    ap.add_argument("--batch", type=int, default=B)
+    ap.add_argument("--heads", type=int, default=N)
+    ap.add_argument("--seq", type=int, default=T)
+    ap.add_argument("--dqk", type=int, default=DQK)
+    ap.add_argument("--dv", type=int, default=DV)
+    args = ap.parse_args(argv)
+    B, N, T, DQK, DV = args.batch, args.heads, args.seq, args.dqk, args.dv
+    NKV, WINDOW = args.kv_heads or N, args.window
     device = jax.devices()[0]
     if device.platform != "tpu":
         raise SystemExit(f"a chip measurement: JAX has {device}")
-    names = argv or list(VARIANTS)
+    names = args.variants or [v for v in VARIANTS if v != "dkv_outside"]
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    # visible 512 x 512 tiles of one pass: 36 a head, 64 heads
-    tiles = (T // BLOCK) * (T // BLOCK + 1) // 2 * B * N
+    # 512 x 512 tiles the forward grid walks in one pass, every head
+    tiles = A.walked_pairs(T, T, True, WINDOW, BLOCK, BLOCK)[0] \
+        // (BLOCK * BLOCK) * B * N
     with open(os.path.join(ROOT, "chiprun_out", "flash_tile_times.jsonl"),
               "a") as f:
         def say(row):
@@ -187,7 +229,9 @@ def main(argv) -> int:
 
         stats_ms = beside()
         say({"device": device.device_kind, "beside_ms": stats_ms,
-             "tiles_a_pass": tiles})
+             "tiles_a_pass": tiles, "batch": B, "heads": N,
+             "kv_heads": NKV, "seq": T, "dqk": DQK, "dv": DV,
+             "window": WINDOW})
         for name in names:
             if name.startswith("shape:"):       # shape:512x1024[:full]
                 dims, *full = name[6:].split(":")
