@@ -11,8 +11,7 @@ Adam), with random weights and a synthetic task both made from a seed:
    the prefetch thread, ``RecompileGuard`` and events armed; the loss
    must be finite and lower at the end than at the start, and the
    compiled step's HLO must hold Mosaic custom calls (the Pallas LSTM
-   and the fused optimizer went through the compiler, not through
-   ``ref`` or ``interpret``);
+   went through the compiler, not through ``ref`` or ``interpret``);
 2. serve: the trained parameters merged to a ``.ptmodel``, loaded by
    ``ServingPredictor.from_merged`` behind ``ServingEngine`` and
    ``make_server`` on a free port; ``ServingClient`` scores batches of
@@ -188,12 +187,6 @@ def train_phase(w: Width, *, mesh=None, expect_mosaic: bool = True,
         if report["lstm_dispatch"] != "resident" or \
                 tally.get("lstm") != {"resident": w.layers}:
             raise AssertionError(f"LSTM left the resident kernel: {report}")
-        # every dense f32 parameter takes the fused update: on one
-        # chip directly, on the data-parallel mesh on each device over
-        # its own replica (ops/opt_update.py)
-        if set(tally.get("opt_update", {})) != {"fused"}:
-            raise AssertionError(
-                f"a parameter left the fused optimizer update: {tally}")
     if split > 1 and expect_mosaic:
         # the kernels must see the per-device batch, not the gathered one
         local = f"{w.seqlen},{local_batch},"

@@ -13,9 +13,10 @@ by Pallas TPU kernels with two fallback tiers:
 
 Selection is automatic (see ``common.use_pallas``); nothing else in the
 framework needs to know which tier ran. ``common`` is the one policy for
-every kernel in the tree (``docs/kernels.md``): the entries here, the
-fused optimizer update (``ops/opt_update.py``) and the grouped products
-of ``parallel/moe.py``.
+every kernel in the tree (``docs/kernels.md``): the entries here and the
+grouped products of ``parallel/moe.py``. The optimizers' element-wise
+updates are no kernel: the compiler's own loop fusion makes one pass of
+each (``optim/optimizers.py``).
 """
 
 from paddle_tpu.ops.common import use_pallas, force_mode

@@ -147,8 +147,7 @@ def current_mesh():
 def partitioned() -> bool:
     """Is the code being traced part of a step XLA has to partition over
     several devices? A kernel there either runs per device
-    (``batch_local``, ``replica_local``) or stands down to its
-    reference."""
+    (``batch_local``) or stands down to its reference."""
     mesh = current_mesh()
     return mesh is not None and mesh.size > 1
 
@@ -189,25 +188,6 @@ def batch_local(fn, split: int, in_dims, out_dims):
         fn, mesh, in_specs=tuple(spec(d) for d in in_dims),
         out_specs=(tuple(spec(d) for d in out_dims)
                    if isinstance(out_dims, tuple) else spec(out_dims)))
-
-
-def replica_local(fn):
-    """``fn`` run whole by every device on operands each device holds
-    whole (a replicated parameter, its all-reduced gradient, its
-    slots): ``fn`` itself when nothing is partitioned, a replicated
-    ``shard_map`` when every device of the step mesh is a data-parallel
-    replica, and None — the caller stands down to what XLA can
-    partition — on a mesh with a model, seq or pipe axis, where a
-    parameter may be sharded and a replicated spec would gather it."""
-    if not partitioned():
-        return fn
-    from jax.sharding import PartitionSpec as P
-
-    from paddle_tpu.parallel import mesh as mesh_lib  # lazy: import cycle
-    mesh = current_mesh()
-    if mesh_lib.data_parallel_degree(mesh) != mesh.size:
-        return None
-    return mesh_lib.shard_map_compat(fn, mesh, in_specs=P(), out_specs=P())
 
 
 # shared kernel-layout vocabulary -------------------------------------------

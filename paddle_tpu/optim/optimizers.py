@@ -133,14 +133,13 @@ class Optimizer:
             p_new, slots_new = self._apply_sparse(
                 p, g, slots, lr_t * lr_mult, l1, l2, t)
         else:
-            # the dense elementwise chain routes through the fused
-            # update (ops/opt_update.py): Pallas-on-TPU for the
-            # Momentum/Adam chains, _apply_one itself everywhere else —
-            # so the replicated, ZeRO-1 shard-wise and packed FSDP
-            # updates all share the one fused entry
-            from paddle_tpu.ops import opt_update as _fused
-            p_new, slots_new = _fused.apply_one(
-                self, p, g, slots, lr_t * lr_mult, l2, t)
+            # the dense chain is plain jnp in the leaf's own shape: the
+            # TPU compiler makes ONE loop fusion of it (with the clip
+            # above, the gradient's convert, the l1 shrink and the mask
+            # below), every output aliased, nothing re-laid-out
+            # (tests/test_tpu_compile.py pins that)
+            p_new, slots_new = self._apply_one(
+                p, g, slots, lr_t * lr_mult, l2, t)
             if l1 > 0:
                 shrink = l1 * lr_t * lr_mult
                 p_new = jnp.sign(p_new) * jnp.maximum(

@@ -19,8 +19,9 @@ TINY = chip_smoke.Width(vocab=200, embed=16, hidden=128, layers=2, batch=8,
 def test_phases_run_tiny_with_interpreted_kernels():
     with common.force_mode("interpret"):
         trained = chip_smoke.train_phase(TINY, expect_mosaic=False)
-        assert trained["dispatch_tally"]["lstm"] == {"resident": 2}
-        assert trained["dispatch_tally"]["opt_update"]["fused"] >= 1
+        # the LSTM is the step's one kernel entry: the optimizer's
+        # update is plain jnp, left to the compiler's loop fusion
+        assert trained["dispatch_tally"] == {"lstm": {"resident": 2}}
         assert trained["loss_last_pass"] < trained["loss_first_pass"]
         served = chip_smoke.serve_phase(TINY, trained)
         assert served["repeat_byte_equal"] and served["fatal"] is None
@@ -28,11 +29,8 @@ def test_phases_run_tiny_with_interpreted_kernels():
         meshed = chip_smoke.mesh_phase(TINY, trained, expect_mosaic=False)
         assert meshed["mesh"]["data"] == 4 and meshed["all_reduce_in_hlo"]
         # XLA cannot partition a Mosaic kernel: on the mesh the LSTM
-        # kernels run per device on their own batch rows (batch_local),
-        # the fused optimizer update on each device's replica
-        # (replica_local)
-        assert meshed["dispatch_tally"]["lstm"] == {"resident": 2}
-        assert set(meshed["dispatch_tally"]["opt_update"]) == {"fused"}
+        # kernels run per device on their own batch rows (batch_local)
+        assert meshed["dispatch_tally"] == {"lstm": {"resident": 2}}
 
 
 def test_real_entry_refuses_a_non_tpu_backend(capsys):

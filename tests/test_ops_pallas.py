@@ -700,44 +700,6 @@ def test_kernels_run_per_device_or_stand_down_under_a_mesh():
                                    rtol=2e-4, atol=2e-5)
 
 
-def test_fused_optimizer_runs_per_replica_under_a_data_parallel_mesh():
-    """Traced into a step partitioned over a data-parallel mesh, the
-    fused update runs on every device over its own replica
-    (``common.replica_local``) and equals the one-chip call; on a mesh
-    with a model axis — a parameter may be sharded there — it stands
-    down to ``_apply_one``, and ``record_dispatch`` counts it."""
-    from paddle_tpu.ops import opt_update
-    from paddle_tpu.ops import common
-    from paddle_tpu.optim import Adam, Momentum
-    from paddle_tpu.parallel import create_mesh
-    dp = create_mesh(n_data=4, devices=jax.devices()[:4])
-    tp = create_mesh(n_data=2, n_model=2, devices=jax.devices()[:4])
-    rng = np.random.RandomState(0)
-    p, g, m, v = (jnp.asarray(rng.rand(7, 13).astype(np.float32))
-                  for _ in range(4))
-    for opt, slots in ((Adam(learning_rate=1e-3), {"mom": m, "v": v}),
-                       (Momentum(learning_rate=0.1, momentum=0.9),
-                        {"mom": m})):
-        def update(opt=opt):
-            # a fresh function per trace: the step mesh is read at trace
-            # time and is no part of jit's cache key
-            return jax.jit(lambda p_, g_, slots_: opt_update.apply_one(
-                opt, p_, g_, slots_, 0.01, 1e-4, jnp.int32(3)))
-        with common.force_mode("interpret"), \
-                common.record_dispatch() as tally:
-            one = update()(p, g, slots)
-            with common.step_mesh(dp):
-                meshed = update()(p, g, slots)
-            with common.step_mesh(tp):
-                stood_down = update()(p, g, slots)
-        assert tally == {"opt_update": {"fused": 2, "apply_one": 1}}
-        for got in (meshed, stood_down):
-            for a, b in zip(jax.tree_util.tree_leaves(one),
-                            jax.tree_util.tree_leaves(got)):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           rtol=1e-6, atol=1e-7)
-
-
 # ------------------------------------------------- tiled-H LSTM (big H)
 def test_lstm_dispatch_pins_bench_shapes():
     """The benchmark shapes must take their intended kernel path

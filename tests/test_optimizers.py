@@ -243,3 +243,76 @@ def test_pruning_hook_via_v1_config_attr():
     attr = ParameterAttribute(
         update_hooks=HookAttribute("pruning", sparsity_ratio=0.7))
     assert attr.to_param_attr().sparsity_ratio == 0.7
+
+
+_CHAIN_CASES = {
+    "plain": {},
+    "l2": {"l2_rate": 8e-4},
+    "clipping": {"gradient_clipping_threshold": 0.5},
+    "l1": {"l1_rate": 0.05},
+    "prune_mask": {"mask": True},
+    "all": {"l2_rate": 8e-4, "gradient_clipping_threshold": 0.5,
+            "l1_rate": 0.05, "mask": True},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHAIN_CASES))
+@pytest.mark.parametrize("kind", ["adam", "momentum"])
+def test_update_param_is_the_numpy_chain(kind, case):
+    """``_update_param``'s dense branch, whole: value clipping, L2 inside
+    the gradient, the optimizer's chain in the leaf's own shape, the l1
+    shrink and the prune mask, against the same chain spelled in NumPy
+    float32. There is one spelling of the dense update (no kernel stands
+    in for ``_apply_one``), so this is what every leaf of every step
+    runs."""
+    from paddle_tpu.core.registry import ParamSpec
+    opts = dict(_CHAIN_CASES[case])
+    masked = opts.pop("mask", False)
+    opt = (Adam(learning_rate=0.1, beta1=0.9, beta2=0.95, **opts)
+           if kind == "adam"
+           else Momentum(learning_rate=0.1, momentum=0.9, **opts))
+    rng = np.random.RandomState(3)
+    f32 = np.float32
+    p, g, m, v = (rng.randn(3, 5, 7).astype(f32) for _ in range(4))
+    v = np.abs(v)
+    mask = (rng.rand(3, 5, 7) > 0.4).astype(f32)
+    slots = {"mom": m} if kind == "momentum" else {"mom": m, "v": v}
+    if masked:
+        slots["prune_mask"] = mask
+    lr_t, lr_mult, t = f32(0.05), 0.5, 3
+    spec = ParamSpec(shape=p.shape, learning_rate=lr_mult)
+
+    got_p, got_s = opt._update_param(
+        jnp.asarray(g), jnp.asarray(p),
+        {k: jnp.asarray(a) for k, a in slots.items()}, spec,
+        jnp.float32(lr_t), jnp.int32(t))
+
+    lr = f32(lr_t * f32(lr_mult))
+    th = opt.gradient_clipping_threshold
+    gc = np.clip(g, -th, th) if th > 0 else g
+    gd = gc + f32(opt.l2_rate) * p
+    if kind == "momentum":
+        want_s = {"mom": f32(0.9) * m - lr * gd}
+        want_p = p + want_s["mom"]
+    else:
+        b1, b2 = f32(0.9), f32(0.95)
+        want_s = {"mom": b1 * m + (f32(1) - b1) * gd,
+                  "v": b2 * v + (f32(1) - b2) * np.square(gd)}
+        alpha = lr * np.sqrt(f32(1) - b2 ** f32(t)) / (f32(1) - b1 ** f32(t))
+        want_p = p - alpha * want_s["mom"] / (
+            np.sqrt(want_s["v"]) + f32(opt.epsilon))
+    if opt.l1_rate > 0:
+        want_p = np.sign(want_p) * np.maximum(
+            np.abs(want_p) - f32(opt.l1_rate) * lr, f32(0))
+    if masked:
+        want_p = want_p * mask
+        want_s["prune_mask"] = mask
+
+    assert set(got_s) == set(want_s)
+    np.testing.assert_allclose(np.asarray(got_p), want_p,
+                               rtol=2e-6, atol=1e-7)
+    for k, want in want_s.items():
+        np.testing.assert_allclose(np.asarray(got_s[k]), want,
+                                   rtol=2e-6, atol=1e-7)
+    if masked:
+        assert np.all(np.asarray(got_p)[mask == 0] == 0.0)

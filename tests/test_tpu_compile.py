@@ -1,9 +1,14 @@
-"""The new cell's kernels compiled for a described TPU v5e at the
-widths the cell runs them, with no chip: Mosaic refuses here what it
-would refuse there (a slice off the tiling, too much VMEM). Nothing
-runs; a compile that passes is not a chip run. All in this one file:
+"""The language-model cells' kernels, and the optimizer's update of one
+leaf, compiled for a described TPU v5e at the widths the cells run
+them, with no chip: Mosaic refuses here what it would refuse there (a
+slice off the tiling, too much VMEM), and the compiled text shows what
+the chip's compiler makes of plain jnp. Nothing runs; a compile that
+passes is not a chip run. All in this one file:
 the worker that gets it is the one process that loads the TPU's
 compiler."""
+
+import math
+import re
 
 import pytest
 
@@ -29,13 +34,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *shapes):
+def _compile(fn, *shapes, donate_argnums=()):
     from jax.experimental.compilation_cache import compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
         with common.force_mode("pallas"):
-            return jax.jit(fn).lower(*shapes).compile()
+            return jax.jit(fn, donate_argnums=donate_argnums).lower(
+                *shapes).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
 
@@ -179,3 +185,69 @@ def test_routed_experts_forward_and_backward(one_chip):
         sd((8, 768, 2048)), sd((8192, 8), jnp.int32),
         sd((8192, 8), jnp.float32), sd((8192, 2048)))
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(")
+
+
+def _entry_ops(compiled):
+    """``[(opcode, elements of its largest result)]`` of the compiled
+    module's entry computation."""
+    text = compiled.as_text()
+    ops = []
+    for line in text[text.index("ENTRY"):].splitlines()[1:]:
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+        ops.append((m.group(2), max(sizes, default=0)))
+    return ops
+
+
+@pytest.mark.parametrize("grad", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(2048, 8192), (8, 2048, 768),
+                                   (3, 3, 256, 256), (2048,), (1280, 2)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("kind", ["adam", "momentum"])
+def test_update_is_one_pass_over_a_leaf_in_its_own_layout(one_chip, kind,
+                                                          shape, grad):
+    """One leaf through ``Optimizer._update_param`` (L2 and value
+    clipping on, the gradient converted inside, parameter and slots
+    donated), as the step runs it: ONE loop fusion holds every
+    leaf-sized result, so no ``reshape``, ``copy``, ``pad``,
+    ``concatenate`` or ``slice`` re-lays a leaf out on the way in or out
+    (the `[rows, 128]` view of PR 16's kernel cost four reshapes in and
+    three back whenever the last dimension was not 128: a float32
+    matrix is tiled (8, 128)); no temporary; the parameter and every
+    slot updated in place. A ``copy-start``/``copy-done`` pair is the
+    compiler's prefetch of a small operand into VMEM, not a relayout.
+    This is the optimizer row's static counter (``PERF.md`` section 3)."""
+    from paddle_tpu.optim import Adam, Momentum
+    opt = (Adam(learning_rate=1e-5, beta1=0.9, beta2=0.95, l2_rate=8e-4,
+                gradient_clipping_threshold=25.0)
+           if kind == "adam" else
+           Momentum(learning_rate=0.1, momentum=0.9, l2_rate=1e-4,
+                    gradient_clipping_threshold=25.0))
+
+    def sd(dtype=jnp.float32, shape=shape):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    slots = {name: sd() for name in opt.slot_names()}
+
+    def update(g, p, slots, lr, t):
+        return opt._update_param(g.astype(jnp.float32), p, slots, None,
+                                 lr, t)
+
+    compiled = _compile(update, sd(grad), sd(), slots, sd(shape=()),
+                        sd(jnp.int32, ()), donate_argnums=(1, 2))
+    leaf = math.prod(shape)
+    big = [op for op, n in _entry_ops(compiled)
+           if n >= leaf and op != "parameter"]
+    assert big.count("fusion") == 1, big
+    assert set(big) <= {"fusion", "copy-start", "copy-done"}, big
+    assert "tpu_custom_call" not in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == 4 * leaf * (1 + len(slots))

@@ -345,34 +345,6 @@ def main(argv=None) -> int:
          lambda lp_: ctc_loss(lp_, lab, in_m, lab_m, blank=11),
          (lp,), (0,), shape="B32 T40 C12 L8 (S=17 padded to 128)")
 
-    # ------------------------------------------------- ops/opt_update.py
-    # the fused entry against Optimizer._apply_one (its own fallback):
-    # "ref" mode routes apply_one straight to it
-    from paddle_tpu.ops import opt_update
-    from paddle_tpu.optim import Adam, Momentum
-    shapes = {"lstm_w_1MiB": (256, 1024), "w_1p5MiB": (384, 1024),
-              "embedding_15MB": (30000, 128), "bias": (1024,),
-              "ragged": (7, 13)}
-    for oname, opt, slots in (
-            ("momentum", Momentum(learning_rate=0.1, momentum=0.9),
-             ("mom",)),
-            ("adam", Adam(learning_rate=1e-3), ("mom", "v"))):
-        for sname, shp in shapes.items():
-            p, g = arr(*shp), arr(*shp)
-            st = {s: jnp.abs(arr(*shp)) for s in slots}
-
-            def update(p_, g_, opt=opt, st=st):
-                p2, s2 = opt_update.apply_one(
-                    opt, p_, g_, st, jnp.float32(0.01), 1e-4,
-                    jnp.int32(3))
-                return jnp.concatenate(
-                    [p2.reshape(-1)] + [s2[k].reshape(-1)
-                                        for k in sorted(s2)])
-
-            case(report, f"opt_update_{oname}_{sname}",
-                 "ops/opt_update.py", update, (p, g),
-                 shape=f"{shp} float32", fwd_tol=1e-5)
-
     # ------------------------------ on-device checkgrad of the custom VJPs
     t, b, h = 8, 8, 128
     cx, cm = arr(t, b, 4 * h), jnp.ones((t, b), jnp.float32)

@@ -3,6 +3,7 @@
     python3 -m benchmark.run --workload <cell> --seed <n> --seconds 30 \\
         --trace 1 --keep-trace FILE
     python3 tools/trace_scope_ops.py FILE mla_core
+    python3 tools/trace_scope_ops.py FILE '^(?!.*jvp\()' shapes
 
 ``FILE`` is the run's ``.xplane.pb`` and ``FILE.scopes.json`` the
 compiled step's ``{instruction: op_name}`` beside it. Every operation
@@ -11,8 +12,11 @@ direction (``fwd`` / ``bwd``, from ``jvp`` / ``transpose(jvp``), its
 opcode and, for a custom call, its result's type: that tells the
 attention core's three kernels apart (forward ``(bf16[..,Dv],
 f32[..,128])``, dK/dV ``(bf16[..,Dqk], bf16[..,Dv])``, dQ
-``bf16[..,Dqk]``). A step is what most instructions ran: their count of
-events. One JSON object a line, the dearest first.
+``bf16[..,Dqk]``). A third word, ``shapes``, keys every operation by
+its result's type, and the second pattern above takes what carries no
+layer (the optimizer's update, casts, metrics): that is how PR 32 found
+the update's relayouts. A step is what most instructions ran: their
+count of events. One JSON object a line, the dearest first.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ def main(argv) -> int:
     from benchmark import trace_reduce
 
     path, pattern = argv[0], re.compile(argv[1])
+    shapes = argv[2:] == ["shapes"]
     with open(path + ".scopes.json") as f:
         scopes = json.load(f)
     plane = min((p for p in ProfileData.from_file(path).planes
@@ -56,7 +61,7 @@ def main(argv) -> int:
                 continue
             way = trace_reduce.scope_label(scope).rsplit(".", 1)[-1]
             kind = (f"{opcode} -> {re.sub(r'{[^{}]*}', '', result)}"
-                    if opcode == "custom-call" else opcode)
+                    if shapes or opcode == "custom-call" else opcode)
             ns[f"{way} {kind}"] += ev.duration_ns
     steps = collections.Counter(runs.values()).most_common(1)[0][0]
     for key, total in ns.most_common():
