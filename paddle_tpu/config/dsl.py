@@ -109,6 +109,12 @@ def _param(attr) -> Optional[ParamAttr]:
     raise TypeError(f"bad param attr {attr!r}")
 
 
+def _owner(params_of) -> Optional[str]:
+    """``params_of=``: the layer (handle or name) whose whole parameter
+    set the new layer uses (``LayerDef.params_of``)."""
+    return getattr(params_of, "name", params_of)
+
+
 # ----------------------------------------------------------------- layers
 def data(name: str, size: int, *, height: int = None, width: int = None,
          channels: int = None, is_sequence: bool = False) -> LayerOutput:
@@ -151,27 +157,30 @@ def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
 
 
 def swiglu(input, *, hidden: int, name: str = None,
-           layer_attr: dict = None) -> LayerOutput:
+           layer_attr: dict = None, params_of=None) -> LayerOutput:
     """``(silu(x W_g) * (x W_u)) W_d``, no bias: a decoder block's dense
-    feed-forward half."""
+    feed-forward half. ``params_of`` names a layer whose weights this one
+    uses instead of its own."""
     extra = _layer_attr(layer_attr)
     ldef = LayerDef(name=name or _auto_name("swiglu"), type="swiglu",
                     inputs=[Input(_in(input)[0].name)], bias=False,
                     attrs={"hidden": hidden, **extra.pop("attrs", {})},
-                    **extra)
+                    params_of=_owner(params_of), **extra)
     return _add(ldef)
 
 
 def rms_norm(input, *, epsilon: float = 1e-6, name: str = None,
-             param_attr=None, layer_attr: dict = None) -> LayerOutput:
-    """``x / sqrt(mean(x^2) + epsilon) * g`` over the feature dim."""
+             param_attr=None, layer_attr: dict = None,
+             params_of=None) -> LayerOutput:
+    """``x / sqrt(mean(x^2) + epsilon) * g`` over the feature dim;
+    ``params_of`` names a layer whose scale this one uses."""
     extra = _layer_attr(layer_attr)
     ldef = LayerDef(name=name or _auto_name("rms_norm"), type="rms_norm",
                     inputs=[Input(_in(input)[0].name,
                                   param_attr=_param(param_attr))],
                     bias=False,
                     attrs={"epsilon": epsilon, **extra.pop("attrs", {})},
-                    **extra)
+                    params_of=_owner(params_of), **extra)
     return _add(ldef)
 
 
@@ -365,7 +374,7 @@ def gqa_attention(input, *, num_heads: int, num_kv_heads: int,
                   head_dim: int, window: int = None, rotary_dim: int = None,
                   rope_theta: float = 10000.0, yarn: dict = None,
                   gate: bool = True, block: int = 512, name: str = None,
-                  layer_attr: dict = None) -> LayerOutput:
+                  layer_attr: dict = None, params_of=None) -> LayerOutput:
     """Causal grouped-query self-attention (`layers/attention.py`):
     ``num_heads`` query heads over ``num_kv_heads`` key-value heads of
     ``head_dim``, a sliding ``window`` (None: the whole sequence), rotary
@@ -374,7 +383,8 @@ def gqa_attention(input, *, num_heads: int, num_kv_heads: int,
     ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
     ``attention_factor``), a per-head sigmoid ``gate`` on the core's
     output, the flash kernels' tiles ``block`` x ``block``; no bias,
-    output size = input size."""
+    output size = input size. ``params_of`` names a layer whose
+    projections this one uses (a stack run several times)."""
     extra = _layer_attr(layer_attr)
     attrs = {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
              "head_dim": head_dim, "window": window,
@@ -383,7 +393,7 @@ def gqa_attention(input, *, num_heads: int, num_kv_heads: int,
              **extra.pop("attrs", {})}
     ldef = LayerDef(name=name or _auto_name("gqa"), type="gqa_attention",
                     inputs=[Input(_in(input)[0].name)], bias=False,
-                    attrs=attrs, **extra)
+                    attrs=attrs, params_of=_owner(params_of), **extra)
     return _add(ldef)
 
 
@@ -406,6 +416,26 @@ def lm_cost(input, ids, *, vocab_size: int, shift: int = 1,
                             Input(_in(ids)[0].name)], bias=False,
                     attrs={"vocab_size": vocab_size, "shift": shift,
                            "coeff": coeff, "chunk": chunk})
+    return _add(ldef)
+
+
+def looped_lm_cost(states, ids, *, vocab_size: int, shift: int = 1,
+                   beta: float = 0.1, chunk: int = 2048, name: str = None,
+                   param_attr=None) -> LayerOutput:
+    """The cost of a stack run ``R = len(states)`` times (`layers/lm.py`):
+    the head on each pass's state, an exit gate ``sigmoid(x w + b)`` on
+    each, the exit distribution ``p`` over the passes, and each row's
+    mean of ``sum_t p_t CE_t - beta H(p)`` over the positions that have
+    a target. The head's weight is ``param_attr``'s to share, the gate
+    is the layer's own (float32)."""
+    first, *rest = _in(states)
+    ldef = LayerDef(name=name or _auto_name("looped_lm_cost"),
+                    type="looped_lm_cost",
+                    inputs=[Input(first.name, param_attr=_param(param_attr))]
+                    + [Input(s.name) for s in rest]
+                    + [Input(_in(ids)[0].name)], bias=False,
+                    attrs={"vocab_size": vocab_size, "shift": shift,
+                           "beta": beta, "chunk": chunk})
     return _add(ldef)
 
 

@@ -61,6 +61,11 @@ class LayerDef:
     bias: Union[bool, ParamAttr] = True
     drop_rate: float = 0.0
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # the layer whose whole parameter set this one uses: every suffix
+    # resolves to ``_<params_of>.<suffix>`` (parameter sharing by name,
+    # as ``ParamAttr(name=...)`` shares one weight; a stack run several
+    # times over one copy of its weights)
+    params_of: Optional[str] = None
 
     def input_names(self) -> List[str]:
         return [i.layer_name for i in self.inputs]

@@ -55,7 +55,10 @@ def _resolve_param_name(layer: LayerDef, suffix: str, spec: ParamSpec,
         return spec.absolute_name
     if attr is not None and attr.name:
         return attr.name
-    return f"_{layer.name}.{suffix}"
+    # a layer that uses another layer's parameters resolves every suffix
+    # to that layer's name: the table then holds one leaf, one master and
+    # one pair of optimizer slots, and the gradient is the sum over uses
+    return f"_{layer.params_of or layer.name}.{suffix}"
 
 
 def _apply_attr(spec: ParamSpec, attr: Optional[ParamAttr]) -> ParamSpec:
@@ -132,6 +135,17 @@ class Network:
                 else:
                     self.param_specs[pname] = spec
                 self._layer_params[name][suffix] = pname
+        for name in self.order:
+            owner = model.layers[name].params_of
+            if owner is None:
+                continue
+            lent = set(self._layer_params.get(owner, {}).values())
+            stray = {p for p in self._layer_params[name].values()
+                     if p.startswith(f"_{owner}.")} - lent
+            if stray:
+                raise ValueError(
+                    f"layer {name!r} uses the parameters of {owner!r}, "
+                    f"which has no {sorted(stray)}")
 
     # ------------------------------------------------------------------ init
     def init_params(self, key: jax.Array, dtype=jnp.float32,

@@ -10,6 +10,19 @@ emits each row's mean over the positions that have a target, times
 are never held whole: rows of hidden states go through the head
 ``chunk`` at a time under ``jax.checkpoint``, so a chunk's [chunk, V]
 float32 logits live only while its loss, or its gradient, is worked out.
+
+``looped_lm_cost`` (inputs: the ``R`` normed states of a stack run ``R``
+times over one copy of its weights, then the ids) is the cost of a looped
+decoder (Ouro, arXiv:2510.25741, stage I): the one head on every pass's
+state, an exit gate ``lam_t = sigmoid(x_t w + b)`` on each, the exit
+distribution ``p_t = lam_t prod_{j<t}(1 - lam_j)`` (``p_R`` the rest),
+and per position ``sum_t p_t CE_t - beta H(p)``. The ``R`` passes' rows
+go through the same chunked head as ``lm_cost``'s, so no ``[R, T, V]``
+logits exist. Its ``state["counters"]``: ``loop_exit_step_mean`` (``sum_t
+t p_t``, 1..R: pinned at either end, the gate has collapsed and three of
+the four heads are dead weight) and ``loop_exit_entropy`` (mean ``H(p)``,
+0..ln R). The gate, ``p`` and the entropy run under the inner scope
+``loop_gate``, in float32 whatever the states are stored in.
 """
 
 from __future__ import annotations
@@ -69,6 +82,31 @@ def chunked_cross_entropy(h, w, targets, chunk: int):
     return ce.reshape(-1)[:R]
 
 
+def shifted_cross_entropy(cfg, w, states, ids_arg):
+    """What both costs need: ``(ce [R,B,T], has_target [B,T])``, position
+    i of each of the ``R`` states ``[B,T,d]`` scored through the head
+    ``w`` against the id at ``i + shift``; the states' rows go through
+    the chunked head as one run of ``R*B*T`` rows."""
+    ids = ids_arg.value.astype(jnp.int32)
+    B, T = ids.shape
+    k = int(cfg.attrs.get("shift", 1))
+    live = (ids_arg.mask if ids_arg.mask is not None
+            else jnp.ones((B, T), jnp.float32))
+    rows = [s.reshape(B * T, s.shape[-1]) for s in states]
+    ce = chunked_cross_entropy(
+        jnp.concatenate(rows), w,
+        jnp.tile(shift_left(ids, k).reshape(B * T), len(rows)),
+        int(cfg.attrs.get("chunk", 2048)))
+    # has_target: 0 on a row's last k positions and on padding
+    return ce.reshape(len(rows), B, T), shift_left(live, k)
+
+
+def row_mean(per_position, has_target):
+    """Each row's mean over the positions that have a target, [B]."""
+    return jnp.sum(per_position * has_target, axis=1) / jnp.maximum(
+        jnp.sum(has_target, axis=1), 1.0)
+
+
 @register_layer("lm_cost")
 class LmCostLayer(LayerImpl):
     def infer(self, cfg, in_infos):
@@ -79,17 +117,62 @@ class LmCostLayer(LayerImpl):
                                        int(cfg.attrs["vocab_size"])))}
 
     def apply(self, cfg, params, ins, ctx):
-        h, ids = ins[0].value, ins[1].value.astype(jnp.int32)
-        B, T, d = h.shape
-        k = int(cfg.attrs.get("shift", 1))
-        live = (ins[1].mask if ins[1].mask is not None
-                else jnp.ones((B, T), jnp.float32))
-        has_target = shift_left(live, k)          # [B,T], 0 on the last k
-        ce = chunked_cross_entropy(
-            h.reshape(B * T, d), params["w0"],
-            shift_left(ids, k).reshape(B * T),
-            int(cfg.attrs.get("chunk", 2048))).reshape(B, T)
-        row = jnp.sum(ce * has_target, axis=1) / jnp.maximum(
-            jnp.sum(has_target, axis=1), 1.0)
+        ce, has_target = shifted_cross_entropy(
+            cfg, params["w0"], [ins[0].value], ins[1])
+        row = row_mean(ce[0], has_target)
         return Argument(value=(float(cfg.attrs.get("coeff", 1.0))
-                               * row).reshape(B, 1))
+                               * row).reshape(-1, 1))
+
+
+def exit_distribution(gates):
+    """``gates [R, ...]``, every pass's exit gate's logit (the last
+    pass's plays no part: whatever has not left by then exits there):
+    ``(p, log p)``, ``p_t = sigmoid(g_t) prod_{j<t} sigmoid(-g_j)`` for
+    ``t < R`` and ``p_R`` what is left, through the logarithms so that
+    neither a product nor the entropy's ``log p`` underflows. Float32."""
+    g = gates[:-1].astype(jnp.float32)
+    stay = jnp.concatenate([                 # log prod_{j<t} (1 - lam_j)
+        jnp.zeros((1,) + g.shape[1:], jnp.float32),
+        jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)])
+    log_p = jnp.concatenate([jax.nn.log_sigmoid(g) + stay[:-1], stay[-1:]])
+    return jnp.exp(log_p), log_p
+
+
+@register_layer("looped_lm_cost")
+class LoopedLmCostLayer(LayerImpl):
+    def infer(self, cfg, in_infos):
+        return ShapeInfo(size=1)
+
+    def params(self, cfg, in_infos):
+        d = in_infos[0].size
+        return {"w0": ParamSpec(shape=(d, int(cfg.attrs["vocab_size"]))),
+                # a Linear(d -> 1) on the normed state, float32 like a
+                # router: its sigmoid weighs the passes' losses
+                "wgate": ParamSpec(shape=(d, 1), compute_f32=True),
+                "bgate": ParamSpec(shape=(1,), init="zeros",
+                                   compute_f32=True)}
+
+    def apply(self, cfg, params, ins, ctx):
+        states = [a.value for a in ins[:-1]]
+        R = len(states)
+        ce, has_target = shifted_cross_entropy(cfg, params["w0"], states,
+                                               ins[-1])
+        with jax.named_scope("loop_gate"):
+            w = params["wgate"].astype(jnp.float32)[:, 0]
+            gates = jnp.stack([
+                jnp.einsum("btd,d->bt", x.astype(jnp.float32), w,
+                           precision=lax.Precision.HIGHEST)
+                + params["bgate"].astype(jnp.float32) for x in states])
+            p, log_p = exit_distribution(gates)            # [R,B,T]
+            entropy = -jnp.sum(p * log_p, axis=0)          # [B,T]
+            steps = jnp.arange(1, R + 1, dtype=jnp.float32)
+            exit_step = jnp.einsum("r,rbt->bt", steps, p)
+            n = jnp.maximum(jnp.sum(has_target), 1.0)
+            counters = {
+                "loop_exit_step_mean": jnp.sum(exit_step * has_target) / n,
+                "loop_exit_entropy": jnp.sum(entropy * has_target) / n}
+        per_position = jnp.sum(p * ce, axis=0) \
+            - float(cfg.attrs.get("beta", 0.1)) * entropy
+        return Argument(value=row_mean(per_position,
+                                       has_target).reshape(-1, 1),
+                        state={"counters": counters})

@@ -415,6 +415,17 @@ def _case_lm_cost():
             {"x": _seq(full=True), "ids": _seq_ids()})
 
 
+def _case_looped_lm_cost():
+    return ([("x1", 6, {"is_sequence": True}),
+             ("x2", 6, {"is_sequence": True}),
+             ("x3", 6, {"is_sequence": True}),
+             ("ids", 4, {"is_sequence": True})],
+            L("out", "looped_lm_cost", ["x1", "x2", "x3", "ids"],
+              vocab_size=4, shift=1, beta=0.1, chunk=4),
+            {"x1": _seq(full=True), "x2": _seq(full=True),
+             "x3": _seq(full=True), "ids": _seq_ids()})
+
+
 def _case_agent():
     return ([("x", 6, {})], L("out", "agent", ["x"]), {"x": _dense()})
 
@@ -654,7 +665,7 @@ GRAD_CASES = {
     "mla_attention": _case_mla_attention,
     "gqa_attention": _case_gqa_attention, "rms_norm": _case_rms_norm,
     "swiglu": _case_swiglu, "seq_shift": _case_seq_shift,
-    "lm_cost": _case_lm_cost,
+    "lm_cost": _case_lm_cost, "looped_lm_cost": _case_looped_lm_cost,
     "agent": _case_agent,
     "scatter_agent": _case_scatter_agent,
     "gather_agent": _case_gather_agent,

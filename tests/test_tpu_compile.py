@@ -166,6 +166,49 @@ def test_recomputed_sliding_layer_runs_the_forward_kernel_once(one_chip):
         'custom_call_target="tpu_custom_call"') == 3
 
 
+def test_looped_step_runs_each_cores_forward_kernel_once(one_chip):
+    """A small looped decoder whole (``models.ouro``: 2 layers run 3
+    times over one copy of their weights, 2 heads of 128 over 1,024
+    tokens of 256, ``recompute`` on), cost and every leaf's gradient:
+    three Mosaic calls an application of an attention layer (forward,
+    dK/dV, dQ) and no forward kernel a second time for a recomputation
+    or for a later pass over the same weights: PR 28's finding holds
+    across passes."""
+    from paddle_tpu import models
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.argument import Argument
+    from paddle_tpu.trainer.trainer import Topology
+
+    layers, passes = 2, 3
+    dsl.reset()
+    cost, _out, _names = models.ouro(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=layers, num_attention_heads=2,
+        num_key_value_heads=2, head_dim=128, total_ut_steps=passes,
+        recompute=True, loss_chunk=512, attention_block=512)
+    net = Topology(cost).network
+    assert len(net.param_specs) == 5 + 11 * layers      # one leaf a weight
+
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape.shape, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        sd, jax.eval_shape(net.init_params, jax.random.PRNGKey(0)))
+    ids = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
+
+    def step(params, ids):
+        def loss(params):
+            out = net.apply(params, {"words": Argument(value=ids)},
+                            train=True)
+            return jnp.mean(out["out_head"].value)
+        return jax.value_and_grad(loss)(params)
+
+    compiled = _compile(step, params, ids)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3 * layers * passes
+
+
 def test_routed_experts_forward_and_backward(one_chip):
     """8 held experts of 256, 8 a token, 8,192 tokens of 2,048, width
     768: the loop over buffers of 4,096 rows, forward and backward."""
