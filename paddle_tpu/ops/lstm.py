@@ -1,4 +1,5 @@
-"""Fused LSTM sequence kernel (Pallas) with analytic backward.
+"""Fused LSTM sequence op: two Pallas kernels and a ``lax.scan``, one
+analytic backward.
 
 TPU-native equivalent of the reference's fused LSTM cell kernels
 (`paddle/cuda/include/hl_gpu_lstm.cuh:46-67`, driven per-timestep by
@@ -24,9 +25,14 @@ Padded timesteps (mask==0) hold the carried state; outputs are zeroed —
 this preserves the reference's ragged-sequence semantics
 (`Argument.sequenceStartPositions`) in a static-shape layout.
 
-Backward is an analytic reverse-time `lax.scan` over residuals saved by the
-forward kernel (activated gates + state chains), mirroring the cuDNN-style
-"save gates, no recompute" strategy.
+Every dispatched path (the resident kernel, the tiled kernel, the scan
+that runs where neither fits) is a ``jax.custom_vjp`` with the same
+backward, ``_bwd_rule``: an analytic reverse-time ``lax.scan`` over
+residuals saved by the forward (activated gates + state chains, the
+cuDNN-style "save gates, no recompute" strategy) that carries ``(dh, dc)``
+alone and emits ``dgates``; the weight gradient is one product over the
+stacked ``dgates`` after the scan. JAX differentiates only
+``lstm_sequence_ref``, the tests' gold.
 """
 
 from __future__ import annotations
@@ -42,23 +48,30 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.ops import common
 
 
+def _cell(gates, c, check_i, check_f, check_o):
+    """One step of the cell math above on pre-activation ``gates`` [B,4H]
+    and the previous cell state: (i, ig, fg, og, c_new, h_new)."""
+    a_i, a_ig, a_fg, a_og = jnp.split(gates, 4, axis=-1)
+    i = jnp.tanh(a_i)
+    ig = jax.nn.sigmoid(a_ig + c * check_i)
+    fg = jax.nn.sigmoid(a_fg + c * check_f)
+    c_new = i * ig + c * fg
+    og = jax.nn.sigmoid(a_og + c_new * check_o)
+    return i, ig, fg, og, c_new, og * jnp.tanh(c_new)
+
+
 def lstm_sequence_ref(xs, mask, w, gate_bias, check_i, check_f, check_o,
                       h0, c0):
-    """Pure lax.scan reference. xs [T,B,4H] (pre-projected inputs), mask
-    [T,B], w [H,4H]. Returns (ys [T,B,H], hT, cT)."""
-    H = h0.shape[-1]
+    """Pure lax.scan reference, differentiated by JAX itself: the tests'
+    gold for values and gradients of every dispatched path. xs [T,B,4H]
+    (pre-projected inputs), mask [T,B], w [H,4H]. Returns (ys [T,B,H],
+    hT, cT)."""
 
     def step(carry, inp):
         h, c = carry
         x_t, m_t = inp
-        gates = x_t + h @ w + gate_bias
-        a_i, a_ig, a_fg, a_og = jnp.split(gates, 4, axis=-1)
-        i = jnp.tanh(a_i)
-        ig = jax.nn.sigmoid(a_ig + c * check_i)
-        fg = jax.nn.sigmoid(a_fg + c * check_f)
-        c_new = i * ig + c * fg
-        og = jax.nn.sigmoid(a_og + c_new * check_o)
-        h_new = og * jnp.tanh(c_new)
+        *_, c_new, h_new = _cell(x_t + h @ w + gate_bias, c, check_i,
+                                 check_f, check_o)
         m = m_t[:, None]
         h_next = jnp.where(m > 0, h_new, h)
         c_next = jnp.where(m > 0, c_new, c)
@@ -66,6 +79,32 @@ def lstm_sequence_ref(xs, mask, w, gate_bias, check_i, check_f, check_o,
 
     (hT, cT), ys = lax.scan(step, (h0, c0), (xs, mask))
     return ys, hT, cT
+
+
+def _lstm_scan(xs, mask, w, pI, pF, pO, h0, c0, with_residuals):
+    """The "ref" dispatch: the reference's scan over xs with the gate
+    bias already folded in. With residuals it also emits, step by step,
+    what ``_bwd_rule`` reads: the state a step took in, the cell state it
+    left (both guarded) and its activated gates."""
+
+    def step(carry, inp):
+        h, c = carry
+        x_t, m_t = inp
+        i, ig, fg, og, c_new, h_new = _cell(x_t + h @ w, c, pI, pF, pO)
+        m = m_t[:, None]
+        h_next = jnp.where(m > 0, h_new, h)
+        c_next = jnp.where(m > 0, c_new, c)
+        y = h_new * m
+        if with_residuals:
+            return (h_next, c_next), (y, h, c, c_next, jnp.concatenate(
+                [i, ig, fg, og], axis=-1))
+        return (h_next, c_next), y
+
+    (hT, cT), out = lax.scan(step, (h0, c0), (xs, mask))
+    if with_residuals:
+        ys, *res = out
+        return ys, hT, cT, res
+    return out, hT, cT
 
 
 # ---------------------------------------------------------------- pallas fwd
@@ -340,10 +379,19 @@ def _lstm_core(xs, mask, w, pI, pF, pO, h0, c0):
     return ys, hT, cT
 
 
+def _kernel_residuals(mask, w, pI, pF, pO, h0, c0, hs, cs, gates):
+    """``_bwd_rule``'s residuals from a training kernel's outputs: the
+    states every step took in are the guarded chains shifted by one
+    (h_prev[t] = hs[t-1], h0 at t=0)."""
+    h_prev = jnp.concatenate([h0[None], hs[:-1]], axis=0)
+    c_prev = jnp.concatenate([c0[None], cs[:-1]], axis=0)
+    return mask, w, pI, pF, pO, h_prev, c_prev, cs, gates
+
+
 def _fwd_rule(xs, mask, w, pI, pF, pO, h0, c0):
     ys, hs, cs, gates = _lstm_pallas(xs, mask, w, pI, pF, pO, h0, c0,
                                      with_residuals=True)
-    res = (mask, w, pI, pF, pO, h0, c0, hs, cs, gates)
+    res = _kernel_residuals(mask, w, pI, pF, pO, h0, c0, hs, cs, gates)
     return (ys, hs[-1], cs[-1]), res
 
 
@@ -363,24 +411,52 @@ def _fwd_rule_tiled(xs, mask, w, pI, pF, pO, h0, c0):
     ys, hs, cs, gates = _lstm_pallas_tiled(
         xs, mask, w, pI, pF, pO, h0, c0, with_residuals=True,
         hb=_hb_of(xs))
-    res = (mask, w, pI, pF, pO, h0, c0, hs, cs, gates)
+    res = _kernel_residuals(mask, w, pI, pF, pO, h0, c0, hs, cs, gates)
     return (ys, hs[-1], cs[-1]), res
 
 
-def _bwd_rule(res, grads):
-    dys, dhT, dcT = grads
-    mask, w, pI, pF, pO, h0, c0, hs, cs, gates = res
-    T, B, H = hs.shape
-    # previous-state chains (guarded): h_prev[t] = hs[t-1] (h0 at t=0)
-    h_prev = jnp.concatenate([h0[None], hs[:-1]], axis=0)
-    c_prev = jnp.concatenate([c0[None], cs[:-1]], axis=0)
+@jax.custom_vjp
+def _lstm_core_scan(xs, mask, w, pI, pF, pO, h0, c0):
+    return _lstm_scan(xs, mask, w, pI, pF, pO, h0, c0,
+                      with_residuals=False)
 
-    dt = hs.dtype
+
+def _fwd_rule_scan(xs, mask, w, pI, pF, pO, h0, c0):
+    ys, hT, cT, res = _lstm_scan(xs, mask, w, pI, pF, pO, h0, c0,
+                                 with_residuals=True)
+    return (ys, hT, cT), (mask, w, pI, pF, pO, *res)
+
+
+# Cotangents through y = tanh(x) and y = sigmoid(x). These, and the
+# sums in ``_bwd_rule``'s step, round in the order JAX's own rules do, so
+# ``dgates`` and the state gradients equal an autodiff'd scan's bit for
+# bit (``tests/test_ops_pallas.py`` holds them to it). On the chip every
+# product rounds its operands to bfloat16, and over 100 steps that turns
+# a last-bit difference in ``dgates`` into 1e-4 of a leaf's gradient and
+# 1.5e-3 of a bias leaf's: past the benchmark's check (PERF.md, PR 34).
+
+def _dtanh(ct, y):
+    u = ct * (1 - y)
+    return u + u * y
+
+
+def _dsigmoid(ct, y):
+    return ct * (y * (1 - y))
+
+
+def _bwd_rule(res, grads):
+    """The analytic reverse-time scan of every path. ``res`` holds, per
+    step, the states it took in (``h_prev``, ``c_prev``), the cell state
+    it left (``cs``) and its activated gates."""
+    dys, dhT, dcT = grads
+    mask, w, pI, pF, pO, h_prev, c_prev, cs, gates = res
+    H = cs.shape[-1]
+    dt = cs.dtype
     f32 = jnp.float32
 
     def step(carry, inp):
-        dh, dc, dW, dpI, dpF, dpO = carry
-        dy_t, m_t, g_t, c_new, c_pv, h_pv = inp
+        dh, dc = carry
+        dy_t, m_t, g_t, c_new, c_pv = inp
         # the mask stays f32 (count data, never cast); under a bf16
         # compute dtype its products promote, so each is cast back to
         # the carry dtype — a no-op in f32
@@ -392,33 +468,42 @@ def _bwd_rule(res, grads):
         dh_new = (m * (dh + dy_t)).astype(dt)
         dc_new = (m * dc).astype(dt)
         tc = jnp.tanh(c_new)
-        da_og = (dh_new * tc) * og * (1 - og)
-        dc_tot = dc_new + dh_new * og * (1 - tc * tc) + da_og * pO
-        da_i = dc_tot * ig * (1 - i * i)
-        da_ig = (dc_tot * i) * ig * (1 - ig)
-        da_fg = (dc_tot * c_pv) * fg * (1 - fg)
-        dc_prev = (((1 - m) * dc).astype(dt) + dc_tot * fg + da_ig * pI
-                   + da_fg * pF)
+        da_og = _dsigmoid(dh_new * tc, og)
+        # tanh(c_new)'s cotangent reaches the sum as its two terms
+        u = (dh_new * og) * (1 - tc)
+        dc_tot = dc_new + u + u * tc + da_og * pO
+        da_i = _dtanh(dc_tot * ig, i)
+        da_ig = _dsigmoid(dc_tot * i, ig)
+        da_fg = _dsigmoid(dc_tot * c_pv, fg)
+        dc_prev = (((1 - m) * dc).astype(dt) + dc_tot * fg + da_fg * pF
+                   + da_ig * pI)
         dgates = jnp.concatenate([da_i, da_ig, da_fg, da_og], axis=-1)
-        dh_prev = ((1 - m) * dh).astype(dt) + dgates @ w.T
-        # weight/peephole gradients accumulate over T steps in f32
-        dW = dW + jnp.dot(h_pv.T, dgates, preferred_element_type=f32)
-        dpI = dpI + jnp.sum((da_ig * c_pv).astype(f32), axis=0)
-        dpF = dpF + jnp.sum((da_fg * c_pv).astype(f32), axis=0)
-        dpO = dpO + jnp.sum((da_og * c_new).astype(f32), axis=0)
-        return (dh_prev, dc_prev, dW, dpI, dpF, dpO), dgates
+        dh_prev = ((1 - m) * dh).astype(dt) + lax.dot_general(
+            dgates, w, (((1,), (1,)), ((), ())), preferred_element_type=dt)
+        # a step's share of the peephole gradients, summed over its rows
+        # while they are at hand: over the stacked dgates afterwards the
+        # same sums cost a pass over HBM (0.87 ms a layer at h=1280: chip
+        # run, PERF.md PR 34)
+        dpeep = jnp.stack([jnp.sum((da * c_).astype(f32), axis=0)
+                           for da, c_ in ((da_ig, c_pv), (da_fg, c_pv),
+                                          (da_og, c_new))])
+        return (dh_prev, dc_prev), (dgates, dpeep)
 
-    zW = jnp.zeros(w.shape, f32)
-    zH = jnp.zeros(pI.shape, f32)
-    (dh0, dc0, dW, dpI, dpF, dpO), dxs = lax.scan(
-        step, (dhT, dcT, zW, zH, zH, zH),
-        (dys, mask, gates, cs, c_prev, h_prev), reverse=True)
+    # the carry is (dh, dc) alone: a weight-shaped sum in it crosses HBM
+    # every step (26 MB read and written at h=1280). The weight gradient
+    # is one [T*B,H]^T x [T*B,4H] product over the stacked dgates after
+    # the scan, summed in f32 as the peephole gradients are.
+    (dh0, dc0), (dxs, dpeeps) = lax.scan(
+        step, (dhT, dcT), (dys, mask, gates, cs, c_prev), reverse=True)
+    dW = jnp.einsum("tbh,tbg->hg", h_prev, dxs, preferred_element_type=f32)
+    dpI, dpF, dpO = jnp.sum(dpeeps, axis=0)
     return (dxs, None, dW.astype(w.dtype), dpI.astype(pI.dtype),
             dpF.astype(pF.dtype), dpO.astype(pO.dtype), dh0, dc0)
 
 
 _lstm_core.defvjp(_fwd_rule, _bwd_rule)
 _lstm_core_tiled.defvjp(_fwd_rule_tiled, _bwd_rule)
+_lstm_core_scan.defvjp(_fwd_rule_scan, _bwd_rule)
 
 
 # ---------------------------------------------------------------- public
@@ -470,13 +555,13 @@ def lstm_sequence(xs, mask, w, gate_bias, check_i, check_f, check_o, h0, c0,
     Dispatch (``lstm_dispatch``): the resident Pallas kernel when the
     recurrent weight fits VMEM for all T steps, the tiled Pallas kernel
     (weight streamed in gate-column blocks) for big hidden sizes, else
-    the lax.scan reference. ``reverse=True`` runs the recurrence
-    back-to-front (outputs stay in input time order). Traced into a step
-    partitioned over a mesh (``common.step_mesh``) whose batch axes
-    divide B, each device runs the kernel on its own rows
-    (``common.batch_local``) and dispatch sees the per-device batch;
-    under one that cannot split B the reference runs. Returns
-    (ys [T,B,H], hT, cT). Differentiable on every path.
+    the same recurrence as a lax.scan (noted as ``ref``). ``reverse=True``
+    runs the recurrence back-to-front (outputs stay in input time order).
+    Traced into a step partitioned over a mesh (``common.step_mesh``)
+    whose batch axes divide B, each device runs the kernel on its own
+    rows (``common.batch_local``) and dispatch sees the per-device batch;
+    under one that cannot split B the scan runs. Returns (ys [T,B,H],
+    hT, cT). Differentiable on every path, by ``_bwd_rule``.
     """
     if reverse:
         ys, hT, cT = lstm_sequence(jnp.flip(xs, 0), jnp.flip(mask, 0), w,
@@ -488,10 +573,10 @@ def lstm_sequence(xs, mask, w, gate_bias, check_i, check_f, check_o, h0, c0,
     split = common.batch_split(B)
     path = common.note("lstm", lstm_dispatch(
         B // split, H, jnp.dtype(xs.dtype).itemsize) if split else "ref")
-    if path == "ref":
-        return lstm_sequence_ref(xs, mask, w, gate_bias, check_i, check_f,
-                                 check_o, h0, c0)
     xs_b = xs + gate_bias  # fold bias into the pre-projected input once
+    if path == "ref":
+        return _lstm_core_scan(xs_b, mask, w, check_i, check_f, check_o,
+                               h0, c0)
     core = common.batch_local(
         _lstm_core if path == "resident" else _lstm_core_tiled, split,
         in_dims=(1, 1, None, None, None, None, 0, 0), out_dims=(1, 0, 0))

@@ -6,6 +6,8 @@ kernel is compared — values AND gradients — against the pure-JAX reference
 implementation it replaces.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -825,3 +827,131 @@ def test_lstm_tiled_matches_ref_fwd_bwd():
                                rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(gw_t), np.asarray(gw_r),
                                rtol=3e-4, atol=3e-3)
+
+
+# ------------------------------- the scan path's analytic backward (PR 34)
+def _scan_case(mask_kind, state, peep, dtype=jnp.float32, bias=0.1,
+               T=9, B=6, H=16):
+    rng = np.random.default_rng(5)
+
+    def arr(*shape, scale):
+        return jnp.asarray(rng.normal(size=shape) * scale, dtype)
+
+    mask = (_ragged_mask(T, B, rng) if mask_kind == "ragged"
+            else np.ones((T, B), np.float32))
+    args = (arr(T, B, 4 * H, scale=1.0), arr(H, 4 * H, scale=0.1),
+            arr(4 * H, scale=bias), arr(H, scale=peep), arr(H, scale=peep),
+            arr(H, scale=peep), arr(B, H, scale=state),
+            arr(B, H, scale=state))
+    weights = (arr(T, B, H, scale=1.0), arr(B, H, scale=1.0),
+               arr(B, H, scale=1.0))
+    return jnp.asarray(mask), args, weights
+
+
+def _scan_grads(fn, mask, args, weights):
+    """Gradients of a weighted sum of all three outputs with respect to
+    all eight differentiable arguments."""
+    def loss(xs, *rest):
+        out = fn(xs, mask, *rest)
+        return sum(jnp.sum(o.astype(jnp.float32) * r.astype(jnp.float32))
+                   for o, r in zip(out, weights))
+    return jax.jit(jax.grad(loss, argnums=tuple(range(8))))(*args)
+
+
+def _reversed_ref(xs, mask, *rest):
+    ys, hT, cT = lstm_sequence_ref(jnp.flip(xs, 0), jnp.flip(mask, 0), *rest)
+    return jnp.flip(ys, 0), hT, cT
+
+
+@pytest.mark.parametrize("peep", [0.0, 0.1], ids=["peep0", "peep"])
+@pytest.mark.parametrize("state", [0.0, 0.3], ids=["state0", "state"])
+@pytest.mark.parametrize("reverse", [False, True], ids=["fwd", "rev"])
+@pytest.mark.parametrize("mask_kind", ["full", "ragged"])
+def test_lstm_scan_path_grads_match_autodiff(mask_kind, reverse, state,
+                                             peep):
+    """The "ref" dispatch is a ``custom_vjp`` with the kernels' analytic
+    backward; JAX's own differentiation of ``lstm_sequence_ref`` is the
+    gold, for all eight arguments."""
+    mask, args, weights = _scan_case(mask_kind, state, peep)
+    with common.force_mode("ref"), common.record_dispatch() as tally:
+        got = _scan_grads(
+            lambda *a: lstm_sequence(*a, reverse=reverse), mask, args,
+            weights)
+    assert tally == {"lstm": {"ref": 1}}
+    want = _scan_grads(_reversed_ref if reverse else lstm_sequence_ref,
+                       mask, args, weights)
+    for g, r in zip(got, want):
+        r = np.asarray(r)
+        np.testing.assert_allclose(np.asarray(g), r, rtol=1e-5,
+                                   atol=1e-5 * np.abs(r).max())
+
+
+def test_lstm_scan_path_grads_bf16():
+    """Under a bfloat16 compute dtype the rule's sums are float32 and its
+    outputs the arguments' dtype, within the tolerance the tiled kernel's
+    bfloat16 case is held to (``tools/tpu_evidence.py:BF16_TOLS``)."""
+    mask, args, weights = _scan_case("ragged", 0.3, 0.1, jnp.bfloat16)
+    with common.force_mode("ref"):
+        got = _scan_grads(lstm_sequence, mask, args, weights)
+    want = _scan_grads(lstm_sequence_ref, mask, args, weights)
+    for g, r, a in zip(got, want, args):
+        assert g.dtype == a.dtype == jnp.bfloat16
+        g, r = (np.asarray(x, np.float32) for x in (g, r))
+        assert np.abs(g - r).max() <= 1e-1 * np.abs(r).max()
+
+
+@pytest.mark.parametrize("peep", [0.0, 0.1], ids=["peep0", "peep"])
+@pytest.mark.parametrize("mask_kind", ["full", "ragged"])
+def test_lstm_scan_path_dgates_equal_autodiff_bit_for_bit(mask_kind, peep):
+    """The rule rounds as JAX's own rules do, so with no gate bias to
+    fold the gradients of xs, h0 and c0 are the autodiff'd scan's bits.
+    The benchmark's check rests on it: on the chip a product rounds its
+    operands to bfloat16 and a last-bit difference in ``dgates`` grows
+    to 1.5e-3 of a bias leaf's gradient over 100 steps (PERF.md PR 34)."""
+    mask, args, weights = _scan_case(mask_kind, 0.3, peep, bias=0.0, T=12)
+    with common.force_mode("ref"):
+        got = _scan_grads(lstm_sequence, mask, args, weights)
+    want = _scan_grads(lstm_sequence_ref, mask, args, weights)
+    for k in (0, 6, 7):
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]))
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_lstm_scan_backward_carries_no_weight_gradient():
+    """The static counter of PR 34: in the gradient of a two-layer stack
+    no ``scan`` carries an array of the weight's shape, and each layer
+    has exactly one ``dot_general`` that contracts T*B rows (``dW``)."""
+    T, B, H = 5, 4, 8
+    mask, args, _ = _scan_case("full", 0.0, 0.1, T=T, B=B, H=H)
+
+    def loss(w0, w1, xs, *rest):
+        with common.force_mode("ref"):
+            ys, _, _ = lstm_sequence(xs, mask, w0, *rest)
+            ys, _, _ = lstm_sequence(jnp.tile(ys, 4), mask, w1, *rest)
+        return jnp.sum(ys)
+
+    w = args[1]
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        w, w, args[0], *args[2:]).jaxpr
+    scans = over_rows = 0
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "scan":
+            scans += 1
+            n, k = eqn.params["num_consts"], eqn.params["num_carry"]
+            carry = [v.aval.shape for v in eqn.invars[n:n + k]]
+            assert carry == [(B, H), (B, H)], carry
+        if eqn.primitive.name == "dot_general":
+            (lhs, _), _ = eqn.params["dimension_numbers"]
+            rows = math.prod(eqn.invars[0].aval.shape[d] for d in lhs)
+            if rows == T * B:
+                over_rows += 1
+                assert eqn.outvars[0].aval.shape == w.shape
+    assert scans == 4 and over_rows == 2

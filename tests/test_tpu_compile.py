@@ -294,3 +294,41 @@ def test_update_is_one_pass_over_a_leaf_in_its_own_layout(one_chip, kind,
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes == 0
     assert memory.alias_size_in_bytes == 4 * leaf * (1 + len(slots))
+
+
+def test_lstm_scan_backward_streams_no_weight_gradient(one_chip):
+    """Two LSTM layers forward and backward at the LSTM cell's shape
+    (T 100, B 256, H 1280, float32; dispatch says ``ref``: the scan), as
+    the chip's compiler sees them: no while loop holds a float32 array
+    of the weight's shape (the weight itself rides along rounded to
+    bfloat16 once; a float32 one would be ``dW`` crossing HBM every
+    step, as in JAX's own transpose of the scan), and each layer's
+    ``dW`` is one product after the scan that still carries the layer's
+    scope, where ``lstm_seq_roofline`` and ``breakdown`` look for it.
+    This is the LSTM row's static counter (``PERF.md`` section 3)."""
+    from paddle_tpu.ops.lstm import lstm_sequence
+    T, B, H = 100, 256, 1280
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(xs, mask, w0, w1, bias, peep, h0):
+        with common.record_dispatch() as tally:
+            for i, w in enumerate((w0, w1)):
+                with jax.named_scope(f"lstm{i}"):
+                    xs = jnp.tile(lstm_sequence(xs, mask, w, bias, peep,
+                                                peep, peep, h0, h0)[0], 4)
+        assert tally == {"lstm": {"ref": 2}}
+        return jnp.sum(xs)
+
+    text = _compile(jax.grad(loss, argnums=(0, 2, 3, 4, 5)),
+                    sd(T, B, 4 * H), sd(T, B), sd(H, 4 * H), sd(H, 4 * H),
+                    sd(4 * H), sd(H), sd(B, H)).as_text()
+    loops = re.findall(r"^\s*%?[\w.\-]+ = (\(.*?\)) while\(", text, re.M)
+    assert len(loops) == 4, len(loops)
+    assert not any(f"f32[{H},{4 * H}]" in loop for loop in loops)
+    for i in range(2):
+        products = re.findall(
+            rf" convolution\(.*op_name=\"[^\"]*transpose\(jvp\(lstm{i}\)\)"
+            r"/tbh,tbg->hg/dot_general\"", text)
+        assert len(products) == 1, (i, products)
