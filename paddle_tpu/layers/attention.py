@@ -35,6 +35,15 @@ runs under the inner scope ``attn_core`` in both, and a windowed layer's
 ``swa_pairs_visited`` (query-key pairs inside the tiles the forward
 kernel's grid walks, a head) and ``swa_pairs_visible`` (pairs the mask
 lets see).
+
+Both layers' ``apply`` lies under four inner scopes, every operation
+under exactly one, so that a device trace divides a layer's time by
+part, forward, recomputed and backward alike (``docs/observability.md``):
+``attn_qkv`` (the input to q, k, v at the core's layout, before any
+rotary turn), ``attn_rope`` (the turns, the concatenations that assemble
+q and k), ``mla_core`` / ``attn_core`` (the kernels) and ``attn_out``
+(gate, head merge, ``wo``, mask). They are names in the compiled text
+and nothing else.
 """
 
 from __future__ import annotations
@@ -172,27 +181,31 @@ class MlaAttentionLayer(LayerImpl):
         def split(x, width):  # [B,T,H*w] -> [B,H,T,w]
             return x.reshape(B, T, heads, width).transpose(0, 2, 1, 3)
 
-        q = split(rms_normalize(u @ params["wqa"], params["qnorm"], eps)
-                  @ params["wqb"], nope + rope)
-        kva = u @ params["wkva"]
-        kv = split(rms_normalize(kva[..., :kvr], params["kvnorm"], eps)
-                   @ params["wkvb"], nope + dv)
-        # one rotary key for all the heads
-        k_rope = rotary_interleaved(kva[..., kvr:], theta)[:, None]
-        q = jnp.concatenate(
-            [q[..., :nope], rotary_interleaved(q[..., nope:], theta)], -1)
-        k = jnp.concatenate(
-            [kv[..., :nope],
-             jnp.broadcast_to(k_rope, (B, heads, T, rope))], -1)
+        with jax.named_scope("attn_qkv"):
+            q = split(rms_normalize(u @ params["wqa"], params["qnorm"], eps)
+                      @ params["wqb"], nope + rope)
+            kva = u @ params["wkva"]
+            kv = split(rms_normalize(kva[..., :kvr], params["kvnorm"], eps)
+                       @ params["wkvb"], nope + dv)
+        with jax.named_scope("attn_rope"):
+            # one rotary key for all the heads
+            k_rope = rotary_interleaved(kva[..., kvr:], theta)[:, None]
+            q = jnp.concatenate(
+                [q[..., :nope], rotary_interleaved(q[..., nope:], theta)],
+                -1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (B, heads, T, rope))], -1)
         with jax.named_scope("mla_core"):
             # 512 x 512 tiles: 168 MFLOP a grid step, a few times a
             # step's fixed cost (the kernels' default 256 is a quarter)
             out = flash_attention(q, k, kv[..., nope:], ins[0].mask,
                                   causal=True, block_q=512, block_k=512)
-        out = out.transpose(0, 2, 1, 3).reshape(B, T, heads * dv) \
-            @ params["wo"]
-        if ins[0].mask is not None:
-            out = out * ins[0].mask[..., None].astype(out.dtype)
+        with jax.named_scope("attn_out"):
+            out = out.transpose(0, 2, 1, 3).reshape(B, T, heads * dv) \
+                @ params["wo"]
+            if ins[0].mask is not None:
+                out = out * ins[0].mask[..., None].astype(out.dtype)
         return Argument(value=out, mask=ins[0].mask)
 
 
@@ -292,21 +305,31 @@ class GqaAttentionLayer(LayerImpl):
         def split(x, n):  # [B,T,n*hd] -> [B,n,T,hd]
             return x.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
 
-        q = rotary_halves(split(u @ params["wq"], heads), inv_freq, factor)
-        k = rotary_halves(split(u @ params["wk"], kv), inv_freq, factor)
-        v = split(u @ params["wv"], kv)
+        def project(w, n):
+            with jax.named_scope("attn_qkv"):
+                return split(u @ params[w], n)
+
+        def turn(x):
+            with jax.named_scope("attn_rope"):
+                return rotary_halves(x, inv_freq, factor)
+
+        q = turn(project("wq", heads))
+        k = turn(project("wk", kv))
+        v = project("wv", kv)
         with jax.named_scope("attn_core"):
             out = flash_attention(q, k, v, ins[0].mask, causal=True,
                                   block_q=block, block_k=block,
                                   window=window)
-        if "wg" in params:
-            gate = jax.nn.sigmoid((u @ params["wg"]).astype(jnp.float32))
-            out = (out * gate.transpose(0, 2, 1)[..., None]) \
-                .astype(out.dtype)
-        out = out.transpose(0, 2, 1, 3).reshape(B, T, heads * hd) \
-            @ params["wo"]
-        if ins[0].mask is not None:
-            out = out * ins[0].mask[..., None].astype(out.dtype)
+        with jax.named_scope("attn_out"):
+            if "wg" in params:
+                gate = jax.nn.sigmoid(
+                    (u @ params["wg"]).astype(jnp.float32))
+                out = (out * gate.transpose(0, 2, 1)[..., None]) \
+                    .astype(out.dtype)
+            out = out.transpose(0, 2, 1, 3).reshape(B, T, heads * hd) \
+                @ params["wo"]
+            if ins[0].mask is not None:
+                out = out * ins[0].mask[..., None].astype(out.dtype)
         state = None
         if window:
             visited, visible = walked_pairs(T, T, True, window, block, block)

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Dict
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.argument import Argument
@@ -80,16 +81,21 @@ class MoELayer(LayerImpl):
     def apply(self, cfg, params, ins, ctx):
         a = ins[0]
         shape = a.value.shape
-        y, rows = moe_ffn(
-            params, a.value.reshape(-1, shape[-1]),
-            top_k=int(cfg.attrs["top_k"]),
-            scale=float(cfg.attrs.get("routed_scaling_factor", 1.0)),
-            offset=int(cfg.attrs.get("expert_offset") or 0),
+        # the layer's own operations (the flattening, the counters) lie
+        # under the inner scopes too: parallel/moe.py opens the rest
+        with jax.named_scope("moe_dispatch"):
+            x = a.value.reshape(-1, shape[-1])
             # padding is routed nowhere: it takes no expert's rows
-            live=a.mask.reshape(-1) if a.mask is not None else None)
-        rows = rows.astype(jnp.float32)     # [held]
-        return Argument(value=y.reshape(shape), mask=a.mask,
-                        state={"counters": {
-                            "moe_rows_max": rows.max(),
-                            "moe_rows_mean": rows.mean(),
-                            "moe_experts_active": (rows > 0).sum()}})
+            live = a.mask.reshape(-1) if a.mask is not None else None
+        y, rows = moe_ffn(
+            params, x, top_k=int(cfg.attrs["top_k"]),
+            scale=float(cfg.attrs.get("routed_scaling_factor", 1.0)),
+            offset=int(cfg.attrs.get("expert_offset") or 0), live=live)
+        with jax.named_scope("moe_combine"):
+            y = y.reshape(shape)
+        with jax.named_scope("moe_dispatch"):
+            rows = rows.astype(jnp.float32)     # [held]
+            counters = {"moe_rows_max": rows.max(),
+                        "moe_rows_mean": rows.mean(),
+                        "moe_experts_active": (rows > 0).sum()}
+        return Argument(value=y, mask=a.mask, state={"counters": counters})

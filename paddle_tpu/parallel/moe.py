@@ -32,6 +32,10 @@ today's large open models carry in their ``config.json``):
   held)``. The loop sits inside a ``custom_vjp`` whose backward makes
   the same turns, recomputing each buffer from the layer's input (a
   loop of traced length has no transpose of its own).
+- scopes: every operation lies under one of ``moe_route``,
+  ``moe_dispatch`` (plan, sort, a chunk's gather, the backward rule's own
+  buffers and sums), ``moe_experts``, ``moe_combine``, ``moe_shared``, so
+  that a device trace divides the layer's time (``docs/observability.md``).
 """
 
 from __future__ import annotations
@@ -129,13 +133,14 @@ def _plan(ids, offset, held: int, live):
     """For every (token, choice) pair the local index of its expert, or
     ``held`` where that expert is not here or the token is padding; and
     the rows each held expert gets."""
-    local = ids - offset
-    here = (local >= 0) & (local < held)
-    if live is not None:
-        here = here & (live.reshape(-1, 1) > 0)
-    key = jnp.where(here, local, held).reshape(-1)
-    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype),
-                     axis=0, dtype=jnp.int32)
+    with jax.named_scope("moe_dispatch"):
+        local = ids - offset
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here = here & (live.reshape(-1, 1) > 0)
+        key = jnp.where(here, local, held).reshape(-1)
+        counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype),
+                         axis=0, dtype=jnp.int32)
     return key, counts
 
 
@@ -155,12 +160,13 @@ def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into):
     flattened [T * K] choices). ``counts [held]`` are the whole batch's
     rows per expert; the chunk's own follow from where it starts."""
     R, K = pairs.shape[0], gates.shape[1]
-    ends = jnp.cumsum(counts)
-    mine = jnp.clip(jnp.minimum(ends, first + R)
-                    - jnp.maximum(ends - counts, first), 0, R)
-    token = pairs // K
-    valid = jnp.arange(R) < jnp.sum(mine)
-    xg = jnp.where(valid[:, None], x[token], 0)
+    with jax.named_scope("moe_dispatch"):
+        ends = jnp.cumsum(counts)
+        mine = jnp.clip(jnp.minimum(ends, first + R)
+                        - jnp.maximum(ends - counts, first), 0, R)
+        token = pairs // K
+        valid = jnp.arange(R) < jnp.sum(mine)
+        xg = jnp.where(valid[:, None], x[token], 0)
     with jax.named_scope("moe_experts"):
         a = (jax.nn.silu(grouped_matmul(xg, wg, mine))
              * grouped_matmul(xg, wu, mine))
@@ -175,23 +181,34 @@ def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into):
 def _sorted_pairs(key, R: int):
     """The (token, choice) pairs sorted by expert, held experts' first,
     padded to whole chunks of ``R``."""
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    return jnp.pad(order, (0, (-order.shape[0]) % R))
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        return jnp.pad(order, (0, (-order.shape[0]) % R))
 
 
 def _over_chunks(R, counts, init, step):
     """``step(c, carry)`` for every chunk of ``R`` sorted rows that holds
-    a held expert's row: as many turns as the rows need, no more."""
-    turns = (jnp.sum(counts) + R - 1) // R
+    a held expert's row: as many turns as the rows need, no more. The
+    loop itself lies under no inner scope, or every operation of its
+    body would carry two."""
+    with jax.named_scope("moe_dispatch"):
+        turns = (jnp.sum(counts) + R - 1) // R
     return lax.fori_loop(0, turns, step, init)
+
+
+def _chunk_pairs(order, c, R):
+    with jax.named_scope("moe_dispatch"):
+        return lax.dynamic_slice(order, (c * R,), (R,))
 
 
 def _routed_sum(R, order, x, wg, wu, wd, gates, counts):
     def step(c, y):
-        pairs = lax.dynamic_slice(order, (c * R,), (R,))
-        return _chunk(x, wg, wu, wd, gates, pairs, counts, c * R, y)
+        return _chunk(x, wg, wu, wd, gates, _chunk_pairs(order, c, R),
+                      counts, c * R, y)
 
-    return _over_chunks(R, counts, jnp.zeros(x.shape, jnp.float32), step)
+    with jax.named_scope("moe_combine"):
+        y = jnp.zeros(x.shape, jnp.float32)
+    return _over_chunks(R, counts, y, step)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -207,20 +224,30 @@ def _routed_fwd(R, x, wg, wu, wd, gates, key, counts):
 
 
 def _routed_bwd(R, saved, dy):
+    """The rule's own operations (the buffers of zeros, a chunk's pairs,
+    the sums over the chunks, the casts back) open ``moe_dispatch``
+    themselves: a hand-written rule inherits no forward scope. What
+    ``_chunk`` opens it opens here too, recomputed and transposed."""
     *floats, order, counts = saved
-    zero = jnp.zeros(floats[0].shape, jnp.float32)
+    dispatch = functools.partial(jax.named_scope, "moe_dispatch")
+    with dispatch():
+        zero = jnp.zeros(floats[0].shape, jnp.float32)
 
     def step(c, grads):
-        pairs = lax.dynamic_slice(order, (c * R,), (R,))
+        pairs = _chunk_pairs(order, c, R)
         _, vjp = jax.vjp(
             lambda *f: _chunk(*f, pairs, counts, c * R, zero), *floats)
-        return tuple(g + d.astype(jnp.float32)
-                     for g, d in zip(grads, vjp(dy)))
+        ds = vjp(dy)
+        with dispatch():
+            return tuple(g + d.astype(jnp.float32)
+                         for g, d in zip(grads, ds))
 
-    grads = _over_chunks(
-        R, counts, tuple(jnp.zeros(f.shape, jnp.float32) for f in floats),
-        step)
-    return (*(g.astype(f.dtype) for g, f in zip(grads, floats)), None, None)
+    with dispatch():
+        init = tuple(jnp.zeros(f.shape, jnp.float32) for f in floats)
+    grads = _over_chunks(R, counts, init, step)
+    with dispatch():
+        return (*(g.astype(f.dtype) for g, f in zip(grads, floats)),
+                None, None)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -235,7 +262,8 @@ def routed_experts(x, wg, wu, wd, ids, gates, *, n_experts: int,
     key, counts = _plan(ids, offset, held, live)
     R = _chunk_rows(x.shape[0], ids.shape[1], n_experts, held)
     y = _routed(R, x, wg, wu, wd, gates.astype(jnp.float32), key, counts)
-    return y.astype(x.dtype), counts
+    with jax.named_scope("moe_combine"):
+        return y.astype(x.dtype), counts
 
 
 def moe_ffn(params, x, *, top_k: int, scale: float = 1.0, offset=0,
@@ -250,7 +278,8 @@ def moe_ffn(params, x, *, top_k: int, scale: float = 1.0, offset=0,
         x, params["wg"], params["wu"], params["wd"], ids, w,
         n_experts=params["wr"].shape[-1], offset=offset, live=live)
     if shared and "sg" in params:
-        y = y + swiglu(x, params["sg"], params["su"], params["sd"])
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(x, params["sg"], params["su"], params["sd"])
     return y, rows
 
 
@@ -274,7 +303,8 @@ def make_moe(mesh: Mesh, axis: str, *, top_k: int, scale: float = 1.0):
                        shared=False)
         y = lax.psum(y, axis)
         if "sg" in params:
-            y = y + swiglu(x, params["sg"], params["su"], params["sd"])
+            with jax.named_scope("moe_shared"):
+                y = y + swiglu(x, params["sg"], params["su"], params["sd"])
         return y
 
     @functools.lru_cache(maxsize=None)

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import importlib.util
 import os
+import re
 from typing import Dict, Tuple
 
 from paddle_tpu.utils.log import logger
@@ -45,10 +46,19 @@ def enable_compile_cache() -> str:
     """Place the persistent compilation cache (before the first compile:
     JAX decides once per process whether a cache is in use). Returns the
     directory in use."""
+    import jax
     path, from_env = compile_cache_dir()
     if not from_env:
-        import jax
         jax.config.update("jax_compilation_cache_dir", path)
+    # JAX's key leaves the names out by default, so two programs that
+    # differ only in their named scopes share an entry, and the second
+    # is handed the first's ``op_name`` paths: the device trace is read
+    # by those (docs/observability.md), so they belong to the key. The
+    # key then holds the call sites' files too: written relative to the
+    # checkout, or two checkouts of one code would share nothing
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_CHECKOUT + os.sep))
     return path
 
 

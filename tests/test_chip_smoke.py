@@ -69,3 +69,44 @@ def test_compile_cache_dir_follows_env_else_fixed_checkout_path(monkeypatch):
     assert runtime.compile_cache_dir() == (path, False)  # nothing moves
     monkeypatch.setenv(runtime.CACHE_ENV, "/somewhere/else")
     assert runtime.compile_cache_dir() == ("/somewhere/else", True)
+
+
+_TWO_NAMES = """
+import re, sys
+import jax, jax.numpy as jnp
+from paddle_tpu.utils import runtime
+runtime.enable_compile_cache()
+from paddle_tpu.parallel.moe import swiglu
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        return swiglu(jnp.sin(x), x, x, x)
+text = jax.jit(f).lower(jnp.ones((64, 64))).compile().as_text()
+print(sorted(set(re.findall(r'op_name="jit\\(f\\)/(\\w+)/', text))),
+      re.findall(r'"([^"]*parallel/moe.py)"', text))
+"""
+
+
+def test_a_cached_program_is_not_served_under_another_scopes_names(tmp_path):
+    """Two programs that differ only in a ``jax.named_scope`` are two
+    entries of the persistent cache: the second process reads its own
+    ``op_name`` paths in its compiled text, not the first's (JAX's key
+    leaves names out unless asked; the benchmark reads device time by
+    those paths, and a PR that only renames a scope would read the
+    parent's)."""
+    import subprocess
+    import sys
+    checkout = os.path.dirname(os.path.abspath(chip_smoke.__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=checkout,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    said = [subprocess.run([sys.executable, "-c", _TWO_NAMES, name], env=env,
+                           capture_output=True, text=True, timeout=120,
+                           check=True).stdout.strip()
+            for name in ("first", "second", "first")]
+    # and the call sites' files are written relative to the checkout, so
+    # that another checkout of the same code finds these entries
+    there = " ['paddle_tpu/parallel/moe.py']"
+    assert said == ["['first']" + there, "['second']" + there,
+                    "['first']" + there]
+    assert len([f for f in os.listdir(tmp_path) if f.startswith("jit_f")]) == 2

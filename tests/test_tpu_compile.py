@@ -7,8 +7,11 @@ passes is not a chip run. All in this one file:
 the worker that gets it is the one process that loads the TPU's
 compiler."""
 
+import contextlib
 import math
+import os
 import re
+import sys
 
 import pytest
 
@@ -332,3 +335,154 @@ def test_lstm_scan_backward_streams_no_weight_gradient(one_chip):
             rf" convolution\(.*op_name=\"[^\"]*transpose\(jvp\(lstm{i}\)\)"
             r"/tbh,tbg->hg/dot_general\"", text)
         assert len(products) == 1, (i, products)
+
+
+# the inner scopes a layer opens, by the layer's kind; PR 35 added all but
+# the cores and ``moe_route`` / ``moe_experts`` / ``moe_combine``
+_PARTS = {"attn": ("attn_qkv", "attn_rope", "mla_core", "attn_core",
+                   "attn_out"),
+          "moe": ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
+                  "moe_shared")}
+_ADDED = {"attn_qkv", "attn_rope", "attn_out", "moe_dispatch", "moe_shared"}
+_NAMED = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(.*op_name=\"([^\"]+)\"",
+    re.M)
+# what may lie under a layer's scope and under none of its parts, and is
+# no operation of the layer's: the checkpoint's own call (constants and a
+# weight's copy the compiler hoists out of it), the expert loop's control
+# (the ``while``, its bound's test, its counter) and the one sum of the
+# input's cotangents across ``_routed``'s call (a custom_vjp's call is an
+# equation of the layer, so its sum is named by the layer alone)
+_BARE = re.compile(r"jvp\(blk0_\w+\)\)?/(?:remat2|add_any|"
+                   r"while(?:/cond/lt|/body/add)?)$")
+
+
+def _layer_step(kind, one_chip):
+    """``(step, shapes, layer's name)``: one layer of a cell at its
+    widths over 1,024 tokens through the executor, its output and the
+    gradients of its input and parameters."""
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.argument import Argument
+    from paddle_tpu.core.network import Network
+
+    dsl.reset()
+    x = dsl.data(name="x", size=2048, is_sequence=True)
+    remat = {"recompute": True}
+    if kind == "latent":
+        layer = dsl.mla_attention(
+            x, num_heads=32, q_lora_rank=1536, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            rope_theta=32e6, name="blk0_attn", layer_attr=remat)
+    elif kind == "full":
+        layer = dsl.gqa_attention(
+            x, num_heads=48, num_kv_heads=8, head_dim=128, rotary_dim=64,
+            rope_theta=5e5, name="blk0_attn", layer_attr=remat,
+            yarn={"factor": 64.0, "original_max_position_embeddings": 4096})
+    elif kind == "windowed":
+        layer = dsl.gqa_attention(
+            x, num_heads=64, num_kv_heads=8, head_dim=128, window=512,
+            name="blk0_swa", layer_attr=remat)
+    else:
+        layer = dsl.moe(
+            x, expert_hidden=768, num_experts=256, top_k=8, experts_held=8,
+            expert_offset=8, shared_hidden=768, routed_scaling_factor=2.5,
+            name="blk0_moe")
+    net = Network(dsl.current_graph(), outputs=[layer.name])
+
+    def sd(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        sd, jax.eval_shape(net.init_params, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((1, 1024, 2048), jnp.bfloat16,
+                                  sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 1024), jnp.float32, sharding=one_chip)
+
+    def step(params, xv, m, g):
+        def apply(params, xv):
+            return net.apply(params, {"x": Argument(value=xv, mask=m)},
+                             train=True)[layer.name].value
+        out, back = jax.vjp(apply, params, xv)
+        return out, back(g)
+
+    return step, (params, tokens, mask, tokens), layer.name
+
+
+@pytest.mark.parametrize("kind", ["latent", "full", "windowed", "experts"])
+def test_every_operation_of_a_layer_lies_in_one_inner_scope(
+        one_chip, kind, monkeypatch):
+    """A latent layer, a full and a windowed grouped-query layer under
+    ``recompute`` and an expert layer with its shared expert, output and
+    gradients: every instruction named under the layer carries exactly
+    one of the layer's inner scopes, forward, recomputed and backward
+    (but ``_BARE``); ``rematted_computation`` marks the recomputed
+    forward and nothing else (its products are the forward's, the
+    backward's are twice those); and with PR 35's scopes muted the
+    compiled text is the same but for its metadata, so the scopes cost
+    the step nothing and the cores' patterns match the instructions
+    they matched before."""
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import step_text        # `same`'s comparison: the text without metadata
+    step, shapes, name = _layer_step(kind, one_chip)
+    text = _compile(step, *shapes).as_text()
+    scope = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda part: contextlib.nullcontext()
+        if part in _ADDED else scope(part))
+    # a step of its own: jit keeps the trace of a function it has seen
+    step, shapes, _ = _layer_step(kind, one_chip)
+    before = _compile(step, *shapes).as_text()
+    monkeypatch.undo()
+
+    assert "attn_qkv" not in before and "moe_dispatch" not in before
+    assert step_text.bare(text) == step_text.bare(before)
+
+    parts = _PARTS["moe" if kind == "experts" else "attn"]
+    under = [(inst, op, path) for inst, op, path in _NAMED.findall(text)
+             if f"jvp({name})" in path]
+    assert len(under) > 100
+    products = {}
+    for inst, op, path in under:
+        have = [p for p in parts if re.search(rf"\b{p}\b", path)]
+        if not have:
+            assert _BARE.search(path), (inst, op, path)
+            assert op in ("constant", "copy", "while", "compare", "add",
+                          "get-tuple-element"), (inst, op, path)
+            continue
+        assert len(have) == 1, (inst, path)
+        way = ("again" if "rematted_computation" in path else
+               "bwd" if f"transpose(jvp({name}))" in path else "fwd")
+        if op == "convolution":
+            products[way, have[0]] = products.get((way, have[0]), 0) + 1
+    if kind == "experts":           # not under recompute: nothing again
+        assert "rematted_computation" not in text
+        assert {p for _, p in products} == {"moe_route", "moe_shared"}
+        assert products["bwd", "moe_shared"] \
+            == 2 * products["fwd", "moe_shared"] == 6
+    else:
+        # q, k and v's products run forward, again, and twice backward;
+        # `wo`'s (and the gate's) output feeds nothing the backward pass
+        # needs, so only the gate's, which the core's output is
+        # multiplied by, is run again
+        n = {"latent": 4, "full": 3, "windowed": 3}[kind]
+        gate = 0 if kind == "latent" else 1
+        assert products == {
+            ("fwd", "attn_qkv"): n, ("again", "attn_qkv"): n,
+            ("bwd", "attn_qkv"): 2 * n, ("fwd", "attn_out"): 1 + gate,
+            ("bwd", "attn_out"): 2 * (1 + gate),
+            **({("again", "attn_out"): gate} if gate else {})}, products
+    # the cores' and the grouped products' patterns (benchmark/metrics/)
+    # read what they read before: the same instructions by name
+    for pattern in (r"_attn\).*mla_core", r"_attn\).*attn_core",
+                    r"_swa\).*attn_core", r"_moe\).*moe_experts",
+                    r"jvp\(\w+_moe\)"):
+        now, then = ({inst for inst, _, path in _NAMED.findall(t)
+                      if re.search(pattern, path)} for t in (text, before))
+        assert now == then, pattern
+    core = {"latent": "mla_core", "experts": "moe_experts"}.get(
+        kind, "attn_core")
+    calls = [path for _, op, path in under
+             if op == "custom-call" and "pallas_call" in path]
+    assert len(calls) >= 3 and all(core in path for path in calls)

@@ -1,4 +1,4 @@
-"""Device time a step under one scope of a kept trace, by operation.
+r"""Device time a step under one scope of a kept trace, by operation.
 
     python3 -m benchmark.run --workload <cell> --seed <n> --seconds 30 \\
         --trace 1 --keep-trace FILE
@@ -8,7 +8,9 @@
 ``FILE`` is the run's ``.xplane.pb`` and ``FILE.scopes.json`` the
 compiled step's ``{instruction: op_name}`` beside it. Every operation
 whose ``op_name`` path matches the pattern is summed under its
-direction (``fwd`` / ``bwd``, from ``jvp`` / ``transpose(jvp``), its
+direction (``fwd`` / ``bwd``, from ``jvp`` / ``transpose(jvp``; ``again``
+for a ``recompute`` layer's forward run a second time in the backward
+pass, ``rematted_computation``), its
 opcode and, for a custom call, its result's type: that tells the
 attention core's three kernels apart (forward ``(bf16[..,Dv],
 f32[..,128])``, dK/dV ``(bf16[..,Dqk], bf16[..,Dv])``, dQ
@@ -59,7 +61,8 @@ def main(argv) -> int:
             runs[name] += 1
             if opcode == "while" or not pattern.search(scope):
                 continue
-            way = trace_reduce.scope_label(scope).rsplit(".", 1)[-1]
+            way = ("again" if "rematted_computation" in scope else
+                   trace_reduce.scope_label(scope).rsplit(".", 1)[-1])
             kind = (f"{opcode} -> {re.sub(r'{[^{}]*}', '', result)}"
                     if shapes or opcode == "custom-call" else opcode)
             ns[f"{way} {kind}"] += ev.duration_ns
