@@ -15,11 +15,18 @@ Three tiers:
 - ``flash_attention`` — Pallas kernels: forward on a grid (batch·heads,
   steps), a step one (q block, kv block) tile and a q block's kv tiles
   consecutive, so the accumulator lives in VMEM scratch across that
-  sweep; it also hands back each row's log-sum-exp. Backward = two
-  kernels that recompute the scores tile by tile from that log-sum-exp
-  (dK/dV walking a kv block's q tiles, dQ a q block's kv tiles): memory
-  linear in T, nothing of size T_q·T_k is ever held. The grid holds the
-  tiles in which a query sees a key and no others (``_Tiles``): under
+  sweep; it also hands back each row's log-sum-exp. The backward
+  recomputes the scores tile by tile from that log-sum-exp: memory
+  linear in T, nothing of size T_q·T_k is ever held. It is ONE kernel
+  over dK/dV's walk (a kv block's q tiles) that computes a tile's
+  scores, probabilities and score gradient once and feeds dK, dV and dQ
+  from them, where dQ's float32 sums for the q blocks that are open at
+  once fit VMEM beside the rest (``_flash_backward`` reckons the bytes
+  against ``common.VMEM_BUDGET_BYTES``; ``flash_backward`` in a tally
+  says ``fused`` or ``split``), and two kernels that each recompute the
+  tile (dK/dV; dQ walking a q block's kv tiles) where they do not: many
+  query heads a key-value head over a long row with no window. The grid
+  holds the tiles in which a query sees a key and no others (``_Tiles``): under
   ``causal`` the pairs wholly above the diagonal are not skipped steps
   but no steps, named by a table of steps in SMEM; a grid step costs
   about 0.4 us on a v5e even when it computes nothing, and a sweep
@@ -41,9 +48,10 @@ T_k, Dv] (the two head sizes may differ: latent attention has 192 and
   key-value head ``n // (N / N_kv)``. The kernels never see K and V
   repeated to the query heads: the forward and dQ kernels walk a query
   head's tiles and their index maps name its group's K and V blocks;
-  the dK/dV kernel walks a KEY-VALUE head's tiles and, inside one kv
-  block's sweep, the group's query heads in turn, so the sums over the
-  group stay in the VMEM accumulators and dK, dV leave at ``N_kv`` heads
+  the dK/dV kernel and the fused backward walk a KEY-VALUE head's tiles
+  and, inside one kv block's sweep, the group's query heads in turn, so
+  the sums over the group stay in the VMEM accumulators and dK, dV
+  leave at ``N_kv`` heads
   (per-query-head results summed outside cost a write and a read of
   ``N / N_kv`` times the bytes: PERF.md §5 has both timings).
 - **A window.** With ``causal``, ``window=W`` lets query ``i`` see key
@@ -174,6 +182,7 @@ _TRANS_B = (((1,), (1,)), ((), ()))     # a [M,K] x b [N,K] -> [M,N]
 _STAT_LANES = 128     # a row statistic is kept broadcast over one lane tile
 _WALK_TABLE_BYTES = 512 * 1024      # of SMEM, for a walk's table of steps
 _FIRST, _LAST = 1, 2      # a step's place in its sweep, in the walk's table
+_OPENS, _CLOSES = 4, 8    # and in its q block's steps (the fused backward)
 
 
 def _scores(tiles, scale, q, k, msk, qb, kb):
@@ -278,6 +287,46 @@ def _flash_dq_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
         dq_ref[0] = dq_s[:].astype(dq_ref.dtype)
 
 
+def _flash_bwd_kernel(tiles, scale, walk_ref, q_ref, k_ref, v_ref, mask_ref,
+                      do_ref, st_ref, dk_ref, dv_ref, dq_ref, dk_s, dv_s,
+                      dq_s):
+    """dK/dV's walk with dQ beside it: a tile's terms once, three sums.
+    ``dq_s`` holds a slot for every q block that is open (some of its
+    kv blocks walked, not all); a q block's kv blocks come in ascending
+    order here as in dQ's own walk, so each sum adds what the two
+    kernels add, in their order."""
+    qb, kb, first, last = tiles.step(walk_ref)
+    row, opens, closes = tiles.dq_step(walk_ref)
+    rows = pl.ds(pl.multiple_of(row, tiles.bq), tiles.bq)
+
+    @pl.when(first)
+    def _():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+
+    @pl.when(opens)
+    def _():
+        dq_s[rows, :] = jnp.zeros((tiles.bq, dq_s.shape[1]), dq_s.dtype)
+
+    q, k, do, p, ds = _tile_terms(tiles, scale, q_ref, k_ref, v_ref,
+                                  mask_ref, do_ref, st_ref, qb, kb)
+    dv_s[:] += jnp.dot(p.T.astype(do.dtype), do,
+                       preferred_element_type=jnp.float32)
+    dk_s[:] += jnp.dot(ds.T.astype(q.dtype), q,
+                       preferred_element_type=jnp.float32)
+    dq_s[rows, :] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _():
+        dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+    @pl.when(closes)
+    def _():
+        dq_ref[0] = dq_s[rows, :].astype(dq_ref.dtype)
+
+
 def _seen(off, causal, window, block_q, block_k, nq, nk):
     """``[nq, nk]``: does some query of q block ``qb`` see some key of kv
     block ``kb``? A block is a rectangle and what the mask lets see a
@@ -312,15 +361,24 @@ class _Tiles:
     tile is its two indices."""
 
     def __init__(self, heads, off, causal, block_q, block_k, nq, nk,
-                 kv_major, window=None, group=1):
+                 kv_major, window=None, group=1, dq_too=False):
         self.N, self.off, self.causal = heads, off, causal
         self.window, self.group, self.nq = window, group, nq
         self.bq, self.bk, self.kv_major = block_q, block_k, kv_major
         qb, kb, seen = _seen(off, causal, window, block_q, block_k, nq, nk)
+        band = seen.copy()
         if kv_major:
             seen[nq - 1, ~seen.any(axis=0)] = True
-        else:
+        if dq_too or not kv_major:
             seen[~seen.any(axis=1), 0] = True
+        if dq_too:
+            # dQ's slots: a q block is open from its first kv block's
+            # sweep to its last one's, so the q blocks open at once are
+            # those that see one kv block, consecutive ones, and ``ring``
+            # slots taken in turn hold them (all of them where a tile
+            # outside the band was added: its q block is open out of turn)
+            self.ring = int(seen.sum(axis=0).max()) \
+                if (seen == band).all() else nq
         # the group's query heads share a kv block's sweep (dK/dV alone)
         share = group if kv_major else 1
         # every pair a step: the grid is (rows, sweeps, steps of a sweep)
@@ -338,11 +396,25 @@ class _Tiles:
         ends[np.r_[0, edge]] |= _FIRST
         ends[np.r_[edge - 1, len(sweep) - 1]] |= _LAST
         self.steps = len(sweep)
+        cols = [qs[order], ks[order], ends] \
+            + ([head[order]] if share > 1 else [])
+        if dq_too:
+            # a (query head, q block)'s first and last step, and for every
+            # step the one that closes next: dQ's output block stays that
+            # one's until it is written
+            ident = (head * nq + qs)[order]
+            opens = np.unique(ident, return_index=True)[1]
+            closes = np.sort(len(ident) - 1
+                             - np.unique(ident[::-1], return_index=True)[1])
+            ends[opens] |= _OPENS
+            ends[closes] |= _CLOSES
+            self.out_at = len(cols) * self.steps
+            cols.append(ident[closes[np.searchsorted(
+                closes, np.arange(len(ident)))]])
         # [qb of every step | kb of every step | its place in its sweep
-        #  | under a group, the query head of it the step is for]
-        self.walk = jnp.asarray(np.concatenate(
-            [qs[order], ks[order], ends]
-            + ([head[order]] if share > 1 else [])), jnp.int32)
+        #  | under a group, the query head of it the step is for
+        #  | with dQ, the query head and q block its output block is]
+        self.walk = jnp.asarray(np.concatenate(cols), jnp.int32)
 
     def _qb(self, *at):
         """The q block of the grid step ``at``: (sweep, step of it) where
@@ -378,6 +450,34 @@ class _Tiles:
             ends = walk[2 * self.steps + at[0]]
             first, last = (ends & _FIRST) != 0, (ends & _LAST) != 0
         return self._qb(*at), self._kb(*at), first, last
+
+    def dq_step(self, walk):
+        """``(its q block's first row in the dQ slots, is this the q
+        block's first step?, its last?)`` of the grid step the fused
+        backward kernel is in."""
+        if self.whole:  # a slot a (query head, q block), in a sweep's order
+            sweep, t = pl.program_id(1), pl.program_id(2)
+            return t * self.bq, sweep == 0, sweep == self.whole[0] - 1
+        t = pl.program_id(1)
+        ends = walk[2 * self.steps + t]
+        slot = walk[t] % self.ring
+        if self.group > 1:
+            slot += self._head(t, walk) * self.ring
+        return slot * self.bq, (ends & _OPENS) != 0, (ends & _CLOSES) != 0
+
+    def dq_spec(self, d):
+        """dQ's block in the fused backward: the (query head, q block)
+        that closes next, so that a block is held from the step after
+        the one before it closed to its own last step, and written
+        there."""
+        def index(r, *at):
+            if self.whole:
+                nxt = jnp.where(at[0] == self.whole[0] - 1, at[1], 0)
+            else:
+                t, walk = at
+                nxt = walk[self.out_at + t]
+            return r * self.group + nxt // self.nq, nxt % self.nq, 0
+        return pl.BlockSpec((1, self.bq, d), index, memory_space=pltpu.VMEM)
 
     def specs(self):
         """``(q-like(d), kv-like(d), mask)`` block-spec makers. A grid
@@ -473,17 +573,86 @@ def _flash_forward(cfg, qf, kf, vf, mask):
     return out, lse[..., 0]
 
 
+def _vmem_bytes(item, bq, bk, Dqk, Dv, dq_rows=0):
+    """What the backward holds in VMEM, as ``common.VMEM_BUDGET_BYTES``
+    counts: every operand block twice (the pipeline's two buffers), the
+    float32 accumulators and the score-sized temporaries once. dK/dV's
+    kernel holds the most of the three; the fused backward adds dQ's
+    block and ``dq_rows`` rows of dQ's float32 slots, whole lane tiles
+    wide."""
+    held = (2 * item * (bq * (Dqk + Dv) + bk * (Dqk + Dv))
+            + 2 * 4 * bq * _STAT_LANES + 4 * bk * (Dqk + Dv)
+            + 4 * 4 * bq * bk)
+    if dq_rows:
+        held += 2 * item * bq * Dqk \
+            + 4 * dq_rows * -(-Dqk // common.LANE) * common.LANE
+    return held
+
+
+def _backward_stats(out, lse, do):
+    """The backward kernels' row statistics, one operand and one fetch a
+    tile: lane 0 the log-sum-exp, lane 1 delta = rowsum(dO * O)."""
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+    return jnp.pad(jnp.stack([lse, delta], axis=-1),
+                   ((0, 0), (0, 0), (0, _STAT_LANES - 2)))
+
+
+def _fused_walk(cfg, qf, kf, vf):
+    """``(the fused backward's walk, does it fit?)``: dQ's slots and
+    block beside what the two kernels hold within the VMEM budget, the
+    longer table within SMEM's."""
+    heads, off, _, causal, block_q, block_k, window, group = cfg
+    tiles = _Tiles(heads, off, causal, block_q, block_k,
+                   qf.shape[1] // block_q, kf.shape[1] // block_k,
+                   kv_major=True, window=window, group=group, dq_too=True)
+    held = _vmem_bytes(qf.dtype.itemsize, block_q, block_k, qf.shape[2],
+                       vf.shape[2], dq_rows=group * tiles.ring * block_q)
+    return tiles, held <= common.VMEM_BUDGET_BYTES and bool(
+        tiles.whole or 4 * tiles.walk.size <= _WALK_TABLE_BYTES)
+
+
 def _flash_backward(cfg, qf, kf, vf, mask, out, lse, do):
+    """dq, dk, dv. One kernel over dK/dV's walk where dQ's open q blocks
+    fit VMEM beside it (a group of 1 at the cells' 4,096 positions; any
+    group under a window, whose band keeps few q blocks open), else the
+    two kernels: the reckoned bytes decide, and ``flash_backward`` in a
+    tally says which."""
+    operands = (qf, kf, vf, mask, do, _backward_stats(out, lse, do))
+    tiles, fits = _fused_walk(cfg, qf, kf, vf)
+    if common.note("flash_backward", "fused" if fits else "split") == "fused":
+        return _backward_fused(tiles, cfg[2], *operands)
+    return _backward_split(cfg, *operands)
+
+
+def _backward_fused(tiles, scale, qf, kf, vf, mask, do, stats):
+    """A row of the grid is a key-value head, as in dK/dV's kernel; dq
+    leaves a (query head, q block) at a time, when its last tile is
+    done."""
+    Dqk, Dv = qf.shape[2], vf.shape[2]
+    q_like, kv_like, mask_spec = tiles.specs()
+    dk, dv, dq = tiles.call(
+        _flash_bwd_kernel, scale, kf.shape[0],
+        [q_like(Dqk), kv_like(Dqk), kv_like(Dv), mask_spec,
+         q_like(Dv), q_like(_STAT_LANES)],
+        [kv_like(Dqk), kv_like(Dv), tiles.dq_spec(Dqk)],
+        [jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+         jax.ShapeDtypeStruct(vf.shape, vf.dtype),
+         jax.ShapeDtypeStruct(qf.shape, qf.dtype)],
+        [pltpu.VMEM((tiles.bk, Dqk), jnp.float32),
+         pltpu.VMEM((tiles.bk, Dv), jnp.float32),
+         pltpu.VMEM((tiles.group * tiles.ring * tiles.bq, Dqk),
+                    jnp.float32)],
+        qf, kf, vf, mask, do, stats)
+    return dq, dk, dv
+
+
+def _backward_split(cfg, *operands):
     heads, off, scale, causal, block_q, block_k, window, group = cfg
+    qf, kf, vf = operands[:3]
     BN, Tq, Dqk = qf.shape
     Tk, Dv = vf.shape[1], vf.shape[2]
     nq, nk = Tq // block_q, Tk // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
-    # lane 0 the log-sum-exp, lane 1 delta: one operand, one fetch a tile
-    stats = jnp.pad(jnp.stack([lse, delta], axis=-1),
-                    ((0, 0), (0, 0), (0, _STAT_LANES - 2)))
-    operands = (qf, kf, vf, mask, do, stats)
     # a row of dK/dV's grid is a key-value head: its group's query heads
     # are swept inside, so dk and dv leave at kf's and vf's own heads
     tiles = _Tiles(heads, off, causal, block_q, block_k, nq, nk,
@@ -571,13 +740,9 @@ def flash_attention(q, k, v, kv_mask=None, causal=False, scale=None,
     Dqk, Dv = q.shape[-1], v.shape[-1]
     scale = scale if scale is not None else Dqk ** -0.5
     bq, bk = min(block_q, q.shape[2]), min(block_k, k.shape[2])
-    # the backward's dK/dV kernel holds the most: every operand block
-    # twice (the pipeline's two buffers), the f32 accumulators and the
-    # score-sized temporaries once
-    item = jnp.dtype(q.dtype).itemsize
-    resident = (2 * item * (bq * (Dqk + Dv) + bk * (Dqk + Dv))
-                + 2 * 4 * bq * _STAT_LANES + 4 * bk * (Dqk + Dv)
-                + 4 * 4 * bq * bk)
+    # what the two-kernel backward holds; whether the one-kernel backward
+    # fits beside dQ's slots is `_flash_backward`'s to reckon
+    resident = _vmem_bytes(jnp.dtype(q.dtype).itemsize, bq, bk, Dqk, Dv)
     # the causal walk's table of steps lives in SMEM (1 MiB on a v5e),
     # 12 bytes a (q block, kv block) pair at most, and 16 for each of a
     # group's query heads in dK/dV's

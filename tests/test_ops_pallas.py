@@ -378,6 +378,184 @@ def test_flash_attention_grouped_queries(N, Nkv, causal, window):
             np.testing.assert_allclose(g, rg, rtol=1e-4, atol=3e-5)
 
 
+# the backward as one kernel -------------------------------------------
+
+def _backward_operands(B, N, Nkv, Tq, Tk, Dqk, Dv, bq, bk, causal, window,
+                       masked, dtype):
+    """``cfg`` and what the backward kernels take, the forward kernel's
+    output and log-sum-exp among them, as `_flash_backward` hands them
+    on."""
+    from paddle_tpu.ops import attention as A
+    keys = jax.random.split(jax.random.PRNGKey(29), 4)
+    q, k, v, do = (
+        jax.random.normal(key, shape, jnp.float32).astype(dtype)
+        for key, shape in zip(keys, [(B * N, Tq, Dqk), (B * Nkv, Tk, Dqk),
+                                     (B * Nkv, Tk, Dv), (B * N, Tq, Dv)]))
+    mask = jnp.ones((B, 1, Tk), jnp.float32)
+    if masked:
+        mask = mask.at[:, :, Tk - 5:].set(0).at[:, :, 3].set(0)
+    cfg = (N, Tk - Tq, Dqk ** -0.5, causal, bq, bk, window, N // Nkv)
+    out, lse = A._flash_forward(cfg, q, k, v, mask)
+    return cfg, (q, k, v, mask, out, lse, do), (
+        q, k, v, mask, do, A._backward_stats(out, lse, do))
+
+
+bf16, f32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("case", [
+    # B, N, Nkv, Tq, Tk, Dqk, Dv, bq, bk, causal, window, masked, dtype
+    pytest.param((2, 2, 2, 128, 128, 192, 128, 32, 32, True, None, False,
+                  bf16), id="latent_192_128_group1"),
+    pytest.param((1, 2, 2, 128, 128, 128, 128, 32, 32, True, None, False,
+                  bf16), id="looped_128_group1"),
+    pytest.param((1, 6, 1, 128, 128, 128, 128, 32, 32, True, None, False,
+                  bf16), id="group6"),
+    pytest.param((1, 6, 1, 128, 128, 128, 128, 32, 32, True, 32, False,
+                  bf16), id="group6_window"),
+    pytest.param((1, 8, 1, 128, 128, 128, 128, 32, 32, True, None, False,
+                  bf16), id="group8"),
+    pytest.param((1, 8, 1, 128, 128, 128, 128, 32, 32, True, 32, False,
+                  bf16), id="group8_window_a_block"),
+    pytest.param((1, 16, 2, 128, 128, 128, 128, 32, 32, True, 48, True,
+                  bf16), id="group8_window_off_the_blocks_kv_mask"),
+    pytest.param((2, 2, 2, 128, 128, 128, 128, 32, 32, True, None, True,
+                  bf16), id="kv_mask"),
+    pytest.param((1, 2, 2, 64, 128, 128, 128, 32, 32, True, None, False,
+                  bf16), id="more_keys_than_queries"),
+    pytest.param((1, 2, 2, 64, 256, 128, 128, 32, 32, True, 32, False,
+                  bf16), id="kv_blocks_no_query_sees"),
+    pytest.param((1, 2, 2, 128, 128, 128, 128, 32, 32, False, None, True,
+                  bf16), id="not_causal_kv_mask"),
+    pytest.param((1, 4, 2, 96, 128, 64, 32, 32, 64, False, None, False,
+                  bf16), id="not_causal_group2_blocks_differ"),
+    pytest.param((1, 2, 2, 64, 64, 24, 16, 16, 16, True, None, True, f32),
+                 id="float32"),
+])
+def test_fused_backward_equals_the_two_kernels(case):
+    """dq, dk and dv of the one backward kernel against dK/dV's and dQ's,
+    on the same operands: every sum adds the same terms in the same
+    order, so they are the same bits, at the three cells' head sizes and
+    groups (cut in length), under a window, a key mask, more keys than
+    queries and no ``causal``. The float32 case holds both to
+    ``blockwise_attention``'s own gradients."""
+    from paddle_tpu.ops import attention as A
+    B, N, Nkv, Tq, Tk, Dqk, Dv = case[:7]
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        cfg, residuals, operands = _backward_operands(*case)
+        fused = A._flash_backward(cfg, *residuals)
+        split = A._backward_split(cfg, *operands)
+    assert tally["flash_backward"] == {"fused": 1}
+    for name, a, b in zip(("dq", "dk", "dv"), fused, split):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), name)
+    if case[-1] == f32:
+        q, k, v, mask, _, _, do = residuals
+        causal, window = case[9], case[10]
+        _, back = jax.vjp(
+            lambda q, k, v: blockwise_attention(
+                q, k, v, mask[:, 0], causal=causal, window=window,
+                scale=cfg[2], block_k=cfg[5]),
+            q.reshape(B, N, Tq, Dqk), k.reshape(B, Nkv, Tk, Dqk),
+            v.reshape(B, Nkv, Tk, Dv))
+        for g, rg in zip(fused, back(do.reshape(B, N, Tq, Dv))):
+            np.testing.assert_allclose(g.reshape(rg.shape), rg, rtol=1e-4,
+                                       atol=2e-5)
+
+
+def test_fused_backward_keeps_the_tile_of_a_q_block_that_sees_nothing():
+    """32 queries more than keys, causal: the first q blocks see no key.
+    The one kernel writes their dq from the all-masked tile that the
+    forward's and dQ's walks keep for them (dK/dV's own walk has no such
+    tile), so dq is dQ's bits, and dK, dV differ from dK/dV's only in kv
+    block 0, by those rows' share."""
+    from paddle_tpu.ops import attention as A
+    case = (1, 2, 2, 64, 32, 128, 128, 16, 16, True, None, False, bf16)
+    with common.force_mode("interpret"):
+        cfg, residuals, operands = _backward_operands(*case)
+        fused = A._flash_backward(cfg, *residuals)
+        split = A._backward_split(cfg, *operands)
+    np.testing.assert_array_equal(np.asarray(fused[0], np.float32),
+                                  np.asarray(split[0], np.float32))
+    for a, b in zip(fused[1:], split[1:]):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(a[:, 16:], np.float32),
+                                      np.asarray(b[:, 16:], np.float32))
+
+
+@pytest.mark.parametrize("shape,window,path", [
+    ((2, 32, 32, 4096, 192, 128), None, "fused"),       # the JoyAI cell's
+    ((1, 16, 16, 4096, 128, 128), None, "fused"),       # the Ouro cell's
+    ((1, 64, 8, 8192, 128, 128), 512, "fused"),         # Laguna, sliding
+    ((1, 48, 8, 8192, 128, 128), None, "split"),        # Laguna, full
+])
+def test_the_backward_is_one_kernel_where_dq_fits_beside_it(shape, window,
+                                                            path):
+    """Which backward runs follows from the reckoned bytes against
+    ``VMEM_BUDGET_BYTES``, and the tally says it: at 512 x 512 tiles the
+    cells' cores of one query head a key-value head hold dQ for the
+    whole of a row (4.2 and 2.1 MB), a band of 512 keeps two q blocks
+    open for each of a group's 8 heads (4.2 MB), and 6 heads over the
+    whole of 8,192 positions would take 25 MB: the two kernels."""
+    B, N, Nkv, T, Dqk, Dv = shape
+
+    def sd(*s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16)
+
+    def back(q, k, v, g):
+        return jax.vjp(lambda *a: flash_attention(
+            *a, None, causal=True, block_q=512, block_k=512,
+            window=window), q, k, v)[1](g)
+
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        jax.eval_shape(back, sd(B, N, T, Dqk), sd(B, Nkv, T, Dqk),
+                       sd(B, Nkv, T, Dv), sd(B, N, T, Dv))
+    assert tally == {"flash_attention": {"interpret": 1},
+                     "flash_backward": {path: 1}}
+
+
+@pytest.mark.parametrize("args", [
+    # off, causal, nq, nk, window, group (blocks of 16)
+    (0, True, 4, 4, None, 1), (0, True, 4, 4, None, 3),
+    (0, True, 8, 8, 16, 2), (0, True, 8, 8, 24, 1), (0, True, 8, 8, 5, 2),
+    (32, True, 4, 6, None, 1), (64, True, 4, 8, 16, 2),
+    (-32, True, 4, 2, None, 1),
+])
+def test_the_fused_walk_opens_and_closes_every_q_block_once(args):
+    """The table the one backward kernel walks: every (query head, q
+    block) is opened at its first step and closed at its last, the slot
+    it takes of dQ's ``ring`` is no other open block's meanwhile, and
+    dQ's output block is, at every step, the block that closes next: a
+    run of consecutive steps that ends where it is written. A band
+    keeps fewer slots than q blocks."""
+    from paddle_tpu.ops.attention import _CLOSES, _OPENS, _Tiles
+    off, causal, nq, nk, window, group = args
+    tiles = _Tiles(group, off, causal, 16, 16, nq, nk, kv_major=True,
+                   window=window, group=group, dq_too=True)
+    cols = np.asarray(tiles.walk).reshape(-1, tiles.steps)
+    qb, ends, out = cols[0], cols[2], cols[-1]
+    head = cols[3] if group > 1 else np.zeros_like(qb)
+    open_in, closed, runs = {}, set(), []
+    for t in range(tiles.steps):
+        ident, slot = head[t] * nq + qb[t], (head[t], qb[t] % tiles.ring)
+        if ends[t] & _OPENS:
+            assert slot not in open_in and ident not in closed
+            open_in[slot] = ident
+        assert open_in[slot] == ident
+        if not runs or runs[-1] != out[t]:
+            runs.append(out[t])
+        if ends[t] & _CLOSES:
+            assert out[t] == ident
+            closed.add(open_in.pop(slot))
+    assert not open_in and closed == set(range(group * nq))
+    assert sorted(runs) == list(range(group * nq))     # one run a block
+    if window is not None and off <= window:
+        assert tiles.ring == -(-window // 16) + (window % 16 != 1) < nq
+
+
 def test_grouped_queries_need_a_divisor_and_a_window_needs_causal():
     x = jnp.zeros((1, 6, 16, 8))
     with pytest.raises(ValueError, match="do not divide"):

@@ -5,9 +5,12 @@ H --seq T --dqk D --dv D] [variant ...]`).
 
 At the JoyAI cell's shape by default (batch x heads 2 x 32, 4,096
 positions, q/k 192, v 128, bf16, 512 x 512 tiles, causal, a key mask of
-ones) it times the forward, the dK/dV and the dQ kernel alone, each
-jitted by itself, as `ops/attention.py` has them. `--window` makes the
-causal pass a band, `--kv-heads` gives K and V fewer heads than q (the
+ones) it times the forward, the dK/dV and the dQ kernel and the fused
+backward kernel (dK, dV and dQ from one visit of a tile) alone, each
+jitted by itself on the same operands, as `ops/attention.py` has them;
+`fused_fits` says whether `_flash_backward` would take the fused kernel
+at this shape, `fused_equals_split` whether its three results are the
+two kernels' bit for bit. `--window` makes the causal pass a band, `--kv-heads` gives K and V fewer heads than q (the
 Laguna cell's sliding layers: `--batch 1 --heads 64 --kv-heads 8 --seq
 8192 --dqk 128 --window 512`; its full layers: `--heads 48` and no
 window). A variant is one of
@@ -28,10 +31,11 @@ window). A variant is one of
 No variant: all of `VARIANTS`. One JSON object a line on standard output
 and in `chiprun_out/flash_tile_times.jsonl`; `ms` is the median of
 `REPEATS` host-clock timings of `CALLS` calls (XLA's copies of the
-operands into the kernels' layout, about 0.7 ms, are in it, and the
-backward kernels' are less `beside_ms`, the statistics operand's
-making), `layers6_ms` six layers of it, `us_a_tile` over the tiles the
-forward grid walks at 512 x 512 (2,304 at the default shape).
+operands into the kernels' layout, about 0.7 ms, are in it; the
+backward kernels take their statistics operand ready-made, `beside_ms`
+is what making it costs a backward pass), `layers6_ms` six layers of
+it, `us_a_tile` over the tiles the forward grid walks at 512 x 512
+(2,304 at the default shape).
 """
 
 from __future__ import annotations
@@ -159,7 +163,6 @@ def measure(name, bq=BLOCK, bk=BLOCK, causal=True):
         q, k, v, do, mask = _operands()
         fwd = jax.jit(lambda *a: A._flash_forward(cfg, *a))
         out, lse = jax.block_until_ready(fwd(q, k, v, mask))
-        res = (q, k, v, mask, out, lse, do)
         if name == "dkv_outside":
             # a query head a grid row, its group's K and V beside it;
             # the group's dK and dV summed by XLA afterwards
@@ -169,32 +172,40 @@ def measure(name, bq=BLOCK, bk=BLOCK, causal=True):
 
             def outside(q, k, v, *rest):
                 return [g.reshape(B, NKV, group, T, -1).sum(axis=2)
-                        for g in A._flash_backward(one, q, k, v, *rest)[1:]]
+                        for g in A._backward_split(one, q, k, v, *rest)[1:]]
 
-            return {"dkv": _time(jax.jit(outside), q, *rep, *res[3:])}
-        # one backward kernel each: the other's call is dead code
-        dkv = jax.jit(lambda *a: A._flash_backward(cfg, *a)[1:])
-        dq = jax.jit(lambda *a: A._flash_backward(cfg, *a)[0])
+            return {"dkv": _time(jax.jit(outside), q, *rep, mask, do,
+                                 jax.jit(A._backward_stats)(out, lse, do))}
+        # one of the two kernels each: the other's call is dead code
+        dkv = jax.jit(lambda *a: A._backward_split(cfg, *a)[1:])
+        dq = jax.jit(lambda *a: A._backward_split(cfg, *a)[0])
+        args = (q, k, v, mask, do, jax.jit(A._backward_stats)(out, lse, do))
         times = {"fwd": _time(fwd, q, k, v, mask),
-                 "dkv": _time(dkv, *res), "dq": _time(dq, *res)}
+                 "dkv": _time(dkv, *args), "dq": _time(dq, *args)}
+        # the one kernel that makes all three, whether or not the entry
+        # would take it at this shape (`fused_fits`; Mosaic may refuse)
+        walk, times["fused_fits"] = A._fused_walk(cfg, q, k, v)
+        fused = jax.jit(lambda *a: A._backward_fused(walk, cfg[2], *a))
+        try:
+            times["fused"] = _time(fused, *args)
+            same = [bool(jnp.array_equal(a, b)) for a, b in zip(
+                fused(*args), jax.jit(
+                    lambda *a: A._backward_split(cfg, *a))(*args))]
+            times["fused_equals_split"] = all(same)
+        except Exception as e:  # noqa: BLE001 - Mosaic's refusal
+            times["fused_error"] = str(e)[-400:]
     finally:
         A._scores, A._tile_terms = own
     return times
 
 
 def beside():
-    """The backward's work beside the kernels (delta and the statistics'
-    128-lane buffer), which both backward timings above hold."""
+    """The backward's work beside its kernels (delta and the statistics'
+    128-lane buffer): once a backward pass, in none of the timings
+    above."""
     q, k, v, do, mask = _operands()
-
-    def stats(out, lse, do):
-        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1)
-        return jnp.pad(jnp.stack([lse, delta], axis=-1),
-                       ((0, 0), (0, 0), (0, A._STAT_LANES - 2)))
-
     lse = jnp.zeros((B * N, T), jnp.float32)
-    return _time(jax.jit(stats), do, lse, do)
+    return _time(jax.jit(A._backward_stats), do, lse, do)
 
 
 def main(argv) -> int:
@@ -244,8 +255,9 @@ def main(argv) -> int:
             else:
                 got = measure(name)
             for kernel, ms in got.items():
-                if kernel != "fwd":
-                    ms -= stats_ms
+                if not isinstance(ms, float):
+                    say({"variant": name, kernel: ms})
+                    continue
                 say({"variant": name, "kernel": kernel, "ms": ms,
                      "layers6_ms": 6 * ms, "us_a_tile": 1e3 * ms / tiles})
     return 0

@@ -12,10 +12,11 @@ direction (``fwd`` / ``bwd``, from ``jvp`` / ``transpose(jvp``; ``again``
 for a ``recompute`` layer's forward run a second time in the backward
 pass, ``rematted_computation``), its
 opcode and, for a custom call, its result's type: that tells the
-attention core's three kernels apart (forward ``(bf16[..,Dv],
-f32[..,128])``, dK/dV ``(bf16[..,Dqk], bf16[..,Dv])``, dQ
-``bf16[..,Dqk]``). A third word, ``shapes``, keys every operation by
-its result's type, and the second pattern above takes what carries no
+attention core's kernels apart (forward ``(bf16[..,Dv],
+f32[..,128])``; the one backward kernel ``(bf16[..,Dqk], bf16[..,Dv],
+bf16[..,Dqk])``, or where that does not fit VMEM dK/dV ``(bf16[..,Dqk],
+bf16[..,Dv])`` and dQ ``bf16[..,Dqk]``). A third word, ``shapes``,
+keys every operation by its result's type, and the second pattern above takes what carries no
 layer (the optimizer's update, casts, metrics): that is how PR 32 found
 the update's relayouts. A step is what most instructions ran: their
 count of events. One JSON object a line, the dearest first.
