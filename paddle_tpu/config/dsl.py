@@ -136,9 +136,11 @@ def fc(input, size: int, *, act: str = "tanh", name: str = None,
 def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
         experts_held: int = None, expert_offset: int = 0,
         shared_hidden: int = 0, routed_scaling_factor: float = 1.0,
-        name: str = None, layer_attr: dict = None) -> LayerOutput:
+        norm_eps: float = 0.0, name: str = None,
+        layer_attr: dict = None) -> LayerOutput:
     """Mixture-of-experts FFN (TPU-native capability-add; output size =
-    input size): sigmoid top-``top_k`` routing over ``num_experts``,
+    input size): sigmoid top-``top_k`` routing over ``num_experts``
+    (the chosen scores normalised by their sum plus ``norm_eps``),
     SwiGLU experts, a shared expert of width ``shared_hidden`` (0: none).
     The layer holds experts ``expert_offset .. + experts_held`` (all of
     them by default) and computes their part of the sum: the chip's share
@@ -150,6 +152,8 @@ def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
              "expert_offset": expert_offset, "shared_hidden": shared_hidden,
              "routed_scaling_factor": routed_scaling_factor,
              **extra.pop("attrs", {})}
+    if norm_eps:
+        attrs["norm_eps"] = norm_eps
     ldef = LayerDef(name=name or _auto_name("moe"), type="moe",
                     inputs=[Input(src.name)], bias=False, attrs=attrs,
                     **extra)
@@ -373,8 +377,10 @@ def mla_attention(input, *, num_heads: int, q_lora_rank: int,
 def gqa_attention(input, *, num_heads: int, num_kv_heads: int,
                   head_dim: int, window: int = None, rotary_dim: int = None,
                   rope_theta: float = 10000.0, yarn: dict = None,
-                  gate: bool = True, block: int = 512, name: str = None,
-                  layer_attr: dict = None, params_of=None) -> LayerOutput:
+                  gate: bool = True, block: int = 512,
+                  qk_norm: bool = False, qk_norm_eps: float = 1e-6,
+                  name: str = None, layer_attr: dict = None,
+                  params_of=None) -> LayerOutput:
     """Causal grouped-query self-attention (`layers/attention.py`):
     ``num_heads`` query heads over ``num_kv_heads`` key-value heads of
     ``head_dim``, a sliding ``window`` (None: the whole sequence), rotary
@@ -383,17 +389,37 @@ def gqa_attention(input, *, num_heads: int, num_kv_heads: int,
     ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
     ``attention_factor``), a per-head sigmoid ``gate`` on the core's
     output, the flash kernels' tiles ``block`` x ``block``; no bias,
-    output size = input size. ``params_of`` names a layer whose
-    projections this one uses (a stack run several times)."""
+    output size = input size. ``qk_norm`` puts an RMS normalisation
+    (``qk_norm_eps``) over each head of q and of k before the rotary
+    turn, one learned scale of ``head_dim`` for all the query heads and
+    one for the key heads. ``params_of`` names a layer whose projections
+    this one uses (a stack run several times)."""
     extra = _layer_attr(layer_attr)
     attrs = {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
              "head_dim": head_dim, "window": window,
              "rotary_dim": rotary_dim, "rope_theta": rope_theta,
              "yarn": yarn, "gate": gate, "block": block,
              **extra.pop("attrs", {})}
+    if qk_norm:
+        attrs.update(qk_norm=True, qk_norm_eps=qk_norm_eps)
     ldef = LayerDef(name=name or _auto_name("gqa"), type="gqa_attention",
                     inputs=[Input(_in(input)[0].name)], bias=False,
                     attrs=attrs, params_of=_owner(params_of), **extra)
+    return _add(ldef)
+
+
+def short_conv(input, *, kernel: int = 3, name: str = None,
+               layer_attr: dict = None) -> LayerOutput:
+    """The gated short convolution (`layers/short_conv.py`): ``[B | C |
+    X] = u W_in``, ``y = (C * conv(B * X)) W_out`` with a depthwise
+    causal convolution of ``kernel`` taps over time; no bias, output
+    size = input size."""
+    extra = _layer_attr(layer_attr)
+    ldef = LayerDef(name=name or _auto_name("short_conv"),
+                    type="short_conv", inputs=[Input(_in(input)[0].name)],
+                    bias=False,
+                    attrs={"kernel": kernel, **extra.pop("attrs", {})},
+                    **extra)
     return _add(ldef)
 
 
@@ -405,17 +431,26 @@ def seq_shift(input, *, offset: int, name: str = None) -> LayerOutput:
 
 def lm_cost(input, ids, *, vocab_size: int, shift: int = 1,
             coeff: float = 1.0, chunk: int = 2048, name: str = None,
-            param_attr=None) -> LayerOutput:
+            param_attr=None, tied_to=None) -> LayerOutput:
     """The output head fused with its cross-entropy: position i's logits
     against the id at ``i + shift``, each row's mean over the positions
     that have a target, times ``coeff`` (`layers/lm.py`). The head's
-    weight is ``param_attr``'s to share (``ParamAttr(name=...)``)."""
+    weight is ``param_attr``'s to share (``ParamAttr(name=...)``).
+    ``tied_to`` names an embedding layer (handle or name) whose table
+    ``[V, d]`` is the head, used transposed: one leaf in the parameter
+    table, its gradient the sum of the lookup's and the head's (an
+    inference output shares it as a ``trans_full_matrix`` projection of
+    the same name)."""
+    attrs = {"vocab_size": vocab_size, "shift": shift, "coeff": coeff,
+             "chunk": chunk}
+    if tied_to is not None:
+        param_attr = ParamAttr(name=f"_{_owner(tied_to)}.w0")
+        attrs["tied"] = True
     ldef = LayerDef(name=name or _auto_name("lm_cost"), type="lm_cost",
                     inputs=[Input(_in(input)[0].name,
                                   param_attr=_param(param_attr)),
                             Input(_in(ids)[0].name)], bias=False,
-                    attrs={"vocab_size": vocab_size, "shift": shift,
-                           "coeff": coeff, "chunk": chunk})
+                    attrs=attrs)
     return _add(ldef)
 
 

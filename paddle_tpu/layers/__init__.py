@@ -20,3 +20,4 @@ from paddle_tpu.layers import detection  # noqa: F401
 from paddle_tpu.layers import attention  # noqa: F401
 from paddle_tpu.layers import moe  # noqa: F401
 from paddle_tpu.layers import lm  # noqa: F401
+from paddle_tpu.layers import short_conv  # noqa: F401

@@ -29,7 +29,10 @@ r/2``) over the first ``rotary_dim`` elements of a head with plain or
 YaRN frequencies (``yarn_inv_freq``; cos and sin times YaRN's
 ``attention_factor``), and a per-head sigmoid gate on the core's output
 (``sigmoid(u W_g)``, one scalar a head and token; arXiv:2505.06708's
-head-wise form). One layer type for windowed and full layers: the core
+head-wise form), and with ``qk_norm`` an RMS
+normalisation of every head of q and of k before the turn (``gq``,
+``gk`` ``[head_dim]``: one scale for all the heads of a kind; float32
+statistics). One layer type for windowed and full layers: the core
 runs under the inner scope ``attn_core`` in both, and a windowed layer's
 ``state["counters"]`` names what its band cost the tiling:
 ``swa_pairs_visited`` (query-key pairs inside the tiles the forward
@@ -42,8 +45,9 @@ part, forward, recomputed and backward alike (``docs/observability.md``):
 ``attn_qkv`` (the input to q, k, v at the core's layout, before any
 rotary turn), ``attn_rope`` (the turns, the concatenations that assemble
 q and k), ``mla_core`` / ``attn_core`` (the kernels) and ``attn_out``
-(gate, head merge, ``wo``, mask). They are names in the compiled text
-and nothing else.
+(gate, head merge, ``wo``, mask); a grouped-query layer with a q/k
+normalisation has a fifth, ``attn_qk_norm``, between the first two. They
+are names in the compiled text and nothing else.
 """
 
 from __future__ import annotations
@@ -253,8 +257,8 @@ def rotary_halves(x, inv_freq, factor: float = 1.0):
 @register_layer("gqa_attention")
 class GqaAttentionLayer(LayerImpl):
     """Causal grouped-query self-attention, windowed or full, with
-    partial rotary and a per-head output gate; no bias. Output size =
-    input size."""
+    partial rotary, an optional per-head q/k normalisation and a
+    per-head output gate; no bias. Output size = input size."""
 
     @staticmethod
     def _dims(cfg):
@@ -275,6 +279,10 @@ class GqaAttentionLayer(LayerImpl):
                  "wo": ParamSpec(shape=(heads * hd, d))}
         if cfg.attrs.get("gate", True):
             specs["wg"] = ParamSpec(shape=(d, heads))
+        if cfg.attrs.get("qk_norm"):
+            ones = dict(init="const", initial_mean=1.0, initial_std=0.0)
+            specs.update(gq=ParamSpec(shape=(hd,), **ones),
+                         gk=ParamSpec(shape=(hd,), **ones))
         return specs
 
     @staticmethod
@@ -309,12 +317,19 @@ class GqaAttentionLayer(LayerImpl):
             with jax.named_scope("attn_qkv"):
                 return split(u @ params[w], n)
 
+        def normed(x, g):
+            if not cfg.attrs.get("qk_norm"):
+                return x
+            with jax.named_scope("attn_qk_norm"):
+                return rms_normalize(x, params[g],
+                                     cfg.attrs.get("qk_norm_eps", 1e-6))
+
         def turn(x):
             with jax.named_scope("attn_rope"):
                 return rotary_halves(x, inv_freq, factor)
 
-        q = turn(project("wq", heads))
-        k = turn(project("wk", kv))
+        q = turn(normed(project("wq", heads), "gq"))
+        k = turn(normed(project("wk", kv), "gk"))
         v = project("wv", kv)
         with jax.named_scope("attn_core"):
             out = flash_attention(q, k, v, ins[0].mask, causal=True,

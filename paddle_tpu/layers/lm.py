@@ -6,7 +6,9 @@ output head fused with its shifted cross-entropy.
 ``lm_cost`` (inputs: hidden states [B,T,d], the ids [B,T]): position i's
 logits ``h_i W`` are scored against the id at ``i + shift``; the layer
 emits each row's mean over the positions that have a target, times
-``coeff``, so the trainer's batch mean is the mean over rows. The logits
+``coeff``, so the trainer's batch mean is the mean over rows. A tied head
+(``dsl.lm_cost(tied_to=<embedding>)``) is the embedding's own leaf ``E
+[V, d]``, its logits ``h_i E^T``. The logits
 are never held whole: rows of hidden states go through the head
 ``chunk`` at a time under ``jax.checkpoint``, so a chunk's [chunk, V]
 float32 logits live only while its loss, or its gradient, is worked out.
@@ -58,11 +60,12 @@ class SeqShiftLayer(LayerImpl):
                         else shift_left(a.mask, k))
 
 
-def chunked_cross_entropy(h, w, targets, chunk: int):
+def chunked_cross_entropy(h, w, targets, chunk: int, tied: bool = False):
     """``-log softmax(h W)[target]`` for every row of ``h [R, d]``, float32
     [R]; the logits ``chunk`` rows at a time, recomputed in the backward
     pass (the product in ``h``'s type with a float32 sum, the softmax in
-    float32)."""
+    float32). ``w`` is ``[d, V]``, or with ``tied`` an embedding's table
+    ``[V, d]``, contracted on ``d`` as it lies."""
     R, d = h.shape
     chunk = min(int(chunk), R)
     pad = (-R) % chunk
@@ -72,8 +75,10 @@ def chunked_cross_entropy(h, w, targets, chunk: int):
 
     @jax.checkpoint
     def one(hc, tc):
-        logits = jnp.dot(hc, w.astype(hc.dtype),
-                         preferred_element_type=jnp.float32)
+        wc = w.astype(hc.dtype)
+        logits = (jnp.einsum("rd,vd->rv", hc, wc,
+                             preferred_element_type=jnp.float32) if tied
+                  else jnp.dot(hc, wc, preferred_element_type=jnp.float32))
         lse = jax.nn.logsumexp(logits, axis=-1)
         return lse - jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
 
@@ -96,7 +101,7 @@ def shifted_cross_entropy(cfg, w, states, ids_arg):
     ce = chunked_cross_entropy(
         jnp.concatenate(rows), w,
         jnp.tile(shift_left(ids, k).reshape(B * T), len(rows)),
-        int(cfg.attrs.get("chunk", 2048)))
+        int(cfg.attrs.get("chunk", 2048)), bool(cfg.attrs.get("tied")))
     # has_target: 0 on a row's last k positions and on padding
     return ce.reshape(len(rows), B, T), shift_left(live, k)
 
@@ -113,8 +118,10 @@ class LmCostLayer(LayerImpl):
         return ShapeInfo(size=1)
 
     def params(self, cfg, in_infos):
-        return {"w0": ParamSpec(shape=(in_infos[0].size,
-                                       int(cfg.attrs["vocab_size"])))}
+        shape = (in_infos[0].size, int(cfg.attrs["vocab_size"]))
+        # a tied head is the embedding's table as it lies, [V, d]
+        return {"w0": ParamSpec(shape=shape[::-1] if cfg.attrs.get("tied")
+                                else shape)}
 
     def apply(self, cfg, params, ins, ctx):
         ce, has_target = shifted_cross_entropy(

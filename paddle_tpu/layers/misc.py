@@ -17,6 +17,7 @@ from paddle_tpu.core.argument import Argument
 from paddle_tpu.core.registry import (LayerImpl, ParamSpec, ShapeInfo,
                                       register_layer)
 from paddle_tpu.layers.conv import to_nhwc
+from paddle_tpu.ops.short_conv import depthwise_time_conv
 
 
 @register_layer("agent")
@@ -448,15 +449,10 @@ class RowConvLayer(LayerImpl):
 
     def apply(self, cfg, params, ins, ctx):
         x, mask = ins[0].value, ins[0].mask  # [B, T, D]
-        k = cfg.attrs["context_length"]
-        w = params["w0"]
-        B, T, D = x.shape
         xm = x if mask is None else x * mask[:, :, None]
-        pad = jnp.zeros((B, k - 1, D), x.dtype)
-        xp = jnp.concatenate([xm, pad], axis=1)
-        out = jnp.zeros_like(x)
-        for j in range(k):  # k is small and static: unrolled adds fuse
-            out = out + xp[:, j:j + T] * w[j]
+        # the short convolution's taps, looking ahead instead of back
+        out = depthwise_time_conv(xm, params["w0"],
+                                  causal=False).astype(x.dtype)
         if mask is not None:
             out = out * mask[:, :, None]
         return Argument(value=out, mask=mask)

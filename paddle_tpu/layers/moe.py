@@ -90,7 +90,8 @@ class MoELayer(LayerImpl):
         y, rows = moe_ffn(
             params, x, top_k=int(cfg.attrs["top_k"]),
             scale=float(cfg.attrs.get("routed_scaling_factor", 1.0)),
-            offset=int(cfg.attrs.get("expert_offset") or 0), live=live)
+            offset=int(cfg.attrs.get("expert_offset") or 0), live=live,
+            norm_eps=float(cfg.attrs.get("norm_eps") or 0.0))
         with jax.named_scope("moe_combine"):
             y = y.reshape(shape)
         with jax.named_scope("moe_dispatch"):

@@ -83,11 +83,12 @@ def swiglu(x, wg, wu, wd):
     return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
 
 
-def route(x, wr, br, top_k: int, scale: float):
+def route(x, wr, br, top_k: int, scale: float, norm_eps: float = 0.0):
     """``(ids [T,k] int32, weights [T,k] float32)``: sigmoid scores over
     all the experts in float32 (the product at ``highest``: a TPU's
     default float32 product rounds its operands to bfloat16), the choice
-    by ``s + b``, the weights by ``s``, normalised and scaled."""
+    by ``s + b``, the weights by ``s``, normalised (by their sum, plus
+    ``norm_eps`` where a model publishes one) and scaled."""
     with jax.named_scope("moe_route"):
         s = jax.nn.sigmoid(jnp.matmul(
             x.astype(jnp.float32), wr.astype(jnp.float32),
@@ -95,7 +96,10 @@ def route(x, wr, br, top_k: int, scale: float):
         _, ids = lax.top_k(s + lax.stop_gradient(br.astype(jnp.float32)),
                            top_k)
         w = jnp.take_along_axis(s, ids, axis=-1)
-        w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        if norm_eps:        # added only where asked: the others' text stays
+            total = total + norm_eps
+        w = w / total * scale
     return ids.astype(jnp.int32), w
 
 
@@ -267,13 +271,13 @@ def routed_experts(x, wg, wu, wd, ids, gates, *, n_experts: int,
 
 
 def moe_ffn(params, x, *, top_k: int, scale: float = 1.0, offset=0,
-            live=None, shared: bool = True):
+            live=None, shared: bool = True, norm_eps: float = 0.0):
     """The layer on one device: ``x [T, d]`` -> ``(y [T, d], rows)``.
     ``params["wg"]`` holds ``held`` experts, those from ``offset`` on, of
     the ``params["wr"].shape[-1]`` the router scores. ``live`` ([T], 0 or
     1) marks real tokens: padding is routed nowhere. ``shared`` False
     leaves the shared expert out (``make_moe`` adds it once)."""
-    ids, w = route(x, params["wr"], params["br"], top_k, scale)
+    ids, w = route(x, params["wr"], params["br"], top_k, scale, norm_eps)
     y, rows = routed_experts(
         x, params["wg"], params["wu"], params["wd"], ids, w,
         n_experts=params["wr"].shape[-1], offset=offset, live=live)

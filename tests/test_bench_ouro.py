@@ -149,7 +149,8 @@ def test_the_benchmark_names_the_cell_and_its_metrics():
     bench = _load(ROOT, "BENCHMARK.json")
     entry = {w["name"]: w for w in bench["workloads"]}[FULL_CELL]
     assert entry["chips"] == 1 and entry["traffic"] == "train_bs1_seq4096"
-    assert bench["workloads"][-1] == entry      # appended, not inserted
+    # appended, not inserted (PR 37 appended one cell after it)
+    assert bench["workloads"][4] == entry
     _, mix = full()
     assert (mix["kind"], mix["batch"], mix["seq_len"], mix["pool"],
             mix["mesh"]) == ("train", 1, 4096, 4, None)
@@ -172,8 +173,8 @@ def test_the_benchmark_names_the_cell_and_its_metrics():
     # the Laguna cell still reports its full cores through the same reader
     full_core = {m["name"]: m for m in bench["per_layer"]}[
         "attn_full_core_roofline"]
-    assert full_core["workloads"] == ["laguna_xs2_ep32.train_bs1_seq8192",
-                                      FULL_CELL]
+    assert full_core["workloads"][:2] == [
+        "laguna_xs2_ep32.train_bs1_seq8192", FULL_CELL]
     limits = _load(ROOT, "benchmark", "cells", FULL_CELL + ".json")["limits"]
     assert limits["nonfinite_costs"] == 0 and "grad_diff" in limits
 

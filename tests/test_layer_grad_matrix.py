@@ -392,6 +392,11 @@ def _case_gqa_attention():
             {"x": _seq(d=8)})
 
 
+def _case_short_conv():
+    return ([("x", 6, {"is_sequence": True})],
+            L("out", "short_conv", ["x"], kernel=3), {"x": _seq()})
+
+
 def _case_rms_norm():
     return ([("x", 6, {"is_sequence": True})],
             L("out", "rms_norm", ["x"]), {"x": _seq()})
@@ -665,6 +670,7 @@ GRAD_CASES = {
     "mla_attention": _case_mla_attention,
     "gqa_attention": _case_gqa_attention, "rms_norm": _case_rms_norm,
     "swiglu": _case_swiglu, "seq_shift": _case_seq_shift,
+    "short_conv": _case_short_conv,
     "lm_cost": _case_lm_cost, "looped_lm_cost": _case_looped_lm_cost,
     "agent": _case_agent,
     "scatter_agent": _case_scatter_agent,

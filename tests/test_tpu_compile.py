@@ -347,11 +347,14 @@ def test_lstm_scan_backward_streams_no_weight_gradient(one_chip):
 
 # the inner scopes a layer opens, by the layer's kind; PR 35 added all but
 # the cores and ``moe_route`` / ``moe_experts`` / ``moe_combine``
-_PARTS = {"attn": ("attn_qkv", "attn_rope", "mla_core", "attn_core",
-                   "attn_out"),
+_PARTS = {"attn": ("attn_qkv", "attn_qk_norm", "attn_rope", "mla_core",
+                   "attn_core", "attn_out"),
           "moe": ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
-                  "moe_shared")}
-_ADDED = {"attn_qkv", "attn_rope", "attn_out", "moe_dispatch", "moe_shared"}
+                  "moe_shared"),
+          "sconv": ("sconv_in", "sconv_core", "sconv_out")}
+# ... and PR 37 the short convolution's three and ``attn_qk_norm``
+_ADDED = {"attn_qkv", "attn_rope", "attn_out", "moe_dispatch", "moe_shared",
+          "attn_qk_norm", "sconv_in", "sconv_core", "sconv_out"}
 _NAMED = re.compile(
     r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(.*op_name=\"([^\"]+)\"",
     re.M)
@@ -370,8 +373,6 @@ def _layer_step(kind, one_chip):
     widths over 1,024 tokens through the executor, its output and the
     gradients of its input and parameters."""
     from paddle_tpu.config import dsl
-    from paddle_tpu.core.argument import Argument
-    from paddle_tpu.core.network import Network
 
     dsl.reset()
     x = dsl.data(name="x", size=2048, is_sequence=True)
@@ -390,12 +391,32 @@ def _layer_step(kind, one_chip):
         layer = dsl.gqa_attention(
             x, num_heads=64, num_kv_heads=8, head_dim=128, window=512,
             name="blk0_swa", layer_attr=remat)
+    elif kind == "qk_norm":         # the LFM2 cell's: a head of 64, no gate
+        layer = dsl.gqa_attention(
+            x, num_heads=32, num_kv_heads=8, head_dim=64, rope_theta=1e6,
+            gate=False, qk_norm=True, qk_norm_eps=1e-5, name="blk0_attn",
+            layer_attr=remat)
+    elif kind == "sconv":
+        layer = dsl.short_conv(x, kernel=3, name="blk0_sconv",
+                               layer_attr=remat)
     else:
         layer = dsl.moe(
             x, expert_hidden=768, num_experts=256, top_k=8, experts_held=8,
             expert_offset=8, shared_hidden=768, routed_scaling_factor=2.5,
             name="blk0_moe")
-    net = Network(dsl.current_graph(), outputs=[layer.name])
+    return (*_output_and_gradients(layer.name, one_chip, 1024), layer.name)
+
+
+def _output_and_gradients(out_name, one_chip, tokens):
+    """``(step, shapes)`` over the DSL's current graph, whose one input
+    ``x`` is a row of ``tokens`` of 2,048 in bfloat16 with its mask: the
+    layer ``out_name``'s output and the gradients of the parameters and
+    of ``x``."""
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.argument import Argument
+    from paddle_tpu.core.network import Network
+
+    net = Network(dsl.current_graph(), outputs=[out_name])
 
     def sd(leaf):
         return jax.ShapeDtypeStruct(leaf.shape, jnp.bfloat16,
@@ -403,18 +424,18 @@ def _layer_step(kind, one_chip):
 
     params = jax.tree_util.tree_map(
         sd, jax.eval_shape(net.init_params, jax.random.PRNGKey(0)))
-    tokens = jax.ShapeDtypeStruct((1, 1024, 2048), jnp.bfloat16,
-                                  sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((1, 1024), jnp.float32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((1, tokens, 2048), jnp.bfloat16,
+                                sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, tokens), jnp.float32, sharding=one_chip)
 
     def step(params, xv, m, g):
         def apply(params, xv):
             return net.apply(params, {"x": Argument(value=xv, mask=m)},
-                             train=True)[layer.name].value
+                             train=True)[out_name].value
         out, back = jax.vjp(apply, params, xv)
         return out, back(g)
 
-    return step, (params, tokens, mask, tokens), layer.name
+    return step, (params, rows, mask, rows)
 
 
 @pytest.mark.parametrize("shape,window", [
@@ -456,11 +477,14 @@ def test_the_cores_backward_is_one_mosaic_call_inside_the_budget(
         == [(o.shape, o.dtype) for o in two.out_info]
 
 
-@pytest.mark.parametrize("kind", ["latent", "full", "windowed", "experts"])
+@pytest.mark.parametrize("kind", ["latent", "full", "windowed", "experts",
+                                  "qk_norm", "sconv"])
 def test_every_operation_of_a_layer_lies_in_one_inner_scope(
         one_chip, kind, monkeypatch):
-    """A latent layer, a full and a windowed grouped-query layer under
-    ``recompute`` and an expert layer with its shared expert, output and
+    """A latent layer, a full and a windowed grouped-query layer, one
+    with a q/k normalisation at a head of 64 and a gated short
+    convolution under ``recompute``, and an expert layer with its shared
+    expert, output and
     gradients: every instruction named under the layer carries exactly
     one of the layer's inner scopes, forward, recomputed and backward
     (but ``_BARE``); ``rematted_computation`` marks the recomputed
@@ -486,7 +510,7 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
     assert "attn_qkv" not in before and "moe_dispatch" not in before
     assert step_text.bare(text) == step_text.bare(before)
 
-    parts = _PARTS["moe" if kind == "experts" else "attn"]
+    parts = _PARTS[{"experts": "moe", "sconv": "sconv"}.get(kind, "attn")]
     under = [(inst, op, path) for inst, op, path in _NAMED.findall(text)
              if f"jvp({name})" in path]
     assert len(under) > 100
@@ -508,13 +532,23 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
         assert {p for _, p in products} == {"moe_route", "moe_shared"}
         assert products["bwd", "moe_shared"] \
             == 2 * products["fwd", "moe_shared"] == 6
+    elif kind == "sconv":
+        # `W_in`'s product runs forward, again, and twice backward;
+        # `W_out`'s output feeds nothing the backward pass needs
+        assert products == {
+            ("fwd", "sconv_in"): 1, ("again", "sconv_in"): 1,
+            ("bwd", "sconv_in"): 2, ("fwd", "sconv_out"): 1,
+            ("bwd", "sconv_out"): 2}, products
+        assert {p for _, _, path in under for p in parts
+                if re.search(rf"\b{p}\b", path)} == set(parts)
+        return
     else:
         # q, k and v's products run forward, again, and twice backward;
         # `wo`'s (and the gate's) output feeds nothing the backward pass
         # needs, so only the gate's, which the core's output is
         # multiplied by, is run again
-        n = {"latent": 4, "full": 3, "windowed": 3}[kind]
-        gate = 0 if kind == "latent" else 1
+        n = {"latent": 4}.get(kind, 3)
+        gate = 0 if kind in ("latent", "qk_norm") else 1
         assert products == {
             ("fwd", "attn_qkv"): n, ("again", "attn_qkv"): n,
             ("bwd", "attn_qkv"): 2 * n, ("fwd", "attn_out"): 1 + gate,
@@ -528,8 +562,84 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
         now, then = ({inst for inst, _, path in _NAMED.findall(t)
                       if re.search(pattern, path)} for t in (text, before))
         assert now == then, pattern
+    if kind == "qk_norm":   # the norms' own instructions, all three ways
+        ways = {("again" if "rematted_computation" in path else
+                 "bwd" if f"transpose(jvp({name}))" in path else "fwd")
+                for _, _, path in under if "attn_qk_norm" in path}
+        assert ways == {"fwd", "again", "bwd"}
     core = {"latent": "mla_core", "experts": "moe_experts"}.get(
         kind, "attn_core")
     calls = [path for _, op, path in under
              if op == "custom-call" and "pallas_call" in path]
     assert len(calls) >= 2 and all(core in path for path in calls)
+
+
+def _tokens_step(layer_of, one_chip, tokens=8192):
+    """``(step, shapes)``: a norm, the layer ``layer_of(normed)`` under
+    ``recompute`` and the residual add around it, as a block has them,
+    over one row of ``tokens`` of 2,048; output and gradients."""
+    from paddle_tpu.config import dsl
+
+    dsl.reset()
+    x = dsl.data(name="x", size=2048, is_sequence=True)
+    layer = layer_of(dsl.rms_norm(x, epsilon=1e-5, name="blk0_a_norm"))
+    out = dsl.addto([x, layer], name="blk0_op_add")
+    return _output_and_gradients(out.name, one_chip, tokens)
+
+
+def test_the_head_of_64_runs_the_forward_kernel_once_and_two_backward(
+        one_chip):
+    """The LFM2 cell's one attention layer at its shape (8,192 tokens, 32
+    query heads over 8 of 64, a q/k normalisation, under ``recompute``):
+    Mosaic takes a head of half a lane tile as it is; the forward kernel
+    once, not again for the recomputation, and the backward as dK/dV and
+    dQ (dQ's float32 slots for 4 heads x 8,192 positions, a whole lane
+    tile wide, are 16.8 MB: over the budget, as the Laguna cell's full
+    layers')."""
+    from paddle_tpu.config import dsl
+    step, shapes = _tokens_step(lambda x: dsl.gqa_attention(
+        x, num_heads=32, num_kv_heads=8, head_dim=64, rope_theta=1e6,
+        gate=False, qk_norm=True, qk_norm_eps=1e-5, name="blk0_attn",
+        layer_attr={"recompute": True}), one_chip)
+    with common.record_dispatch() as tally:
+        text = _compile(step, *shapes).as_text()
+    assert tally == {"flash_attention": {"pallas": 1},
+                     "flash_backward": {"split": 1}}
+    assert text.count(_MOSAIC) == 3
+
+
+def test_the_short_convolutions_core_is_one_pass_forward_and_two_backward(
+        one_chip):
+    """What the compiler makes of ``gated_short_conv`` in plain jnp at the
+    cell's shape (8,192 tokens of 2,048, bfloat16, under ``recompute``,
+    a norm before and the residual add after): under ``sconv_core`` ONE
+    fusion that writes a ``[T, d]`` array forward (``s = B * X``; the
+    taps and the ``C`` gate ride in the ``W_out`` product's fusion), the
+    same one again for the recomputation, two backward; none of them
+    float32 (``s`` kept in float32 was written out at twice the bytes,
+    and four float32 buffers backward); the taps' gradient is no pass of
+    its own."""
+    from paddle_tpu.config import dsl
+    T, d = 8192, 2048
+    step, shapes = _tokens_step(lambda x: dsl.short_conv(
+        x, kernel=3, name="blk0_sconv", layer_attr={"recompute": True}),
+        one_chip, T)
+    text = _compile(step, *shapes).as_text()
+    entry = text[text.index("\nENTRY "):]
+    wide = {}
+    for inst, op, path in _NAMED.findall(entry):
+        if op != "fusion" or "sconv_core" not in path:
+            continue
+        line = re.search(rf"^\s*(?:ROOT )?%?{re.escape(inst)} = (.*?) fusion\(",
+                         entry, re.M).group(1)
+        outs = re.findall(rf"(\w+)\[1,{T},{d}\]", line)
+        if outs:
+            way = ("again" if "rematted_computation" in path else
+                   "bwd" if "transpose(jvp(" in path else "fwd")
+            wide.setdefault(way, []).append(outs)
+    # fusions, and the [T, d] arrays they write: dB and dX from one, dC
+    # (or the taps' input gradient) from the other
+    assert {way: (len(f), sum(map(len, f))) for way, f in wide.items()} \
+        == {"fwd": (1, 1), "again": (1, 1), "bwd": (2, 3)}, wide
+    assert {t for f in wide.values() for outs in f for t in outs} \
+        == {"bf16"}, wide

@@ -5,7 +5,7 @@
     python3 tools/trace_layer_split.py FILE
 
 For each kind of layer that opens inner scopes (``*_attn``, ``*_swa``,
-``*_moe``: ``docs/observability.md``) one JSON object a part: the
+``*_sconv``, ``*_moe``: ``docs/observability.md``) one JSON object a part: the
 milliseconds a step under the part's scope, ``fwd | again | bwd``
 (``again``: the forward run a second time in the backward pass, which
 ``jax.checkpoint`` names ``rematted_computation``), then the layer
@@ -31,8 +31,10 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-ATTENTION = ("attn_qkv", "attn_rope", "mla_core", "attn_core", "attn_out")
+ATTENTION = ("attn_qkv", "attn_qk_norm", "attn_rope", "mla_core",
+             "attn_core", "attn_out")
 KINDS = {"attn": ATTENTION, "swa": ATTENTION,
+         "sconv": ("sconv_in", "sconv_core", "sconv_out"),
          "moe": ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                  "moe_shared")}
 AGAIN = "rematted_computation"
