@@ -14,8 +14,10 @@ sum and leaves the rest out; no token routed to a held expert is ever
 dropped. Its output's ``state["counters"]`` names what the step counted
 here (the trainer hands every layer's counters back with the cost, into
 ``StepBreakdown.totals``): ``moe_rows_max`` and ``moe_rows_mean``, the
-rows the fullest held expert got and the mean held expert's, and
-``moe_experts_active``, how many held experts got any row.
+rows the fullest held expert got and the mean held expert's,
+``moe_experts_active``, how many held experts got any row, and
+``moe_turns``, the chunks of sorted rows the expert loop took (1 for the
+usual batch: more says the overflow path ran).
 """
 
 from __future__ import annotations
@@ -87,7 +89,7 @@ class MoELayer(LayerImpl):
             x = a.value.reshape(-1, shape[-1])
             # padding is routed nowhere: it takes no expert's rows
             live = a.mask.reshape(-1) if a.mask is not None else None
-        y, rows = moe_ffn(
+        y, rows, turns = moe_ffn(
             params, x, top_k=int(cfg.attrs["top_k"]),
             scale=float(cfg.attrs.get("routed_scaling_factor", 1.0)),
             offset=int(cfg.attrs.get("expert_offset") or 0), live=live,
@@ -98,5 +100,6 @@ class MoELayer(LayerImpl):
             rows = rows.astype(jnp.float32)     # [held]
             counters = {"moe_rows_max": rows.max(),
                         "moe_rows_mean": rows.mean(),
-                        "moe_experts_active": (rows > 0).sum()}
+                        "moe_experts_active": (rows > 0).sum(),
+                        "moe_turns": turns.astype(jnp.float32)}
         return Argument(value=y, mask=a.mask, state={"counters": counters})

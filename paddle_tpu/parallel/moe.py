@@ -33,8 +33,8 @@ today's large open models carry in their ``config.json``):
   the same turns, recomputing each buffer from the layer's input (a
   loop of traced length has no transpose of its own).
 - scopes: every operation lies under one of ``moe_route``,
-  ``moe_dispatch`` (plan, sort, a chunk's gather, the backward rule's own
-  buffers and sums), ``moe_experts``, ``moe_combine``, ``moe_shared``, so
+  ``moe_dispatch`` (plan, sort, a chunk's gather, the backward rule's sums
+  over the chunks), ``moe_experts``, ``moe_combine``, ``moe_shared``, so
   that a device trace divides the layer's time (``docs/observability.md``).
 """
 
@@ -190,14 +190,18 @@ def _sorted_pairs(key, R: int):
         return jnp.pad(order, (0, (-order.shape[0]) % R))
 
 
-def _over_chunks(R, counts, init, step):
-    """``step(c, carry)`` for every chunk of ``R`` sorted rows that holds
-    a held expert's row: as many turns as the rows need, no more. The
-    loop itself lies under no inner scope, or every operation of its
-    body would carry two."""
+def _turns(R, counts):
+    """The chunks of ``R`` sorted rows that the held experts' rows fill."""
     with jax.named_scope("moe_dispatch"):
-        turns = (jnp.sum(counts) + R - 1) // R
-    return lax.fori_loop(0, turns, step, init)
+        return (jnp.sum(counts) + R - 1) // R
+
+
+def _over_chunks(R, counts, init, step, first=0):
+    """``step(c, carry)`` for every chunk of ``R`` sorted rows from
+    ``first`` on that holds a held expert's row: as many turns as the
+    rows need, no more. The loop itself lies under no inner scope, or
+    every operation of its body would carry two."""
+    return lax.fori_loop(first, _turns(R, counts), step, init)
 
 
 def _chunk_pairs(order, c, R):
@@ -228,30 +232,35 @@ def _routed_fwd(R, x, wg, wu, wd, gates, key, counts):
 
 
 def _routed_bwd(R, saved, dy):
-    """The rule's own operations (the buffers of zeros, a chunk's pairs,
-    the sums over the chunks, the casts back) open ``moe_dispatch``
-    themselves: a hand-written rule inherits no forward scope. What
-    ``_chunk`` opens it opens here too, recomputed and transposed."""
+    """The first chunk's gradients come out in the types the transposed
+    ``_chunk`` gives them (the input's and the leaves' own; float32 for
+    the gates) and are the carry of a loop over the chunks after it. The
+    usual batch is one chunk, so that loop makes no turn, and no buffer
+    is filled with zeros, summed in float32 or cast back. At two chunks
+    the add of two partials in their own type is their float32 sum
+    rounded once, as a float32 sum over the chunks would give; from
+    three on each turn's add rounds: a batch that sends this chip more
+    than four times a uniform router's rows. The rule's own operations
+    (a chunk's pairs, the sums) open ``moe_dispatch`` themselves: a
+    hand-written rule inherits no forward scope. What ``_chunk`` opens
+    it opens here too, recomputed and transposed."""
     *floats, order, counts = saved
-    dispatch = functools.partial(jax.named_scope, "moe_dispatch")
-    with dispatch():
+    with jax.named_scope("moe_dispatch"):
         zero = jnp.zeros(floats[0].shape, jnp.float32)
 
-    def step(c, grads):
+    def grads_of(c):
         pairs = _chunk_pairs(order, c, R)
         _, vjp = jax.vjp(
             lambda *f: _chunk(*f, pairs, counts, c * R, zero), *floats)
-        ds = vjp(dy)
-        with dispatch():
-            return tuple(g + d.astype(jnp.float32)
-                         for g, d in zip(grads, ds))
+        return vjp(dy)
 
-    with dispatch():
-        init = tuple(jnp.zeros(f.shape, jnp.float32) for f in floats)
-    grads = _over_chunks(R, counts, init, step)
-    with dispatch():
-        return (*(g.astype(f.dtype) for g, f in zip(grads, floats)),
-                None, None)
+    def step(c, grads):
+        ds = grads_of(c)
+        with jax.named_scope("moe_dispatch"):
+            return tuple(g + d for g, d in zip(grads, ds))
+
+    return (*_over_chunks(R, counts, grads_of(0), step, first=1),
+            None, None)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -261,30 +270,34 @@ def routed_experts(x, wg, wu, wd, ids, gates, *, n_experts: int,
                    offset=0, live=None):
     """``sum_i w_i E_i(x)`` over the chosen experts that are held here:
     experts ``offset .. offset + wg.shape[0]`` of ``n_experts``. Returns
-    ``(y [T, d], rows [held] int32)``, the rows each held expert got."""
+    ``(y [T, d], rows [held] int32, turns int32)``: the rows each held
+    expert got, and the chunks of sorted rows the loop over them took (1
+    for the usual batch; more is the overflow path)."""
     held = wg.shape[0]
     key, counts = _plan(ids, offset, held, live)
     R = _chunk_rows(x.shape[0], ids.shape[1], n_experts, held)
     y = _routed(R, x, wg, wu, wd, gates.astype(jnp.float32), key, counts)
+    turns = _turns(R, counts)
     with jax.named_scope("moe_combine"):
-        return y.astype(x.dtype), counts
+        return y.astype(x.dtype), counts, turns
 
 
 def moe_ffn(params, x, *, top_k: int, scale: float = 1.0, offset=0,
             live=None, shared: bool = True, norm_eps: float = 0.0):
-    """The layer on one device: ``x [T, d]`` -> ``(y [T, d], rows)``.
+    """The layer on one device: ``x [T, d]`` -> ``(y [T, d], rows,
+    turns)`` (``routed_experts``'s counts).
     ``params["wg"]`` holds ``held`` experts, those from ``offset`` on, of
     the ``params["wr"].shape[-1]`` the router scores. ``live`` ([T], 0 or
     1) marks real tokens: padding is routed nowhere. ``shared`` False
     leaves the shared expert out (``make_moe`` adds it once)."""
     ids, w = route(x, params["wr"], params["br"], top_k, scale, norm_eps)
-    y, rows = routed_experts(
+    y, rows, turns = routed_experts(
         x, params["wg"], params["wu"], params["wd"], ids, w,
         n_experts=params["wr"].shape[-1], offset=offset, live=live)
     if shared and "sg" in params:
         with jax.named_scope("moe_shared"):
             y = y + swiglu(x, params["sg"], params["su"], params["sd"])
-    return y, rows
+    return y, rows, turns
 
 
 _EXPERT_LEAVES = ("wg", "wu", "wd")
@@ -302,9 +315,9 @@ def make_moe(mesh: Mesh, axis: str, *, top_k: int, scale: float = 1.0):
 
     def local(params, x, live):
         held = params["wg"].shape[0]
-        y, _ = moe_ffn(params, x, top_k=top_k, scale=scale,
-                       offset=lax.axis_index(axis) * held, live=live,
-                       shared=False)
+        y = moe_ffn(params, x, top_k=top_k, scale=scale,
+                    offset=lax.axis_index(axis) * held, live=live,
+                    shared=False)[0]
         y = lax.psum(y, axis)
         if "sg" in params:
             with jax.named_scope("moe_shared"):

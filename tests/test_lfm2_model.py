@@ -364,8 +364,8 @@ def test_shares_add_up():
     total = 0.0
     with jax.default_matmul_precision("highest"):
         for lo in (0, 4):
-            part, rows = moe_lib.moe_ffn(share(lo, lo + 4), x, top_k=k,
-                                         offset=lo, norm_eps=1e-6)
+            part, rows, _ = moe_lib.moe_ffn(
+                share(lo, lo + 4), x, top_k=k, offset=lo, norm_eps=1e-6)
             np.testing.assert_allclose(
                 np.asarray(part), np.asarray(by_reference(
                     share(lo, lo + 4), 4, lo)), rtol=2e-5, atol=2e-6)

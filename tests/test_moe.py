@@ -94,15 +94,20 @@ def test_selection_bias_chooses_but_does_not_weigh(setup):
         np.sort(np.asarray(jax.lax.top_k(s + params["br"], K)[1]), axis=-1))
 
 
+def _collapsed(params):
+    """Every token chooses experts 2 and 3: the worst case T * k rows for
+    a layer that holds them."""
+    return dict(params, br=jnp.zeros((E,)).at[jnp.array([2, 3])].set(50.))
+
+
 @pytest.mark.parametrize("mode", ["ref", "interpret"])
 def test_no_token_is_dropped_at_any_skew(setup, mode):
     """Every token chooses the same two experts, both held: the worst
     case T * k rows, two buffers' worth. Exact, on both paths."""
     params, x = setup
-    params = dict(params, br=jnp.zeros((E,)).at[jnp.array([2, 3])].set(50.))
-    held = share(params, 2, 4)
+    held = share(_collapsed(params), 2, 4)
     with common.force_mode(mode), common.record_dispatch() as tally:
-        y, rows = moe_ffn(held, x, top_k=K, scale=SCALE, offset=2)
+        y, rows, turns = moe_ffn(held, x, top_k=K, scale=SCALE, offset=2)
     assert list(np.asarray(rows)) == [T, T]
     assert mode in tally["moe_grouped_matmul"]
     np.testing.assert_allclose(
@@ -110,6 +115,91 @@ def test_no_token_is_dropped_at_any_skew(setup, mode):
         rtol=2e-5, atol=2e-6)
     # the buffer holds twice a uniform router's rows: this took 2 turns
     assert moe_lib._chunk_rows(T, K, E, 2) * 2 == T * K
+    assert int(turns) == 2
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+@pytest.mark.parametrize("turns", [0, 2, 4])
+def test_gradients_over_any_number_of_chunks(setup, mode, turns,
+                                             monkeypatch):
+    """The collapsed router's gradients, every leaf's and the input's,
+    equal the plain reference's where the rows take 2 chunks and, with a
+    smaller buffer, 4 (the loop after the first chunk turns once and
+    three times), and where no row is held here (0 chunks: the first
+    chunk's gradients, computed outside any loop, are zero)."""
+    params, x = setup
+    lo = 4 if turns == 0 else 2
+    held = share(_collapsed(params), lo, lo + 2)
+    if turns:
+        monkeypatch.setattr(moe_lib, "_chunk_rows",
+                            lambda *a: T * K // turns)
+    target = jax.random.normal(jax.random.PRNGKey(2), (T, D))
+
+    def loss(f):
+        return lambda p, x_: jnp.mean((f(p, x_) - target) ** 2)
+
+    with common.force_mode(mode):
+        (l0, g0), (l1, g1) = (
+            jax.value_and_grad(f, argnums=(0, 1))(held, x)
+            for f in (loss(lambda p, x_: moe_ffn(p, x_, top_k=K, scale=SCALE,
+                                                 offset=lo)[0]),
+                      loss(lambda p, x_: reference(p, x_, held=2,
+                                                   offset=lo))))
+        got = moe_ffn(held, x, top_k=K, scale=SCALE, offset=lo)
+    assert int(got[2]) == turns
+    assert int(np.asarray(got[1]).sum()) == (T * K if turns else 0)
+    assert float(l0) == pytest.approx(float(l1), rel=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(g0),
+                    jax.tree_util.tree_leaves(g1)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-6)
+    if not turns:
+        assert not any(np.any(np.asarray(g0[0][k])) for k in
+                       ("wg", "wu", "wd"))
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+@pytest.mark.parametrize("turns", [1, 2])
+def test_bfloat16_gradients_are_the_float32_sum_over_chunks_rounded_once(
+        setup, mode, turns):
+    """In bfloat16, at one chunk and at two, the rule's gradients are bit
+    for bit the formula it replaced: each chunk's gradients (the
+    transposed ``_chunk``, in the leaves' own types) added in float32
+    from zeros, the sum cast back once."""
+    params, x = setup
+    held = share(_collapsed(params), 2, 4)
+    bf = {k: v.astype(jnp.bfloat16) for k, v in held.items()}
+    xb = x.astype(jnp.bfloat16)
+    ids, gates = moe_lib.route(xb, held["wr"], held["br"], K, SCALE)
+    key, counts = moe_lib._plan(ids, 2, 2, None)
+    R = T * K // turns
+    dy = jax.random.normal(jax.random.PRNGKey(4), (T, D))
+    floats = (xb, bf["wg"], bf["wu"], bf["wd"], gates)
+
+    @jax.jit
+    def ours(dy, key, counts, *floats):
+        return jax.vjp(lambda *f: moe_lib._routed(R, *f, key, counts),
+                       *floats)[1](dy)[:5]
+
+    @jax.jit
+    def formula(dy, key, counts, *floats):
+        order = moe_lib._sorted_pairs(key, R)
+        zero = jnp.zeros(x.shape, jnp.float32)
+        sums = [jnp.zeros(f.shape, jnp.float32) for f in floats]
+        for c in range(turns):
+            _, vjp = jax.vjp(lambda *f: moe_lib._chunk(
+                *f, order[c * R:(c + 1) * R], counts, c * R, zero), *floats)
+            sums = [s + d.astype(jnp.float32)
+                    for s, d in zip(sums, vjp(dy))]
+        return [s.astype(f.dtype) for s, f in zip(sums, floats)]
+
+    assert int(moe_lib._turns(R, counts)) == turns
+    with common.force_mode(mode):
+        got, want = (f(dy, key, counts, *floats) for f in (ours, formula))
+    for a, b, f in zip(got, want, floats):
+        assert a.dtype == b.dtype == f.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
 
 
 def test_shares_add_up():
@@ -124,8 +214,8 @@ def test_shares_add_up():
     total = shared
     for i in range(4):
         mine = share(params, 8 * i, 8 * i + 8)
-        part, rows = moe_ffn(mine, x, top_k=K, scale=SCALE, offset=8 * i,
-                             shared=False)
+        part, rows, _ = moe_ffn(mine, x, top_k=K, scale=SCALE,
+                                offset=8 * i, shared=False)
         np.testing.assert_allclose(
             np.asarray(part), np.asarray(reference(
                 mine, x, held=8, offset=8 * i, shared=False)),
@@ -166,11 +256,11 @@ def test_masked_tokens_take_no_rows(setup):
     """Padding is routed nowhere: it takes no expert's rows, gets the
     shared expert only, and leaves the live tokens' outputs alone."""
     params, x = setup
-    y_ref, rows_ref = moe_ffn(params, x[:8], top_k=K, scale=SCALE)
+    y_ref, rows_ref, _ = moe_ffn(params, x[:8], top_k=K, scale=SCALE)
     pad = jax.random.normal(jax.random.PRNGKey(3), (24, D))
     live = jnp.concatenate([jnp.zeros(24), jnp.ones(8)])
-    y_pad, rows = moe_ffn(params, jnp.concatenate([pad, x[:8]]), top_k=K,
-                          scale=SCALE, live=live)
+    y_pad, rows, _ = moe_ffn(params, jnp.concatenate([pad, x[:8]]),
+                             top_k=K, scale=SCALE, live=live)
     np.testing.assert_allclose(np.asarray(y_pad[24:]), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_ref))
@@ -214,7 +304,7 @@ def test_moe_layer_respects_sequence_mask():
                                rtol=1e-5, atol=1e-5)
     short, long = out_short.state["counters"], out_long.state["counters"]
     assert set(short) == {"moe_rows_max", "moe_rows_mean",
-                          "moe_experts_active"}
+                          "moe_experts_active", "moe_turns"}
     for name in short:
         assert float(long[name]) == float(short[name]), name
     # 5 live tokens choose K of E experts each; 4 of the E are held
@@ -292,10 +382,14 @@ def test_layer_counters_reach_totals_and_the_armed_steps_span():
         trace.install(None)
     steps = [s for s in tracer.spans() if s["name"] == "train.step"]
     assert len(steps) == 2
-    for name in ("moe_rows_max", "moe_rows_mean", "moe_experts_active"):
+    for name in ("moe_rows_max", "moe_rows_mean", "moe_experts_active",
+                 "moe_turns"):
         assert tr.breakdown.totals[name] == pytest.approx(
             sum(s["attrs"][name] for s in steps))
     assert steps[0]["attrs"]["moe_rows_mean"] == pytest.approx(16 * K / E)
+    # 16 tokens' 32 pairs fill one buffer of 32 rows: one turn a step
+    assert moe_lib._chunk_rows(16, K, E, E) == 16 * K
+    assert tr.breakdown.totals["moe_turns"] == 2.0
     bd = StepBreakdown()
     bd.add_counters({"anything": np.float32(2.5)})
     bd.add_counters({"anything": 1})
@@ -337,7 +431,7 @@ def test_unwritten_rows_of_the_kernel_poison_nothing(setup, monkeypatch):
     target = jax.random.normal(jax.random.PRNGKey(2), (T, D))
 
     def loss(p, x_):
-        y, _ = moe_ffn(p, x_, top_k=K, scale=SCALE, offset=2)
+        y = moe_ffn(p, x_, top_k=K, scale=SCALE, offset=2)[0]
         return jnp.mean((y - target) ** 2)
 
     want = jax.value_and_grad(loss, argnums=(0, 1))(held, x)

@@ -220,9 +220,21 @@ def test_looped_step_runs_each_cores_forward_kernel_once(one_chip):
     assert compiled.as_text().count(_MOSAIC) == 2 * layers * passes
 
 
-def test_routed_experts_forward_and_backward(one_chip):
-    """8 held experts of 256, 8 a token, 8,192 tokens of 2,048, width
-    768: the loop over buffers of 4,096 rows, forward and backward."""
+@pytest.mark.parametrize("hidden,n_experts,top_k,temp_mb", [
+    (1536, 64, 4, 400),         # the LFM2 cell's
+    (768, 256, 8, 150),         # the JoyAI cell's
+    (512, 256, 8, 150),         # the Laguna cell's
+])
+def test_routed_experts_forward_and_backward(one_chip, hidden, n_experts,
+                                             top_k, temp_mb):
+    """8 held experts, 8,192 tokens of 2,048, a cell's expert width, top
+    k and expert count: the loop over buffers of twice a uniform
+    router's rows, forward and backward. **The expert rule's static
+    counter** (``PERF.md`` section 3): the first buffer's gradients are
+    the loop's carry in the leaves' own types, so no instruction writes
+    and no ``while`` carries a float32 array of an expert leaf's shape,
+    and the temporaries stay under a bound the float32 sums over the
+    buffers passed (701, 364, 339 MB before PR 38)."""
     from paddle_tpu.parallel.moe import routed_experts
 
     def sd(shape, dtype=jnp.bfloat16):
@@ -231,14 +243,25 @@ def test_routed_experts_forward_and_backward(one_chip):
     def step(x, wg, wu, wd, ids, gates, dy):
         def f(x, wg, wu, wd, gates):
             return routed_experts(x, wg, wu, wd, ids, gates,
-                                  n_experts=256, offset=8)[0]
+                                  n_experts=n_experts, offset=8)[0]
         return jax.vjp(f, x, wg, wu, wd, gates)[1](dy)
 
     compiled = _compile(
-        step, sd((8192, 2048)), sd((8, 2048, 768)), sd((8, 2048, 768)),
-        sd((8, 768, 2048)), sd((8192, 8), jnp.int32),
-        sd((8192, 8), jnp.float32), sd((8192, 2048)))
-    assert compiled.as_text().count("tpu_custom_call") >= 9
+        step, sd((8192, 2048)), sd((8, 2048, hidden)),
+        sd((8, 2048, hidden)), sd((8, hidden, 2048)),
+        sd((8192, top_k), jnp.int32), sd((8192, top_k), jnp.float32),
+        sd((8192, 2048)))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 9
+    leaf = re.compile(rf"\b(\w+)\[(?:8,2048,{hidden}|8,{hidden},2048)\]")
+    assert {m.group(1) for m in leaf.finditer(text)} == {"bf16"}
+    # the loop over the buffers after the first carries the leaves in
+    # their own type
+    carried = [set(leaf.findall(line)) for line in text.splitlines()
+               if " while(" in line]
+    assert {"bf16"} in carried and all(c <= {"bf16"} for c in carried)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_mb * 1e6, temp
 
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(")
