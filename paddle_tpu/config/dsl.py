@@ -136,7 +136,7 @@ def fc(input, size: int, *, act: str = "tanh", name: str = None,
 def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
         experts_held: int = None, expert_offset: int = 0,
         shared_hidden: int = 0, routed_scaling_factor: float = 1.0,
-        norm_eps: float = 0.0, name: str = None,
+        norm_eps: float = 0.0, score: str = "sigmoid", name: str = None,
         layer_attr: dict = None) -> LayerOutput:
     """Mixture-of-experts FFN (TPU-native capability-add; output size =
     input size): sigmoid top-``top_k`` routing over ``num_experts``
@@ -144,7 +144,10 @@ def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
     SwiGLU experts, a shared expert of width ``shared_hidden`` (0: none).
     The layer holds experts ``expert_offset .. + experts_held`` (all of
     them by default) and computes their part of the sum: the chip's share
-    of an expert-parallel group (`parallel/moe.py`)."""
+    of an expert-parallel group (`parallel/moe.py`). ``score="softmax"``
+    routes by a softmax over all the experts instead (weights normalised
+    to sum 1, no bias, no scale) and hands the router's statistics on for
+    ``moe_balance_cost``."""
     src = _in(input)[0]
     extra = _layer_attr(layer_attr)
     attrs = {"num_experts": num_experts, "expert_hidden": expert_hidden,
@@ -154,9 +157,23 @@ def moe(input, *, expert_hidden: int, num_experts: int, top_k: int,
              **extra.pop("attrs", {})}
     if norm_eps:
         attrs["norm_eps"] = norm_eps
+    if score != "sigmoid":
+        attrs["score"] = score
     ldef = LayerDef(name=name or _auto_name("moe"), type="moe",
                     inputs=[Input(src.name)], bias=False, attrs=attrs,
                     **extra)
+    return _add(ldef)
+
+
+def moe_balance_cost(layers, *, coeff: float,
+                     name: str = None) -> LayerOutput:
+    """The load-balancing term of every expert layer in ``layers`` (each
+    built with ``score="softmax"``) together, times ``coeff``: one cost for
+    the step, to be added to the model's loss (`layers/moe.py`)."""
+    ldef = LayerDef(name=name or _auto_name("moe_balance_cost"),
+                    type="moe_balance_cost",
+                    inputs=[Input(x.name) for x in _in(layers)], bias=False,
+                    attrs={"coeff": coeff})
     return _add(ldef)
 
 

@@ -18,6 +18,18 @@ rows the fullest held expert got and the mean held expert's,
 ``moe_experts_active``, how many held experts got any row, and
 ``moe_turns``, the chunks of sorted rows the expert loop took (1 for the
 usual batch: more says the overflow path ran).
+
+``dsl.moe(..., score="softmax")`` routes by a softmax over all the
+experts and hands the router's statistics on in its output's
+``state["balance"]`` (``parallel/moe.py:balance_sums``), which one
+``dsl.moe_balance_cost(layers, coeff=...)`` reads from every expert
+layer of the model together: ``coeff * E * sum_e c_e P_e`` over the
+(layer, token) rows of the step, ``c_e`` the share of the choices that
+went to expert ``e`` and ``P_e`` its mean probability (Qwen3-MoE's
+``load_balancing_loss_func``). Its counter ``moe_balance`` is that term
+over ``k``: 1 at a balanced router, ``E / k`` at one that sends every
+token to one expert. Its operations, and the statistics' in the expert
+layers, lie under the inner scope ``moe_balance``.
 """
 
 from __future__ import annotations
@@ -89,11 +101,12 @@ class MoELayer(LayerImpl):
             x = a.value.reshape(-1, shape[-1])
             # padding is routed nowhere: it takes no expert's rows
             live = a.mask.reshape(-1) if a.mask is not None else None
-        y, rows, turns = moe_ffn(
+        y, rows, turns, balance = moe_ffn(
             params, x, top_k=int(cfg.attrs["top_k"]),
             scale=float(cfg.attrs.get("routed_scaling_factor", 1.0)),
             offset=int(cfg.attrs.get("expert_offset") or 0), live=live,
-            norm_eps=float(cfg.attrs.get("norm_eps") or 0.0))
+            norm_eps=float(cfg.attrs.get("norm_eps") or 0.0),
+            score=cfg.attrs.get("score", "sigmoid"))
         with jax.named_scope("moe_combine"):
             y = y.reshape(shape)
         with jax.named_scope("moe_dispatch"):
@@ -102,4 +115,29 @@ class MoELayer(LayerImpl):
                         "moe_rows_mean": rows.mean(),
                         "moe_experts_active": (rows > 0).sum(),
                         "moe_turns": turns.astype(jnp.float32)}
-        return Argument(value=y, mask=a.mask, state={"counters": counters})
+        state = {"counters": counters}
+        if balance is not None:     # dead code unless a cost layer reads it
+            state["balance"] = balance
+        return Argument(value=y, mask=a.mask, state=state)
+
+
+@register_layer("moe_balance_cost")
+class MoeBalanceCostLayer(LayerImpl):
+    """The load-balancing term over every expert layer given as input
+    (each routed by a softmax): one value for the step, on every row
+    of the batch so that the trainer's mean over rows is that value."""
+
+    def infer(self, cfg, in_infos):
+        return ShapeInfo(size=1)
+
+    def apply(self, cfg, params, ins, ctx):
+        with jax.named_scope("moe_balance"):
+            sums = [a.state["balance"] for a in ins]
+            n = sum(s["tokens"] for s in sums)
+            share = sum(s["slots"] for s in sums) / n               # c_e
+            prob = sum(s["probs"] for s in sums) / n                # P_e
+            term = prob.shape[0] * jnp.sum(share * prob)
+            value = jnp.full((ins[0].value.shape[0], 1),
+                             float(cfg.attrs["coeff"]) * term)
+            counters = {"moe_balance": term / jnp.sum(share)}
+        return Argument(value=value, state={"counters": counters})

@@ -2,7 +2,7 @@
 it writes (a cost layer first; see the builder's docstring for the
 rest). The 2017 families (``lenet_mnist``, ``resnet``,
 ``lstm_text_classifier``, ``seq2seq_attention``, ``bilstm_crf_tagger``,
-``ctr_model``, ``build_gan``, ``vae``) and four decoder-only language
+``ctr_model``, ``build_gan``, ``vae``) and five decoder-only language
 models of today's kind, every size an argument named by its published
 ``config.json`` key: ``joyai_llm_flash`` (latent attention, every layer
 alike, a multi-token-prediction module) and ``laguna`` (grouped-query
@@ -16,7 +16,9 @@ loss weighted over the passes (``looped_lm_cost``); it shares
 ``lfm2_moe``'s layers differ in operator: gated short convolutions
 (``dsl.short_conv``) three to one with grouped-query attention under a
 per-head q/k normalisation, the expert layer without a shared expert, and
-a head tied to the embedding (``lm_cost(tied_to=)``).
+a head tied to the embedding (``lm_cost(tied_to=)``). ``mellum2`` shares
+``laguna``'s blocks, with every layer's experts routed by a softmax and
+trained against a load-balancing term (``dsl.moe_balance_cost``).
 """
 
 from paddle_tpu.models.ctr import ctr_model  # noqa: F401
@@ -25,6 +27,7 @@ from paddle_tpu.models.joyai import joyai_llm_flash  # noqa: F401
 from paddle_tpu.models.laguna import laguna  # noqa: F401
 from paddle_tpu.models.lenet import lenet_mnist  # noqa: F401
 from paddle_tpu.models.lfm2 import lfm2_moe  # noqa: F401
+from paddle_tpu.models.mellum import mellum2  # noqa: F401
 from paddle_tpu.models.ouro import ouro  # noqa: F401
 from paddle_tpu.models.resnet import resnet  # noqa: F401
 from paddle_tpu.models.lstm_text import lstm_text_classifier  # noqa: F401

@@ -68,28 +68,13 @@ def laguna(*, vocab_size: int = 100352, hidden_size: int = 2048,
         raise ValueError("layer_types, num_attention_heads_per_layer and "
                          "mlp_layer_types name the same layers")
     remat = {"recompute": True} if recompute else None
-    eps = rms_norm_eps
-    rope = rope_parameters or {}
 
     def attention(x, i):
-        kind = layer_types[i]
-        if kind not in (FULL, SLIDING):
-            raise ValueError(f"layer {i}: no attention of kind {kind!r}")
-        r = rope.get(kind, {})
-        rotary_dim = int(head_dim * r.get("partial_rotary_factor", 1))
-        yarn = None
-        if r.get("rope_type", "default") == "yarn":
-            yarn = {k: r[k] for k in (
-                "factor", "original_max_position_embeddings", "beta_fast",
-                "beta_slow", "attention_factor") if k in r}
-        return dsl.gqa_attention(
-            x, num_heads=num_attention_heads_per_layer[i],
-            num_kv_heads=num_key_value_heads, head_dim=head_dim,
-            window=sliding_window if kind == SLIDING else None,
-            rotary_dim=rotary_dim,
-            rope_theta=float(r.get("rope_theta", 10000.0)), yarn=yarn,
-            gate=gating, block=attention_block, layer_attr=remat,
-            name=f"blk{i}_{'swa' if kind == SLIDING else 'attn'}")
+        return attention_layer(
+            x, i, layer_types[i], num_heads=num_attention_heads_per_layer[i],
+            num_key_value_heads=num_key_value_heads, head_dim=head_dim,
+            sliding_window=sliding_window, rope_parameters=rope_parameters,
+            gate=gating, block=attention_block, layer_attr=remat)
 
     def feed_forward(x, i):
         if mlp_layer_types[i] == "dense":
@@ -103,20 +88,68 @@ def laguna(*, vocab_size: int = 100352, hidden_size: int = 2048,
             routed_scaling_factor=moe_routed_scaling_factor,
             name=f"blk{i}_moe")
 
+    words, final, _ = decoder(vocab_size, hidden_size, depth, attention,
+                              feed_forward, rms_norm_eps)
+    cost, out = untied_head(final, words, vocab_size, loss_chunk)
+    return cost, out, ["words"]
+
+
+def attention_layer(x, i, kind, *, num_heads, num_key_value_heads,
+                    head_dim, sliding_window, rope_parameters, gate,
+                    qk_norm=False, qk_norm_eps=1e-6, block, layer_attr):
+    """Layer ``i``'s grouped-query attention of ``kind``: a window of
+    ``sliding_window`` keys and ``rope_parameters.sliding_attention``'s
+    rotary turn (``blk<i>_swa``), or the whole sequence and
+    ``rope_parameters.full_attention``'s, YaRN where its ``rope_type``
+    says so (``blk<i>_attn``); rotary over ``partial_rotary_factor`` of
+    the head (all of it by default)."""
+    if kind not in (FULL, SLIDING):
+        raise ValueError(f"layer {i}: no attention of kind {kind!r}")
+    r = (rope_parameters or {}).get(kind, {})
+    rotary_dim = int(head_dim * r.get("partial_rotary_factor", 1))
+    yarn = None
+    if r.get("rope_type", "default") == "yarn":
+        yarn = {k: r[k] for k in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow", "attention_factor") if k in r}
+    return dsl.gqa_attention(
+        x, num_heads=num_heads, num_kv_heads=num_key_value_heads,
+        head_dim=head_dim, window=sliding_window if kind == SLIDING else None,
+        rotary_dim=rotary_dim,
+        rope_theta=float(r.get("rope_theta", 10000.0)), yarn=yarn, gate=gate,
+        qk_norm=qk_norm, qk_norm_eps=qk_norm_eps, block=block,
+        layer_attr=layer_attr,
+        name=f"blk{i}_{'swa' if kind == SLIDING else 'attn'}")
+
+
+def decoder(vocab_size, hidden_size, depth, attention, feed_forward, eps):
+    """``(words, final, feed_forwards)``: the ids' data layer, the final
+    RMSNorm of ``depth`` pre-norm residual blocks over their embedding,
+    ``h = x + attention(N(x), i)``, ``x' = h + feed_forward(N(h), i)``,
+    and every block's feed-forward layer."""
     words = dsl.data(name="words", size=vocab_size, is_sequence=True)
     x = dsl.embedding(input=words, size=hidden_size, vocab_size=vocab_size,
                       name="embed")
+    feed_forwards = []
     for i in range(depth):
         tag = f"blk{i}"
         a = attention(dsl.rms_norm(x, epsilon=eps, name=f"{tag}_a_norm"), i)
         h = dsl.addto([x, a], name=f"{tag}_attn_add")
         f = feed_forward(dsl.rms_norm(h, epsilon=eps, name=f"{tag}_f_norm"),
                          i)
+        feed_forwards.append(f)
         x = dsl.addto([h, f], name=f"{tag}_ffn_add")
     final = dsl.rms_norm(x, epsilon=eps, name="out_norm")
+    return words, final, feed_forwards
+
+
+def untied_head(final, words, vocab_size, loss_chunk):
+    """``(cost, softmax_output)``: the head, a leaf of its own, fused with
+    the mean cross-entropy of position ``i`` against ``t_{i+1}``, and the
+    same head's softmax for inference (no part of the cost's graph)."""
     head = ParamAttr(name="_out_head.w0")
     cost = dsl.lm_cost(final, words, vocab_size=vocab_size, shift=1,
                        chunk=loss_chunk, name="out_head", param_attr=head)
     out = dsl.fc(input=final, size=vocab_size, act="softmax",
                  bias_attr=False, param_attr=head, name="output")
-    return cost, out, ["words"]
+    return cost, out

@@ -10,7 +10,11 @@ today's large open models carry in their ``config.json``):
   ``k`` chosen are the top ``k`` of ``s + b`` (``b``: a selection bias
   that no gradient trains); their weights are ``s[chosen] /
   sum(s[chosen]) * scale``. The gradient reaches ``W_r`` through the
-  weights; the choice itself is piecewise constant.
+  weights; the choice itself is piecewise constant. Or (``score=
+  "softmax"``, Qwen3-MoE's form) ``p = softmax(u W_r)``, the top ``k`` of
+  ``p`` chosen, weights ``p[chosen] / sum(p[chosen])``, with the
+  load-balancing term of Switch Transformer (arXiv:2101.03961 §2.2) over
+  ``p``: ``balance_sums`` gives a layer's share of it.
 - experts: ``E(u) = (silu(u W_g) * (u W_u)) W_d``, no bias; the routed
   part of the result is ``sum_i w_i E_i(u)`` over the chosen experts.
 - expert parallelism: a device holds experts ``offset .. offset + held``.
@@ -29,12 +33,16 @@ today's large open models carry in their ``config.json``):
   (``lax.fori_loop`` with a traced bound), so a batch in which every
   token chooses held experts comes out exact, and both time and memory
   follow the rows really routed here, not the worst case ``T * min(k,
-  held)``. The loop sits inside a ``custom_vjp`` whose backward makes
-  the same turns, recomputing each buffer from the layer's input (a
-  loop of traced length has no transpose of its own).
+  held)``. The buffers after the first hold at most 8,192 rows: a
+  buffer costs what its size costs, held rows or not, and what spills
+  past the first is mostly a few rows. The loop sits inside a
+  ``custom_vjp`` whose backward makes the same turns, recomputing each
+  buffer from the layer's input (a loop of traced length has no
+  transpose of its own).
 - scopes: every operation lies under one of ``moe_route``,
   ``moe_dispatch`` (plan, sort, a chunk's gather, the backward rule's sums
-  over the chunks), ``moe_experts``, ``moe_combine``, ``moe_shared``, so
+  over the chunks), ``moe_experts``, ``moe_combine``, ``moe_shared`` and
+  ``moe_balance`` (the router's statistics for the balancing term), so
   that a device trace divides the layer's time (``docs/observability.md``).
 """
 
@@ -52,6 +60,7 @@ from paddle_tpu.ops import common
 
 _HIGHEST = lax.Precision.HIGHEST
 _ROW_TILE = 512     # rows a grouped-product tile holds on the TPU
+_SPILL_ROWS = 8192  # the most rows a buffer after the first holds
 
 
 def init_moe_params(key, d_model: int, d_hidden: int, n_experts: int,
@@ -83,24 +92,59 @@ def swiglu(x, wg, wu, wd):
     return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
 
 
-def route(x, wr, br, top_k: int, scale: float, norm_eps: float = 0.0):
-    """``(ids [T,k] int32, weights [T,k] float32)``: sigmoid scores over
-    all the experts in float32 (the product at ``highest``: a TPU's
-    default float32 product rounds its operands to bfloat16), the choice
-    by ``s + b``, the weights by ``s``, normalised (by their sum, plus
-    ``norm_eps`` where a model publishes one) and scaled."""
+def route(x, wr, br, top_k: int, scale: float, norm_eps: float = 0.0,
+          score: str = "sigmoid"):
+    """``(ids [T,k] int32, weights [T,k] float32, scores [T,E] float32)``:
+    scores over all the experts in float32 (the product at ``highest``: a
+    TPU's default float32 product rounds its operands to bfloat16), the
+    choice and the weights from them. ``score`` ``"sigmoid"``: ``s =
+    sigmoid(u W_r)``, the choice by ``s + b``, the weights ``s[chosen]``
+    normalised (by their sum, plus ``norm_eps`` where a model publishes
+    one) and scaled. ``"softmax"``: ``p = softmax(u W_r)``, the choice by
+    ``p`` (no bias), the weights ``p[chosen] / sum(p[chosen])`` (no eps,
+    no scale: asking for either is an error)."""
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"no router score {score!r}")
+    if score == "softmax" and (scale != 1 or norm_eps):
+        raise ValueError("a softmax router's weights take no scale and no "
+                         f"eps (scale {scale}, norm_eps {norm_eps})")
     with jax.named_scope("moe_route"):
-        s = jax.nn.sigmoid(jnp.matmul(
-            x.astype(jnp.float32), wr.astype(jnp.float32),
-            precision=_HIGHEST))
-        _, ids = lax.top_k(s + lax.stop_gradient(br.astype(jnp.float32)),
-                           top_k)
+        logits = jnp.matmul(x.astype(jnp.float32), wr.astype(jnp.float32),
+                            precision=_HIGHEST)
+        if score == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            _, ids = lax.top_k(s, top_k)
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, ids = lax.top_k(
+                s + lax.stop_gradient(br.astype(jnp.float32)), top_k)
         w = jnp.take_along_axis(s, ids, axis=-1)
         total = jnp.sum(w, axis=-1, keepdims=True)
         if norm_eps:        # added only where asked: the others' text stays
             total = total + norm_eps
-        w = w / total * scale
-    return ids.astype(jnp.int32), w
+        w = w / total
+        if score == "sigmoid":
+            w = w * scale
+    return ids.astype(jnp.int32), w, s
+
+
+def balance_sums(ids, probs, live=None):
+    """A layer's share of the load-balancing term, over its live tokens:
+    ``{"probs": sum_n p_n [E], "slots": [E] the (token, choice) pairs
+    that chose each expert, "tokens": the live tokens}``, float32. The
+    slots are counts of a choice and carry no gradient; ``probs`` does.
+    Summed over layers, ``E * sum_e (slots_e / N) (probs_e / N)`` is the
+    term (``N`` the summed tokens): ``k`` at a balanced router, ``E`` at
+    one that sends every token to one expert."""
+    with jax.named_scope("moe_balance"):
+        T, E = probs.shape
+        live = (jnp.ones((T,), jnp.float32) if live is None
+                else live.reshape(-1).astype(jnp.float32))[:, None]
+        slots = jnp.sum(ids[..., None] == jnp.arange(E, dtype=ids.dtype),
+                        axis=1, dtype=jnp.float32)          # [T, E]
+        return {"probs": jnp.sum(probs * live, axis=0),
+                "slots": jnp.sum(slots * live, axis=0),
+                "tokens": jnp.sum(live)}
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -182,41 +226,73 @@ def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into):
         return into.at[token].add(o.astype(jnp.float32) * g[:, None])
 
 
+def _spill_rows(R: int) -> int:
+    """Rows of each chunk after the first, which takes ``R``. A chunk
+    costs what its size costs, held rows or not (each of its rows is
+    gathered, selected and scattered), and what a batch sends past the
+    first chunk is mostly a small part of it: so these chunks hold at
+    most ``_SPILL_ROWS``."""
+    return min(R, _SPILL_ROWS)
+
+
+def _first_row(c, R: int):
+    """Where chunk ``c`` starts among the sorted pairs: the first holds
+    ``R`` rows, each after it ``_spill_rows(R)``."""
+    S = _spill_rows(R)
+    if S == R:
+        return c * R
+    return c * S + jnp.minimum(c, 1) * (R - S)
+
+
 def _sorted_pairs(key, R: int):
     """The (token, choice) pairs sorted by expert, held experts' first,
-    padded to whole chunks of ``R``."""
+    padded to a first chunk of ``R`` and whole chunks after it."""
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        return jnp.pad(order, (0, (-order.shape[0]) % R))
+        n = order.shape[0]
+        pad = max(R - n, 0) + (-max(n - R, 0)) % _spill_rows(R)
+        return jnp.pad(order, (0, pad))
 
 
 def _turns(R, counts):
-    """The chunks of ``R`` sorted rows that the held experts' rows fill."""
+    """The chunks that the held experts' rows fill: a first of ``R``
+    sorted rows, then as many of ``_spill_rows(R)`` as the rest needs."""
+    S = _spill_rows(R)
     with jax.named_scope("moe_dispatch"):
-        return (jnp.sum(counts) + R - 1) // R
+        rows = jnp.sum(counts)
+        if S == R:
+            return (rows + R - 1) // R
+        return jnp.where(rows > 0,
+                         1 + (jnp.maximum(rows - R, 0) + S - 1) // S, 0)
 
 
 def _over_chunks(R, counts, init, step, first=0):
-    """``step(c, carry)`` for every chunk of ``R`` sorted rows from
-    ``first`` on that holds a held expert's row: as many turns as the
-    rows need, no more. The loop itself lies under no inner scope, or
-    every operation of its body would carry two."""
+    """``step(c, carry)`` for every chunk of sorted rows from ``first``
+    on that holds a held expert's row: as many turns as the rows need,
+    no more. The loop itself lies under no inner scope, or every
+    operation of its body would carry two."""
     return lax.fori_loop(first, _turns(R, counts), step, init)
 
 
-def _chunk_pairs(order, c, R):
+def _chunk_pairs(order, c, R: int, rows: int):
+    """The ``rows`` sorted pairs of chunk ``c``."""
     with jax.named_scope("moe_dispatch"):
-        return lax.dynamic_slice(order, (c * R,), (R,))
+        return lax.dynamic_slice(order, (_first_row(c, R),), (rows,))
 
 
 def _routed_sum(R, order, x, wg, wu, wd, gates, counts):
-    def step(c, y):
-        return _chunk(x, wg, wu, wd, gates, _chunk_pairs(order, c, R),
-                      counts, c * R, y)
+    S = _spill_rows(R)
+
+    def chunk(c, rows, y):
+        return _chunk(x, wg, wu, wd, gates, _chunk_pairs(order, c, R, rows),
+                      counts, _first_row(c, R), y)
 
     with jax.named_scope("moe_combine"):
         y = jnp.zeros(x.shape, jnp.float32)
-    return _over_chunks(R, counts, y, step)
+    if S == R:      # one loop over chunks of one size, from the first
+        return _over_chunks(R, counts, y, lambda c, y: chunk(c, R, y))
+    return _over_chunks(R, counts, chunk(0, R, y),
+                        lambda c, y: chunk(c, S, y), first=1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -240,26 +316,27 @@ def _routed_bwd(R, saved, dy):
     the add of two partials in their own type is their float32 sum
     rounded once, as a float32 sum over the chunks would give; from
     three on each turn's add rounds: a batch that sends this chip more
-    than four times a uniform router's rows. The rule's own operations
-    (a chunk's pairs, the sums) open ``moe_dispatch`` themselves: a
-    hand-written rule inherits no forward scope. What ``_chunk`` opens
-    it opens here too, recomputed and transposed."""
+    rows than the first chunk and one after it hold. The rule's own
+    operations (a chunk's pairs, the sums) open ``moe_dispatch``
+    themselves: a hand-written rule inherits no forward scope. What
+    ``_chunk`` opens it opens here too, recomputed and transposed."""
     *floats, order, counts = saved
     with jax.named_scope("moe_dispatch"):
         zero = jnp.zeros(floats[0].shape, jnp.float32)
 
-    def grads_of(c):
-        pairs = _chunk_pairs(order, c, R)
+    def grads_of(c, rows):
+        pairs = _chunk_pairs(order, c, R, rows)
         _, vjp = jax.vjp(
-            lambda *f: _chunk(*f, pairs, counts, c * R, zero), *floats)
+            lambda *f: _chunk(*f, pairs, counts, _first_row(c, R), zero),
+            *floats)
         return vjp(dy)
 
     def step(c, grads):
-        ds = grads_of(c)
+        ds = grads_of(c, _spill_rows(R))
         with jax.named_scope("moe_dispatch"):
             return tuple(g + d for g, d in zip(grads, ds))
 
-    return (*_over_chunks(R, counts, grads_of(0), step, first=1),
+    return (*_over_chunks(R, counts, grads_of(0, R), step, first=1),
             None, None)
 
 
@@ -283,21 +360,26 @@ def routed_experts(x, wg, wu, wd, ids, gates, *, n_experts: int,
 
 
 def moe_ffn(params, x, *, top_k: int, scale: float = 1.0, offset=0,
-            live=None, shared: bool = True, norm_eps: float = 0.0):
-    """The layer on one device: ``x [T, d]`` -> ``(y [T, d], rows,
-    turns)`` (``routed_experts``'s counts).
+            live=None, shared: bool = True, norm_eps: float = 0.0,
+            score: str = "sigmoid"):
+    """The layer on one device: ``x [T, d]`` -> ``(y [T, d], rows, turns,
+    balance)`` (``routed_experts``'s counts; ``balance_sums`` of a softmax
+    router's scores, None for a sigmoid router, whose scores are no
+    distribution).
     ``params["wg"]`` holds ``held`` experts, those from ``offset`` on, of
     the ``params["wr"].shape[-1]`` the router scores. ``live`` ([T], 0 or
     1) marks real tokens: padding is routed nowhere. ``shared`` False
     leaves the shared expert out (``make_moe`` adds it once)."""
-    ids, w = route(x, params["wr"], params["br"], top_k, scale, norm_eps)
+    ids, w, s = route(x, params["wr"], params["br"], top_k, scale, norm_eps,
+                      score)
     y, rows, turns = routed_experts(
         x, params["wg"], params["wu"], params["wd"], ids, w,
         n_experts=params["wr"].shape[-1], offset=offset, live=live)
     if shared and "sg" in params:
         with jax.named_scope("moe_shared"):
             y = y + swiglu(x, params["sg"], params["su"], params["sd"])
-    return y, rows, turns
+    balance = balance_sums(ids, s, live) if score == "softmax" else None
+    return y, rows, turns, balance
 
 
 _EXPERT_LEAVES = ("wg", "wu", "wd")

@@ -18,6 +18,7 @@ JOYAI = "joyai_llm_flash_ep32.train_bs2_seq4096"
 LAGUNA = "laguna_xs2_ep32.train_bs1_seq8192"
 OURO = "ouro_2_6b_pp6.train_bs1_seq4096"
 LFM2 = "lfm2_24b_a2b_ep8.train_bs1_seq8192"      # appended by PR 37
+MELLUM = "mellum2_12b_a2b5_ep8.train_bs2_seq8192"
 
 STEP = "jit(step)/jit(main)/"
 FWD = STEP + "jvp({0})/"
@@ -75,8 +76,9 @@ EXPECTED = {
     "moe_dispatch_ms": 1e3 * (0.004 + 0.030 + 0.006 + 0.002) / 10,
     "moe_combine_ms": 1e3 * (0.007 + 0.009) / 10,
 }
-CELLS = {name: [JOYAI, LAGUNA, OURO, LFM2] if not name.startswith("moe")
-         else [JOYAI, LAGUNA, LFM2] for name in EXPECTED}
+CELLS = {name: [JOYAI, LAGUNA, OURO, LFM2, MELLUM]
+         if not name.startswith("moe") else [JOYAI, LAGUNA, LFM2, MELLUM]
+         for name in EXPECTED}
 
 # a model with none of these layers: the LSTM cell's scopes
 OTHER = [(STEP + "jvp(lstm0)/while/body/dot_general", 0.0, 0.5),
@@ -158,12 +160,13 @@ def test_the_benchmark_lists_a_parts_metric(name):
                      "moves": "samples_per_s", "workloads": CELLS[name]}
     assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
                                        name + ".py"))
-    # appended after every entry the benchmark had, and PR 37's three
-    # after them
-    assert [m["name"] for m in bench["per_layer"][-9:]] == [
+    # appended after every entry the benchmark had, then the short
+    # convolution's and the q/k norm's three, then the balancing term's two
+    assert [m["name"] for m in bench["per_layer"][-11:]] == [
         "attn_proj_ms", "attn_rope_ms", "attn_out_ms", "recompute_ms",
         "moe_dispatch_ms", "moe_combine_ms", "short_conv_roofline",
-        "short_conv_core_ms", "attn_qk_norm_ms"]
+        "short_conv_core_ms", "attn_qk_norm_ms", "moe_balance_ms",
+        "moe_balance_ratio"]
 
 
 def test_the_split_of_a_kept_trace_by_part_and_direction():

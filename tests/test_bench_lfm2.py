@@ -188,7 +188,10 @@ def test_the_benchmark_names_the_cell_and_its_metrics():
     for name in ("short_conv_roofline", "short_conv_core_ms",
                  "attn_qk_norm_ms"):
         m = {m["name"]: m for m in bench["per_layer"]}[name]
-        assert m["workloads"] == [FULL_CELL]
+        # the Mellum2 cell has q/k norms too
+        assert m["workloads"] == [FULL_CELL] + (
+            ["mellum2_12b_a2b5_ep8.train_bs2_seq8192"]
+            if name == "attn_qk_norm_ms" else [])
         assert m["moves"] == "samples_per_s"
         assert m["source"] == "device_trace"
     limits = _load(ROOT, "benchmark", "cells", FULL_CELL + ".json")["limits"]

@@ -285,9 +285,9 @@ def test_shares_add_up():
     with jax.default_matmul_precision("highest"):
         total = moe_lib.swiglu(x, params["sg"], params["su"], params["sd"])
         for i in range(32):
-            part, _, _ = moe_lib.moe_ffn(share(8 * i, 8 * i + 8), x,
-                                         top_k=k, scale=2.5, offset=8 * i,
-                                         shared=False)
+            part, _, _, _ = moe_lib.moe_ffn(share(8 * i, 8 * i + 8), x,
+                                            top_k=k, scale=2.5, offset=8 * i,
+                                            shared=False)
             if i == 1:
                 np.testing.assert_allclose(
                     np.asarray(part), np.asarray(reference(

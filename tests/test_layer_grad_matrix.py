@@ -705,6 +705,11 @@ COVERED_ELSEWHERE = {
            "so a numeric difference can cross a boundary; the layer's "
            "gradients are compared there with the plain reference's, "
            "leaf by leaf, with sharded parity and the no-drop case)",
+    "moe_balance_cost": "tests/test_mellum_model.py (it reads the router "
+                        "statistics that expert layers built with "
+                        "balance hand on; its value and its gradient to "
+                        "every router logit against a hand count and the "
+                        "plain reference)",
     "recurrent_layer_group": "tests/test_recurrent_group.py",
     "beam_search_group": "tests/test_generation.py, tests/test_seq_models.py",
     "group_output": "tests/test_recurrent_group.py",

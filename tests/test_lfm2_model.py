@@ -364,7 +364,7 @@ def test_shares_add_up():
     total = 0.0
     with jax.default_matmul_precision("highest"):
         for lo in (0, 4):
-            part, rows, _ = moe_lib.moe_ffn(
+            part, rows, _, _ = moe_lib.moe_ffn(
                 share(lo, lo + 4), x, top_k=k, offset=lo, norm_eps=1e-6)
             np.testing.assert_allclose(
                 np.asarray(part), np.asarray(by_reference(
@@ -374,8 +374,9 @@ def test_shares_add_up():
                                np.asarray(by_reference(params, e, 0)),
                                rtol=2e-5, atol=5e-6)
     # the normaliser's eps is in the weights, and only where asked
-    ids, plain_w = moe_lib.route(x, params["wr"], params["br"], k, 1.0)
-    _, eps_w = moe_lib.route(x, params["wr"], params["br"], k, 1.0, 1e-6)
+    ids, plain_w, _ = moe_lib.route(x, params["wr"], params["br"], k, 1.0)
+    _, eps_w, _ = moe_lib.route(x, params["wr"], params["br"], k, 1.0,
+                                 1e-6)
     np.testing.assert_allclose(np.asarray(plain_w.sum(-1)), 1.0, rtol=1e-6)
     assert np.all(np.asarray(eps_w) <= np.asarray(plain_w))
     assert "1e-06" not in str(jax.make_jaxpr(

@@ -80,8 +80,9 @@ def test_layer_matches_plain_reference_and_trains(setup):
 
 def test_selection_bias_chooses_but_does_not_weigh(setup):
     params, x = setup
-    ids0, w0 = moe_lib.route(x, params["wr"], jnp.zeros((E,)), K, SCALE)
-    ids1, w1 = moe_lib.route(x, params["wr"], params["br"], K, SCALE)
+    ids0, w0, _ = moe_lib.route(x, params["wr"], jnp.zeros((E,)), K,
+                                SCALE)
+    ids1, w1, _ = moe_lib.route(x, params["wr"], params["br"], K, SCALE)
     assert np.any(np.asarray(ids0) != np.asarray(ids1))
     s = jax.nn.sigmoid(jnp.matmul(x, params["wr"], precision="highest"))
     chosen = jnp.take_along_axis(s, ids1, axis=-1)
@@ -107,7 +108,8 @@ def test_no_token_is_dropped_at_any_skew(setup, mode):
     params, x = setup
     held = share(_collapsed(params), 2, 4)
     with common.force_mode(mode), common.record_dispatch() as tally:
-        y, rows, turns = moe_ffn(held, x, top_k=K, scale=SCALE, offset=2)
+        y, rows, turns, _ = moe_ffn(held, x, top_k=K, scale=SCALE,
+                                    offset=2)
     assert list(np.asarray(rows)) == [T, T]
     assert mode in tally["moe_grouped_matmul"]
     np.testing.assert_allclose(
@@ -119,20 +121,26 @@ def test_no_token_is_dropped_at_any_skew(setup, mode):
 
 
 @pytest.mark.parametrize("mode", ["ref", "interpret"])
-@pytest.mark.parametrize("turns", [0, 2, 4])
-def test_gradients_over_any_number_of_chunks(setup, mode, turns,
-                                             monkeypatch):
+@pytest.mark.parametrize("turns,first,spill", [
+    (0, None, None), (2, T * K // 2, None), (4, T * K // 4, None),
+    (5, T * K // 2, T * K // 8), (4, T * K // 2, 24)])
+def test_gradients_over_any_number_of_chunks(setup, mode, turns, first,
+                                             spill, monkeypatch):
     """The collapsed router's gradients, every leaf's and the input's,
     equal the plain reference's where the rows take 2 chunks and, with a
     smaller buffer, 4 (the loop after the first chunk turns once and
     three times), and where no row is held here (0 chunks: the first
-    chunk's gradients, computed outside any loop, are zero)."""
+    chunk's gradients, computed outside any loop, are zero). With chunks
+    after the first smaller than it (``spill`` rows), 4 and 5 chunks:
+    one of 64 rows and 64 more in chunks of 16, or of 24, which leave
+    the last chunk reaching past the pairs into their padding."""
     params, x = setup
     lo = 4 if turns == 0 else 2
     held = share(_collapsed(params), lo, lo + 2)
-    if turns:
-        monkeypatch.setattr(moe_lib, "_chunk_rows",
-                            lambda *a: T * K // turns)
+    if first:
+        monkeypatch.setattr(moe_lib, "_chunk_rows", lambda *a: first)
+    if spill:
+        monkeypatch.setattr(moe_lib, "_SPILL_ROWS", spill)
     target = jax.random.normal(jax.random.PRNGKey(2), (T, D))
 
     def loss(f):
@@ -170,7 +178,7 @@ def test_bfloat16_gradients_are_the_float32_sum_over_chunks_rounded_once(
     held = share(_collapsed(params), 2, 4)
     bf = {k: v.astype(jnp.bfloat16) for k, v in held.items()}
     xb = x.astype(jnp.bfloat16)
-    ids, gates = moe_lib.route(xb, held["wr"], held["br"], K, SCALE)
+    ids, gates, _ = moe_lib.route(xb, held["wr"], held["br"], K, SCALE)
     key, counts = moe_lib._plan(ids, 2, 2, None)
     R = T * K // turns
     dy = jax.random.normal(jax.random.PRNGKey(4), (T, D))
@@ -214,8 +222,8 @@ def test_shares_add_up():
     total = shared
     for i in range(4):
         mine = share(params, 8 * i, 8 * i + 8)
-        part, rows, _ = moe_ffn(mine, x, top_k=K, scale=SCALE,
-                                offset=8 * i, shared=False)
+        part, rows, _, _ = moe_ffn(mine, x, top_k=K, scale=SCALE,
+                                   offset=8 * i, shared=False)
         np.testing.assert_allclose(
             np.asarray(part), np.asarray(reference(
                 mine, x, held=8, offset=8 * i, shared=False)),
@@ -256,11 +264,11 @@ def test_masked_tokens_take_no_rows(setup):
     """Padding is routed nowhere: it takes no expert's rows, gets the
     shared expert only, and leaves the live tokens' outputs alone."""
     params, x = setup
-    y_ref, rows_ref, _ = moe_ffn(params, x[:8], top_k=K, scale=SCALE)
+    y_ref, rows_ref, _, _ = moe_ffn(params, x[:8], top_k=K, scale=SCALE)
     pad = jax.random.normal(jax.random.PRNGKey(3), (24, D))
     live = jnp.concatenate([jnp.zeros(24), jnp.ones(8)])
-    y_pad, rows, _ = moe_ffn(params, jnp.concatenate([pad, x[:8]]),
-                             top_k=K, scale=SCALE, live=live)
+    y_pad, rows, _, _ = moe_ffn(params, jnp.concatenate([pad, x[:8]]),
+                                top_k=K, scale=SCALE, live=live)
     np.testing.assert_allclose(np.asarray(y_pad[24:]), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_ref))

@@ -373,11 +373,13 @@ def test_lstm_scan_backward_streams_no_weight_gradient(one_chip):
 _PARTS = {"attn": ("attn_qkv", "attn_qk_norm", "attn_rope", "mla_core",
                    "attn_core", "attn_out"),
           "moe": ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
-                  "moe_shared"),
+                  "moe_shared", "moe_balance"),
           "sconv": ("sconv_in", "sconv_core", "sconv_out")}
-# ... and PR 37 the short convolution's three and ``attn_qk_norm``
+# ... then the short convolution's three, ``attn_qk_norm`` and
+# ``moe_balance``, the balancing term's
 _ADDED = {"attn_qkv", "attn_rope", "attn_out", "moe_dispatch", "moe_shared",
-          "attn_qk_norm", "sconv_in", "sconv_core", "sconv_out"}
+          "attn_qk_norm", "sconv_in", "sconv_core", "sconv_out",
+          "moe_balance"}
 _NAMED = re.compile(
     r"^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(.*op_name=\"([^\"]+)\"",
     re.M)
@@ -422,6 +424,13 @@ def _layer_step(kind, one_chip):
     elif kind == "sconv":
         layer = dsl.short_conv(x, kernel=3, name="blk0_sconv",
                                layer_attr=remat)
+    elif kind == "balanced":        # the Mellum2 cell's: a softmax router
+        layer = dsl.moe(
+            x, expert_hidden=896, num_experts=64, top_k=8, experts_held=8,
+            expert_offset=8, score="softmax", name="blk0_moe")
+        dsl.moe_balance_cost([layer], coeff=0.001, name="moe_balance")
+        return (*_output_and_gradients(layer.name, one_chip, 1024,
+                                       costs=["moe_balance"]), layer.name)
     else:
         layer = dsl.moe(
             x, expert_hidden=768, num_experts=256, top_k=8, experts_held=8,
@@ -430,16 +439,17 @@ def _layer_step(kind, one_chip):
     return (*_output_and_gradients(layer.name, one_chip, 1024), layer.name)
 
 
-def _output_and_gradients(out_name, one_chip, tokens):
+def _output_and_gradients(out_name, one_chip, tokens, costs=()):
     """``(step, shapes)`` over the DSL's current graph, whose one input
     ``x`` is a row of ``tokens`` of 2,048 in bfloat16 with its mask: the
     layer ``out_name``'s output and the gradients of the parameters and
-    of ``x``."""
+    of ``x``, with the layers ``costs``' values added to the loss that
+    is differentiated."""
     from paddle_tpu.config import dsl
     from paddle_tpu.core.argument import Argument
     from paddle_tpu.core.network import Network
 
-    net = Network(dsl.current_graph(), outputs=[out_name])
+    net = Network(dsl.current_graph(), outputs=[out_name, *costs])
 
     def sd(leaf):
         return jax.ShapeDtypeStruct(leaf.shape, jnp.bfloat16,
@@ -453,10 +463,12 @@ def _output_and_gradients(out_name, one_chip, tokens):
 
     def step(params, xv, m, g):
         def apply(params, xv):
-            return net.apply(params, {"x": Argument(value=xv, mask=m)},
-                             train=True)[out_name].value
-        out, back = jax.vjp(apply, params, xv)
-        return out, back(g)
+            outs = net.apply(params, {"x": Argument(value=xv, mask=m)},
+                             train=True)
+            return outs[out_name].value, [outs[c].value for c in costs]
+
+        (out, added), back = jax.vjp(apply, params, xv)
+        return out, back((g, [jnp.ones_like(c) for c in added]))
 
     return step, (params, rows, mask, rows)
 
@@ -501,7 +513,7 @@ def test_the_cores_backward_is_one_mosaic_call_inside_the_budget(
 
 
 @pytest.mark.parametrize("kind", ["latent", "full", "windowed", "experts",
-                                  "qk_norm", "sconv"])
+                                  "qk_norm", "sconv", "balanced"])
 def test_every_operation_of_a_layer_lies_in_one_inner_scope(
         one_chip, kind, monkeypatch):
     """A latent layer, a full and a windowed grouped-query layer, one
@@ -533,7 +545,8 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
     assert "attn_qkv" not in before and "moe_dispatch" not in before
     assert step_text.bare(text) == step_text.bare(before)
 
-    parts = _PARTS[{"experts": "moe", "sconv": "sconv"}.get(kind, "attn")]
+    parts = _PARTS[{"experts": "moe", "balanced": "moe",
+                    "sconv": "sconv"}.get(kind, "attn")]
     under = [(inst, op, path) for inst, op, path in _NAMED.findall(text)
              if f"jvp({name})" in path]
     assert len(under) > 100
@@ -555,6 +568,13 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
         assert {p for _, p in products} == {"moe_route", "moe_shared"}
         assert products["bwd", "moe_shared"] \
             == 2 * products["fwd", "moe_shared"] == 6
+    elif kind == "balanced":
+        # the router's product alone (no shared expert); the balancing
+        # statistics forward and, through the probabilities, backward
+        assert {p for _, p in products} == {"moe_route"}
+        ways = {("bwd" if f"transpose(jvp({name}))" in path else "fwd")
+                for _, _, path in under if "moe_balance" in path}
+        assert ways == {"fwd", "bwd"}
     elif kind == "sconv":
         # `W_in`'s product runs forward, again, and twice backward;
         # `W_out`'s output feeds nothing the backward pass needs
@@ -590,8 +610,8 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
                  "bwd" if f"transpose(jvp({name}))" in path else "fwd")
                 for _, _, path in under if "attn_qk_norm" in path}
         assert ways == {"fwd", "again", "bwd"}
-    core = {"latent": "mla_core", "experts": "moe_experts"}.get(
-        kind, "attn_core")
+    core = {"latent": "mla_core", "experts": "moe_experts",
+            "balanced": "moe_experts"}.get(kind, "attn_core")
     calls = [path for _, op, path in under
              if op == "custom-call" and "pallas_call" in path]
     assert len(calls) >= 2 and all(core in path for path in calls)
@@ -666,3 +686,60 @@ def test_the_short_convolutions_core_is_one_pass_forward_and_two_backward(
         == {"fwd": (1, 1), "again": (1, 1), "bwd": (2, 3)}, wide
     assert {t for f in wide.values() for outs in f for t in outs} \
         == {"bf16"}, wide
+
+
+def test_the_mellum2_cells_model_fits_the_chip_with_its_mosaic_calls(
+        one_chip):
+    """The Mellum2 cell's model at its shape (2 x 8,192 tokens, every
+    width as published, bfloat16 compute over float32 masters, the
+    routers float32): its loss and every gradient compiled for a
+    described v5e, from shapes alone. Its bytes lie well under the chip
+    (the trainer's step adds the moments and the update: 8.69 GB by
+    ``benchmark.rehearse``), and its Mosaic calls are pinned: the four
+    attention layers' forward kernels, the three sliding layers' fused
+    backward and the full layer's dK/dV and dQ (dQ's float32 slots for
+    its query heads over 8,192 positions do not fit the budget), and 24
+    grouped products an expert layer (forward 3 for the first buffer of
+    32,768 rows and 3 in the loop's body after it, over buffers of
+    8,192; the first buffer's backward 9, the recomputed forward among
+    them; the loop's body after it 9)."""
+    import json
+    from paddle_tpu import models
+    from paddle_tpu.config import dsl
+    from paddle_tpu.core.argument import Argument
+    from paddle_tpu.trainer.trainer import Topology
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2_12b_a2b5_ep8.json")) as f:
+        cfg = json.load(f)
+    dsl.reset()
+    cost = models.mellum2(**cfg["model"]["args"])[0]
+    net = Topology(cost).network
+    f32 = {n for n, spec in net.param_specs.items() if spec.compute_f32}
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {n: sd(v.shape, jnp.float32) for n, v in jax.eval_shape(
+        net.init_params, jax.random.PRNGKey(0)).items()}
+
+    def step(params, ids, mask):
+        def loss(p):
+            p = {n: v if n in f32 else v.astype(jnp.bfloat16)
+                 for n, v in p.items()}
+            out = net.apply(p, {"words": Argument(value=ids, mask=mask)},
+                            train=True)
+            return jnp.mean(out[cost.name].value.astype(jnp.float32))
+        return jax.value_and_grad(loss)(params)
+
+    with common.record_dispatch() as tally:
+        compiled = _compile(step, params, sd((2, 8192), jnp.int32),
+                            sd((2, 8192), jnp.float32))
+    assert tally == {"flash_attention": {"pallas": 4},
+                     "flash_backward": {"fused": 3, "split": 1},
+                     "moe_grouped_matmul": {"pallas": 48}}
+    assert compiled.as_text().count(_MOSAIC) == 4 + 3 + 2 + 4 * 24
+    ma = compiled.memory_analysis()
+    held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert held < 0.75 * 16_909_336_064, held

@@ -36,7 +36,7 @@ ATTENTION = ("attn_qkv", "attn_qk_norm", "attn_rope", "mla_core",
 KINDS = {"attn": ATTENTION, "swa": ATTENTION,
          "sconv": ("sconv_in", "sconv_core", "sconv_out"),
          "moe": ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
-                 "moe_shared")}
+                 "moe_shared", "moe_balance")}
 AGAIN = "rematted_computation"
 
 
