@@ -49,6 +49,7 @@ today's large open models carry in their ``config.json``):
 from __future__ import annotations
 
 import functools
+import importlib
 from typing import Dict, Optional
 
 import jax
@@ -152,29 +153,112 @@ def grouped_matmul(lhs, rhs, group_sizes):
     ``group_sizes [G]`` (their sum may fall short of ``R``): row r times
     its group's matrix; rows past the sum come out zero. (The kernel
     leaves them unwritten, and what lies there may be no number at all:
-    they are zeroed by a select, never by a product, here and, by the
-    select's own transpose, in the backward pass.)"""
-    R, k = lhs.shape
-    n = rhs.shape[-1]
+    they are zeroed by a select, never by a product, here and in the
+    left operand's gradient.)"""
     group_sizes = group_sizes.astype(jnp.int32)
     if common.partitioned() or not common.use_pallas():
         common.note("moe_grouped_matmul", "ref")
         return lax.ragged_dot(lhs, rhs, group_sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
     common.note("moe_grouped_matmul", common.pallas_path())
+    return _gmm(lhs, rhs, group_sizes, common.interpret())
 
-    def tile(size, cap):
-        for t in (cap, 512, 256, 128):
-            if t <= cap and size % t == 0:
-                return t
-        return size
 
-    out = megablox.gmm(
-        lhs, rhs, group_sizes, lhs.dtype,
-        (tile(R, _ROW_TILE), tile(k, 1024), tile(n, 1024)),
-        interpret=common.interpret())
-    written = jnp.arange(R)[:, None] < jnp.sum(group_sizes)
-    return jnp.where(written, out, 0)
+def _megablox():
+    """megablox's kernels, ``gmm`` and ``tgmm`` (the package exports its
+    custom-VJP ``gmm`` under the module's name)."""
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _widths(size: int):
+    """A dimension's tile widths, widest first: the multiples of 128 that
+    divide it (no block runs past its end, so no remainder is masked),
+    or the whole of a dimension that is no multiple of 128."""
+    if size % common.LANE:
+        return (size,)
+    return tuple(t for t in range(size, 0, -common.LANE) if size % t == 0)
+
+
+def _vmem_bytes(kind: str, tm: int, tk: int, tn: int, item: int) -> int:
+    """What a megablox call holds in VMEM at tiles ``(tm, tk, tn)``, as
+    ``common.VMEM_BUDGET_BYTES`` counts: every block twice (the
+    pipeline's two buffers) and the float32 accumulator; for ``gmm`` the
+    product's float32 ``[tm, tn]`` and one more left block, for ``tgmm``
+    (output block ``[tk, tn]``) the left block's float32 transpose. Each
+    count lies at or above what Mosaic allocates at the expert cells'
+    tiles (``tests/test_tpu_compile.py`` compiles them under the budget)."""
+    if kind == "tgmm":
+        return 2 * item * (tm * tk + tm * tn + tk * tn) + 4 * (tk * tn
+                                                               + tk * tm)
+    return (item * (3 * tm * tk + 2 * tk * tn + 2 * tm * tn)
+            + 8 * tm * tn)
+
+
+def gmm_tiles(kind: str, m: int, k: int, n: int, item: int):
+    """``(tm, tk, tn)`` for one megablox call, ``kind`` ``"gmm"`` or
+    ``"tgmm"``, of ``m`` rows, contraction ``k`` and output width ``n``
+    (for ``tgmm``: ``[G, k, n]`` summed over the ``m`` rows), operands of
+    ``item`` bytes: of the widths that divide their dimension, the tiles
+    with the most work a grid step that fit the VMEM budget, the larger
+    contraction first where two do as much. ``tm`` is the rows' tile."""
+    tm = next((t for t in (_ROW_TILE, 256, 128) if m % t == 0), m)
+    fits = [(a, b) for a in _widths(k) for b in _widths(n)
+            if _vmem_bytes(kind, tm, a, b, item) <= common.VMEM_BUDGET_BYTES]
+    tk, tn = max(fits, key=lambda t: (t[0] * t[1], t[0]),
+                 default=(_widths(k)[-1], _widths(n)[-1]))
+    return tm, tk, tn
+
+
+def _kernel(name, lhs, rhs, group_sizes, dtype, interpret):
+    """One megablox call at its own tiles, noted as ``moe_gmm_tiles``:
+    ``"fwd"`` ``lhs [m, k]`` times ``rhs [G, k, n]``; ``"dlhs"`` times
+    ``rhs [G, n, k]`` transposed; ``"drhs"`` ``lhs [m, k]^T`` times ``rhs
+    [m, n]``, group by group, into ``[G, k, n]``."""
+    m, k = lhs.shape
+    kernels = _megablox()
+    if name == "drhs":
+        tiles = gmm_tiles("tgmm", m, k, rhs.shape[1], lhs.dtype.itemsize)
+        # tgmm takes the left operand [k, m] and swaps it back
+        call = functools.partial(kernels.tgmm, lhs.swapaxes(0, 1))
+    else:
+        n = rhs.shape[1 if name == "dlhs" else 2]
+        tiles = gmm_tiles("gmm", m, k, n, lhs.dtype.itemsize)
+        call = functools.partial(kernels.gmm, lhs,
+                                 transpose_rhs=name == "dlhs")
+    common.note("moe_gmm_tiles", name + " " + "x".join(map(str, tiles)))
+    return call(rhs, group_sizes, dtype, tiles, interpret=interpret)
+
+
+def _written(rows: int, group_sizes):
+    return jnp.arange(rows)[:, None] < jnp.sum(group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm(lhs, rhs, group_sizes, interpret):
+    return _gmm_fwd(lhs, rhs, group_sizes, interpret)[0]
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, interpret):
+    out = _kernel("fwd", lhs, rhs, group_sizes, lhs.dtype, interpret)
+    return (jnp.where(_written(lhs.shape[0], group_sizes), out, 0),
+            (lhs, rhs, group_sizes))
+
+
+def _gmm_bwd(interpret, saved, g):
+    """megablox's own rule (``ops._gmm_bwd``) with each call's tiles
+    reckoned from its own shape: ``dlhs = g rhs^T`` by ``gmm``
+    transposed, ``drhs = lhs^T g`` by ``tgmm``. The forward's select
+    passes no cotangent to the rows past the sum, and ``gmm`` leaves them
+    unwritten in ``dlhs``: a select zeroes them there too."""
+    lhs, rhs, group_sizes = saved
+    written = _written(lhs.shape[0], group_sizes)
+    g = jnp.where(written, g, 0)
+    dlhs = _kernel("dlhs", g, rhs, group_sizes, lhs.dtype, interpret)
+    drhs = _kernel("drhs", lhs, g, group_sizes, rhs.dtype, interpret)
+    return jnp.where(written, dlhs, 0), drhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def _plan(ids, offset, held: int, live):

@@ -91,7 +91,9 @@ def _call_grouped_matmul():
 # the name its reference path notes, the name its kernel notes).
 # `flash_backward` is noted where the kernels' backward rule is traced,
 # beside the forward's note: the reference path is differentiated by JAX
-# and notes no backward
+# and notes no backward. `moe_gmm_tiles` is noted beside
+# `moe_grouped_matmul`'s kernel path, one a megablox call, with the
+# call's tiles
 ENTRIES = {
     "lstm": ("lstm", _call_lstm, "ref", "resident"),
     "gru": ("gru", _call_gru, "ref", "interpret"),
@@ -103,7 +105,12 @@ ENTRIES = {
     "ctc": ("ctc", _call_ctc, "ref", "interpret"),
     "moe_grouped_matmul": ("moe_grouped_matmul", _call_grouped_matmul,
                            "ref", "interpret"),
+    "moe_gmm_tiles": ("moe_gmm_tiles", _call_grouped_matmul,
+                      None, "fwd 128x128x128"),
 }
+# an entry noted beside another's note -> that other
+BESIDE = {"flash_backward": "flash_attention",
+          "moe_gmm_tiles": "moe_grouped_matmul"}
 
 
 @pytest.mark.parametrize("mode", ["ref", "interpret"])
@@ -119,8 +126,10 @@ def test_every_kernel_entry_follows_the_one_policy(entry, mode,
     with common.force_mode(mode), common.record_dispatch() as tally:
         call()
     want = ref_path if mode == "ref" else kernel_path
-    if name == "flash_backward":
-        assert tally.pop("flash_attention") == {mode: 1}
+    if name in BESIDE:
+        assert tally.pop(BESIDE[name]) == {mode: 1}
+    if name == "moe_grouped_matmul" and mode == "interpret":
+        assert tally.pop("moe_gmm_tiles") == {"fwd 128x128x128": 1}, tally
     assert set(tally) == ({name} if want else set()), tally
     assert want is None or set(tally[name]) == {want}, tally
 

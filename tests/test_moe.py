@@ -6,6 +6,7 @@ one-hot combine with no sort), with itself over a CPU mesh, and with
 itself on the kernel's path (megablox in interpret mode)."""
 
 import importlib
+import types
 
 import numpy as np
 import pytest
@@ -409,32 +410,32 @@ def test_unwritten_rows_of_the_kernel_poison_nothing(setup, monkeypatch):
     expert's unwritten, forward and in its left operand's gradient, and
     on the chip what lies there may be no number (it was, PR 27: a zero
     cotangent times such a row made every gradient upstream NaN on some
-    seeds). With NaN planted there, output and gradients are those of
-    the other path."""
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    seeds). With NaN planted there by megablox's two kernels as
+    ``grouped_matmul``'s rule calls them (``gmm`` forward and transposed
+    for the left operand's gradient, ``tgmm`` for the right's), output
+    and gradients are those of the other path."""
     params, x = setup
 
     def written(rows, sizes):
         return jnp.arange(rows)[:, None] < jnp.sum(sizes)
 
-    @jax.custom_vjp
-    def poisoned(lhs, rhs, sizes):
-        out = jax.lax.ragged_dot(lhs, rhs, sizes)
+    def gmm(lhs, rhs, sizes, dtype, tiles, transpose_rhs=False,
+            interpret=False):
+        out = jax.lax.ragged_dot(
+            lhs, rhs.swapaxes(1, 2) if transpose_rhs else rhs, sizes)
         return jnp.where(written(lhs.shape[0], sizes), out, jnp.nan)
 
-    def fwd(lhs, rhs, sizes):
-        return poisoned(lhs, rhs, sizes), (lhs, rhs, sizes)
+    def tgmm(lhs_t, rhs, sizes, dtype, tiles, interpret=False):
+        ends = jnp.cumsum(sizes)
+        row = jnp.arange(rhs.shape[0])[:, None]
+        mine = (row >= ends - sizes) & (row < ends)          # [m, G]
+        return jnp.stack([
+            jnp.where(mine[:, g:g + 1], lhs_t.T, 0).T
+            @ jnp.where(mine[:, g:g + 1], rhs, 0)
+            for g in range(sizes.shape[0])]).astype(dtype)
 
-    def bwd(saved, g):
-        lhs, rhs, sizes = saved
-        dl, dr = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes),
-                         lhs, rhs)[1](g)
-        return jnp.where(written(lhs.shape[0], sizes), dl, jnp.nan), dr, None
-
-    poisoned.defvjp(fwd, bwd)
-    monkeypatch.setattr(megablox, "gmm",
-                        lambda lhs, rhs, sizes, *a, **kw: poisoned(
-                            lhs, rhs, sizes))
+    monkeypatch.setattr(moe_lib, "_megablox", lambda: types.SimpleNamespace(
+        gmm=gmm, tgmm=tgmm))
     held = share(params, 2, 6)
     target = jax.random.normal(jax.random.PRNGKey(2), (T, D))
 
@@ -450,3 +451,43 @@ def test_unwritten_rows_of_the_kernel_poison_nothing(setup, monkeypatch):
         assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("k,n,sizes", [
+    (384, 640, (40, 0, 50, 0)),     # 384 x 640 tiles where 128 x 128 were
+    (512, 384, (0, 128, 0, 0)),     # dlhs contracted 384 over a tile of 512
+    (256, 1152, (20, 30, 0, 78)),   # every row held, n in tiles of 1152
+])
+def test_each_call_takes_its_own_tiles_and_the_products_gradients(k, n,
+                                                                   sizes):
+    """megablox interpreted with each of its three calls at the tiles its
+    own shape gives (a width of 128s in the widest tile that divides it,
+    where the forward's tiling by (1024, 512, 256, 128) handed its
+    transposes a tile past the contraction's end): the product and both
+    operands' gradients equal ``lax.ragged_dot``'s, with empty groups and
+    with rows past the sum, which come out zero forward and in the left
+    operand's gradient."""
+    R = 128
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    lhs = jax.random.normal(keys[0], (R, k))
+    rhs = jax.random.normal(keys[1], (len(sizes), k, n))
+    dout = jax.random.normal(keys[2], (R, n))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    def run(a, b):
+        out, vjp = jax.vjp(
+            lambda a, b: moe_lib.grouped_matmul(a, b, group_sizes), a, b)
+        return (out, *vjp(dout))
+
+    with common.force_mode("interpret"), common.record_dispatch() as tally:
+        got = run(lhs, rhs)
+    want = run(lhs, rhs)            # off the TPU: lax.ragged_dot
+    assert tally["moe_grouped_matmul"] == {"interpret": 1}
+    assert set(tally["moe_gmm_tiles"]) == {
+        f"fwd {R}x{k}x{n}", f"dlhs {R}x{n}x{k}", f"drhs {R}x{k}x{n}"}
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-4)
+    past = sum(sizes)
+    assert not np.any(np.asarray(got[0])[past:])
+    assert not np.any(np.asarray(got[1])[past:])

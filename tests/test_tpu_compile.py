@@ -220,21 +220,30 @@ def test_looped_step_runs_each_cores_forward_kernel_once(one_chip):
     assert compiled.as_text().count(_MOSAIC) == 2 * layers * passes
 
 
-@pytest.mark.parametrize("hidden,n_experts,top_k,temp_mb", [
-    (1536, 64, 4, 400),         # the LFM2 cell's
-    (768, 256, 8, 150),         # the JoyAI cell's
-    (512, 256, 8, 150),         # the Laguna cell's
+@pytest.mark.parametrize("d,tokens,hidden,n_experts,top_k,temp_mb", [
+    (2048, 8192, 1536, 64, 4, 400),         # the LFM2 cell's
+    (2048, 8192, 768, 256, 8, 150),         # the JoyAI cell's
+    (2048, 8192, 512, 256, 8, 150),         # the Laguna cell's
+    (2304, 16384, 896, 64, 8, 1000),        # the Mellum2 cell's
 ])
-def test_routed_experts_forward_and_backward(one_chip, hidden, n_experts,
-                                             top_k, temp_mb):
-    """8 held experts, 8,192 tokens of 2,048, a cell's expert width, top
-    k and expert count: the loop over buffers of twice a uniform
-    router's rows, forward and backward. **The expert rule's static
-    counter** (``PERF.md`` section 3): the first buffer's gradients are
-    the loop's carry in the leaves' own types, so no instruction writes
-    and no ``while`` carries a float32 array of an expert leaf's shape,
-    and the temporaries stay under a bound the float32 sums over the
-    buffers passed (701, 364, 339 MB before PR 38)."""
+def test_routed_experts_forward_and_backward(one_chip, d, tokens, hidden,
+                                             n_experts, top_k, temp_mb,
+                                             monkeypatch):
+    """8 held experts, a cell's tokens, model width, expert width, top k
+    and expert count: the loop over buffers of twice a uniform router's
+    rows, forward and backward. **The expert rule's static counter**
+    (``PERF.md`` section 3): the first buffer's gradients are the loop's
+    carry in the leaves' own types, so no instruction writes and no
+    ``while`` carries a float32 array of an expert leaf's shape, and the
+    temporaries stay under a bound the float32 sums over the buffers
+    passed (701, 364, 339 MB at the LFM2, JoyAI and Laguna shapes).
+    **The grouped products' tiles**: every megablox call (forward, and
+    the backward's ``gmm`` transposed and ``tgmm``) takes tiles that
+    divide its dimensions, and Mosaic fits each into
+    ``VMEM_BUDGET_BYTES``: compiled with the scoped limit LOWERED to the
+    budget (here alone; the program sets no limit); at Mellum2's widths
+    no tile is 128 wide."""
+    from jax.experimental.pallas import tpu as pltpu
     from paddle_tpu.parallel.moe import routed_experts
 
     def sd(shape, dtype=jnp.bfloat16):
@@ -246,14 +255,25 @@ def test_routed_experts_forward_and_backward(one_chip, hidden, n_experts,
                                   n_experts=n_experts, offset=8)[0]
         return jax.vjp(f, x, wg, wu, wd, gates)[1](dy)
 
-    compiled = _compile(
-        step, sd((8192, 2048)), sd((8, 2048, hidden)),
-        sd((8, 2048, hidden)), sd((8, hidden, 2048)),
-        sd((8192, top_k), jnp.int32), sd((8192, top_k), jnp.float32),
-        sd((8192, 2048)))
+    params = pltpu.CompilerParams
+    monkeypatch.setattr(pltpu, "CompilerParams", lambda **kw: params(
+        vmem_limit_bytes=common.VMEM_BUDGET_BYTES, **kw))
+    with common.record_dispatch() as tally:
+        compiled = _compile(
+            step, sd((tokens, d)), sd((8, d, hidden)), sd((8, d, hidden)),
+            sd((8, hidden, d)), sd((tokens, top_k), jnp.int32),
+            sd((tokens, top_k), jnp.float32), sd((tokens, d)))
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 9
-    leaf = re.compile(rf"\b(\w+)\[(?:8,2048,{hidden}|8,{hidden},2048)\]")
+    noted = [t.split() for t in tally["moe_gmm_tiles"]]
+    assert {call for call, _ in noted} == {"fwd", "dlhs", "drhs"}
+    for _, tiles in noted:
+        tm, tk, tn = map(int, tiles.split("x"))
+        # (k, n) is (d, hidden) or (hidden, d) for every call of the two
+        assert any(k % tk == 0 and n % tn == 0
+                   for k, n in ((d, hidden), (hidden, d))), noted
+        assert d != 2304 or 128 not in (tk, tn), noted
+    leaf = re.compile(rf"\b(\w+)\[(?:8,{d},{hidden}|8,{hidden},{d})\]")
     assert {m.group(1) for m in leaf.finditer(text)} == {"bf16"}
     # the loop over the buffers after the first carries the leaves in
     # their own type
@@ -735,9 +755,15 @@ def test_the_mellum2_cells_model_fits_the_chip_with_its_mosaic_calls(
     with common.record_dispatch() as tally:
         compiled = _compile(step, params, sd((2, 8192), jnp.int32),
                             sd((2, 8192), jnp.float32))
+    # each grouped product's three calls at their own tiles: wg and wu
+    # (2,304 -> 896) and wd (896 -> 2,304), forward traced twice a buffer
     assert tally == {"flash_attention": {"pallas": 4},
                      "flash_backward": {"fused": 3, "split": 1},
-                     "moe_grouped_matmul": {"pallas": 48}}
+                     "moe_grouped_matmul": {"pallas": 48},
+                     "moe_gmm_tiles": {
+                         "fwd 512x1152x896": 32, "fwd 512x896x1152": 16,
+                         "dlhs 512x896x1152": 16, "dlhs 512x1152x896": 8,
+                         "drhs 512x768x896": 16, "drhs 512x896x1152": 8}}
     assert compiled.as_text().count(_MOSAIC) == 4 + 3 + 2 + 4 * 24
     ma = compiled.memory_analysis()
     held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
