@@ -33,9 +33,10 @@ today's large open models carry in their ``config.json``):
   (``lax.fori_loop`` with a traced bound), so a batch in which every
   token chooses held experts comes out exact, and both time and memory
   follow the rows really routed here, not the worst case ``T * min(k,
-  held)``. The buffers after the first hold at most 8,192 rows: a
-  buffer costs what its size costs, held rows or not, and what spills
-  past the first is mostly a few rows. The loop sits inside a
+  held)``. The buffers after the first hold at most 8,192 rows. On the
+  TPU the rows are moved in and out of a buffer by ``ops/rows.py``'s
+  row kernels, which move only the rows it holds; XLA's gather and
+  scatter-add (the reference) pay for every row of it. The loop sits inside a
   ``custom_vjp`` whose backward makes the same turns, recomputing each
   buffer from the layer's input (a loop of traced length has no
   transpose of its own).
@@ -58,6 +59,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.ops import common
+from paddle_tpu.ops import rows as row_ops
 
 _HIGHEST = lax.Precision.HIGHEST
 _ROW_TILE = 512     # rows a grouped-product tile holds on the TPU
@@ -286,19 +288,20 @@ def _chunk_rows(T: int, K: int, E: int, held: int) -> int:
     return up(min(2 * T * K * held / E, T * min(K, held)))
 
 
-def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into):
+def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into, slot=None):
     """``into`` ([T, d] float32) plus the routed sum of the sorted pairs
     ``first .. first + R`` (``pairs [R]``: their indices into the
     flattened [T * K] choices). ``counts [held]`` are the whole batch's
-    rows per expert; the chunk's own follow from where it starts."""
+    rows per expert; the chunk's own follow from where it starts. The
+    buffer's rows are moved by ``ops/rows.py``'s kernels where ``slot``
+    (every pair's sorted position) is given, else by XLA's gather and
+    scatter-add."""
     R, K = pairs.shape[0], gates.shape[1]
     with jax.named_scope("moe_dispatch"):
         ends = jnp.cumsum(counts)
         mine = jnp.clip(jnp.minimum(ends, first + R)
                         - jnp.maximum(ends - counts, first), 0, R)
-        token = pairs // K
-        valid = jnp.arange(R) < jnp.sum(mine)
-        xg = jnp.where(valid[:, None], x[token], 0)
+        xg = row_ops.gather_rows(x, pairs, K, mine, slot, first)
     with jax.named_scope("moe_experts"):
         a = (jax.nn.silu(grouped_matmul(xg, wg, mine))
              * grouped_matmul(xg, wu, mine))
@@ -306,14 +309,13 @@ def _chunk(x, wg, wu, wd, gates, pairs, counts, first, into):
     with jax.named_scope("moe_combine"):
         # a token's up to k contributions are added in float32; rows
         # past the last expert's are zero and add nothing
-        g = gates.reshape(-1)[pairs]
-        return into.at[token].add(o.astype(jnp.float32) * g[:, None])
+        return row_ops.sum_rows(o, gates, pairs, mine, slot, first, into)
 
 
 def _spill_rows(R: int) -> int:
     """Rows of each chunk after the first, which takes ``R``. A chunk
-    costs what its size costs, held rows or not (each of its rows is
-    gathered, selected and scattered), and what a batch sends past the
+    costs what its size costs, held rows or not (the grouped products'
+    grid and every row's zero or copy), and what a batch sends past the
     first chunk is mostly a small part of it: so these chunks hold at
     most ``_SPILL_ROWS``."""
     return min(R, _SPILL_ROWS)
@@ -323,19 +325,23 @@ def _first_row(c, R: int):
     """Where chunk ``c`` starts among the sorted pairs: the first holds
     ``R`` rows, each after it ``_spill_rows(R)``."""
     S = _spill_rows(R)
-    if S == R:
-        return c * R
-    return c * S + jnp.minimum(c, 1) * (R - S)
+    with jax.named_scope("moe_dispatch"):
+        if S == R:
+            return c * R
+        return c * S + jnp.minimum(c, 1) * (R - S)
 
 
 def _sorted_pairs(key, R: int):
     """The (token, choice) pairs sorted by expert, held experts' first,
-    padded to a first chunk of ``R`` and whole chunks after it."""
+    padded to a first chunk of ``R`` and whole chunks after it; and,
+    where the row kernels run, every pair's place in that order (else
+    None)."""
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         n = order.shape[0]
         pad = max(R - n, 0) + (-max(n - R, 0)) % _spill_rows(R)
-        return jnp.pad(order, (0, pad))
+        slot = row_ops.slots(order, n) if row_ops.kernels(n, R) else None
+        return jnp.pad(order, (0, pad)), slot
 
 
 def _turns(R, counts):
@@ -364,12 +370,12 @@ def _chunk_pairs(order, c, R: int, rows: int):
         return lax.dynamic_slice(order, (_first_row(c, R),), (rows,))
 
 
-def _routed_sum(R, order, x, wg, wu, wd, gates, counts):
+def _routed_sum(R, order, slot, x, wg, wu, wd, gates, counts):
     S = _spill_rows(R)
 
     def chunk(c, rows, y):
         return _chunk(x, wg, wu, wd, gates, _chunk_pairs(order, c, R, rows),
-                      counts, _first_row(c, R), y)
+                      counts, _first_row(c, R), y, slot)
 
     with jax.named_scope("moe_combine"):
         y = jnp.zeros(x.shape, jnp.float32)
@@ -381,14 +387,15 @@ def _routed_sum(R, order, x, wg, wu, wd, gates, counts):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _routed(R, x, wg, wu, wd, gates, key, counts):
-    return _routed_sum(R, _sorted_pairs(key, R), x, wg, wu, wd, gates,
+    return _routed_sum(R, *_sorted_pairs(key, R), x, wg, wu, wd, gates,
                        counts)
 
 
 def _routed_fwd(R, x, wg, wu, wd, gates, key, counts):
-    order = _sorted_pairs(key, R)       # sorted once, kept for the backward
-    return (_routed_sum(R, order, x, wg, wu, wd, gates, counts),
-            (x, wg, wu, wd, gates, order, counts))
+    # sorted once, kept for the backward
+    order, slot = _sorted_pairs(key, R)
+    return (_routed_sum(R, order, slot, x, wg, wu, wd, gates, counts),
+            (x, wg, wu, wd, gates, order, slot, counts))
 
 
 def _routed_bwd(R, saved, dy):
@@ -404,14 +411,15 @@ def _routed_bwd(R, saved, dy):
     operations (a chunk's pairs, the sums) open ``moe_dispatch``
     themselves: a hand-written rule inherits no forward scope. What
     ``_chunk`` opens it opens here too, recomputed and transposed."""
-    *floats, order, counts = saved
+    *floats, order, slot, counts = saved
     with jax.named_scope("moe_dispatch"):
         zero = jnp.zeros(floats[0].shape, jnp.float32)
 
     def grads_of(c, rows):
         pairs = _chunk_pairs(order, c, R, rows)
         _, vjp = jax.vjp(
-            lambda *f: _chunk(*f, pairs, counts, _first_row(c, R), zero),
+            lambda *f: _chunk(*f, pairs, counts, _first_row(c, R), zero,
+                              slot),
             *floats)
         return vjp(dy)
 

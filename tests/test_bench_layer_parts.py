@@ -161,8 +161,10 @@ def test_the_benchmark_lists_a_parts_metric(name):
     assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
                                        name + ".py"))
     # appended after every entry the benchmark had, then the short
-    # convolution's and the q/k norm's three, then the balancing term's two
-    assert [m["name"] for m in bench["per_layer"][-11:]] == [
+    # convolution's and the q/k norm's three, then the balancing term's
+    # two, then the buffer's fill
+    assert bench["per_layer"][-1]["name"] == "moe_buffer_fill"
+    assert [m["name"] for m in bench["per_layer"][-12:-1]] == [
         "attn_proj_ms", "attn_rope_ms", "attn_out_ms", "recompute_ms",
         "moe_dispatch_ms", "moe_combine_ms", "short_conv_roofline",
         "short_conv_core_ms", "attn_qk_norm_ms", "moe_balance_ms",

@@ -177,12 +177,13 @@ def test_the_benchmark_names_the_cell_and_its_metrics():
     assert set(config["reduced"]) == set(full()[0]["reduced"])
     listed = {m["name"] for m in bench["per_layer"]
               if FULL_CELL in m.get("workloads", [])}
-    assert len(listed) == 24
+    assert len(listed) == 25
     assert {"short_conv_roofline", "short_conv_core_ms", "attn_qk_norm_ms",
             "attn_full_core_roofline", "attn_proj_ms", "attn_rope_ms",
             "attn_out_ms", "recompute_ms", "moe_experts_roofline",
             "moe_ffn_ms", "moe_load_max_over_mean", "moe_dispatch_ms",
-            "moe_combine_ms", "step_mfu_pct", "device_idle_pct"} <= listed
+            "moe_combine_ms", "step_mfu_pct", "device_idle_pct",
+            "moe_buffer_fill"} <= listed
     assert not {"mla_core_roofline", "attn_window_core_roofline",
                 "swa_tile_waste", "lstm_seq_roofline"} & listed
     for name in ("short_conv_roofline", "short_conv_core_ms",

@@ -175,13 +175,13 @@ def test_the_benchmark_names_the_cell_and_its_metrics():
     assert set(config["reduced"]) == set(full()[0]["reduced"])
     listed = {m["name"] for m in bench["per_layer"]
               if FULL_CELL in m.get("workloads", [])}
-    assert len(listed) == 26
+    assert len(listed) == 27
     assert {"attn_full_core_roofline", "attn_window_core_roofline",
             "swa_tile_waste", "attn_proj_ms", "attn_rope_ms", "attn_out_ms",
             "attn_qk_norm_ms", "recompute_ms", "moe_experts_roofline",
             "moe_ffn_ms", "moe_load_max_over_mean", "moe_dispatch_ms",
             "moe_combine_ms", "step_mfu_pct", "device_idle_pct",
-            "moe_balance_ms", "moe_balance_ratio"} <= listed
+            "moe_balance_ms", "moe_balance_ratio", "moe_buffer_fill"} <= listed
     assert not {"mla_core_roofline", "short_conv_roofline",
                 "lstm_seq_roofline", "loop_head_ms"} & listed
     for name, source in (("moe_balance_ms", "device_trace"),
@@ -252,3 +252,26 @@ def test_the_readers_are_silent_where_there_is_nothing_to_read():
         at_close = {"steps": 16, "moe_rows_mean": 2.0}
 
     assert reader("moe_balance_ratio")(dict(ctx, window=Bare())) is None
+
+
+def test_the_buffer_fill_is_reckoned_from_the_programs_counters():
+    """``moe_buffer_fill`` at the full cell: the held rows
+    (``moe_rows_mean`` times the 8 experts held) over the first buffer
+    of 32,768 rows and, for each turn past it, a buffer of 8,192; silent
+    where the program counts no turns."""
+    cfg, mix = full()
+    read = reader("moe_buffer_fill")
+
+    def window(rows, turns):
+        class Counted(_Window):
+            at_open = {"steps": 6, "moe_rows_mean": 6 * rows,
+                       "moe_turns": 6 * turns}
+            at_close = {"steps": 16, "moe_rows_mean": 16 * rows,
+                        "moe_turns": 16 * turns}
+        return {"cfg": cfg, "mix": mix, "chips": 1, "window": Counted()}
+
+    assert read(window(2048.0, 1.0)) == pytest.approx(0.5)
+    assert read(window(2304.0, 1.25)) == pytest.approx(
+        2304 * 8 / (32768 + 0.25 * 8192))
+    assert read(dict(window(2048.0, 1.0), window=_Window())) is None
+    assert read(window(0.0, 0.0)) is None

@@ -87,6 +87,33 @@ def _call_grouped_matmul():
                    jnp.asarray([80, 40], jnp.int32))
 
 
+def _rows_plan():
+    """16 tokens' 32 (token, choice) pairs sorted by their expert, two of
+    them held (7 and 12 pairs): the first chunk of 24 sorted rows holds
+    19, and where the row kernel runs, the place of every pair in that
+    order."""
+    from paddle_tpu.ops import rows
+    expert = np.repeat([0, 1, 2], [7, 12, 13])
+    _rng(6).shuffle(expert)
+    order = jnp.asarray(np.argsort(expert, kind="stable"), jnp.int32)
+    slot = rows.slots(order, 32) if rows.kernels(32, 24) else None
+    return order[:24], jnp.asarray([7, 12], jnp.int32), slot
+
+
+def _call_gather_rows():
+    from paddle_tpu.ops import rows
+    pairs, runs, slot = _rows_plan()
+    rows.gather_rows(_f32(_rng(7), 16, 256), pairs, 2, runs, slot)
+
+
+def _call_sum_rows():
+    from paddle_tpu.ops import rows
+    pairs, runs, slot = _rows_plan()
+    r = _rng(8)
+    rows.sum_rows(_f32(r, 24, 256), _f32(r, 16, 2), pairs, runs, slot,
+                  into=jnp.zeros((16, 256)))
+
+
 # entry -> (its name in the tally, a call at a shape inside the budget,
 # the name its reference path notes, the name its kernel notes).
 # `flash_backward` is noted where the kernels' backward rule is traced,
@@ -107,7 +134,12 @@ ENTRIES = {
                            "ref", "interpret"),
     "moe_gmm_tiles": ("moe_gmm_tiles", _call_grouped_matmul,
                       None, "fwd 128x128x128"),
+    "moe_gather_rows": ("moe_gather_rows", _call_gather_rows,
+                        "ref", "interpret"),
+    "moe_sum_rows": ("moe_sum_rows", _call_sum_rows, "ref", "interpret"),
 }
+# the row kernels' entries note once a call, in either mode
+ONCE = {"moe_gather_rows", "moe_sum_rows"}
 # an entry noted beside another's note -> that other
 BESIDE = {"flash_backward": "flash_attention",
           "moe_gmm_tiles": "moe_grouped_matmul"}
@@ -132,6 +164,8 @@ def test_every_kernel_entry_follows_the_one_policy(entry, mode,
         assert tally.pop("moe_gmm_tiles") == {"fwd 128x128x128": 1}, tally
     assert set(tally) == ({name} if want else set()), tally
     assert want is None or set(tally[name]) == {want}, tally
+    if name in ONCE:
+        assert tally == {name: {want: 1}}, tally
 
 
 def test_the_policy_test_covers_every_noting_entry():

@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from benchmark.reference import plain
 from paddle_tpu.ops import common
+from paddle_tpu.ops import rows as row_ops
 from paddle_tpu.parallel import create_mesh
 from paddle_tpu.parallel import moe as moe_lib
 from paddle_tpu.parallel.moe import (init_moe_params, make_moe, moe_ffn,
@@ -124,7 +125,8 @@ def test_no_token_is_dropped_at_any_skew(setup, mode):
 @pytest.mark.parametrize("mode", ["ref", "interpret"])
 @pytest.mark.parametrize("turns,first,spill", [
     (0, None, None), (2, T * K // 2, None), (4, T * K // 4, None),
-    (5, T * K // 2, T * K // 8), (4, T * K // 2, 24)])
+    (5, T * K // 2, T * K // 8), (4, T * K // 2, 24),
+    (1, T * K, None), (3, T * K // 2, T * K // 4)])
 def test_gradients_over_any_number_of_chunks(setup, mode, turns, first,
                                              spill, monkeypatch):
     """The collapsed router's gradients, every leaf's and the input's,
@@ -134,7 +136,9 @@ def test_gradients_over_any_number_of_chunks(setup, mode, turns, first,
     chunk's gradients, computed outside any loop, are zero). With chunks
     after the first smaller than it (``spill`` rows), 4 and 5 chunks:
     one of 64 rows and 64 more in chunks of 16, or of 24, which leave
-    the last chunk reaching past the pairs into their padding."""
+    the last chunk reaching past the pairs into their padding; and 1
+    chunk that holds every row, and 3 (64 rows, then two of 32). In
+    ``interpret`` the row kernel sums the rows (``ops/rows.py``)."""
     params, x = setup
     lo = 4 if turns == 0 else 2
     held = share(_collapsed(params), lo, lo + 2)
@@ -192,12 +196,13 @@ def test_bfloat16_gradients_are_the_float32_sum_over_chunks_rounded_once(
 
     @jax.jit
     def formula(dy, key, counts, *floats):
-        order = moe_lib._sorted_pairs(key, R)
+        order, slot = moe_lib._sorted_pairs(key, R)
         zero = jnp.zeros(x.shape, jnp.float32)
         sums = [jnp.zeros(f.shape, jnp.float32) for f in floats]
         for c in range(turns):
             _, vjp = jax.vjp(lambda *f: moe_lib._chunk(
-                *f, order[c * R:(c + 1) * R], counts, c * R, zero), *floats)
+                *f, order[c * R:(c + 1) * R], counts, c * R, zero, slot),
+                *floats)
             sums = [s + d.astype(jnp.float32)
                     for s, d in zip(sums, vjp(dy))]
         return [s.astype(f.dtype) for s, f in zip(sums, floats)]
@@ -491,3 +496,143 @@ def test_each_call_takes_its_own_tiles_and_the_products_gradients(k, n,
     past = sum(sizes)
     assert not np.any(np.asarray(got[0])[past:])
     assert not np.any(np.asarray(got[1])[past:])
+
+
+def _rows_grads(ids, kernels, monkeypatch, gmm=None):
+    """Output and every gradient of the routed sum of 4 held experts
+    (2..5 of E) over float32 inputs, kernels interpreted; the rows moved
+    by the row kernels (``kernels``) or by XLA's gather and
+    scatter-add."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    x = jax.random.normal(keys[0], (T, D))
+    w = [jax.random.normal(keys[i], shape) * 0.3 for i, shape in
+         ((1, (4, D, H)), (2, (4, D, H)), (3, (4, H, D)))]
+    gates = jax.random.uniform(keys[4], (T, K))
+    target = jax.random.normal(keys[5], (T, D))
+
+    def loss(x, wg, wu, wd, g):
+        y = moe_lib.routed_experts(x, wg, wu, wd, ids, g, n_experts=E,
+                                   offset=2)[0]
+        return jnp.mean((y - target) ** 2)
+
+    with monkeypatch.context() as m:
+        m.setattr(moe_lib.row_ops, "kernels", lambda *a: kernels)
+        if gmm is not None:
+            m.setattr(moe_lib, "grouped_matmul", gmm)
+        with common.force_mode("interpret"), \
+                common.record_dispatch() as tally:
+            out = jax.value_and_grad(loss, argnums=range(5))(x, *w, gates)
+    path = "interpret" if kernels else "ref"
+    assert tally["moe_gather_rows"] == {path: tally["moe_gather_rows"][
+        path]} and set(tally["moe_sum_rows"]) == {path}, tally
+    return out
+
+
+def _ids(held_rows):
+    """[T, K] choices: the first ``held_rows`` (token, choice) pairs in
+    token order name held experts (2, 4, 5 in turn: expert 3 gets none,
+    and a token's two choices are two held experts), the rest experts
+    this device does not hold."""
+    held = np.array([2, 4, 5])[np.arange(T * K) % 3]
+    away = np.array([0, 1, 6, 7])[np.arange(T * K) % 4]
+    return jnp.asarray(np.where(np.arange(T * K) < held_rows, held, away)
+                       .reshape(T, K), jnp.int32)
+
+
+@pytest.mark.parametrize("held_rows,first", [
+    (T * K, T * K),     # every pair held, one chunk
+    (32, None),         # n on a block's end (blocks of 16 buffer rows)
+    (37, None),         # n inside a block
+    (0, None),          # nothing held: no chunk at all
+    (100, 48),          # 48 rows, then chunks of 48: n = 4 in the last
+])
+def test_row_kernels_equal_xla_gather_and_scatter_add(held_rows, first,
+                                                      monkeypatch):
+    """``sum_rows`` as the combine and as the gather's transpose
+    (interpreted, 16 buffer rows and 16 tokens a grid step, so that
+    ``n`` lands on and off a block's end) give the routed sum and every gradient that XLA's gather and
+    scatter-add give: an empty held expert, tokens whose two choices are
+    both held, one chunk, several, none."""
+    monkeypatch.setattr(row_ops, "_WORD_ROWS", 16)
+    monkeypatch.setattr(row_ops, "_SUM_SLOTS", 16 * K)
+    if first:
+        monkeypatch.setattr(moe_lib, "_chunk_rows", lambda *a: first)
+    ids = _ids(held_rows)
+    (l0, g0), (l1, g1) = (_rows_grads(ids, k, monkeypatch)
+                          for k in (True, False))
+    assert float(l0) == pytest.approx(float(l1), rel=1e-6)
+    for a, b in zip(g0, g1):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    if not held_rows:
+        assert not np.any(np.asarray(g0[1]))
+
+
+def test_rows_past_the_held_ones_poison_nothing_under_the_row_kernels(
+        monkeypatch):
+    """With NaN in every row of the buffer past the held ones, forward
+    and in the left operand's gradient (what an unwritten row of the
+    grouped products may hold on the chip), the row kernels read none of
+    them: the routed sum and every gradient are finite and those of the
+    XLA path with the products as they are."""
+    def nan_gmm(lhs, rhs, sizes):
+        rows = jnp.arange(lhs.shape[0])[:, None]
+
+        @jax.custom_vjp
+        def f(lhs, rhs):
+            return jnp.where(rows < jnp.sum(sizes),
+                             jax.lax.ragged_dot(lhs, rhs, sizes), jnp.nan)
+
+        def f_bwd(saved, g):
+            lhs, rhs = saved
+            g = jnp.where(rows < jnp.sum(sizes), g, 0)
+            dlhs = jax.lax.ragged_dot(g, rhs.swapaxes(1, 2), sizes)
+            _, vjp = jax.vjp(lambda r: jax.lax.ragged_dot(lhs, r, sizes),
+                             rhs)
+            return (jnp.where(rows < jnp.sum(sizes), dlhs, jnp.nan),
+                    vjp(jnp.where(rows < jnp.sum(sizes), g, 0))[0])
+
+        f.defvjp(lambda lhs, rhs: (f(lhs, rhs), (jnp.where(
+            rows < jnp.sum(sizes), lhs, 0), rhs)), f_bwd)
+        return f(lhs, rhs)
+
+    monkeypatch.setattr(row_ops, "_WORD_ROWS", 16)
+    ids = _ids(37)
+    l0, g0 = _rows_grads(ids, True, monkeypatch, gmm=nan_gmm)
+    l1, g1 = _rows_grads(ids, False, monkeypatch)
+    assert np.isfinite(float(l0))
+    assert float(l0) == pytest.approx(float(l1), rel=1e-6)
+    for a, b in zip(g0, g1):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_experts,path", [(64, "interpret"),
+                                            (256, "ref")])
+def test_the_row_kernel_takes_a_layer_by_its_slots_a_buffer_row(n_experts,
+                                                                 path):
+    """The row kernel moves a layer's rows where its first buffer has a
+    row for at most ``_SLOTS_A_ROW`` (token, choice) slots: 4 slots a row
+    at 8 held experts of 64 (as the Mellum2 and LFM2 cells), where it is
+    the faster on the chip; 16 at 8 of 256 (as JoyAI and Laguna), where
+    XLA's scatter-add is."""
+    tokens, k, d, h = 64, 8, 128, 32
+    R = moe_lib._chunk_rows(tokens, k, n_experts, 8)
+    assert (tokens * k <= row_ops._SLOTS_A_ROW * R) == (path != "ref")
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(keys[0], (tokens, d))
+    w = [jax.random.normal(keys[i], shape) for i, shape in
+         ((1, (8, d, h)), (2, (8, d, h)), (3, (8, h, d)))]
+    ids = jax.random.randint(keys[4], (tokens, k), 0, n_experts)
+    gates = jnp.full((tokens, k), 1.0 / k)
+
+    def loss(x, *w):
+        return jnp.sum(moe_lib.routed_experts(
+            x, *w, ids, gates, n_experts=n_experts, offset=8)[0])
+
+    with common.force_mode("interpret"), \
+            common.record_dispatch() as tally:
+        jax.eval_shape(jax.grad(loss, argnums=range(4)), x, *w)
+    assert set(tally["moe_gather_rows"]) == {path}, tally
+    assert set(tally["moe_sum_rows"]) == {path}, tally

@@ -284,6 +284,103 @@ def test_routed_experts_forward_and_backward(one_chip, d, tokens, hidden,
     assert temp < temp_mb * 1e6, temp
 
 
+def _expert_stack(layers, one_chip):
+    """``(step, shapes)``: ``layers`` expert layers of the Mellum2 cell's
+    kind (softmax top-8 of 64, 8 held, experts of 896) one after another
+    over 1,024 tokens of 2,048, the last one's output and the
+    gradients."""
+    from paddle_tpu.config import dsl
+    dsl.reset()
+    h = dsl.data(name="x", size=2048, is_sequence=True)
+    for i in range(layers):
+        h = dsl.moe(h, expert_hidden=896, num_experts=64, top_k=8,
+                    experts_held=8, expert_offset=8, score="softmax",
+                    name=f"blk{i}_moe")
+    return _output_and_gradients(h.name, one_chip, 1024)
+
+
+_ROW_KERNELS = ("_words_kernel", "_sum_kernel")
+
+
+def test_row_kernels_are_lowered_once_a_shape_however_many_layers_call_them(
+        one_chip):
+    """**Set-up's guard** (``ops/rows.py``): the row kernel's entry is a
+    module-level ``jax.jit`` function, so the lowered step emits each
+    kernel (the sum and the packing of its rows) once for each shape and
+    type it is called at, however many layers call it: one expert
+    layer's step and three layers' lower the same row kernels, where
+    the compiled program holds three times the calls. (A ``pallas_call``
+    made at each call site is lowered at each, warm runs included: the
+    persistent cache's key is the lowered module.)"""
+    emitted, called = [], []
+    for layers in (1, 3):
+        step, shapes = _expert_stack(layers, one_chip)
+        with common.force_mode("pallas"):
+            lowered = jax.jit(step).lower(*shapes)
+        text = lowered.as_text()
+        emitted.append({k: len(re.findall(
+            rf'custom_call @tpu_custom_call\(.*kernel_name = "{k}"', text))
+            for k in _ROW_KERNELS})
+        called.append(len(re.findall(
+            r"jit\(_sum_call\)/pallas_call",
+            _compile(step, *shapes).as_text())))
+    assert emitted[0] == emitted[1], emitted
+    assert all(emitted[0][k] >= 1 for k in _ROW_KERNELS), emitted
+    assert called[1] == 3 * called[0] > 0, called
+
+
+@pytest.mark.parametrize("tokens,d,top_k,n_experts,rows", [
+    (8192, 2048, 4, 64, None),      # the LFM2 cell's
+    (16384, 2304, 8, 64, None),     # the Mellum2 cell's first buffer
+    (16384, 2304, 8, 64, 8192),     # and its buffers after the first
+])
+def test_row_kernels_compile_inside_the_budget(one_chip, tokens, d, top_k,
+                                               n_experts, rows,
+                                               monkeypatch):
+    """The row kernel at each shape an expert cell takes it at (a first
+    buffer of twice a uniform router's rows for 8 held experts, and the
+    Mellum2 cell's spill buffers), as the combine and as the gather's
+    transpose, compiled with the scoped VMEM limit LOWERED to
+    ``VMEM_BUDGET_BYTES``: Mosaic fits every block, the rows' staging
+    buffer and the index tables (SMEM) into it."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops import rows as row_ops
+    from paddle_tpu.parallel.moe import _chunk_rows
+
+    R = rows or _chunk_rows(tokens, top_k, n_experts, 8)
+    assert tokens * top_k <= row_ops._SLOTS_A_ROW * _chunk_rows(
+        tokens, top_k, n_experts, 8)
+    params = pltpu.CompilerParams
+    monkeypatch.setattr(pltpu, "CompilerParams", lambda **kw: params(
+        vmem_limit_bytes=common.VMEM_BUDGET_BYTES, **kw))
+    jax.clear_caches()          # the entries' traces hold their params
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(x, o, gates, order, runs, dy):
+        slot = row_ops.slots(order, tokens * top_k)
+        pairs = order[:R]
+        xg, back = jax.vjp(lambda x: row_ops.gather_rows(
+            x, pairs, top_k, runs, slot), x)
+        y, sum_back = jax.vjp(lambda o, g: row_ops.sum_rows(
+            o, g, pairs, runs, slot, into=jnp.zeros(x.shape, jnp.float32)),
+            o, gates)
+        return xg, back(xg), y, sum_back(dy)
+
+    with common.record_dispatch() as tally:
+        text = _compile(step, sd((tokens, d)), sd((R, d)),
+                        sd((tokens, top_k), jnp.float32),
+                        sd((tokens * top_k,), jnp.int32),
+                        sd((8,), jnp.int32),
+                        sd((tokens, d), jnp.float32)).as_text()
+    jax.clear_caches()
+    assert tally == {"moe_gather_rows": {"pallas": 1},
+                     "moe_sum_rows": {"pallas": 1}}, tally
+    # a packing and a sum each: the combine and the gather's transpose
+    assert text.count(_MOSAIC) == 4
+
+
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(")
 
 
@@ -563,7 +660,7 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
     monkeypatch.undo()
 
     assert "attn_qkv" not in before and "moe_dispatch" not in before
-    assert step_text.bare(text) == step_text.bare(before)
+    assert _renamed(step_text.bare(text)) == _renamed(step_text.bare(before))
 
     parts = _PARTS[{"experts": "moe", "balanced": "moe",
                     "sconv": "sconv"}.get(kind, "attn")]
@@ -622,7 +719,7 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
     for pattern in (r"_attn\).*mla_core", r"_attn\).*attn_core",
                     r"_swa\).*attn_core", r"_moe\).*moe_experts",
                     r"jvp\(\w+_moe\)"):
-        now, then = ({inst for inst, _, path in _NAMED.findall(t)
+        now, then = ({inst for inst, _, path in _NAMED.findall(_renamed(t))
                       if re.search(pattern, path)} for t in (text, before))
         assert now == then, pattern
     if kind == "qk_norm":   # the norms' own instructions, all three ways
@@ -634,7 +731,25 @@ def test_every_operation_of_a_layer_lies_in_one_inner_scope(
             "balanced": "moe_experts"}.get(kind, "attn_core")
     calls = [path for _, op, path in under
              if op == "custom-call" and "pallas_call" in path]
+    # the row kernel sums the buffer's rows back where the layer has
+    # 4 slots a buffer row (the Mellum2 kind), not at JoyAI's 16
+    rows = [path for path in calls if re.search(
+        r"jit\(_sum_call\)", path)]
+    assert all(re.search(r"\bmoe_(?:dispatch|combine)\b", path)
+               for path in rows), rows
+    assert bool(rows) == (kind == "balanced")
+    calls = [path for path in calls if path not in rows]
     assert len(calls) >= 2 and all(core in path for path in calls)
+
+
+def _renamed(text):
+    """The text with its instructions renamed in the order they are
+    first named: where a call site's scopes differ, XLA numbers the
+    instructions it inlines from a jitted kernel entry (``ops/rows.py``)
+    in another order, and nothing else differs."""
+    names = {}
+    return re.sub(r"%[\w.\-]+", lambda m: names.setdefault(
+        m.group(0), f"%i{len(names)}"), text)
 
 
 def _tokens_step(layer_of, one_chip, tokens=8192):
@@ -722,7 +837,10 @@ def test_the_mellum2_cells_model_fits_the_chip_with_its_mosaic_calls(
     grouped products an expert layer (forward 3 for the first buffer of
     32,768 rows and 3 in the loop's body after it, over buffers of
     8,192; the first buffer's backward 9, the recomputed forward among
-    them; the loop's body after it 9)."""
+    them; the loop's body after it 9), and 8 row kernels an expert
+    layer (``ops/rows.py``): 4 sums (the combine forward and the
+    input's cotangent, in both buffers) and the packing of each one's
+    rows."""
     import json
     from paddle_tpu import models
     from paddle_tpu.config import dsl
@@ -760,11 +878,17 @@ def test_the_mellum2_cells_model_fits_the_chip_with_its_mosaic_calls(
     assert tally == {"flash_attention": {"pallas": 4},
                      "flash_backward": {"fused": 3, "split": 1},
                      "moe_grouped_matmul": {"pallas": 48},
+                     "moe_gather_rows": {"pallas": 16},
+                     "moe_sum_rows": {"pallas": 16},
                      "moe_gmm_tiles": {
                          "fwd 512x1152x896": 32, "fwd 512x896x1152": 16,
                          "dlhs 512x896x1152": 16, "dlhs 512x1152x896": 8,
                          "drhs 512x768x896": 16, "drhs 512x896x1152": 8}}
-    assert compiled.as_text().count(_MOSAIC) == 4 + 3 + 2 + 4 * 24
+    text = compiled.as_text()
+    assert text.count(_MOSAIC) == 4 + 3 + 2 + 4 * (24 + 8)
+    rows = re.findall(r'op_name="[^"]*jit\((_sum_call|_words)\)/pallas_call',
+                      text)
+    assert [rows.count(k) for k in ("_sum_call", "_words")] == [4 * 4, 4 * 4]
     ma = compiled.memory_analysis()
     held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
